@@ -10,8 +10,10 @@ models (the reference framework's GPU training path; BASELINE.md north-star row
 
 Timing methodology: the train state is threaded through consecutive steps (step N+1
 consumes step N's output), so the measured wall time covers real execution; a final
-device_get syncs the chain. This matters on remote-dispatch backends where
-block_until_ready alone under-measures.
+device_get syncs the chain.
+
+A device measurement: it fails where JAX finds no TPU, and on a chip whose published
+peak is not in `PEAK_BF16_FLOPS`. It has no CPU size.
 """
 
 from __future__ import annotations
@@ -20,20 +22,23 @@ import json
 import time
 
 
+# Published bf16 peak per chip, keyed by `jax.devices()[0].device_kind`.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM, 819 GB/s).
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+
+
 def peak_flops_per_chip() -> float:
-    """bf16 peak for the local chip generation."""
+    """bf16 peak of the local chip; a device that is not in the table is an error."""
     import jax
 
-    kind = jax.devices()[0].device_kind.lower()
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v6" in kind or "trillium" in kind:
-        return 918e12
-    return 197e12
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"bench.py measures a TPU; JAX found {dev.platform!r} ({dev.device_kind})")
+    if dev.device_kind not in PEAK_BF16_FLOPS:
+        raise SystemExit(
+            f"no published peak for device_kind {dev.device_kind!r}; add it to "
+            "PEAK_BF16_FLOPS with its source")
+    return PEAK_BF16_FLOPS[dev.device_kind]
 
 
 def main():
@@ -45,10 +50,9 @@ def main():
     from ray_tpu.parallel import mesh as mesh_lib
     from ray_tpu.parallel.spmd import build_train_step, init_state
 
-    on_tpu = jax.default_backend() == "tpu"
-    batch, seq = (8, 1024) if on_tpu else (2, 128)
-    cfg = get_config("gpt2-125m", remat=False, max_seq=seq,
-                     attention="flash" if on_tpu else "reference")
+    peak = peak_flops_per_chip()  # first: fails off-TPU before anything compiles
+    batch, seq = 8, 1024
+    cfg = get_config("gpt2-125m", remat=False, max_seq=seq, attention="flash")
     model = Transformer(cfg)
     mesh = mesh_lib.create_mesh({"dp": 1})  # single chip; dp>1 when more are visible
     # First-moment state in bf16 (mu_dtype): halves one optimizer-state stream's
@@ -68,7 +72,7 @@ def main():
     with mesh:
         state, metrics = step_fn(state, data)  # compile + warm
         _ = float(metrics["loss"])
-        iters = 20 if on_tpu else 3
+        iters = 20
         t0 = time.perf_counter()
         for _ in range(iters):
             state, metrics = step_fn(state, data)
@@ -81,8 +85,8 @@ def main():
     # Training FLOPs/token ~= 6N (fwd 2N + bwd 4N); attention term added explicitly.
     attn_flops = 12 * cfg.n_layers * cfg.hidden * seq  # per token, causal-averaged
     flops_per_token = 6 * n_params + attn_flops
-    mfu = tokens_per_sec * flops_per_token / peak_flops_per_chip()
-    vs_baseline = mfu / 0.40 if on_tpu else 0.0
+    mfu = tokens_per_sec * flops_per_token / peak
+    vs_baseline = mfu / 0.40
 
     print(json.dumps({
         "metric": "gpt2_125m_train_tokens_per_sec_per_chip",
@@ -95,7 +99,9 @@ def main():
             "batch": batch,
             "seq": seq,
             "params_m": round(n_params / 1e6, 1),
-            "backend": jax.default_backend(),
+            "device": {"platform": jax.devices()[0].platform,
+                       "kind": jax.devices()[0].device_kind,
+                       "count": len(jax.devices())},
         },
     }))
 

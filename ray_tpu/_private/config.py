@@ -25,7 +25,7 @@ _DEFS: dict[str, tuple[type, Any, str]] = {
     "idle_worker_kill_s": (float, 300.0, "kill idle workers after this many seconds"),
     "get_poll_interval_s": (float, 0.002, "poll interval for blocking gets"),
     "heartbeat_interval_s": (float, 1.0, "raylet -> GCS resource/health report interval"),
-    "node_death_timeout_s": (float, 5.0, "GCS marks a node dead after missing heartbeats for this long"),
+    "node_death_timeout_s": (float, 30.0, "GCS marks a node dead after missing heartbeats for this long; must outlast a host-wide stall (starting the TPU runtime freezes a v5e host for about 8 s); a raylet that dies is seen at once by its closed connection"),
     "object_store_memory_fraction": (float, 0.3, "fraction of system memory for the per-node shared-memory object store"),
     "store_pretouch_bytes": (int, 1 << 30, "fault in this much of the shm arena at store startup so first puts run at warm-page speed (0 disables)"),
     "object_report_flush_s": (float, 0.02, "raylet batching window for GCS object-directory reports/frees"),

@@ -6,14 +6,21 @@ CoreWorker, then block in the task loop (here the loop is the event-driven io th
 
 from __future__ import annotations
 
+import faulthandler
 import os
+import signal
 import threading
 
 from ray_tpu._private.ids import WorkerID
 from ray_tpu._private.worker import CoreWorker, set_global_worker
+from ray_tpu.util.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()  # whatever this worker compiles, its successor reads back
+    # `kill -USR1 <pid>` writes every thread's stack to the worker's log: the way to
+    # see where a worker hangs on a machine that is thrown away afterwards.
+    faulthandler.register(signal.SIGUSR1, all_threads=True)
     worker_id = WorkerID.from_hex(os.environ["RAY_TPU_WORKER_ID"])
     raylet_port = int(os.environ["RAY_TPU_RAYLET_PORT"])
     worker = CoreWorker(

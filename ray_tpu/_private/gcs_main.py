@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import faulthandler
 import json
 import os
 import signal
@@ -82,6 +83,7 @@ def main():
                    help="comma host:port list of ALL candidates (self included); "
                         "more than one entry enables quorum-HA candidate mode")
     args = p.parse_args()
+    faulthandler.register(signal.SIGUSR1, all_threads=True)  # `kill -USR1`: stacks to the log
     asyncio.run(amain(args))
 
 

@@ -235,6 +235,8 @@ class Raylet:
         self.node_view: dict[NodeID, dict] = {}  # cluster view from GCS
         self._sched_wakeup = asyncio.Event()
         self._spawning = 0  # worker spawns awaiting registration
+        # TPU chip id -> the process pinned to it (see _free_chips).
+        self._chip_holders: dict[int, subprocess.Popen] = {}
         self._pulls_inflight: dict[ObjectID, asyncio.Future] = {}
         # Tasks this raylet forwarded to a peer and is responsible for until the
         # results reach the owner (reference: the owner-side NormalTaskSubmitter
@@ -512,15 +514,50 @@ class Raylet:
 
     # ------------------------------------------------------------------ worker pool
 
+    def _free_chips(self, n_tpu: float) -> list[int] | None:
+        """Ids of `n_tpu` chips that no live process holds, or None while too few
+        are free. A chip is free again when the process that was given it has
+        exited, which is when libtpu lets go of it: there is no release call.
+        Raises ValueError for a demand no process can be pinned to."""
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+        if n_tpu != int(n_tpu):
+            raise ValueError(
+                f"an actor asked for {n_tpu} TPU: a chip belongs to one process, "
+                "ask for whole chips")
+        on_host = int(self.resources.total.get("TPU", 0))
+        TPUAcceleratorManager.chip_bounds(int(n_tpu), on_host)
+        self._chip_holders = {c: p for c, p in self._chip_holders.items()
+                              if p.poll() is None}
+        free = [c for c in range(on_host) if c not in self._chip_holders]
+        return free[:int(n_tpu)] if len(free) >= n_tpu else None
+
     def _spawn_worker(self, kind: str = "worker", python_exe: str | None = None,
-                      env_key: str | None = None) -> WorkerHandle:
+                      env_key: str | None = None,
+                      chips: list[int] | None = None) -> WorkerHandle:
+        """Start a worker process. `chips` are the TPU chips it was granted
+        (`_free_chips`): it is pinned to them. A worker that was granted none, on
+        a host that has chips, is held to the CPU backend, so it cannot take a
+        chip from the worker that was granted it. Pooled task workers are spawned
+        before they are leased and are therefore never granted chips: TPU work
+        runs in actors (ROADMAP, Design D9)."""
         worker_id = WorkerID.from_random()
         log_dir = os.path.join(self.session_dir, "logs")
         os.makedirs(log_dir, exist_ok=True)
-        log_path = os.path.join(log_dir, f"worker-{worker_id.hex()[:12]}.log")
+        # The whole id: ids of one process share their leading bytes (seed + counter),
+        # so a prefix names one file for every worker of the node.
+        log_path = os.path.join(log_dir, f"worker-{worker_id.hex()}.log")
         out = open(log_path, "wb")
         env = dict(os.environ)
         env.update(self.worker_env)
+        chips_on_host = int(self.resources.total.get("TPU", 0))
+        if chips:
+            from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+            TPUAcceleratorManager.set_visible_chips(
+                chips, env, chips_on_host=chips_on_host)
+        elif chips_on_host and "JAX_PLATFORMS" not in self.worker_env:
+            env["JAX_PLATFORMS"] = "cpu"
         from ray_tpu._private.node import _package_pythonpath
 
         env["PYTHONPATH"] = _package_pythonpath(env.get("PYTHONPATH"))
@@ -566,6 +603,8 @@ class Raylet:
             stderr=subprocess.STDOUT,
         )
         out.close()  # child owns its duplicated fd; don't leak one per spawn
+        for c in chips or ():
+            self._chip_holders[c] = proc
         if self._cgroup is not None and not (renv and renv.get("image_uri")):
             # Containerized workers: proc is the engine CLI, not the worker —
             # the engine owns the container's cgroup, placing the client pid
@@ -769,10 +808,14 @@ class Raylet:
 
         monitor = MemoryMonitor(CONFIG.meminfo_path)
         threshold = CONFIG.memory_usage_threshold
+        loop = asyncio.get_running_loop()
         above_since: float | None = None
         while not self._shutdown:
             await asyncio.sleep(refresh_ms / 1000.0)
-            frac = monitor.usage_fraction()
+            # Off the loop: a read of /proc/meminfo blocks for seconds while a worker
+            # initialises the TPU runtime (seen: 8 s on a v5e host, PERF.md PR 21), and
+            # a raylet that misses node_death_timeout_s of heartbeats is declared dead.
+            frac = await loop.run_in_executor(None, monitor.usage_fraction)
             if frac is None or frac < threshold:
                 above_since = None
                 continue
@@ -1885,6 +1928,16 @@ class Raylet:
                 await asyncio.sleep(0.25)
         if not self.resources.acquire(demand, pg_key):
             return {"ok": False, "reason": "resources"}
+        chips = None
+        if demand.get("TPU"):
+            try:
+                chips = self._free_chips(demand["TPU"])
+            except ValueError as e:
+                self.resources.release(demand, pg_key)
+                return {"ok": False, "reason": str(e), "fatal": True}
+            if chips is None:  # the last holder is still exiting: GCS asks again
+                self.resources.release(demand, pg_key)
+                return {"ok": False, "reason": "resources"}
 
         async def cleanup(handle):
             # Detach bookkeeping BEFORE killing so _on_worker_lost (conn-close
@@ -1898,6 +1951,7 @@ class Raylet:
         handle = self._spawn_worker(
             kind="actor", python_exe=python_exe,
             env_key=runtime_env_mod.env_key(spec.get("runtime_env")),
+            chips=chips,
         )
         try:
             await asyncio.wait_for(handle.registered.wait(), CONFIG.worker_register_timeout_s)
@@ -2088,6 +2142,8 @@ class Raylet:
                 {
                     "worker_id": h.worker_id.hex()[:12],
                     "kind": h.kind,
+                    "pid": h.proc.pid if h.proc is not None else None,
+                    "chips": [c for c, p in self._chip_holders.items() if p is h.proc],
                     "actor_id": h.actor_id.hex()[:12] if h.actor_id else None,
                     "leased": h.leased_to is not None,
                     "acquired": dict(h.acquired),

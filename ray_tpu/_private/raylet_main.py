@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import faulthandler
 import json
 import os
 import signal
@@ -96,6 +97,7 @@ def main():
     p.add_argument("--object-store-bytes", type=int, default=0)
     p.add_argument("--ready-file", default="")
     args = p.parse_args()
+    faulthandler.register(signal.SIGUSR1, all_threads=True)  # `kill -USR1`: stacks to the log
     asyncio.run(amain(args))
 
 

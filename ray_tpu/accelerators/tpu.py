@@ -11,7 +11,10 @@ TPU_WORKER_ID), sets TPU_VISIBLE_CHIPS for workers, and publishes three resource
 
 from __future__ import annotations
 
+import functools
+import glob
 import os
+import urllib.request
 
 
 def _env(name: str) -> str | None:
@@ -19,29 +22,49 @@ def _env(name: str) -> str | None:
     return v if v else None
 
 
-import functools
+def _on_gce() -> bool:
+    """True where the firmware says this is a Google Compute Engine VM. A file
+    read: it cannot stall, which a name lookup on a machine with no network can."""
+    try:
+        with open("/sys/class/dmi/id/product_name") as f:
+            return "Google" in f.read()
+    except OSError:
+        return False
 
 
 @functools.lru_cache(maxsize=None)
 def _gce_metadata(key: str) -> str | None:
-    """GCE instance metadata lookup (reference tpu.py:199-250); best-effort,
-    short timeout — returns None off-GCE or when the metadata server is absent.
-    Cached: off-GCE the DNS stall must happen at most once per process."""
+    """GCE instance metadata lookup (reference tpu.py:199-250). Asked only on a
+    GCE VM, by the server's link-local address (no DNS), once per process; None
+    elsewhere or when the server does not answer within the timeout."""
+    if _env("TPU_SKIP_MDS_QUERY") or not _on_gce():  # libtpu's own "ask nobody" switch
+        return None
+    req = urllib.request.Request(
+        f"http://169.254.169.254/computeMetadata/v1/instance/attributes/{key}",
+        headers={"Metadata-Flavor": "Google"},
+    )
     try:
-        import urllib.request
-
-        req = urllib.request.Request(
-            f"http://metadata.google.internal/computeMetadata/v1/instance/attributes/{key}",
-            headers={"Metadata-Flavor": "Google"},
-        )
         with urllib.request.urlopen(req, timeout=0.5) as resp:
             return resp.read().decode() or None
-    except Exception:
+    except OSError:  # URLError, timeouts and refused connections are all OSError
         return None
 
 
 def _accelerator_type() -> str | None:
     return _env("TPU_ACCELERATOR_TYPE") or _gce_metadata("accelerator-type")
+
+
+def _count_device_files() -> int:
+    """TPU chips as the kernel exposes them: `/dev/accel*` (v2-v4) or the numbered
+    entries of `/dev/vfio` (v5e and later). Reference tpu.py does the same. This
+    never loads libtpu, so the process that asks does not take the chip."""
+    accel = glob.glob("/dev/accel*")
+    if accel:
+        return len(accel)
+    try:
+        return sum(1 for e in os.listdir("/dev/vfio") if e.isdigit())
+    except OSError:
+        return 0
 
 
 def _chips_per_host(accel: str) -> int:
@@ -69,19 +92,18 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
+        """Chips this host can hand out. Never `jax.devices()`: this runs in the
+        driver, and a chip belongs to the one process that initialises it."""
         explicit = _env("TPU_CHIPS_PER_HOST")
         if explicit:
             return int(explicit)
+        # Device files first: the accelerator type names the slice the host belongs
+        # to, not what this machine was given (seen: v5litepod-4 with one chip).
+        attached = _count_device_files()
+        if attached:
+            return attached
         accel = _accelerator_type()  # e.g. "v4-16", "v5e-8"
-        if accel is None:
-            # Fall back to live JAX discovery when running on a TPU VM.
-            try:
-                import jax
-
-                return len([d for d in jax.devices() if d.platform == "tpu"])
-            except Exception:
-                return 0
-        return _chips_per_host(accel)
+        return _chips_per_host(accel) if accel else 0
 
     @staticmethod
     def get_current_node_accelerator_type() -> str | None:
@@ -111,8 +133,30 @@ class TPUAcceleratorManager:
         return TPUAcceleratorManager.get_worker_id() == 0
 
     @staticmethod
-    def set_visible_chips(chip_ids: list[int], env: dict) -> None:
+    def chip_bounds(n_chips: int, chips_on_host: int) -> str | None:
+        """`TPU_CHIPS_PER_HOST_BOUNDS` for a process granted `n_chips` of a host's
+        chips; None when it is granted all of them and libtpu's defaults hold.
+        Raises for a count libtpu has no topology for (three of four, say)."""
+        if n_chips == chips_on_host:
+            return None
+        bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}.get(n_chips)
+        if bounds is None or n_chips > chips_on_host:
+            raise ValueError(
+                f"cannot pin a process to {n_chips} of {chips_on_host} TPU chips: "
+                "ask for 1, 2, 4 or all of a host's chips")
+        return bounds
+
+    @staticmethod
+    def set_visible_chips(chip_ids: list[int], env: dict, *, chips_on_host: int) -> None:
+        """Pin the process that gets `env` to `chip_ids` (reference tpu.py
+        set_current_process_visible_accelerator_ids). A process granted every chip
+        keeps the host's environment as it is."""
+        bounds = TPUAcceleratorManager.chip_bounds(len(chip_ids), chips_on_host)
+        if bounds is None:
+            return
         env["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chip_ids)
+        env["TPU_CHIPS_PER_HOST_BOUNDS"] = bounds
+        env["TPU_HOST_BOUNDS"] = "1,1,1"
 
     @staticmethod
     def node_resources() -> dict[str, float]:

@@ -13,7 +13,12 @@ import inspect
 from ray_tpu._private.ids import ActorID
 from ray_tpu._private.worker import global_worker
 from ray_tpu.exceptions import ActorDiedError
-from ray_tpu.remote_function import _build_pg_spec, _build_resources, _resolve_scheduling
+from ray_tpu.remote_function import (
+    _build_pg_spec,
+    _build_resources,
+    _check_options,
+    _resolve_scheduling,
+)
 
 _ACTOR_DEFAULTS = {
     "num_cpus": 0,
@@ -179,7 +184,7 @@ class ActorHandle:
 class ActorClass:
     def __init__(self, cls, options: dict):
         self._cls = cls
-        self._options = {**_ACTOR_DEFAULTS, **options}
+        self._options = _check_options(options, _ACTOR_DEFAULTS)
         self._cls_key = None
 
     def options(self, **overrides) -> "ActorClass":

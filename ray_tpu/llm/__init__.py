@@ -124,11 +124,11 @@ def load_model(config: "LLMConfig"):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import CONFIGS, Transformer, get_config
+    from ray_tpu.models.transformer import Transformer, get_config
 
-    cfg = config.model_config or get_config(
-        config.model_id if config.model_id in CONFIGS else "test-tiny"
-    )
+    # An unknown model_id with no model_config raises (get_config): serving
+    # test-tiny under another model's name would look like a working replica.
+    cfg = config.model_config or get_config(config.model_id)
     cfg = dataclasses.replace(cfg, scan_layers=False, remat=False)
     model = Transformer(cfg)
     if config.checkpoint_path:
@@ -175,6 +175,14 @@ def replica_resources(config: "LLMConfig") -> dict:
     if n_dev > 1 and resources:
         resources = {k: float(v) * n_dev for k, v in resources.items()}
     return resources
+
+
+def replica_actor_options(config: "LLMConfig") -> dict:
+    """`ray_actor_options` for one replica of any LLM deployment: the replica's
+    accelerator demand as `resources=`, which is what the scheduler reads (a bare
+    `TPU=` key among the options reserves nothing and is refused)."""
+    resources = replica_resources(config)
+    return {"num_cpus": resources.pop("CPU", 0), "resources": resources}
 
 
 class LLMServer:
@@ -617,11 +625,10 @@ class OpenAIRouter:
 
 def build_llm_deployment(config: LLMConfig) -> "serve.Application":
     """One LLM server deployment. Parity: serve.llm.build_llm_deployment."""
-    resources = replica_resources(config)
     deployment = serve.deployment(
         name=f"LLMServer-{config.model_id}",
         num_replicas=config.num_replicas,
-        ray_actor_options={"num_cpus": 0, **resources},
+        ray_actor_options=replica_actor_options(config),
         max_ongoing_requests=config.num_slots * 4,
     )(LLMServer)
     return deployment.bind(config)
@@ -648,5 +655,6 @@ __all__ = [
     "UnknownAdapterError",
     "build_llm_deployment",
     "build_openai_app",
+    "replica_actor_options",
     "replica_resources",
 ]

@@ -360,8 +360,8 @@ class DecodeEngine:
         )
         # Multi-step decode: N greedy tokens per dispatch (argmax on device,
         # lax.scan over decode steps) — one host round trip per CHUNK instead
-        # of per token. The win is dispatch-latency-bound regimes (remote
-        # tunnels, small models where the step is microseconds); the role of
+        # of per token. The win is dispatch-latency-bound regimes (small
+        # models where the step is microseconds); the role of
         # vLLM's multi-step scheduling (num_scheduler_steps). Engaged only
         # when every active slot samples greedily; host-side stop/max_tokens
         # handling rolls per-slot state back after the readback.
@@ -891,6 +891,14 @@ class DecodeEngine:
         # compiled-program rows + the process-wide device-memory ledger.
         out["programs"] = self._xprof.report(owner=self._xprof_owner)
         out["memory"] = xprof.device_memory_report()
+        # What is being served, by its widths: a replica that came up on another
+        # model than the one asked for shows here, not in a model_id string.
+        cfg = self.cfg
+        out["model"] = {
+            "hidden": cfg.hidden, "n_layers": cfg.n_layers, "n_heads": cfg.n_heads,
+            "n_kv_heads": cfg.n_kv_heads, "vocab_size": cfg.vocab_size,
+            "num_slots": self.B, "max_seq": self.T, "tp": self.tp,
+        }
         return out
 
     def _flush_observability(self) -> dict:

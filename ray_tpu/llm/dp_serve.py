@@ -694,17 +694,16 @@ def build_dp_openai_app(config: LLMConfig, *, dp_size: int = 2):
     replica's whole device gang atomically (cross-host gangs reserve through
     `cluster_utils.reserve_tp_slice` placement groups)."""
     from ray_tpu import serve
-    from ray_tpu.llm import replica_resources
+    from ray_tpu.llm import replica_actor_options
 
     assigner = ray_tpu.remote(num_cpus=0)(DPRankAssigner).options(
         name=f"DPRankAssigner-{config.model_id}", get_if_exists=True,
         namespace="llm_dp",
     ).remote(dp_size)
-    resources = replica_resources(config)
     server = serve.deployment(
         name=f"DPLLMServer-{config.model_id}",
         num_replicas=dp_size,
-        ray_actor_options={"num_cpus": 0, **resources},
+        ray_actor_options=replica_actor_options(config),
         max_ongoing_requests=config.num_slots * 4,
     )(DPLLMServer).bind(config, assigner)
     router = serve.deployment(name=f"DPRouter-{config.model_id}")(DPRouter)

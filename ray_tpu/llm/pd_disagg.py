@@ -683,18 +683,17 @@ def build_pd_openai_app(config: LLMConfig, *, num_prefill: int = 1,
     engines and each replica's accelerator demand scales by the TP device
     count (docs/serving_tp.md)."""
     from ray_tpu import serve
-    from ray_tpu.llm import replica_resources
+    from ray_tpu.llm import replica_actor_options
 
-    resources = replica_resources(config)
     prefill = serve.deployment(
         name=f"Prefill-{config.model_id}",
         num_replicas=num_prefill,
-        ray_actor_options={"num_cpus": 0, **resources},
+        ray_actor_options=replica_actor_options(config),
     )(PrefillServer)
     decode = serve.deployment(
         name=f"Decode-{config.model_id}",
         num_replicas=num_decode,
-        ray_actor_options={"num_cpus": 0, **resources},
+        ray_actor_options=replica_actor_options(config),
         max_ongoing_requests=config.num_slots * 4,
     )(DecodeServer)
     router = serve.deployment(name=f"PDRouter-{config.model_id}")(PDRouter)

@@ -13,11 +13,14 @@ tied output head; bfloat16 activations with float32 RMSNorm accumulation (MXU-fr
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax._src.mesh import thread_resources  # what `with mesh:` sets; pjit reads it too
+from jax.sharding import PartitionSpec
 
 from ray_tpu.ops.attention import flash_attention, reference_attention
 
@@ -199,6 +202,42 @@ class _OutProjBhsd(nn.Module):
         )
 
 
+def _flash_on_mesh(flash, q, k, v, names: tuple):
+    """Call a flash-attention entry point under the mesh of the enclosing `with mesh:`.
+
+    The TPU compiler refuses to partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so a step over more than one device cannot leave the kernel to
+    GSPMD. Batch and heads are split the way the logical rules split them; the
+    sequence and head_dim stay whole on every shard, which is what the kernel
+    needs. Heads are split only if q and kv heads split alike and evenly (GQA
+    groups must stay together); otherwise every shard computes all heads. The
+    CPU takes the same path, so the virtual-mesh tests cover it.
+
+    `names` are q's logical axis names, e.g. ("batch", "heads", "seq", "head_dim").
+    """
+    mesh = thread_resources.env.physical_mesh
+    if mesh.empty or mesh.size == 1 or not jax.sharding.get_abstract_mesh().empty:
+        return flash(q, k, v, True, None)  # one device, or already inside a shard_map
+    axes = dict(zip(names, nn.logical_to_mesh_axes(names)))
+    kv_axes = nn.logical_to_mesh_axes(("kv_heads",))[0]
+
+    def ways(a) -> int:
+        return math.prod(mesh.shape[x] for x in ((a,) if isinstance(a, str) else a or ()))
+
+    i_b, i_h = names.index("batch"), names.index("heads")
+    batch = axes["batch"] if q.shape[i_b] % ways(axes["batch"]) == 0 else None
+    heads = axes["heads"]
+    if heads != kv_axes or q.shape[i_h] % ways(heads) or k.shape[i_h] % ways(heads):
+        heads = None
+    parts = [None] * len(names)
+    parts[i_b], parts[i_h] = batch, heads
+    spec = PartitionSpec(*parts)
+    return jax.shard_map(
+        lambda q, k, v: flash(q, k, v, True, None), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(q, k, v)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
@@ -288,7 +327,8 @@ class Attention(nn.Module):
 
             out = ulysses_attention(q, k, v, cfg.sp_axis, causal=True)
         else:
-            out = flash_attention(q, k, v, True, None)
+            out = _flash_on_mesh(flash_attention, q, k, v,
+                                 ("batch", "seq", "heads", "head_dim"))
         if cfg.remat and cfg.remat_policy == "attn":
             from jax.ad_checkpoint import checkpoint_name
 
@@ -335,7 +375,8 @@ class Attention(nn.Module):
             q = checkpoint_name(q, "save")
             k = checkpoint_name(k, "save")
             v = checkpoint_name(v, "save")
-        out = flash_attention_bhsd(q, k, v, True, None)
+        out = _flash_on_mesh(flash_attention_bhsd, q, k, v,
+                             ("batch", "heads", "seq", "head_dim"))
         if cfg.remat and cfg.remat_policy == "attn":
             from jax.ad_checkpoint import checkpoint_name
 
@@ -584,5 +625,7 @@ def init_params(cfg: ModelConfig, rng=None, batch: int = 1, seq: int | None = No
 
 
 def get_config(name: str, **overrides) -> ModelConfig:
+    if name not in CONFIGS:
+        raise ValueError(f"unknown model {name!r}; known: {sorted(CONFIGS)}")
     cfg = CONFIGS[name]
     return dataclasses.replace(cfg, **overrides) if overrides else cfg

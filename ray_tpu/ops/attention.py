@@ -21,10 +21,9 @@ _NEG_INF = -1e30
 
 
 def _use_pallas() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # No except: a backend that fails to start must fail the caller, not hand it
+    # the reference path in silence.
+    return jax.default_backend() == "tpu"
 
 
 def reference_attention(q, k, v, *, causal: bool = True, scale: float | None = None,

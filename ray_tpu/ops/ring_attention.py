@@ -26,12 +26,9 @@ _NEG_INF = -1e30
 
 def _ensure_varying(x, axis_name):
     """Mark x varying over the manual axis if it isn't already (jax vma typing)."""
-    try:
-        if axis_name in jax.typeof(x).vma:
-            return x
-        return jax.lax.pvary(x, axis_name)
-    except (AttributeError, TypeError):
+    if axis_name in jax.typeof(x).vma:
         return x
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def _chunk_attention(q, k, v, mode, scale):
@@ -91,7 +88,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", *, causal: bool = True,
     out0 = jnp.zeros_like(q)
     lse0 = jnp.full((B, H, Sc), _NEG_INF, jnp.float32)
     # Freshly-created carries must be marked varying over the manual axis for scan's
-    # carry typing under shard_map (jax >= 0.8 vma rules).
+    # carry typing under shard_map (vma rules).
     out0 = _ensure_varying(out0, axis_name)
     lse0 = _ensure_varying(lse0, axis_name)
     (out, _lse, _, _), _ = jax.lax.scan(

@@ -40,10 +40,6 @@ from flax import struct
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# The cross-version shard_map shim moved to util.jax_compat (shared with the
-# collective XLA tier); re-exported here for the existing call sites.
-from ray_tpu.util.jax_compat import shard_map  # noqa: F401
-
 
 class PipelineState(struct.PyTreeNode):
     step: jax.Array
@@ -135,15 +131,8 @@ def build_pipeline_loss(
         vary = tuple(a for a in manual if mesh.shape.get(a, 1) > 1)
 
         def ensure_vary(x):
-            if not hasattr(jax, "typeof"):
-                return x  # pre-vma jax: scan carries carry no varying manner
-            have = getattr(jax.typeof(x), "vma", frozenset())
-            missing = tuple(a for a in vary if a not in have)
-            if not missing:
-                return x
-            if hasattr(lax, "pcast"):  # pvary's replacement in newer jax
-                return lax.pcast(x, missing, to="varying")
-            return lax.pvary(x, missing)
+            missing = tuple(a for a in vary if a not in jax.typeof(x).vma)
+            return lax.pcast(x, missing, to="varying") if missing else x
 
         x0 = ensure_vary(jnp.zeros_like(embeds[0]))
         outs0 = ensure_vary(jnp.zeros_like(embeds))  # [M, b, T, E]
@@ -175,7 +164,7 @@ def build_pipeline_loss(
     # manual view is the same either way.
     in_param_specs = {"embed": P(), "layers": P("pp"), "head": P()}
     data_spec = P(("dp",)) if mesh.shape.get("dp", 1) > 1 else P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         staged_loss,
         mesh=mesh,
         in_specs=(in_param_specs, data_spec, data_spec),

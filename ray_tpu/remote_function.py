@@ -26,6 +26,19 @@ _DEFAULTS = {
 }
 
 
+def _check_options(options: dict, known: dict) -> dict:
+    """Defaults overlaid with `options`. An option nobody reads is an error: a bare
+    resource name (`TPU=1`) used to be dropped here, so the actor reserved nothing."""
+    unknown = sorted(set(options) - set(known))
+    if unknown:
+        raise ValueError(
+            f"unknown option(s) {unknown} for @ray_tpu.remote; custom resources go in "
+            f"resources={{...}} (e.g. resources={{'TPU': 1}}) or num_tpus=. "
+            f"Known options: {sorted(known)}"
+        )
+    return {**known, **options}
+
+
 def _build_resources(opts) -> dict:
     resources = dict(opts.get("resources") or {})
     if opts.get("num_cpus") is not None:
@@ -74,7 +87,7 @@ def _resolve_scheduling(opts):
 class RemoteFunction:
     def __init__(self, fn, options: dict):
         self._fn = fn
-        self._options = {**_DEFAULTS, **options}
+        self._options = _check_options(options, _DEFAULTS)
         self._fn_key = None
         functools.update_wrapper(self, fn)
 

@@ -381,7 +381,10 @@ class ServeController:
                 ).remote(host, port, grpc_port)
                 bound = await async_get(proxy.start.remote(), timeout=30)
             except Exception:
-                continue  # node may have just died; next pass retries
+                # node may have just died; next pass retries. Say why: a proxy that
+                # never binds leaves serve.get_proxy_port() answering None.
+                traceback.print_exc()
+                continue
             self._proxies[nid] = (proxy, bound)
 
     # -- deploy / teardown -------------------------------------------------

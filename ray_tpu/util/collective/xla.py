@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.util.collective.types import ReduceOp
-from ray_tpu.util.jax_compat import axis_size as _axis_size, shard_map
 
 
 def allreduce(x, axis_name, op: ReduceOp = ReduceOp.SUM):
@@ -55,7 +54,7 @@ def reducescatter(x, axis_name, scatter_axis: int = 0, op: ReduceOp = ReduceOp.S
         raise ValueError("reducescatter supports SUM/MEAN (what XLA lowers natively)")
     out = jax.lax.psum_scatter(x, axis_name, scatter_dimension=scatter_axis, tiled=True)
     if op == ReduceOp.MEAN:
-        out = out / _axis_size(axis_name)
+        out = out / jax.lax.axis_size(axis_name)
     return out
 
 
@@ -65,7 +64,7 @@ def ppermute(x, axis_name, perm: list[tuple[int, int]]):
 
 def send_next(x, axis_name):
     """Ring shift: every shard sends to (rank+1) % n. The ring-attention building block."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return jax.lax.ppermute(x, axis_name, [(i, (i + 1) % n) for i in range(n)])
 
 
@@ -113,7 +112,7 @@ class MeshGroup:
         fn = self._cache.get(key)
         if fn is None:
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     partial(allreduce, axis_name=self.axis, op=op),
                     mesh=self.mesh,
                     in_specs=P(self.axis),

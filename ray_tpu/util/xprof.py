@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -42,6 +43,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 __all__ = [
     "ProgramRegistry",
     "ProfilerCapture",
+    "backend_initialized",
     "capture",
     "device_memory_report",
     "is_resource_exhausted",
@@ -273,6 +275,16 @@ def unregister_memory_owner(name: str) -> None:
         _MEM_OWNERS.pop(name, None)
 
 
+def backend_initialized() -> bool:
+    """True once this process has initialised a JAX backend (and so, on a TPU
+    host, holds the chip). Asking does not initialise one."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 def device_memory_report() -> dict:
     """One per-device view of framework-attributed bytes by owner plus raw
     backend ``memory_stats()`` (peak/in-use) where available.  Report-path
@@ -294,7 +306,10 @@ def device_memory_report() -> dict:
             per_device[str(dev)] = per_device.get(str(dev), 0) + int(nbytes)
         out_owners[name] = row
     devices: List[dict] = []
-    try:
+    # Only the process that computes has a backend. Anyone else (driver, CLI,
+    # serve controller) skips the query: `jax.devices()` there would initialise
+    # one, and on a TPU host take the chip from the worker that needs it.
+    if backend_initialized():
         import jax
 
         for d in jax.devices():
@@ -311,8 +326,6 @@ def device_memory_report() -> dict:
                     if k in stats
                 }
             devices.append(dev)
-    except Exception:
-        pass
     report = {
         "owners": out_owners,
         "tracked_bytes_total": tracked_total,

@@ -159,7 +159,7 @@ def test_xla_tier():
 
     from ray_tpu.parallel.mesh import create_mesh
     from ray_tpu.util.collective import ReduceOp, xla
-    from ray_tpu.util.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = create_mesh({"dp": 4})
     group = xla.MeshGroup(mesh, "dp")

@@ -151,7 +151,7 @@ def test_gqa_flash_matches_reference():
 
 
 def test_ring_attention_matches_full():
-    from ray_tpu.util.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.ops.attention import reference_attention
@@ -177,7 +177,7 @@ def test_ring_attention_matches_full():
 
 
 def test_ulysses_attention_matches_full():
-    from ray_tpu.util.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.ops.attention import reference_attention
@@ -273,7 +273,7 @@ def test_ulysses_attention_gqa_with_small_kv_heads():
 
     from ray_tpu.ops.attention import reference_attention
     from ray_tpu.ops.ring_attention import ulysses_attention
-    from ray_tpu.util.jax_compat import shard_map
+    from jax import shard_map
 
     sp = 4
     mesh = mesh_lib.create_mesh({"sp": sp}, devices=jax.devices()[:sp])

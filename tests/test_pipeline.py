@@ -129,13 +129,6 @@ def test_pipeline_rejects_bad_shapes():
         )
 
 
-_OLD_JAX = not hasattr(jax, "typeof")
-
-
-@pytest.mark.skipif(
-    _OLD_JAX,
-    reason="manual-pp + auto-tp composition needs the vma-typed shard_map partitioner (jax>=0.6); 0.4.x SPMD rejects PartitionId inside a partially-auto body",
-)
 @pytest.mark.parametrize("axes,specs", [
     # tp shards the layer matmuls' hidden dim and the head's vocab dim;
     # XLA inserts the tensor-parallel collectives INSIDE the pipeline
@@ -178,10 +171,6 @@ def test_pipeline_composes_with_tp(axes, specs):
                                    rtol=2e-4, atol=2e-6)
 
 
-@pytest.mark.skipif(
-    _OLD_JAX,
-    reason="manual-pp + auto-tp composition needs the vma-typed shard_map partitioner (jax>=0.6)",
-)
 def test_pipeline_train_step_learns_with_tp():
     from jax.sharding import PartitionSpec as P
 
