@@ -1,0 +1,12 @@
+"""`pytest benchmark/tests`: CPU checks of the benchmark's own arithmetic, run by hand.
+They are not part of the repo's tier-1 suite (`tests/`)."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+for p in (ROOT, BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
