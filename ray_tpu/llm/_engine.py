@@ -178,32 +178,67 @@ def _forward_cached(params, cfg: ModelConfig, tokens, positions, caches, write_a
 
     lora: the AdapterCache's STACKED tables ({"q_A": [L, S, M, r], ...}) —
     per-layer views are extracted here inside the trace, so paging swaps the
-    whole table reference without touching program shapes."""
+    whole table reference without touching program shapes.
+
+    The named scopes are the flax model's module names (`layer_<i>/attn`,
+    `mlp`, `attn_norm`, `mlp_norm`, `final_norm`, `lm_head`) plus `embedding`:
+    one list of scopes reads a device trace of either model (PERF.md §3).
+    They are metadata on the operations and change no program."""
     embed = params["embedding"]
-    x = embed[tokens].astype(cfg.dtype)
+    with jax.named_scope("embedding"):
+        x = embed[tokens].astype(cfg.dtype)
     new_caches = []
     for i in range(cfg.n_layers):
         layer = params[f"layer_{i}"]
-        normed = _rmsnorm(x, layer["attn_norm"]["scale"], cfg.norm_eps)
-        attn_out, ck, cv = _attn_cached(
-            layer["attn"], normed, positions, caches[i][0], caches[i][1],
-            write_at, kv_mask, cfg,
-            lora_layer=None if lora is None else {k: v[i] for k, v in lora.items()},
-            adapter_ids=adapter_ids,
-            write_gate=write_gate,
-        )
-        new_caches.append((ck, cv))
-        x = x + attn_out
-        x = x + _mlp(layer["mlp"], _rmsnorm(x, layer["mlp_norm"]["scale"], cfg.norm_eps))
-    x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jax.lax.dot_general(
-            x.astype(cfg.dtype), embed.astype(cfg.dtype),
-            (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-    else:
-        logits = _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
-    return logits.astype(jnp.float32), new_caches
+        with jax.named_scope(f"layer_{i}"):
+            with jax.named_scope("attn_norm"):
+                normed = _rmsnorm(x, layer["attn_norm"]["scale"], cfg.norm_eps)
+            with jax.named_scope("attn"):
+                attn_out, ck, cv = _attn_cached(
+                    layer["attn"], normed, positions, caches[i][0], caches[i][1],
+                    write_at, kv_mask, cfg,
+                    lora_layer=None if lora is None else {k: v[i] for k, v in lora.items()},
+                    adapter_ids=adapter_ids,
+                    write_gate=write_gate,
+                )
+            new_caches.append((ck, cv))
+            x = x + attn_out
+            with jax.named_scope("mlp_norm"):
+                normed = _rmsnorm(x, layer["mlp_norm"]["scale"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                x = x + _mlp(layer["mlp"], normed)
+    with jax.named_scope("final_norm"):
+        x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = jax.lax.dot_general(
+                x.astype(cfg.dtype), embed.astype(cfg.dtype),
+                (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+        else:
+            logits = _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
+        logits = logits.astype(jnp.float32)
+    return logits, new_caches
+
+
+def _rid(req: Request) -> str:
+    """The id a request's `rt.engine.*` spans carry: its flight record's, so
+    that a profiler trace and `request_timing()` name the request alike."""
+    return req.rec.rid if req.rec is not None else (req.rid or "")
+
+
+def _named(name: str, fn, **static):
+    """`fn` (with `static` keyword arguments bound) under a `__name__` of its
+    own. jax names a compiled program after the function it traced
+    (`jit_<name>`), and that name is what a device trace shows: the engine's
+    programs are named here, by what they do and their static sizes, and not
+    by whatever the Python method happens to be called (PERF.md §3)."""
+
+    def program(*args):
+        return fn(*args, **static)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def _scatter_slot_caches(caches, new_slot, slot):
@@ -356,7 +391,8 @@ class DecodeEngine:
         xprof.register_memory_owner(self._xprof_owner, _ledger_row)
         self._jit_prefill = {}
         self._jit_decode = self._xprof.instrument(
-            self._xprof_owner, ("decode",), jax.jit(self._decode_step)
+            self._xprof_owner, ("decode",),
+            jax.jit(_named("rt_decode", self._decode_step)),
         )
         # Multi-step decode: N greedy tokens per dispatch (argmax on device,
         # lax.scan over decode steps) — one host round trip per CHUNK instead
@@ -452,11 +488,10 @@ class DecodeEngine:
         # and of the most recent cache attach (which tier served the rows).
         self.last_prefill: Optional[dict] = None
         self.last_attach: Optional[dict] = None
-        self._jit_decode_multi = self._xprof.instrument(
-            self._xprof_owner, ("decode_multi",),
-            jax.jit(self._decode_multi, static_argnames=("n",)),
-        )  # jax caches one program per distinct static n (the registry
-        # entry counts the object once; per-n compiles stay internal)
+        # One multi-step program per step count n, built on first use and
+        # named for it (`rt_decode_multi_n<n>`): the registry counts each n's
+        # compile, and a device trace says how many steps an execution held.
+        self._jit_decode_multi = {}
         # Speculative decoding as a scheduler-scheduled phase (docs/
         # scheduler.md): a DraftProvider proposes up to k tokens per eligible
         # slot, and ONE batched gated verify forward scores every
@@ -657,7 +692,8 @@ class DecodeEngine:
             logits, c, l = self._decode_step(
                 params, lora, adapter_ids, last, c, l, gate
             )
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (nxt, c, l), nxt
 
         (last, caches, lens), toks = jax.lax.scan(
@@ -693,8 +729,9 @@ class DecodeEngine:
             params, self.cfg, tokens, positions, caches, lens, kv_mask,
             lora=lora, adapter_ids=adapter_ids, write_gate=gate,
         )
-        logits = logits + constraint_mask
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_caches
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits + constraint_mask, axis=-1).astype(jnp.int32)
+        return greedy, new_caches
 
     # -- speculative phase --------------------------------------------------
     def _spec_round(self, plan: Plan):
@@ -729,51 +766,54 @@ class DecodeEngine:
                     budget=s.params.max_tokens - s.generated,
                 )
                 cmask[i, :len(rows)] = rows
-        t_verify = time.time()
-        verify = self._program(
-            self._jit_spec_verify, ("verify", S),
-            lambda: jax.jit(self._spec_verify_batched),
-        )
-        greedy_dev, self._caches = verify(
-            self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
-            jnp.asarray(tokens), self._caches, jnp.asarray(self._lens),
-            jnp.asarray(gate), jnp.asarray(cmask),
-        )
+        with xprof.span("rt.engine.dispatch", steps=1, slots=len(plan.spec_slots),
+                        rows=int(self._lens[plan.spec_slots].sum())) as dispatch:
+            verify = self._program(
+                self._jit_spec_verify, ("verify", S),
+                lambda: jax.jit(_named(f"rt_verify_s{S}", self._spec_verify_batched)),
+            )
+            greedy_dev, self._caches = verify(
+                self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
+                jnp.asarray(tokens), self._caches, jnp.asarray(self._lens),
+                jnp.asarray(gate), jnp.asarray(cmask),
+            )
         # The round's ONE acceptance sync: k+1 tokens per participating slot
         # arrive in a single batched pull — no per-token host round trip.
-        greedy = np.asarray(greedy_dev)  # raylint: disable=RL603 (per-round batched acceptance sync)
+        with xprof.span("rt.engine.readback", bytes=greedy_dev.nbytes):
+            greedy = np.asarray(greedy_dev)  # raylint: disable=RL603 (per-round batched acceptance sync)
         c = self._spec_counters
         c["rounds"] += 1
         round_proposed = round_accepted = 0
-        for i in plan.spec_slots:
-            s = self._sched.slots[i]
-            p = plan.proposals[i]
-            l = base_lens[i]
-            m = 0
-            while m < len(p) and int(greedy[i, m]) == int(p[m]):
-                m += 1
-            emitted = [int(x) for x in p[:m]] + [int(greedy[i, m])]
-            # Bookkeeping: rows [l, l+m] now hold [t0, accepted...]; rows
-            # beyond hold rejected proposals' kv, invisible behind lens and
-            # overwritten write-before-read by the next dispatch.
-            s.host_len = l + m + 1
-            draft.on_accept(i, s, l, p, m)
-            round_proposed += len(p)
-            round_accepted += m
-            if s.rec is not None:
-                s.rec.span("spec-verify", t_verify, time.time(),
-                           proposed=len(p), accepted=m)
-            for token in emitted:
-                if not s.active:
-                    break
-                s.generated += 1
-                s.tokens.append(token)
-                s.history.append(token)
-                self._emit(i, token)
-            self._lens[i] = s.host_len
-            if s.tokens:
-                self._last_token[i] = s.tokens[-1]
-            c["emitted_tokens"] += len(emitted)
+        with xprof.span("rt.engine.sample", slots=len(plan.spec_slots)):
+            for i in plan.spec_slots:
+                s = self._sched.slots[i]
+                p = plan.proposals[i]
+                l = base_lens[i]
+                m = 0
+                while m < len(p) and int(greedy[i, m]) == int(p[m]):
+                    m += 1
+                emitted = [int(x) for x in p[:m]] + [int(greedy[i, m])]
+                # Bookkeeping: rows [l, l+m] now hold [t0, accepted...]; rows
+                # beyond hold rejected proposals' kv, invisible behind lens and
+                # overwritten write-before-read by the next dispatch.
+                s.host_len = l + m + 1
+                draft.on_accept(i, s, l, p, m)
+                round_proposed += len(p)
+                round_accepted += m
+                if s.rec is not None:
+                    s.rec.span("spec-verify", dispatch.t0, time.time(),
+                               proposed=len(p), accepted=m)
+                for token in emitted:
+                    if not s.active:
+                        break
+                    s.generated += 1
+                    s.tokens.append(token)
+                    s.history.append(token)
+                    self._emit(i, token)
+                self._lens[i] = s.host_len
+                if s.tokens:
+                    self._last_token[i] = s.tokens[-1]
+                c["emitted_tokens"] += len(emitted)
         c["proposed_tokens"] += round_proposed
         c["accepted_tokens"] += round_accepted
         # Plain counters only: the llm_spec_* metrics flush their deltas
@@ -781,7 +821,7 @@ class DecodeEngine:
         # round of the decode loop (RL901).
 
     def _insert_prompt_kv(self, slot: int, prompt: List[int], adapter: int,
-                          cached_offset: int):
+                          cached_offset: int, rid: str = ""):
         """Populate the prefix cache from the slot's freshly prefilled rows.
         Skips when the prompt has no full block beyond what the cache already
         held (cached_offset tokens)."""
@@ -793,11 +833,12 @@ class DecodeEngine:
         # prefix rides along (the radix walk dedups it without copying). One
         # bulk pull per INSERT (per admitted prompt), amortized by every
         # future hit skipping the prefix's prefill FLOPs entirely.
-        kv = np.stack([
-            np.stack([np.asarray(ck[slot, :n]), np.asarray(cv[slot, :n])])  # raylint: disable=RL603 (bulk per-insert readback, not per-step)
-            for ck, cv in self._caches
-        ])
-        self._prefix_cache.insert(prompt[:n], kv, namespace=adapter)
+        with xprof.span("rt.engine.kv_insert", rid=rid, rows=n):
+            kv = np.stack([
+                np.stack([np.asarray(ck[slot, :n]), np.asarray(cv[slot, :n])])  # raylint: disable=RL603 (bulk per-insert readback, not per-step)
+                for ck, cv in self._caches
+            ])
+            self._prefix_cache.insert(prompt[:n], kv, namespace=adapter)
 
     def _kv_block_to_device(self, host_kv):
         """Hot-tier promotion copy: one [L, 2, bs, Hkv, D] block onto this
@@ -890,6 +931,9 @@ class DecodeEngine:
         # Compute-plane report (same report-path contract): this engine's
         # compiled-program rows + the process-wide device-memory ledger.
         out["programs"] = self._xprof.report(owner=self._xprof_owner)
+        # Where the stepper thread's time went, by `rt.engine.*` span: count
+        # and seconds since the process started (plain reads of a host table).
+        out["loop"] = xprof.span_totals()
         out["memory"] = xprof.device_memory_report()
         # What is being served, by its widths: a replica that came up on another
         # model than the one asked for shows here, not in a model_id string.
@@ -1400,7 +1444,7 @@ class DecodeEngine:
                         )  # [L, 2, S, Hkv, D]
                         return logits[0], kv
 
-                    return jax.jit(detached)
+                    return jax.jit(_named(f"rt_prefill_detached_b{bucket}", detached))
 
                 prog = self._program(
                     self._jit_prefill, ("detached", bucket), make_detached
@@ -1508,7 +1552,8 @@ class DecodeEngine:
                 ])  # [L, 2, sb, Hkv, D]
                 return logits[0], suffix_kv
 
-            return jax.jit(detached_suffix)
+            return jax.jit(_named(f"rt_prefill_detached_suffix_b{mb}_{sb}",
+                                  detached_suffix))
 
         prog = self._program(
             self._jit_prefill, ("detached_suffix", mb, sb), make_detached_suffix
@@ -1646,7 +1691,6 @@ class DecodeEngine:
         slot = req.slot
         offset = req.prefilled
         if chunk.is_first and req.lease is not None:
-            t_attach = time.time()
             # Attach the cached prefix through the padded-bucket attach
             # path, then prefill only the suffix (in chunks). The lease
             # pins the blocks until the host->device copy is staged; it
@@ -1655,33 +1699,35 @@ class DecodeEngine:
             # req.lease was cleared here, so the release must not depend on
             # the happy path.
             tier = getattr(req.lease, "tier", "host")
-            try:
-                prefix_kv = self._leased_kv(req.lease)
-                if isinstance(prefix_kv, np.ndarray):
-                    xp = np
-                    if tier == "device":
-                        tier = "host"  # device copies dropped mid-lease
-                else:
-                    xp = jnp  # device hot tier: the attach is zero-H2D
-                mb = self._bucket(req.cached_offset)
-                if prefix_kv.shape[2] < mb:
-                    pad = xp.zeros(
-                        (prefix_kv.shape[0], 2, mb - prefix_kv.shape[2])
-                        + tuple(prefix_kv.shape[3:]), prefix_kv.dtype,
+            with xprof.span("rt.engine.attach", rid=_rid(req),
+                            rows=req.cached_offset) as attach_span:
+                try:
+                    prefix_kv = self._leased_kv(req.lease)
+                    if isinstance(prefix_kv, np.ndarray):
+                        xp = np
+                        if tier == "device":
+                            tier = "host"  # device copies dropped mid-lease
+                    else:
+                        xp = jnp  # device hot tier: the attach is zero-H2D
+                    mb = self._bucket(req.cached_offset)
+                    if prefix_kv.shape[2] < mb:
+                        pad = xp.zeros(
+                            (prefix_kv.shape[0], 2, mb - prefix_kv.shape[2])
+                            + tuple(prefix_kv.shape[3:]), prefix_kv.dtype,
+                        )
+                        prefix_kv = xp.concatenate([prefix_kv, pad], axis=2)
+                    attach = self._program(
+                        self._jit_prefill, ("attach", mb),
+                        lambda: jax.jit(_named(f"rt_attach_b{mb}", self._attach_kv)),
                     )
-                    prefix_kv = xp.concatenate([prefix_kv, pad], axis=2)
-                attach = self._program(
-                    self._jit_prefill, ("attach", mb),
-                    lambda: jax.jit(self._attach_kv),
-                )
-                self._caches = attach(
-                    self._caches,
-                    prefix_kv if xp is jnp else jnp.asarray(prefix_kv),
-                    jnp.int32(slot),
-                )
-            finally:
-                req.lease.release()
-                req.lease = None
+                    self._caches = attach(
+                        self._caches,
+                        prefix_kv if xp is jnp else jnp.asarray(prefix_kv),
+                        jnp.int32(slot),
+                    )
+                finally:
+                    req.lease.release()
+                    req.lease = None
             if rec is not None:
                 # Host-stamped dispatch span (the copy is staged async; a
                 # blocking wait here would be the RL603 sync jaxlint bans).
@@ -1691,7 +1737,7 @@ class DecodeEngine:
                 # post-fetch request (docs/observability.md).
                 if rec.route == "remote_fetch":
                     tier = "remote"
-                rec.span("cache-attach", t_attach, time.time(),
+                rec.span("cache-attach", attach_span.t0, attach_span.t1,
                          cached_tokens=req.cached_offset, tier=tier)
             self.last_attach = {
                 "tier": tier, "cached_tokens": req.cached_offset,
@@ -1700,7 +1746,8 @@ class DecodeEngine:
         padded = np.zeros((1, chunk.bucket), np.int32)
         padded[0, : len(chunk.tokens)] = chunk.tokens
         prefill = self._program(
-            self._jit_prefill, chunk.bucket, lambda: jax.jit(self._prefill_at)
+            self._jit_prefill, chunk.bucket,
+            lambda: jax.jit(_named(f"rt_prefill_b{chunk.bucket}", self._prefill_at)),
         )
         last_logits, self._caches = prefill(
             self.params, self._lora_tables(), jnp.asarray(padded), self._caches,
@@ -1727,15 +1774,17 @@ class DecodeEngine:
         # The admission sync: the request's FIRST token must be sampled
         # host-side before the slot can join the decode batch — one
         # [V]-row pull per admitted request, not per step or per chunk.
-        first_row = np.asarray(last_logits)  # raylint: disable=RL603 (one per-admission pull)
-        if req.constraint is not None:
-            first_row = first_row + req.constraint.mask(
-                req.sampling.stop_token_id, budget=req.sampling.max_tokens
-            )
-        first = _sample_host(first_row, req.sampling, self._np_rng)
+        with xprof.span("rt.engine.readback", bytes=last_logits.nbytes):
+            first_row = np.asarray(last_logits)  # raylint: disable=RL603 (one per-admission pull)
+        with xprof.span("rt.engine.sample", slots=1):
+            if req.constraint is not None:
+                first_row = first_row + req.constraint.mask(
+                    req.sampling.stop_token_id, budget=req.sampling.max_tokens
+                )
+            first = _sample_host(first_row, req.sampling, self._np_rng)
         if self._prefix_cache is not None:
             self._insert_prompt_kv(slot, req.prompt, req.adapter,
-                                   req.cached_offset)
+                                   req.cached_offset, rid=_rid(req))
         if self._draft is not None:
             # Draft catch-up: cache-hit admissions (offset > 0) stay
             # spec-eligible — the draft sees the full token history (the
@@ -1753,48 +1802,50 @@ class DecodeEngine:
         the attach program consumes it without a host round-trip."""
         slot = req.slot
         kv = req.kv
-        t_attach = time.time()
-        on_device = isinstance(kv, jax.Array)
-        if on_device and self._mesh is not None:
-            # Normalize a transferred device prefix onto THIS engine's mesh
-            # (no-op when it already is): a prefix committed to one device
-            # (recv_device staging) or sharded on a peer engine's mesh must
-            # not meet mesh-sharded caches inside one jit un-resharded.
-            kv = jax.device_put(
-                kv, kv_prefix_sharding(self._mesh, self.cfg.n_kv_heads)
-            )
-        xp = jnp if on_device else np
         prompt_len = req.prompt_len
-        # Pad the transferred prefix to a bucket so attach programs are reused.
-        P = kv.shape[2]
-        bucket = self._bucket(max(P, prompt_len))
-        if P < bucket:
-            pad = xp.zeros(
-                (kv.shape[0], 2, bucket - P) + tuple(kv.shape[3:]), kv.dtype
+        on_device = isinstance(kv, jax.Array)
+        with xprof.span("rt.engine.attach", rid=_rid(req),
+                        rows=prompt_len) as attach_span:
+            if on_device and self._mesh is not None:
+                # Normalize a transferred device prefix onto THIS engine's mesh
+                # (no-op when it already is): a prefix committed to one device
+                # (recv_device staging) or sharded on a peer engine's mesh must
+                # not meet mesh-sharded caches inside one jit un-resharded.
+                kv = jax.device_put(
+                    kv, kv_prefix_sharding(self._mesh, self.cfg.n_kv_heads)
+                )
+            xp = jnp if on_device else np
+            # Pad the transferred prefix to a bucket so attach programs are reused.
+            P = kv.shape[2]
+            bucket = self._bucket(max(P, prompt_len))
+            if P < bucket:
+                pad = xp.zeros(
+                    (kv.shape[0], 2, bucket - P) + tuple(kv.shape[3:]), kv.dtype
+                )
+                kv = xp.concatenate([kv, pad], axis=2)
+            elif P > bucket:
+                kv = kv[:, :, :bucket]
+            attach = self._program(
+                self._jit_prefill, ("attach", bucket),
+                lambda: jax.jit(_named(f"rt_attach_b{bucket}", self._attach_kv)),
             )
-            kv = xp.concatenate([kv, pad], axis=2)
-        elif P > bucket:
-            kv = kv[:, :, :bucket]
-        attach = self._program(
-            self._jit_prefill, ("attach", bucket),
-            lambda: jax.jit(self._attach_kv),
-        )
-        self._caches = attach(
-            self._caches, kv if on_device else jnp.asarray(kv), jnp.int32(slot)
-        )
+            self._caches = attach(
+                self._caches, kv if on_device else jnp.asarray(kv), jnp.int32(slot)
+            )
         self._lens[slot] = prompt_len
         if req.rec is not None:
-            req.rec.span("pd-attach", t_attach, time.time(),
+            req.rec.span("pd-attach", attach_span.t0, attach_span.t1,
                          prompt_len=prompt_len, bucket=bucket,
                          on_device=on_device)
-        first_row = np.asarray(req.first_logits)
-        if req.constraint is not None:
-            # Guided PD decode: the transferred first-logits row gets the
-            # same start-state mask a local prefill's first sample would.
-            first_row = first_row + req.constraint.mask(
-                req.sampling.stop_token_id, budget=req.sampling.max_tokens
-            )
-        first = _sample_host(first_row, req.sampling, self._np_rng)
+        with xprof.span("rt.engine.sample", slots=1):
+            first_row = np.asarray(req.first_logits)
+            if req.constraint is not None:
+                # Guided PD decode: the transferred first-logits row gets the
+                # same start-state mask a local prefill's first sample would.
+                first_row = first_row + req.constraint.mask(
+                    req.sampling.stop_token_id, budget=req.sampling.max_tokens
+                )
+            first = _sample_host(first_row, req.sampling, self._np_rng)
         prompt_tokens = req.prompt
         # PD-disagg transferred prefixes feed the prefix cache too: the
         # host-side kv is already in pool layout, so insertion is free of
@@ -1958,26 +2009,40 @@ class DecodeEngine:
                 # Disconnect cancels retire FIRST (before planning), so a
                 # cancelled slot never joins another decode dispatch: the
                 # cancel-to-free latency is bounded by one iteration.
-                self._process_cancels()
-                plan = self._sched.next_plan(draft=self._draft)
+                with xprof.span("rt.engine.plan"):
+                    self._process_cancels()
+                    plan = self._sched.next_plan(draft=self._draft)
                 if plan.idle:
-                    time.sleep(0.002)
+                    with xprof.span("rt.engine.idle"):
+                        time.sleep(0.002)
                     continue
-                for chunk in plan.chunks:
-                    self._exec_chunk(chunk)
-                if plan.spec_slots:
-                    self._spec_round(plan)
-                if plan.decode_slots:
-                    if plan.multi_step > 1:
-                        self._multi_round(plan.decode_slots, plan.multi_step)
-                    else:
-                        self._decode_round(plan.decode_slots)
-                    if self._draft is not None:
-                        for i in plan.decode_slots:
-                            # A plain step advances the target but not a model
-                            # draft's cache: its proposals would be garbage.
-                            # (The ngram draft is stateless here: no-op.)
-                            self._draft.on_plain_decode(i)
+                # The `rt.engine.*` spans (docs/observability.md "compute
+                # plane") put this loop's phases into any profiler trace, on
+                # the device's clock, and into scheduler_stats()["loop"].
+                with xprof.span("rt.engine.iter", chunks=len(plan.chunks),
+                                decode_slots=len(plan.decode_slots),
+                                steps=plan.multi_step):
+                    self._exec_plan(plan)
+
+    def _exec_plan(self, plan: Plan):
+        for chunk in plan.chunks:
+            with xprof.span("rt.engine.prefill", rid=_rid(chunk.request),
+                            tokens=len(chunk.tokens), bucket=chunk.bucket,
+                            last=int(chunk.is_last)):
+                self._exec_chunk(chunk)
+        if plan.spec_slots:
+            self._spec_round(plan)
+        if plan.decode_slots:
+            if plan.multi_step > 1:
+                self._multi_round(plan.decode_slots, plan.multi_step)
+            else:
+                self._decode_round(plan.decode_slots)
+            if self._draft is not None:
+                for i in plan.decode_slots:
+                    # A plain step advances the target but not a model
+                    # draft's cache: its proposals would be garbage.
+                    # (The ngram draft is stateless here: no-op.)
+                    self._draft.on_plain_decode(i)
 
     def _decode_round(self, decode_slots: List[int]):
         # lens/last_token/adapter_ids ride host->device per dispatch (an
@@ -1985,76 +2050,88 @@ class DecodeEngine:
         # discarded — the host mirrors below are canonical. The write gate
         # restricts KV writes to exactly the slots whose lens advances
         # below: idle and mid-prefill slots pass through write-free.
-        gate = np.zeros((self.B,), bool)
-        gate[decode_slots] = True
-        logits, self._caches, _ = self._jit_decode(
-            self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
-            jnp.asarray(self._last_token), self._caches,
-            jnp.asarray(self._lens), jnp.asarray(gate),
-        )
+        with xprof.span("rt.engine.dispatch", steps=1, slots=len(decode_slots),
+                        rows=int(self._lens[decode_slots].sum())):
+            gate = np.zeros((self.B,), bool)
+            gate[decode_slots] = True
+            logits, self._caches, _ = self._jit_decode(
+                self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
+                jnp.asarray(self._last_token), self._caches,
+                jnp.asarray(self._lens), jnp.asarray(gate),
+            )
         # The step's ONE device->host pull: every active slot's next-token
         # logits arrive in a single [B, V] readback (sampling params can
         # differ per slot, so sampling itself is host-side).
-        logits_np = np.asarray(logits)  # raylint: disable=RL603 (the per-dispatch batched readback)
-        for i in decode_slots:
-            s = self._sched.slots[i]
-            self._lens[i] += 1  # the decode step wrote this slot's kv row
-            if not s.active:
-                continue
-            row = logits_np[i]
-            if s.constraint is not None:
-                # Guided composition point (docs/generation.md): one cached
-                # [V] mask row + one numpy add on the already-pulled logits
-                # — strictly host-side, zero new compiled programs. When the
-                # unconstrained argmax is already legal the mask cannot
-                # change it, so guided greedy output is token-identical to
-                # unconstrained greedy except where the constraint binds.
-                # budget= steers onto a completable path once remaining
-                # max_tokens gets tight (an unbounded quantifier must not
-                # eat the budget and truncate mid-pattern).
-                row = row + s.constraint.mask(
-                    s.params.stop_token_id,
-                    budget=s.params.max_tokens - s.generated,
-                )
-            token = _sample_host(row, s.params, self._np_rng)
-            s.generated += 1
-            s.host_len += 1
-            s.tokens.append(token)
-            s.history.append(token)
-            self._last_token[i] = token
-            self._emit(i, token)
-
-    def _multi_round(self, decode_slots: List[int], n: int):
-        """One multi-token dispatch + host-side emission with rollback for
-        slots that stop early (stop_token): their device lens/last_token are
-        corrected back to what was actually consumed."""
-        gate = np.zeros((self.B,), bool)
-        gate[decode_slots] = True
-        toks_dev, self._caches, _ = self._jit_decode_multi(
-            self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
-            jnp.asarray(self._last_token), self._caches,
-            jnp.asarray(self._lens), jnp.asarray(gate), n=n,
-        )
-        # The chunk's ONE device->host pull: n tokens x B slots per readback
-        # (the whole point of multi-step decode).
-        toks = np.asarray(toks_dev)  # raylint: disable=RL603 (the per-chunk batched readback)
-        for i in decode_slots:
-            s = self._sched.slots[i]
-            self._lens[i] += n  # device wrote n kv rows for this slot
-            consumed = 0
-            for j in range(n):
+        with xprof.span("rt.engine.readback", bytes=logits.nbytes):
+            logits_np = np.asarray(logits)  # raylint: disable=RL603 (the per-dispatch batched readback)
+        with xprof.span("rt.engine.sample", slots=len(decode_slots)):
+            for i in decode_slots:
+                s = self._sched.slots[i]
+                self._lens[i] += 1  # the decode step wrote this slot's kv row
                 if not s.active:
-                    break
-                token = int(toks[j, i])
-                consumed += 1
+                    continue
+                row = logits_np[i]
+                if s.constraint is not None:
+                    # Guided composition point (docs/generation.md): one cached
+                    # [V] mask row + one numpy add on the already-pulled logits
+                    # — strictly host-side, zero new compiled programs. When the
+                    # unconstrained argmax is already legal the mask cannot
+                    # change it, so guided greedy output is token-identical to
+                    # unconstrained greedy except where the constraint binds.
+                    # budget= steers onto a completable path once remaining
+                    # max_tokens gets tight (an unbounded quantifier must not
+                    # eat the budget and truncate mid-pattern).
+                    row = row + s.constraint.mask(
+                        s.params.stop_token_id,
+                        budget=s.params.max_tokens - s.generated,
+                    )
+                token = _sample_host(row, s.params, self._np_rng)
                 s.generated += 1
                 s.host_len += 1
                 s.tokens.append(token)
                 s.history.append(token)
                 self._last_token[i] = token
                 self._emit(i, token)
-            if consumed < n:
-                # Early stop: rows past the last consumed token are invisible
-                # once lens rolls back (kv_mask <= lens) and get overwritten
-                # by the slot's next occupant.
-                self._lens[i] = s.host_len
+
+    def _multi_round(self, decode_slots: List[int], n: int):
+        """One multi-token dispatch + host-side emission with rollback for
+        slots that stop early (stop_token): their device lens/last_token are
+        corrected back to what was actually consumed."""
+        with xprof.span("rt.engine.dispatch", steps=n, slots=len(decode_slots),
+                        rows=int(self._lens[decode_slots].sum())):
+            gate = np.zeros((self.B,), bool)
+            gate[decode_slots] = True
+            decode_multi = self._program(
+                self._jit_decode_multi, ("decode_multi", n),
+                lambda: jax.jit(_named(f"rt_decode_multi_n{n}", self._decode_multi, n=n)),
+            )
+            toks_dev, self._caches, _ = decode_multi(
+                self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
+                jnp.asarray(self._last_token), self._caches,
+                jnp.asarray(self._lens), jnp.asarray(gate),
+            )
+        # The chunk's ONE device->host pull: n tokens x B slots per readback
+        # (the whole point of multi-step decode).
+        with xprof.span("rt.engine.readback", bytes=toks_dev.nbytes):
+            toks = np.asarray(toks_dev)  # raylint: disable=RL603 (the per-chunk batched readback)
+        with xprof.span("rt.engine.sample", slots=len(decode_slots)):
+            for i in decode_slots:
+                s = self._sched.slots[i]
+                self._lens[i] += n  # device wrote n kv rows for this slot
+                consumed = 0
+                for j in range(n):
+                    if not s.active:
+                        break
+                    token = int(toks[j, i])
+                    consumed += 1
+                    s.generated += 1
+                    s.host_len += 1
+                    s.tokens.append(token)
+                    s.history.append(token)
+                    self._last_token[i] = token
+                    self._emit(i, token)
+                if consumed < n:
+                    # Early stop: rows past the last consumed token are invisible
+                    # once lens rolls back (kv_mask <= lens) and get overwritten
+                    # by the slot's next occupant.
+                    self._lens[i] = s.host_len

@@ -31,6 +31,16 @@ class DPRankAssigner:
         self._free = list(range(dp_size))
         self._held: dict = {}  # holder actor-id hex -> rank
 
+    def ensure_size(self, dp_size: int) -> int:
+        """An app built under a name that is still alive gets this actor as it
+        stands (`get_if_exists`), with the rank count of the app that made it:
+        grow it to the new app's size. Ranks are never taken away here; a
+        smaller app simply leaves the upper ones free."""
+        if dp_size > self._dp_size:
+            self._free.extend(range(self._dp_size, dp_size))
+            self._dp_size = dp_size
+        return self._dp_size
+
     def _reclaim_dead(self):
         from ray_tpu.util.state import list_actors
 
@@ -700,6 +710,7 @@ def build_dp_openai_app(config: LLMConfig, *, dp_size: int = 2):
         name=f"DPRankAssigner-{config.model_id}", get_if_exists=True,
         namespace="llm_dp",
     ).remote(dp_size)
+    ray_tpu.get(assigner.ensure_size.remote(dp_size))
     server = serve.deployment(
         name=f"DPLLMServer-{config.model_id}",
         num_replicas=dp_size,

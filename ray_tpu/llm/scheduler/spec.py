@@ -255,14 +255,18 @@ class ModelDraft(DraftProvider):
         dlens = int(self._host_lens[slot_idx])
         pend = self._pending[slot_idx]
         catchup = pend is not None
+        from ray_tpu.llm._engine import _named
+
         prog = self._program(
             self._progs, ("propose", self.k, catchup),
-            lambda: jax.jit(self._propose_prog, static_argnames=("k", "catchup")),
+            lambda: jax.jit(_named(
+                f"rt_draft_propose_k{self.k}" + ("_catchup" if catchup else ""),
+                self._propose_prog, k=self.k, catchup=catchup)),
         )
         toks_dev, self.caches = prog(
             self.params, self.caches,
             jnp.int32(pend if catchup else t0), jnp.int32(t0),
-            jnp.int32(dlens), jnp.int32(slot_idx), k=self.k, catchup=catchup,
+            jnp.int32(dlens), jnp.int32(slot_idx),
         )
         if catchup:
             self._host_lens[slot_idx] += 1  # the scan head landed pend's kv
@@ -278,9 +282,11 @@ class ModelDraft(DraftProvider):
         bucket = self._bucket(len(prompt))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = prompt
+        from ray_tpu.llm._engine import _named
+
         prog = self._program(
             self._progs, ("dprefill", bucket),
-            lambda: jax.jit(self._prefill_prog),
+            lambda: jax.jit(_named(f"rt_draft_prefill_b{bucket}", self._prefill_prog)),
         )
         self.caches = prog(self.params, self.caches, jnp.asarray(padded),
                            jnp.int32(slot_idx))

@@ -469,7 +469,8 @@ class Transformer(nn.Module):
             (cfg.vocab_size, cfg.hidden),
             cfg.param_dtype,
         )
-        x = embed[tokens].astype(cfg.dtype)
+        with jax.named_scope("embedding"):  # a parameter, not a module: flax names no scope
+            x = embed[tokens].astype(cfg.dtype)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         # Rotary cos/sin computed once, broadcast into every layer (the scan
         # would otherwise recompute the transcendentals per layer).

@@ -171,6 +171,7 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float, block_q: int, block_k
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     if layout == "bhsd":
         return out.reshape(B, H, S, D), lse.reshape(B, H, S)
@@ -319,6 +320,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, scale: float,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd",
     )(qt, gt, lset, deltat, kt, vt)
 
     if layout == "bhsd":

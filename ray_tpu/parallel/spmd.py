@@ -148,20 +148,24 @@ def build_train_step(model, optimizer, mesh: Mesh, rules=None,
                         {"params": params}, batch["tokens"], mutable=["losses"]
                     )
             aux = sum(jnp.sum(v) for v in jax.tree_util.tree_leaves(extra))
-            if use_fused:
-                if model.cfg.tie_embeddings:
-                    table, cdim = params["embedding"], 1
-                else:
-                    table, cdim = params["lm_head"]["kernel"], 0
-                return fused_cross_entropy_loss(
-                    hidden, table, batch["targets"], batch.get("mask"),
-                    contract_dim=cdim, compute_dtype=model.cfg.dtype,
-                ) + aux
-            return loss_fn(logits, batch["targets"], batch.get("mask")) + aux
+            # flax names the model's operations by module; `loss` and `optimizer`
+            # name the rest of the step in a device trace (PERF.md §3).
+            with jax.named_scope("loss"):
+                if use_fused:
+                    if model.cfg.tie_embeddings:
+                        table, cdim = params["embedding"], 1
+                    else:
+                        table, cdim = params["lm_head"]["kernel"], 0
+                    return fused_cross_entropy_loss(
+                        hidden, table, batch["targets"], batch.get("mask"),
+                        contract_dim=cdim, compute_dtype=model.cfg.dtype,
+                    ) + aux
+                return loss_fn(logits, batch["targets"], batch.get("mask")) + aux
 
         loss, grads = jax.value_and_grad(compute_loss)(state.params)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, "step": state.step + 1}
         if with_grad_norm:
             # Optional: a full extra pass over every gradient buffer — perf

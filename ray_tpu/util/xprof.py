@@ -1,7 +1,7 @@
 """Compute-plane observatory: XLA program registry, device-memory ledger,
 and on-demand profiler capture (docs/observability.md "compute plane").
 
-Three pieces, all host-side and pull-free:
+Four pieces, all host-side and pull-free:
 
 - **ProgramRegistry** — a per-process registry every ``_program``-style jit
   cache hooks into (DecodeEngine prefill/decode/verify/install, Learner
@@ -22,6 +22,15 @@ Three pieces, all host-side and pull-free:
   ``profiler_capture``) and leaklint-paired so an abandoned capture cannot
   pin trace buffers forever.  ``capture(duration_s)`` is the one-shot
   helper the actor surfaces expose to ``util.state.capture_profile``.
+
+- **span** — ``with span("rt.engine.sample", slots=3):`` names a phase of a
+  host loop twice over: as a ``jax.profiler.TraceAnnotation``, so that under
+  any profiler session (``capture()``, ``util.state.capture_profile``, a
+  benchmark's ``start_trace``) it is an event of the host plane of the same
+  ``.xplane.pb`` as the device's operations, on one clock; and as a row of a
+  per-process table ``{name: [count, seconds]}`` that ``span_totals()``
+  returns, for an operator without a profiler.  There is no switch: the
+  annotation costs half a microsecond when no session runs.
 
 Flush rule (PR 9/11/13): nothing here touches ``util.metrics`` on the hot
 path.  Registry mutation is plain-int arithmetic; metric objects are
@@ -50,6 +59,8 @@ __all__ = [
     "oom_snapshot",
     "register_memory_owner",
     "registry",
+    "span",
+    "span_totals",
     "start_capture",
     "stop_capture",
     "unregister_memory_owner",
@@ -247,6 +258,57 @@ _REGISTRY = ProgramRegistry()
 def registry() -> ProgramRegistry:
     """The per-process program registry singleton."""
     return _REGISTRY
+
+
+# ---------------------------------------------------------------------------
+# Spans
+# ---------------------------------------------------------------------------
+
+_SPAN_TOTALS: Dict[str, list] = {}  # name -> [count, seconds]
+_SPAN_LOCK = threading.Lock()  # held for two additions, never across a call
+_TRACE_ANNOTATION = None
+
+
+class span:
+    """One named phase of a host loop.  ``t0``/``t1`` are the two
+    ``time.time()`` reads it makes, for a caller (the flight recorder) that
+    stamps the same interval: one pair of reads serves both.  Attributes go
+    to the profiler event only.  Plain arithmetic on exit: no metric object,
+    no device access, no lock held across a call (the flush rule)."""
+
+    __slots__ = ("name", "t0", "t1", "_annotation")
+
+    def __init__(self, name: str, **attrs):
+        global _TRACE_ANNOTATION
+        if _TRACE_ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+
+            _TRACE_ANNOTATION = TraceAnnotation
+        self.name = name
+        self._annotation = _TRACE_ANNOTATION(name, **attrs)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self.t0 = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.time()
+        self._annotation.__exit__(exc_type, exc, tb)
+        with _SPAN_LOCK:  # several engines' steppers may share the process
+            row = _SPAN_TOTALS.get(self.name)
+            if row is None:
+                row = _SPAN_TOTALS[self.name] = [0, 0.0]
+            row[0] += 1
+            row[1] += self.t1 - self.t0
+        return False
+
+
+def span_totals() -> Dict[str, dict]:
+    """``{name: {"count", "seconds"}}`` of every span this process has closed."""
+    with _SPAN_LOCK:
+        return {name: {"count": row[0], "seconds": row[1]}
+                for name, row in sorted(_SPAN_TOTALS.items())}
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,7 @@ import os
 import subprocess
 import sys
 import time
+import types
 
 import pytest
 
@@ -69,7 +70,10 @@ def test_builders_reserve_the_accelerator(monkeypatch, build, replicas, tp, want
     from ray_tpu.actor import ActorClass
     from ray_tpu.llm import LLMConfig
 
-    monkeypatch.setattr(ActorClass, "remote", lambda self, *a, **k: object())
+    # the one call a builder makes on it: the DP builder sizes the assigner it finds
+    assigner = types.SimpleNamespace(ensure_size=types.SimpleNamespace(remote=lambda dp_size: dp_size))
+    monkeypatch.setattr(ActorClass, "remote", lambda self, *a, **k: assigner)
+    monkeypatch.setattr(ray_tpu, "get", lambda ref: ref)
     config = LLMConfig(model_id="test-tiny", accelerator_resources={"TPU": 1}, tp=tp)
     options = _replica_options(build(config))
     assert replicas <= set(options)

@@ -13,6 +13,7 @@ test file. One file only, for the same reason.
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,9 @@ def test_flash_forward_compiles_for_v5e(one_chip, model, layout):
 
     text = jax.jit(fwd).lower(x, x, x).compile().as_text()
     assert "tpu_custom_call" in text
+    # the kernel's own name, in both layouts: a device trace shows the instruction's
+    # name, and the reduction finds the kernel by it (PERF.md §3)
+    assert re.search(r"%flash_fwd(\.\d+)? = ", text) and 'flash_fwd/pallas_call"' in text
 
 
 @pytest.mark.parametrize("layout", ["bshd", "bhsd"])
@@ -85,3 +89,4 @@ def test_flash_backward_compiles_for_v5e(one_chip, model, layout):
 
     text = jax.jit(bwd).lower(x, x, x, x, lse, x).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(r"%flash_bwd(\.\d+)? = ", text) and 'flash_bwd/pallas_call"' in text

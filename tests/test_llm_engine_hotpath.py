@@ -277,3 +277,140 @@ def test_multi_step_decode_single_pull_per_chunk(monkeypatch):
         assert spy.device_pulls == 3
     finally:
         engine.shutdown()
+
+
+# ---- rt.engine.* spans (docs/observability.md "compute plane") --------------
+
+_ITER_CHILDREN = ("rt.engine.prefill", "rt.engine.attach", "rt.engine.kv_insert",
+                  "rt.engine.dispatch", "rt.engine.readback", "rt.engine.sample")
+_ENGINE_SPANS = ("rt.engine.iter", "rt.engine.idle", "rt.engine.plan") + _ITER_CHILDREN
+
+
+def _loop_counts():
+    from ray_tpu.util import xprof
+
+    totals = xprof.span_totals()
+    return {name: totals.get(name, {"count": 0})["count"] for name in _ENGINE_SPANS}
+
+
+def test_spans_add_zero_pulls_and_zero_programs_on_a_warm_engine(monkeypatch):
+    """The spans ride the decode loop for free: a warm generate under them
+    (and under a live profiler session, which is when they record) costs
+    exactly its token accounting in device pulls and builds no program."""
+    import tempfile
+
+    from ray_tpu.llm import _engine as engine_mod
+    from ray_tpu.util import xprof
+
+    spy = _NpSpy()
+    monkeypatch.setattr(engine_mod, "np", spy)
+    engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=1,
+                          prefix_cache=False)
+    try:
+        _generate(engine, [5, 9, 17, 3], max_tokens=4)  # warm every program
+        programs = (len(engine._jit_prefill), len(engine._jit_decode_multi),
+                    engine._jit_decode._cache_size())
+        compiles = engine._xprof.report(owner=engine._xprof_owner)["totals"]["compiles_total"]
+        pulls, before = spy.device_pulls, _loop_counts()
+        cap = xprof.start_capture(log_dir=tempfile.mkdtemp(prefix="xprof_test_"))
+        try:
+            out = _generate(engine, [5, 9, 17, 3], max_tokens=6)
+        finally:
+            cap.stop_capture()
+        assert len(out) == 6
+        assert spy.device_pulls == pulls + 6  # 1 admission + 5 decode steps
+        assert programs == (len(engine._jit_prefill), len(engine._jit_decode_multi),
+                            engine._jit_decode._cache_size())
+        assert engine._xprof.report(owner=engine._xprof_owner)["totals"]["compiles_total"] == compiles
+        after = _loop_counts()
+        assert after["rt.engine.dispatch"] == before["rt.engine.dispatch"] + 5
+        assert after["rt.engine.readback"] == before["rt.engine.readback"] + 6
+        assert after["rt.engine.sample"] == before["rt.engine.sample"] + 6
+    finally:
+        engine.shutdown()
+
+
+def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, monkeypatch):
+    """Prefix-cache insert, a cache-hit attach, chunked prefill, single and
+    multi-step decode and an idle loop, under a CPU profiler capture: every
+    span of the table is an event of the host plane, every child lies inside an
+    `rt.engine.iter`, and a request's engine spans carry its flight-record id."""
+    import glob
+    import os
+    import time
+
+    from jax.profiler import ProfileData
+
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import SamplingParams
+    from ray_tpu.util import xprof
+
+    monkeypatch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+    monkeypatch.setitem(CONFIG._cache, "llm_kv_block_size", 4)
+    monkeypatch.setitem(CONFIG._cache, "llm_prefix_cache_bytes", 1 << 20)
+    engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=4, token_budget=8)
+    prompt = list(range(1, 14))
+    try:
+        cap = xprof.start_capture(log_dir=str(tmp_path))
+        try:
+            _generate(engine, prompt, max_tokens=6)                  # chunks, kv_insert, multi-step
+            done = []
+            engine.submit(prompt + [40, 41], SamplingParams(max_tokens=3, temperature=0.8),
+                          lambda tok, fin: done.append(fin), request_id="req-hit")  # attach, one step at a time
+            deadline = time.time() + 120
+            while not (done and done[-1]) and time.time() < deadline:
+                time.sleep(0.01)
+            assert done and done[-1], engine.error
+            time.sleep(0.02)                                         # a few idle plans
+        finally:
+            cap.stop_capture()
+        assert engine.last_attach is not None, "the second prompt did not hit the prefix cache"
+    finally:
+        engine.shutdown()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("rt.engine."):
+                    events.setdefault(e.name, []).append((e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    assert set(events) == set(_ENGINE_SPANS), sorted(set(_ENGINE_SPANS) ^ set(events))
+    iters = events["rt.engine.iter"]
+    for name in _ITER_CHILDREN:
+        for a, b, _ in events[name]:
+            assert any(i0 <= a and b <= i1 for i0, i1, _ in iters), name
+    for name in ("rt.engine.plan", "rt.engine.idle"):
+        for a, b, _ in events[name]:
+            assert not any(i0 < b and a < i1 for i0, i1, _ in iters), name
+    steps = sorted({int(s["steps"]) for _, _, s in events["rt.engine.dispatch"]})
+    assert steps[0] == 1 and steps[-1] > 1, steps           # single and multi-step dispatches
+    assert all(int(s["rows"]) > 0 and int(s["slots"]) >= 1 for _, _, s in events["rt.engine.dispatch"])
+    assert {str(s["rid"]) for _, _, s in events["rt.engine.attach"]} == {"req-hit"}
+    assert "req-hit" in {str(s["rid"]) for _, _, s in events["rt.engine.prefill"]}
+    assert all(int(s["rows"]) == 12 for _, _, s in events["rt.engine.kv_insert"][:1])
+    assert all(int(s["bytes"]) > 0 for _, _, s in events["rt.engine.readback"])
+
+
+def test_scheduler_stats_reports_the_loop_table_with_distsan_silent():
+    """`scheduler_stats()["loop"]` is the span table, read on the report path;
+    the spans themselves touch no metric and make no GCS call from the loop."""
+    from ray_tpu.devtools import distsan
+
+    engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=1,
+                          prefix_cache=False)
+    try:
+        before = _loop_counts()
+        _generate(engine, [5, 9, 17, 3], max_tokens=4)
+        loop = engine.scheduler_stats()["loop"]
+        for name in ("rt.engine.iter", "rt.engine.plan", "rt.engine.prefill",
+                     "rt.engine.dispatch", "rt.engine.readback", "rt.engine.sample"):
+            assert loop[name]["count"] > before[name], name
+            assert loop[name]["seconds"] > 0.0, name
+        children = sum(loop[n]["seconds"] for n in _ITER_CHILDREN if n in loop)
+        nested = loop.get("rt.engine.attach", {"seconds": 0})["seconds"]  # inside prefill, as kv_insert is
+        assert loop["rt.engine.iter"]["seconds"] >= 0.5 * (children - nested)
+        assert distsan.violations() == []
+    finally:
+        engine.shutdown()

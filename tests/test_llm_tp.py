@@ -90,8 +90,8 @@ def generate(engine, prompt, n=12):
     return acc
 
 def program_count(e):
-    n = len(e._jit_prefill) + len(e._jit_spec_verify)
-    for prog in (e._jit_decode, e._jit_decode_multi):
+    n = len(e._jit_prefill) + len(e._jit_spec_verify) + len(e._jit_decode_multi)
+    for prog in (e._jit_decode, *e._jit_decode_multi.values()):
         try:
             n += prog._cache_size()
         except Exception:

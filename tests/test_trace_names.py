@@ -1,0 +1,172 @@
+"""The names a device trace shows (PERF.md §3): every engine program has a module
+name of its own that no Python function's name decides, the multi-step program's
+says how many steps it holds, and every operation of the engine's model and of the
+train step carries one of one list of scopes. Checked by lowering at `test-tiny`
+size on the CPU; the Pallas kernels' names are checked where the kernels compile
+(`tests/test_chip_compile.py`)."""
+
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+MODEL_SCOPES = ("embedding", "attn_norm", "attn", "mlp_norm", "mlp", "final_norm", "lm_head")
+
+
+def _abstract(tree):
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), tree)
+
+
+def _parts(op_name):
+    """The scopes of an `op_name`, each without what a transformation wrapped round it:
+    `jit(step)/transpose(jvp(loss))/mul` -> [step, loss, mul]."""
+    return [re.sub(r"^(?:\w+\()+|\)+$", "", part) for part in op_name.split("/")]
+
+
+def _lowered(prog, *args):
+    """(module name, every scope of every operation) of the lowered program."""
+    text = prog.lower(*_abstract(args)).as_text(dialect="hlo", debug_info=True)
+    (module,) = re.findall(r"^HloModule (\w+)", text, flags=re.M)
+    return module, {part for name in re.findall(r'op_name="([^"]+)"', text) for part in _parts(name)}
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """One engine that has run chunked prefill, a prefix-cache attach, single and
+    multi-step decode and both detached prefills; one with a model draft."""
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+    from ray_tpu.models.transformer import Transformer, get_config
+
+    cfg = get_config("test-tiny", scan_layers=False, remat=False)
+    params = Transformer(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+
+    def generate(engine, prompt, **sp):
+        done = threading.Event()
+        engine.submit(prompt, SamplingParams(**sp), lambda tok, fin: fin and done.set())
+        assert done.wait(180), engine.error
+
+    saved = {k: CONFIG._cache.get(k) for k in ("llm_prefill_bucket_min",)}
+    CONFIG._cache["llm_prefill_bucket_min"] = 4
+    plain = DecodeEngine(cfg, params, num_slots=2, max_seq=64, multi_step=4, token_budget=8,
+                         prefix_cache=PrefixCacheManager(4, 1 << 20, name="names"))
+    spec = DecodeEngine(cfg, params, num_slots=2, max_seq=64, prefix_cache=False,
+                        spec_config={"num_spec_tokens": 2})
+    try:
+        prompt = list(range(1, 14))
+        generate(plain, prompt, max_tokens=6)                           # prefill b8, multi-step
+        generate(plain, prompt + [40, 41], max_tokens=3, temperature=0.8)  # attach, single steps
+        plain.prefill_detached(list(range(20, 27)))                     # detached
+        plain.prefill_detached(prompt + [50, 51, 52])                   # detached suffix
+        generate(spec, prompt, max_tokens=8)                            # draft prefill, propose, verify
+        yield plain, spec
+    finally:
+        plain.shutdown()
+        spec.shutdown()
+        CONFIG._cache.update(saved)
+
+
+def _engine_programs(plain, spec):
+    """(program, its arguments) for every program the two engines built."""
+    cfg, B, T = plain.cfg, plain.B, plain.T
+    kv = lambda rows: np.zeros((cfg.n_layers, 2, rows, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)  # noqa: E731
+    i32, vec = np.int32(0), np.zeros((B,), np.int32)
+    step = (plain.params, None, vec, vec, plain._caches, vec, np.ones((B,), bool))
+    out = [(plain._jit_decode, step)]
+    out += [(prog, step) for prog in plain._jit_decode_multi.values()]
+    for key, prog in plain._jit_prefill.items():
+        if isinstance(key, int):
+            out.append((prog, (plain.params, None, np.zeros((1, key), np.int32), plain._caches, i32, i32, i32, i32)))
+        elif key[0] == "attach":
+            out.append((prog, (plain._caches, kv(key[1]), i32)))
+        elif key[0] == "detached":
+            out.append((prog, (plain.params, None, np.zeros((1, key[1]), np.int32), i32)))
+        elif key[0] == "detached_suffix":
+            out.append((prog, (plain.params, None, kv(key[1]), np.zeros((1, key[2]), np.int32), i32, i32)))
+    for key, prog in spec._jit_spec_verify.items():
+        S = key[1]
+        out.append((prog, (spec.params, None, vec, np.zeros((B, S), np.int32), spec._caches, vec,
+                           np.ones((B,), bool), np.zeros((B, S, cfg.vocab_size), np.float32))))
+    draft = spec._draft
+    for key, prog in draft._progs.items():
+        if key[0] == "propose":
+            out.append((prog, (draft.params, draft.caches, i32, i32, i32, i32)))
+        else:
+            out.append((prog, (draft.params, draft.caches, np.zeros((1, key[1]), np.int32), i32)))
+    return out
+
+
+def test_every_engine_program_has_a_name_of_its_own_and_the_models_scopes(engines):
+    plain, spec = engines
+    names = {}
+    for prog, args in _engine_programs(plain, spec):
+        module, scopes = _lowered(prog, *args)
+        names[module] = scopes
+    patterns = {
+        r"jit_rt_decode": 1, r"jit_rt_decode_multi_n\d+": 1, r"jit_rt_prefill_b\d+": 1,
+        r"jit_rt_attach_b\d+": 1, r"jit_rt_prefill_detached_b\d+": 1,
+        r"jit_rt_prefill_detached_suffix_b\d+_\d+": 1, r"jit_rt_verify_s3": 1,
+        r"jit_rt_draft_prefill_b\d+": 1, r"jit_rt_draft_propose_k2(_catchup)?": 1,
+    }
+    for pattern, at_least in patterns.items():
+        found = [n for n in names if re.fullmatch(pattern, n)]
+        assert len(found) >= at_least, (pattern, sorted(names))
+    assert all(any(re.fullmatch(p, n) for p in patterns) for n in names), sorted(names)
+    # a program that runs the model carries every scope of the list; the multi-step and
+    # verify programs also `sample` on the device; an attach runs no model
+    for module, scopes in names.items():
+        if "attach" in module:
+            continue
+        want = set(MODEL_SCOPES)
+        if "draft_prefill" in module:
+            want -= {"final_norm", "lm_head"}  # it keeps the KV rows and drops the logits
+        assert want <= scopes, (module, want - scopes)
+        assert {"layer_0", "layer_1"} <= scopes, module
+        assert ("sample" in scopes) == bool(re.search(r"multi|verify", module)), module
+    # the steps of a multi-step program are in its name
+    assert {f"jit_rt_decode_multi_n{key[-1]}" for key in plain._jit_decode_multi} <= set(names)
+
+
+def test_the_registry_counts_each_multi_step_size(engines):
+    plain, _ = engines
+    rows = {r["key"]: r for r in plain._xprof.report(owner=plain._xprof_owner)["programs"]}
+    multi = [k for k in rows if isinstance(k, tuple) and k[0] == "decode_multi"]
+    assert multi and all(len(k) == 2 and rows[k]["compiles"] == 1 for k in multi), rows.keys()
+    assert ("decode",) in rows
+
+
+@pytest.mark.parametrize("fused_ce", [False, True])
+def test_the_train_step_names_model_loss_and_optimizer(fused_ce):
+    import optax
+
+    from ray_tpu.models.transformer import Transformer, get_config
+    from ray_tpu.parallel import mesh as mesh_lib
+    from ray_tpu.parallel.spmd import build_train_step, init_state
+
+    cfg = get_config("test-tiny")
+    model = Transformer(cfg)
+    mesh = mesh_lib.create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    optimizer = optax.adamw(1e-3)
+    state, _ = init_state(model, cfg, optimizer, mesh, sample_shape=(2, 32))
+    step_fn, _ = build_train_step(model, optimizer, mesh, fused_ce=fused_ce, with_grad_norm=False)
+    batch = {"tokens": np.zeros((2, 32), np.int32), "targets": np.zeros((2, 32), np.int32)}
+    with mesh:
+        text = step_fn.lower(*_abstract((state, batch))).as_text(dialect="hlo", debug_info=True)
+    op_names = set(re.findall(r'op_name="([^"]+)"', text))
+    scopes = {part for name in op_names for part in _parts(name)}
+    want = {"embedding", "attn_norm", "attn", "mlp_norm", "mlp", "final_norm", "loss", "optimizer"}
+    if not fused_ce:
+        want.add("lm_head")  # the fused loss multiplies by the head itself, under `loss`
+    assert want <= scopes, want - scopes
+    # forward and backward copies of a scope both keep it
+    assert any("transpose(jvp(Transformer))" in n and "/mlp/" in n for n in op_names)
+    assert any("jvp(Transformer)" in n and "transpose" not in n and "/mlp/" in n for n in op_names)
+    # of the operations the step itself names, those under no scope do no arithmetic of the
+    # loss or the optimizer
+    bare = {n for n in op_names if n.startswith("jit(step)/")
+            and not (set(_parts(n)) & (want | {"lm_head"}))}
+    assert not any(re.search(r"log|exp|sqrt|dot_general", n.rsplit("/", 1)[-1]) for n in bare), sorted(bare)

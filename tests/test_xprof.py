@@ -217,3 +217,108 @@ def test_render_prometheus_alias_preserved():
     from ray_tpu.util import metrics
 
     assert metrics.prometheus_text is metrics.render_prometheus
+
+
+# ---- spans ------------------------------------------------------------------
+
+def _row(name):
+    return xprof.span_totals().get(name, {"count": 0, "seconds": 0.0})
+
+
+def test_span_accumulates_count_and_seconds():
+    import time
+
+    before = _row("rt.test.acc")
+    for _ in range(3):
+        with xprof.span("rt.test.acc", slots=2) as sp:
+            time.sleep(0.01)
+    after = _row("rt.test.acc")
+    assert after["count"] == before["count"] + 3
+    assert after["seconds"] - before["seconds"] >= 0.03
+    # the two clock reads are the caller's to reuse (the flight recorder does)
+    assert sp.t1 - sp.t0 >= 0.01
+
+
+def test_spans_nest_and_the_outer_covers_the_inner():
+    import time
+
+    outer0, inner0 = _row("rt.test.outer"), _row("rt.test.inner")
+    with xprof.span("rt.test.outer") as outer:
+        with xprof.span("rt.test.inner") as inner:
+            time.sleep(0.005)
+        with xprof.span("rt.test.inner"):
+            pass
+    assert outer.t0 <= inner.t0 and inner.t1 <= outer.t1
+    assert _row("rt.test.outer")["count"] == outer0["count"] + 1
+    assert _row("rt.test.inner")["count"] == inner0["count"] + 2
+    grew = lambda name, was: _row(name)["seconds"] - was["seconds"]  # noqa: E731
+    assert grew("rt.test.outer", outer0) >= grew("rt.test.inner", inner0) >= 0.005
+
+
+def test_span_survives_an_exception():
+    before = _row("rt.test.raises")
+    with pytest.raises(KeyError):
+        with xprof.span("rt.test.raises", rid="r1"):
+            raise KeyError("boom")
+    assert _row("rt.test.raises")["count"] == before["count"] + 1
+    with xprof.span("rt.test.raises"):  # and the next one opens as usual
+        pass
+    assert _row("rt.test.raises")["count"] == before["count"] + 2
+
+
+def test_span_table_loses_no_update_under_many_threads():
+    """More threads than cores, the interpreter switching as often as it can: the
+    table counts every span (several engines' steppers may share a process)."""
+    import sys
+    import threading
+
+    before, n_threads, each = _row("rt.test.stress"), 16, 500
+
+    def work():
+        for _ in range(each):
+            with xprof.span("rt.test.stress"):
+                pass
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    assert _row("rt.test.stress")["count"] == before["count"] + n_threads * each
+
+
+def test_span_is_an_event_of_the_captured_host_plane(tmp_path):
+    """Under a profiler session the span is a host-plane event of the same
+    `.xplane.pb` as the device's operations, with its attributes as stats. The
+    spans are made on a second thread, as the engine's stepper makes them."""
+    import glob
+    import threading
+
+    from jax.profiler import ProfileData
+
+    def work():
+        for i in range(3):
+            with xprof.span("rt.test.traced", slots=i, rid="req-7"):
+                jnp.ones((8,)).block_until_ready()
+
+    cap = xprof.start_capture(log_dir=str(tmp_path))
+    try:
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    finally:
+        cap.stop_capture()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = [e for plane in ProfileData.from_file(path).planes if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events if e.name == "rt.test.traced"]
+    assert len(events) == 3
+    stats = [dict(e.stats) for e in events]
+    assert sorted(int(s["slots"]) for s in stats) == [0, 1, 2]
+    assert all(str(s["rid"]) == "req-7" for s in stats)
+    assert all(e.duration_ns > 0 for e in events)
