@@ -21,11 +21,13 @@ prefill adds ZERO new compiled programs.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import logging
 import math
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +60,21 @@ from ray_tpu.models.transformer import ModelConfig, _rope
 from ray_tpu.util import xprof
 
 _NEG_INF = -1e30
+
+# Prefix-cache inserts that may be in flight at once (docs/kvcache.md): each
+# holds one gathered [L, 2, bucket, Hkv, D] device buffer until its copy to the
+# host has landed. A further insert first finishes the oldest.
+_MAX_PENDING_KV_INSERTS = 2
+
+
+class _PendingKVInsert(NamedTuple):
+    """An admitted prompt's whole blocks on their way to the prefix cache:
+    `kv` is the gather program's output, its copy to the host already started."""
+
+    tokens: List[int]
+    namespace: int
+    kv: jax.Array  # [L, 2, bucket >= len(tokens), Hkv, D]
+    rid: str
 
 
 @dataclasses.dataclass
@@ -447,6 +464,23 @@ class DecodeEngine:
                     name=f"engine-{id(self):x}",
                 )
         self._prefix_cache = prefix_cache or None
+        # An admitted prompt's rows reach that cache off the stepper's
+        # critical path (`_insert_prompt_kv`): one gather program per prefill
+        # bucket, an asynchronous copy, and the pool insert once the copy has
+        # landed, on a worker thread of the engine's own (`_kv_insert_loop`).
+        # The stepper appends to the queue without a lock; `_kv_lock` is held
+        # from an insert's copy-out until the pool has it and the queue drops
+        # it, so completions keep their order and whoever takes the lock next
+        # sees them done. The counters are plain numbers, reported with
+        # scheduler_stats()["prefix_cache"] (no Metric.inc on the loop).
+        self._jit_kv_gather = {}
+        self._kv_pending: collections.deque = collections.deque()
+        self._kv_lock = threading.Lock()
+        self._kv_wake = threading.Event()
+        self._kv_counters = {
+            "inserts_issued": 0, "inserts_completed": 0,
+            "insert_waits": 0, "insert_wait_s": 0.0,
+        }
         if max_queue_depth is None:
             max_queue_depth = CONFIG.llm_max_queue_depth
         if token_budget is None:
@@ -459,13 +493,7 @@ class DecodeEngine:
         # (docs/multitenancy.md): resident adapters are preferred, cold ones
         # page in at admission, and a fully-pinned cache back-pressures the
         # tenant instead of crashing the stepper.
-        lookup = None
-        if self._prefix_cache is not None:
-            cache = self._prefix_cache
-
-            def lookup(prompt, adapter):
-                return cache.lookup(prompt, namespace=adapter)
-
+        lookup = self._lookup_prefix if self._prefix_cache is not None else None
         adapter_acquire = adapter_resident = None
         if self._adapters is not None:
             adapter_acquire = self._adapters.try_acquire
@@ -527,10 +555,15 @@ class DecodeEngine:
                     tag_keys=("engine",),
                 ).set_default_tags(tag),
             }
-        self._thread = None
+        self._thread = self._kv_thread = None
         if decode_loop:  # prefill-only servers skip the stepper thread
             self._thread = threading.Thread(target=self._loop, daemon=True)
             self._thread.start()
+            if self._prefix_cache is not None:
+                # only a stepper issues inserts for the worker to finish
+                self._kv_thread = threading.Thread(
+                    target=self._kv_insert_loop, daemon=True)
+                self._kv_thread.start()
 
     def _build_draft(self, spec_config: dict, unbox):
         """spec_config -> DraftProvider. method="ngram" builds the zero-FLOP
@@ -822,23 +855,108 @@ class DecodeEngine:
 
     def _insert_prompt_kv(self, slot: int, prompt: List[int], adapter: int,
                           cached_offset: int, rid: str = ""):
-        """Populate the prefix cache from the slot's freshly prefilled rows.
-        Skips when the prompt has no full block beyond what the cache already
-        held (cached_offset tokens)."""
+        """Start the copy of the slot's freshly prefilled whole blocks to the
+        prefix cache, and do not wait for it. Skips when the prompt has no
+        full block beyond what the cache already held (cached_offset tokens).
+
+        One gather program (`rt_kv_gather_b<bucket>`, one per prefill bucket)
+        puts the slot's rows [0, bucket) of every layer into one array in the
+        pool's layout, and one asynchronous copy takes it to the host. The
+        rows beyond the prompt's whole blocks are padding that the pool's
+        insert ignores; the already-cached prefix rides along (the radix walk
+        dedups it without copying). The device runs the gather before the
+        next decode dispatch (stream order) and nothing rewrites rows [0, n)
+        of a running slot, so the copy holds the prompt's bytes. The worker
+        thread hands them to the pool when the copy has landed; a lookup that
+        comes sooner finishes the insert itself (`_finish_kv_inserts`), and a
+        third pending insert first waits for the oldest."""
         bs = self._prefix_cache.block_size
         n = (len(prompt) // bs) * bs
         if n == 0 or n <= cached_offset:
             return
-        # Host readback of rows [0, n): [L, 2, n, Hkv, D]. The already-cached
-        # prefix rides along (the radix walk dedups it without copying). One
-        # bulk pull per INSERT (per admitted prompt), amortized by every
-        # future hit skipping the prefix's prefill FLOPs entirely.
+        self._finish_kv_inserts(keep=_MAX_PENDING_KV_INSERTS - 1)
         with xprof.span("rt.engine.kv_insert", rid=rid, rows=n):
-            kv = np.stack([
-                np.stack([np.asarray(ck[slot, :n]), np.asarray(cv[slot, :n])])  # raylint: disable=RL603 (bulk per-insert readback, not per-step)
-                for ck, cv in self._caches
-            ])
-            self._prefix_cache.insert(prompt[:n], kv, namespace=adapter)
+            bucket = self._bucket(n)
+            gather = self._program(
+                self._jit_kv_gather, ("kv_gather", bucket),
+                lambda: jax.jit(
+                    _named(f"rt_kv_gather_b{bucket}", self._gather_slot_kv,
+                           rows=bucket),
+                    out_shardings=self.kv_transfer_sharding,
+                ),
+            )
+            kv = gather(self._caches, jnp.int32(slot))
+            kv.copy_to_host_async()
+            self._kv_pending.append(_PendingKVInsert(prompt[:n], adapter, kv, rid))
+            self._kv_counters["inserts_issued"] += 1
+        self._kv_wake.set()
+
+    @staticmethod
+    def _gather_slot_kv(caches, slot, *, rows: int):
+        """Slot `slot`'s cache rows [0, rows) of every layer as one array in
+        the prefix pool's layout, [L, 2, rows, Hkv, D] in the caches' dtype.
+        The caches are read, not consumed: no donation."""
+
+        def take(c):
+            return jax.lax.dynamic_slice(
+                c, (slot, 0, 0, 0), (1, rows) + c.shape[2:])[0]
+
+        return jnp.stack([jnp.stack([take(ck), take(cv)]) for ck, cv in caches])
+
+    def _finish_kv_inserts(self, keep: int = 0, worker: bool = False):
+        """Hand pending inserts to the prefix pool, oldest first, until `keep`
+        are left, blocking until each one's copy has landed. The worker thread
+        does this for every insert (span `rt.engine.kv_copy`). Anyone else is
+        a caller that cannot go on before they are done: a lookup, which must
+        see every insert issued before it, or a third insert. It waits for
+        the worker to let go of the lock, finishes what is left itself (span
+        `rt.engine.kv_insert`), and the wait is counted."""
+        if len(self._kv_pending) <= keep:
+            return
+        t0 = time.perf_counter()
+        with self._kv_lock:
+            while len(self._kv_pending) > keep:
+                if worker and self._stop:
+                    return  # shutdown drops the rest
+                tokens, namespace, kv, rid = self._kv_pending[0]
+                try:
+                    with xprof.span("rt.engine.kv_copy" if worker else "rt.engine.kv_insert",
+                                    rid=rid, rows=len(tokens)):
+                        self._prefix_cache.insert(tokens, np.asarray(kv), namespace=namespace)
+                    self._kv_counters["inserts_completed"] += 1
+                finally:
+                    self._kv_pending.popleft()  # a failed insert is not tried again
+            if not worker:
+                self._kv_counters["insert_waits"] += 1
+                self._kv_counters["insert_wait_s"] += time.perf_counter() - t0
+
+    def _kv_insert_loop(self):
+        """The worker thread: sleeps until the stepper has issued an insert,
+        then waits for its copy and gives it to the pool, so that the stepper
+        never does. It ends with the engine (`shutdown`)."""
+        while True:
+            self._kv_wake.wait()
+            self._kv_wake.clear()
+            if self._stop:
+                return
+            try:
+                self._finish_kv_inserts(worker=True)
+            except Exception:  # noqa: BLE001 - a lost cache entry must not end the worker
+                logging.getLogger(__name__).exception(
+                    "prefix-cache insert failed; the prompt stays uncached")
+
+    def _drop_kv_inserts(self):
+        """Forget pending inserts and free their device buffers (shutdown,
+        stepper death): nobody will look the prompts up."""
+        with self._kv_lock:
+            while self._kv_pending:
+                self._kv_pending.popleft().kv.delete()
+
+    def _lookup_prefix(self, prompt: List[int], adapter: int):
+        """The scheduler's admission lookup: every insert issued before it is
+        in the cache first, so the same prompt sent twice back to back hits."""
+        self._finish_kv_inserts()
+        return self._prefix_cache.lookup(prompt, namespace=adapter)
 
     def _kv_block_to_device(self, host_kv):
         """Hot-tier promotion copy: one [L, 2, bs, Hkv, D] block onto this
@@ -858,7 +976,12 @@ class DecodeEngine:
         llm_kv_tier_* metric deltas flush here. See docs/kvcache.md."""
         if self._prefix_cache is None:
             return None
-        return self._prefix_cache.stats()
+        out = self._prefix_cache.stats()
+        # The insert path's own counts (plain reads; docs/kvcache.md): inserts
+        # whose copy was started, those the pool has, those in flight now, and
+        # how often and how long a lookup or a third insert waited for one.
+        out.update(self._kv_counters, inserts_pending=len(self._kv_pending))
+        return out
 
     # -- cluster prefix plane (docs/kvcache.md) -----------------------------
     def lease_prefix(self, token_ids: List[int], lora: str = ""):
@@ -869,6 +992,7 @@ class DecodeEngine:
         transfer's send leg is done."""
         if self._prefix_cache is None:
             return None
+        self._finish_kv_inserts()
         return self._prefix_cache.lease_prefix(
             token_ids, namespace=self._adapter_index(lora)
         )
@@ -904,7 +1028,7 @@ class DecodeEngine:
         if self._prefix_cache is not None:
             # Report-path flush of the cache counters incl. the tiered
             # llm_kv_tier_* metric deltas (never from the decode loop).
-            out["prefix_cache"] = self._prefix_cache.stats()
+            out["prefix_cache"] = self.prefix_cache_stats()
         if self._draft is not None:
             spec = dict(self._spec_counters)
             spec["accept_rate"] = (
@@ -933,6 +1057,7 @@ class DecodeEngine:
         out["programs"] = self._xprof.report(owner=self._xprof_owner)
         # Where the stepper thread's time went, by `rt.engine.*` span: count
         # and seconds since the process started (plain reads of a host table).
+        # `rt.engine.kv_copy` alone is another thread's, the insert worker's.
         out["loop"] = xprof.span_totals()
         out["memory"] = xprof.device_memory_report()
         # What is being served, by its widths: a replica that came up on another
@@ -1399,7 +1524,7 @@ class DecodeEngine:
             lease = None
             tier = "host"
             if self._prefix_cache is not None:
-                lease = self._prefix_cache.lookup(prompt, namespace=adapter)
+                lease = self._lookup_prefix(prompt, adapter)
             if lease is not None:
                 # finally, not straight-line: a raise out of kv() or the suffix
                 # prefill would otherwise pin the leased blocks forever (the
@@ -1584,8 +1709,10 @@ class DecodeEngine:
         their callbacks fire (token=-1, finished=True) so submitters blocked
         on generation unwind instead of hanging."""
         self._stop = True
-        if self._thread is not None:
-            self._thread.join(timeout=5)
+        self._kv_wake.set()
+        for thread in (self._thread, self._kv_thread):
+            if thread is not None:
+                thread.join(timeout=5)
         for slot in self._sched.slots:
             self._release_slot_pin(slot)  # adapter pins die with the engine
             self._release_slot_constraint(slot)
@@ -1606,6 +1733,7 @@ class DecodeEngine:
         # and span handles balance on engine shutdown by construction —
         # leaksan's flight_record books prove it.
         self._recorder.close()
+        self._drop_kv_inserts()
         close_cache = getattr(self._prefix_cache, "close", None)
         if close_cache is not None:
             close_cache()  # tiered cache: flush + stop the kv-spill worker
@@ -1994,6 +2122,7 @@ class DecodeEngine:
                     except Exception:
                         pass
             self._recorder.close()  # stepper death strands no live records
+            self._drop_kv_inserts()
 
     def _loop_inner(self):
         """Execute one scheduler plan per iteration: prefill chunks, then

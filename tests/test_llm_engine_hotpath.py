@@ -279,6 +279,212 @@ def test_multi_step_decode_single_pull_per_chunk(monkeypatch):
         engine.shutdown()
 
 
+# ---- the prefix-cache insert (docs/kvcache.md "the insert path") -------------
+
+
+def _cache_engine(monkeypatch, **kwargs):
+    """An engine with a prefix cache of 4-token blocks and 4, 8, 16, ... buckets."""
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    monkeypatch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+    kwargs.setdefault("prefix_cache", PrefixCacheManager(4, 1 << 20, name="hotpath"))
+    return _tiny_engine(num_slots=2, max_seq=64, multi_step=1, **kwargs)
+
+
+def _settled(engine):
+    """The engine once the worker thread has nothing left to finish."""
+    engine._finish_kv_inserts()
+    return engine
+
+
+def _compiles(engine):
+    return engine._xprof.report(owner=engine._xprof_owner)["totals"]["compiles_total"]
+
+
+def test_prefix_insert_is_one_pull_and_one_program_per_bucket(monkeypatch):
+    """An admission with the prefix cache on costs ONE device->host pull for the
+    insert (not 2 x n_layers), through one `rt_kv_gather` program per prefill
+    bucket: a warm engine builds nothing for a further insert of a seen bucket,
+    padded or not."""
+    from ray_tpu.llm import _engine as engine_mod
+
+    spy = _NpSpy()
+    monkeypatch.setattr(engine_mod, "np", spy)
+    engine = _cache_engine(monkeypatch)
+    try:
+        out = _generate(engine, list(range(1, 14)), max_tokens=4)  # 12 rows -> bucket 16
+        assert len(out) == 4
+        assert _settled(engine)._kv_thread.is_alive()
+        assert spy.device_pulls == 4 + 1  # 1 admission + 3 decode steps + 1 insert
+        assert list(engine._jit_kv_gather) == [("kv_gather", 16)]
+        pulls, compiles = spy.device_pulls, _compiles(engine)
+        _generate(engine, list(range(21, 35)), max_tokens=4)       # 12 rows again
+        _generate(engine, list(range(41, 57)), max_tokens=4)       # 16 rows: the bucket, unpadded
+        assert _settled(engine) and spy.device_pulls == pulls + 2 * (4 + 1)
+        assert _compiles(engine) == compiles, "a warm insert built a program"
+        _generate(engine, list(range(61, 70)), max_tokens=4)       # 8 rows -> bucket 8
+        assert sorted(engine._jit_kv_gather) == [("kv_gather", 8), ("kv_gather", 16)]
+        rows = engine._xprof.report(owner=engine._xprof_owner)["programs"]
+        gathers = [r for r in rows if isinstance(r["key"], tuple) and r["key"][0] == "kv_gather"]
+        assert len(gathers) == 2 and all(r["compiles"] == 1 for r in gathers), gathers
+        assert {r["key"][1] for r in gathers} <= set(engine._prefill_buckets)
+        stats = _settled(engine).scheduler_stats()["prefix_cache"]
+        assert stats["inserts_issued"] == stats["inserts_completed"] == 4
+        assert stats["inserts_pending"] == 0 and stats["inserted_blocks"] == 3 + 3 + 4 + 2
+    finally:
+        engine.shutdown()
+
+
+def test_same_prompt_back_to_back_hits_on_the_second(monkeypatch):
+    """A lookup sees every insert issued before it, with no sleep anywhere: the
+    second send of a prompt attaches the first one's blocks."""
+    from ray_tpu.util import xprof
+
+    engine = _cache_engine(monkeypatch)
+    prompt = list(range(1, 14))
+    try:
+        attaches = xprof.span_totals().get("rt.engine.attach", {"count": 0})["count"]
+        first = _generate(engine, prompt, max_tokens=3)
+        assert engine.last_attach is None
+        second = _generate(engine, prompt, max_tokens=3)
+        assert second == first
+        assert engine.last_attach == {"tier": "host", "cached_tokens": 12}
+        assert xprof.span_totals()["rt.engine.attach"]["count"] == attaches + 1
+        stats = engine.prefix_cache_stats()
+        assert stats["hits"] == 1 and stats["hit_tokens"] == 12
+        # the second prompt's whole blocks were all cached: nothing more to insert
+        assert stats["inserts_issued"] == stats["inserts_completed"] == 1
+    finally:
+        engine.shutdown()
+
+
+def test_a_lookup_finishes_the_pending_inserts_first(monkeypatch):
+    """With no stepper there is no worker thread, and inserts stay pending until something looks:
+    the scheduler's admission lookup, `lease_prefix` and the detached prefill's
+    lookup each finish them first, and the wait is counted."""
+    engine = _cache_engine(monkeypatch, decode_loop=False)
+    a, b, c = list(range(1, 14)), list(range(21, 30)), list(range(41, 46))
+    try:
+        engine._insert_prompt_kv(0, a, 0, 0, rid="a")
+        stats = engine.prefix_cache_stats()
+        assert (stats["inserts_issued"], stats["inserts_pending"], stats["inserted_blocks"]) == (1, 1, 0)
+        lease = engine._sched._lookup(a, 0)
+        assert lease is not None and lease.matched_tokens == 12
+        lease.release()
+        engine._insert_prompt_kv(1, b, 0, 0, rid="b")
+        lease = engine.lease_prefix(b)
+        assert lease is not None and lease.matched_tokens == 8
+        lease.release()
+        engine._insert_prompt_kv(0, c, 0, 0, rid="c")
+        engine.prefill_detached(c + [7, 8])
+        assert engine.last_prefill["offset"] == 4
+        stats = engine.prefix_cache_stats()
+        assert stats["inserts_completed"] == 3 and stats["inserts_pending"] == 0
+        assert stats["insert_waits"] == 3 and stats["insert_wait_s"] >= 0.0
+    finally:
+        engine.shutdown()
+
+
+def test_a_third_insert_waits_for_the_oldest_and_shutdown_drops_the_rest(monkeypatch):
+    """At most two inserts are in flight: the third finishes the oldest first
+    (counted). `shutdown()` with inserts pending frees their device buffers."""
+    engine = _cache_engine(monkeypatch, decode_loop=False)
+    prompts = [list(range(s, s + 9)) for s in (1, 21, 41)]
+    try:
+        for slot, prompt in enumerate(prompts[:2]):
+            engine._insert_prompt_kv(slot, prompt, 0, 0)
+        stats = engine.prefix_cache_stats()
+        assert (stats["inserts_pending"], stats["insert_waits"]) == (2, 0)
+        engine._insert_prompt_kv(0, prompts[2], 0, 0)
+        stats = engine.prefix_cache_stats()
+        assert (stats["inserts_issued"], stats["inserts_completed"], stats["inserts_pending"],
+                stats["insert_waits"]) == (3, 1, 2, 1)
+        assert engine._prefix_cache.lookup(prompts[0]) is not None   # the oldest is in
+        assert engine._prefix_cache.lookup(prompts[1]) is None       # the others not yet
+        buffers = [p.kv for p in engine._kv_pending]
+        assert len(buffers) == 2 and not any(b.is_deleted() for b in buffers)
+    finally:
+        engine.shutdown()
+    assert not engine._kv_pending and all(b.is_deleted() for b in buffers)
+    assert engine.prefix_cache_stats()["inserts_completed"] == 1
+
+
+def test_inserts_survive_submitters_and_lookups_racing_the_worker(monkeypatch):
+    """Stepper, insert worker, submitting threads and threads that lease prefixes all
+    touch the pending queue at once, with the interpreter switching threads every 10 us:
+    no insert is lost or made twice, and every prompt's blocks are in the pool."""
+    import sys
+
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    engine = _cache_engine(monkeypatch, prefix_cache=PrefixCacheManager(4, 8 << 20, name="race"))
+    prompts = [[100 + 7 * i + j for j in range(9 + i % 8)] for i in range(24)]
+    stop, errors = threading.Event(), []
+
+    def submitter(mine):
+        try:
+            for prompt in mine:
+                assert len(_generate(engine, prompt, max_tokens=2)) == 2
+        except BaseException as e:  # noqa: BLE001 - reported by the main thread
+            errors.append(e)
+
+    def leaser():
+        try:
+            while not stop.is_set():
+                for prompt in prompts[::5]:
+                    lease = engine.lease_prefix(prompt)
+                    if lease is not None:
+                        lease.release()
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [threading.Thread(target=submitter, args=(prompts[i::8],)) for i in range(8)]
+        leasers = [threading.Thread(target=leaser) for _ in range(4)]
+        for t in workers + leasers:
+            t.start()
+        for t in workers:
+            t.join(240)
+        stop.set()
+        for t in leasers:
+            t.join(60)
+        assert not any(t.is_alive() for t in workers + leasers) and not errors, errors
+        stats = _settled(engine).prefix_cache_stats()
+        assert stats["inserts_issued"] == stats["inserts_completed"] == len(prompts)
+        assert stats["inserts_pending"] == 0 and stats["leases_active"] == 0
+        assert stats["inserted_blocks"] == sum(len(p) // 4 for p in prompts)
+        for prompt in prompts:
+            lease = engine._prefix_cache.lease_prefix(prompt)
+            assert lease is not None and lease.matched_tokens == (len(prompt) // 4) * 4
+            lease.release()
+    finally:
+        sys.setswitchinterval(interval)
+        engine.shutdown()
+
+
+def test_shutdown_with_an_insert_in_flight_leaves_no_thread_and_no_buffer(monkeypatch):
+    """The worker is stopped in the middle of its work (the test holds its lock, so
+    the insert of the running request stays in flight): `shutdown()` ends both
+    threads, frees the gathered buffer and never hands it to the pool."""
+    threads = threading.active_count()
+    engine = _cache_engine(monkeypatch)
+    assert threading.active_count() == threads + 2  # the stepper and the insert worker
+    with engine._kv_lock:
+        _generate(engine, list(range(1, 14)), max_tokens=2)
+        (pending,) = engine._kv_pending
+        engine._stop = True          # shutdown()'s first two steps, while the lock is held
+        engine._kv_wake.set()
+    engine.shutdown()
+    assert not engine._thread.is_alive() and not engine._kv_thread.is_alive()
+    assert threading.active_count() == threads
+    assert not engine._kv_pending and pending.kv.is_deleted()
+    stats = engine.prefix_cache_stats()
+    assert (stats["inserts_issued"], stats["inserts_completed"], stats["inserted_blocks"]) == (1, 0, 0)
+
+
 # ---- rt.engine.* spans (docs/observability.md "compute plane") --------------
 
 _ITER_CHILDREN = ("rt.engine.prefill", "rt.engine.attach", "rt.engine.kv_insert",
@@ -376,8 +582,14 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
             for e in line.events:
                 if e.name.startswith("rt.engine."):
                     events.setdefault(e.name, []).append((e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
-    assert set(events) == set(_ENGINE_SPANS), sorted(set(_ENGINE_SPANS) ^ set(events))
+    assert set(events) - {"rt.engine.kv_copy"} == set(_ENGINE_SPANS), sorted(set(_ENGINE_SPANS) ^ set(events))
     iters = events["rt.engine.iter"]
+    # An insert is the gather's dispatch, a `rt.engine.kv_insert` inside the prompt's last
+    # chunk, and its hand-over to the pool: `rt.engine.kv_copy` on the worker thread, or a
+    # second `rt.engine.kv_insert` inside the `rt.engine.plan` of a lookup that came first.
+    handed = events.pop("rt.engine.kv_copy", []) + events["rt.engine.kv_insert"][1:]
+    del events["rt.engine.kv_insert"][1:]
+    assert len(handed) == 1 and int(handed[0][2]["rows"]) == 12, handed
     for name in _ITER_CHILDREN:
         for a, b, _ in events[name]:
             assert any(i0 <= a and b <= i1 for i0, i1, _ in iters), name
@@ -389,7 +601,7 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
     assert all(int(s["rows"]) > 0 and int(s["slots"]) >= 1 for _, _, s in events["rt.engine.dispatch"])
     assert {str(s["rid"]) for _, _, s in events["rt.engine.attach"]} == {"req-hit"}
     assert "req-hit" in {str(s["rid"]) for _, _, s in events["rt.engine.prefill"]}
-    assert all(int(s["rows"]) == 12 for _, _, s in events["rt.engine.kv_insert"][:1])
+    assert all(int(s["rows"]) == 12 for _, _, s in events["rt.engine.kv_insert"])
     assert all(int(s["bytes"]) > 0 for _, _, s in events["rt.engine.readback"])
 
 

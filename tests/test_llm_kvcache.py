@@ -224,6 +224,65 @@ def test_cached_greedy_matches_uncached(tiny_model):
         cached.shutdown()
 
 
+@pytest.mark.parametrize("prompt_len,bucket", [(21, 32), (32, 32), (9, 8)])
+def test_inserted_blocks_equal_the_slots_rows_bit_for_bit(tiny_model, monkeypatch, prompt_len, bucket):
+    """What the gather program and its asynchronous copy hand to the pool is
+    `caches[l][k][slot, :n]`, bit for bit, whether the bucket pads the rows
+    (20 of 32), holds exactly them (32 of 32) or the prompt's whole blocks fill
+    a smaller bucket than its prefill did (8 rows of a 9-token prompt)."""
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import DecodeEngine
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    cfg, model, params = tiny_model
+    monkeypatch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+    rng = np.random.default_rng(prompt_len)
+    warm = list(map(int, rng.integers(0, cfg.vocab_size, 6)))
+    prompt = list(map(int, rng.integers(0, cfg.vocab_size, prompt_len)))
+    n = (prompt_len // 4) * 4
+    engine = DecodeEngine(cfg, params, num_slots=2, max_seq=64,
+                          prefix_cache=PrefixCacheManager(4, 1 << 20, name="bits"))
+    try:
+        # A request whose first callback blocks the stepper holds slot 0 while the
+        # prompt under test is queued, so that one is prefilled into slot 1: the
+        # gather reads the slot it is given, not the first.
+        from ray_tpu.llm import SamplingParams
+
+        hold, started = threading.Event(), threading.Event()
+        done = [threading.Event(), threading.Event()]
+
+        def first(tok, fin):
+            started.set()
+            hold.wait(60)
+            if fin:
+                done[0].set()
+
+        engine.submit(warm, SamplingParams(max_tokens=2), first)
+        assert started.wait(180), engine.error
+        engine.submit(prompt, SamplingParams(max_tokens=3),
+                      lambda tok, fin: fin and done[1].set())
+        hold.set()
+        assert done[0].wait(180) and done[1].wait(180), engine.error
+        assert list(engine._jit_kv_gather) == [("kv_gather", 4), ("kv_gather", bucket)]
+        lease = engine.lease_prefix(prompt)
+        assert lease is not None and lease.matched_tokens == n
+        try:
+            got = lease.kv()
+        finally:
+            lease.release()
+        slot = 1
+        assert int(engine._lens[slot]) == prompt_len + 3 - 1  # it did run there
+        want = np.stack([
+            np.stack([np.asarray(ck[slot, :n]), np.asarray(cv[slot, :n])])
+            for ck, cv in engine._caches
+        ])
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.abs(want).max() > 0
+        np.testing.assert_array_equal(got, want)
+    finally:
+        engine.shutdown()
+
+
 def test_pd_transfer_feeds_decode_cache(tiny_model):
     """A transferred prefix (submit_prefilled + token_ids) lands in the decode
     engine's pool and serves later direct submits suffix-only."""

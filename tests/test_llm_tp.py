@@ -171,6 +171,47 @@ def test_decode_plane_is_mesh_sharded():
         eng.shutdown()
 
 
+@needs_mesh
+def test_prefix_insert_under_a_mesh_gathers_sharded_and_pulls_once(monkeypatch):
+    """The prefix-cache insert of a TP engine is the same one path: the gather
+    program's key carries the mesh, its output stays sharded on kv heads
+    (kv_prefix_sharding), the one host pull assembles it, and the pool gets the
+    slot's rows bit for bit, so the prompt's second send attaches them."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm._engine import DecodeEngine
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    monkeypatch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+    cfg, params = _model(n_kv_heads=4)
+    eng = DecodeEngine(cfg, params, num_slots=2, max_seq=64, tp=2,
+                       prefix_cache=PrefixCacheManager(4, 1 << 20, name="tp-insert"))
+    prompt = list(range(1, 14))
+    try:
+        first = _generate(eng, prompt, n=3)
+        ((key, gather),) = eng._jit_kv_gather.items()
+        assert key[0][0] == "mesh" and key[1] == ("kv_gather", 16), key
+        kv = gather(eng._caches, np.int32(0))
+        assert kv.shape == (cfg.n_layers, 2, 16, 4, cfg.head_dim)
+        assert kv.sharding.spec == P(None, None, None, "tp", None)
+        lease = eng.lease_prefix(prompt)
+        assert lease is not None and lease.matched_tokens == 12
+        try:
+            got = lease.kv()
+        finally:
+            lease.release()
+        want = np.stack([np.stack([np.asarray(ck[0, :12]), np.asarray(cv[0, :12])])
+                         for ck, cv in eng._caches])
+        np.testing.assert_array_equal(got, want)
+        assert _generate(eng, prompt, n=3) == first
+        assert eng.last_attach["cached_tokens"] == 12
+        stats = eng.prefix_cache_stats()
+        assert stats["inserts_issued"] == stats["inserts_completed"] == 1
+    finally:
+        eng.shutdown()
+
+
 # -- adapter paging churn under TP -------------------------------------------
 
 @needs_mesh

@@ -35,8 +35,8 @@ def _lowered(prog, *args):
 
 @pytest.fixture(scope="module")
 def engines():
-    """One engine that has run chunked prefill, a prefix-cache attach, single and
-    multi-step decode and both detached prefills; one with a model draft."""
+    """One engine that has run chunked prefill, a prefix-cache insert and attach, single
+    and multi-step decode and both detached prefills; one with a model draft."""
     from ray_tpu._private.config import CONFIG
     from ray_tpu.llm import DecodeEngine, SamplingParams
     from ray_tpu.llm.kvcache import PrefixCacheManager
@@ -87,6 +87,7 @@ def _engine_programs(plain, spec):
             out.append((prog, (plain.params, None, np.zeros((1, key[1]), np.int32), i32)))
         elif key[0] == "detached_suffix":
             out.append((prog, (plain.params, None, kv(key[1]), np.zeros((1, key[2]), np.int32), i32, i32)))
+    out += [(prog, (plain._caches, i32)) for prog in plain._jit_kv_gather.values()]
     for key, prog in spec._jit_spec_verify.items():
         S = key[1]
         out.append((prog, (spec.params, None, vec, np.zeros((B, S), np.int32), spec._caches, vec,
@@ -108,7 +109,7 @@ def test_every_engine_program_has_a_name_of_its_own_and_the_models_scopes(engine
         names[module] = scopes
     patterns = {
         r"jit_rt_decode": 1, r"jit_rt_decode_multi_n\d+": 1, r"jit_rt_prefill_b\d+": 1,
-        r"jit_rt_attach_b\d+": 1, r"jit_rt_prefill_detached_b\d+": 1,
+        r"jit_rt_attach_b\d+": 1, r"jit_rt_kv_gather_b\d+": 1, r"jit_rt_prefill_detached_b\d+": 1,
         r"jit_rt_prefill_detached_suffix_b\d+_\d+": 1, r"jit_rt_verify_s3": 1,
         r"jit_rt_draft_prefill_b\d+": 1, r"jit_rt_draft_propose_k2(_catchup)?": 1,
     }
@@ -117,9 +118,10 @@ def test_every_engine_program_has_a_name_of_its_own_and_the_models_scopes(engine
         assert len(found) >= at_least, (pattern, sorted(names))
     assert all(any(re.fullmatch(p, n) for p in patterns) for n in names), sorted(names)
     # a program that runs the model carries every scope of the list; the multi-step and
-    # verify programs also `sample` on the device; an attach runs no model
+    # verify programs also `sample` on the device; an attach and the prefix-cache
+    # insert's gather run no model
     for module, scopes in names.items():
-        if "attach" in module:
+        if "attach" in module or "kv_gather" in module:
             continue
         want = set(MODEL_SCOPES)
         if "draft_prefill" in module:
