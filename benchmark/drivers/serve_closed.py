@@ -14,7 +14,7 @@ from __future__ import annotations
 import asyncio
 import time
 
-from lib import arrivals, serving
+from lib import arrivals, hostwatch, serving
 
 
 def run(ctx) -> dict:
@@ -51,11 +51,13 @@ def run(ctx) -> dict:
         before = await serving.counters(server, ctx)
         setup_s = ctx.since_start()
         w0 = time.monotonic()
+        host = hostwatch.start(ticker=not ctx.trace)
         # the window's last seconds: in a closed loop every slot is taken there as anywhere
         tracer = (asyncio.create_task(serving.trace_span(ctx, w0 + ctx.seconds - float(tr["trace_seconds"])))
                   if ctx.trace else None)
         await asyncio.sleep(ctx.seconds)
         w1 = time.monotonic()
+        notes.append(host.stop())
         after = await serving.counters(server, ctx)
         stop.set()
         if tracer is not None:

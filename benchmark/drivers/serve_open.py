@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from lib import arrivals, serving
+from lib import arrivals, hostwatch, serving
 
 
 def schedule(tr: dict, seconds: float, seed: int, vocab: int) -> list:
@@ -83,7 +83,9 @@ def run(ctx) -> dict:
             await asyncio.sleep(max(0.0, w0 - time.monotonic()))
             marks["before"] = await serving.counters(server, ctx)
             marks["setup_s"] = ctx.since_start()
+            host = hostwatch.start(ticker=not ctx.trace)
             await asyncio.sleep(max(0.0, w1 - time.monotonic()))
+            notes.append(host.stop())
             marks["after"] = await serving.counters(server, ctx)
 
         marker = asyncio.create_task(mark_window())

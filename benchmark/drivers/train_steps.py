@@ -1,8 +1,15 @@
-"""Driver `train_steps`: the SPMD train step on a one-device mesh, as `bench.py` calls it.
+"""Driver `train_steps`: the SPMD train step, as `bench.py` calls it, on the mesh the
+traffic file gives: `mesh` is an object of axis sizes as `parallel/mesh.create_mesh`
+takes them (`{"fsdp": 4}`; absent: `{"dp": 1}`, one device), built over the cell's
+chips. Where the product of the axes is not the cell's `chips` there is no run. `batch`
+is the global count of sequences a step, placed with the shardings `build_train_step`
+returns.
 
-Set-up: `init_state` under jit from the seed, the plain reference's loss on the first
-batch (before the first step consumes the parameters), then one step, which compiles
-and whose loss must agree with the reference. Window: steps with the state threaded
+Set-up: `init_state` under jit from the seed, the plain reference's loss at every token
+of the first batch (before the first step consumes the parameters), the program's own
+forward pass on one sequence a chip, whose tokens' losses must agree with the
+reference's, then one step, which compiles and whose loss must agree with the
+reference's mean. Window: steps with the state threaded
 through them, a new seeded batch each made by numpy while the device runs, the host
 never more than `dispatch_ahead` steps in front, one sync at the end. The traced run
 blocks every step so that each has a host time, and takes a profiler window at the end.
@@ -10,6 +17,7 @@ blocks every step so that each has a host time, and takes a profiler window at t
 
 from __future__ import annotations
 
+import math
 import time
 
 import numpy as np
@@ -29,16 +37,20 @@ def run(ctx) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from lib import arrivals, reference
+    from lib import arrivals, blocks
     from ray_tpu.models.transformer import Transformer
     from ray_tpu.parallel import mesh as mesh_lib
-    from ray_tpu.parallel.spmd import build_train_step, init_state
+    from ray_tpu.parallel.spmd import build_train_step, eval_logits_fn, init_state
 
     tr = ctx.traffic
     batch, seq = tr["batch"], tr["seq"]
+    chips, axes = ctx.cell["chips"], dict(tr.get("mesh") or {"dp": 1})
+    if math.prod(axes.values()) != chips:
+        raise SystemExit(f"the traffic's mesh {axes} is not the cell's {chips} chips")
+    reference = blocks.reference(ctx.config)
     cfg = ctx.model_config(attention=tr["attention"])
     model = Transformer(cfg)
-    mesh = mesh_lib.create_mesh({"dp": 1}, devices=ctx.devices[:ctx.cell["chips"]])
+    mesh = mesh_lib.create_mesh(axes, devices=ctx.devices[:chips])
     optimizer = _optimizer(tr["optimizer"])
     setup = {}
 
@@ -59,15 +71,32 @@ def run(ctx) -> dict:
             return {"tokens": jax.device_put(ids[:, :-1], shardings["tokens"]),
                     "targets": jax.device_put(ids[:, 1:], shardings["targets"])}
 
-    # The reference's loss on the first batch, with the state's own parameters, before
-    # the first step donates them.
+    # The reference's loss at every token of the first batch, with the state's own
+    # parameters (sharded as the state holds them: the reference runs under jit on that
+    # tree), before the first step donates them.
     t = time.perf_counter()
     first = make_batch()
-    ref_loss_fn = jax.jit(lambda p, x, y: reference.loss(p, ctx.model, x, y))
-    ref_losses = [float(ref_loss_fn(state.params, first["tokens"][b], first["targets"][b]))
-                  for b in range(batch)]
-    ref_loss = float(np.mean(ref_losses))
+    ref_fn = jax.jit(lambda p, x, y: reference.token_losses(p, ctx.model, x, y))
+    ref_tokens = np.stack([np.asarray(ref_fn(state.params, first["tokens"][b], first["targets"][b]))
+                           for b in range(batch)])
+    ref_loss = float(ref_tokens.mean())
     setup["reference_s"] = time.perf_counter() - t
+
+    # The program's own forward pass (`eval_logits_fn`, the module the step differentiates)
+    # on one sequence a chip, token by token against the reference: a mean over a batch
+    # hides a precision that a token's loss shows (`TOKEN_LOSS_RMS_TOL`).
+    t = time.perf_counter()
+    forward = eval_logits_fn(model)
+
+    def own_token_losses(params, tokens, targets):
+        logits = forward(params, tokens).astype(jnp.float32)
+        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return jax.nn.logsumexp(logits, axis=-1) - gold
+
+    with mesh:
+        own_tokens = np.asarray(jax.jit(own_token_losses)(state.params, first["tokens"][:chips], first["targets"][:chips]))
+    token_rms = float(np.sqrt(np.mean((own_tokens - ref_tokens[:chips]) ** 2)))
+    setup["forward_s"] = time.perf_counter() - t
 
     t = time.perf_counter()
     with mesh:
@@ -75,9 +104,12 @@ def run(ctx) -> dict:
         step_loss = float(metrics["loss"])
     setup["first_step_s"] = time.perf_counter() - t
     diff = abs(step_loss - ref_loss)
-    agrees = bool(np.isfinite(step_loss)) and diff <= reference.LOSS_ABS_TOL
+    agrees = (bool(np.isfinite(step_loss)) and diff <= reference.LOSS_ABS_TOL
+              and token_rms <= reference.TOKEN_LOSS_RMS_TOL)
     notes = [f"reference loss {ref_loss:.6f} step loss {step_loss:.6f} |diff| {diff:.2e} "
-             f"(tolerance {reference.LOSS_ABS_TOL:.1e}) agrees={agrees}"]
+             f"(tolerance {reference.LOSS_ABS_TOL:.1e}); forward pass against the reference, rms of the "
+             f"difference of a token's loss over {own_tokens.size} tokens {token_rms:.3e} "
+             f"(tolerance {reference.TOKEN_LOSS_RMS_TOL:.1e}) agrees={agrees}"]
 
     # One more step outside the window: the first call of a donated program can leave
     # a second layout to settle; after it every step is the steady one.
@@ -143,6 +175,7 @@ def run(ctx) -> dict:
         "steps": len(host_losses),
         "tokens_per_step": batch * seq,
         "seq": seq,
+        "mesh": axes,
         "step_rows": step_rows,
         "losses": host_losses,
         "window_compiles": c2["programs"] - c1["programs"],

@@ -7,7 +7,13 @@ seconds, the ledger's `breakdown`); this file reads what the program names itsel
     python benchmark/lib/program_trace.py <trace dir or .xplane.pb>
 
 prints the device-idle seconds of the window by innermost `rt.*` span and the device
-seconds by program and by scope (PERF.md §5 is written from it).
+seconds by program, by scope and by collective operation (PERF.md §5 is written from it).
+
+One device's lines are read: the first (`/device:TPU:0`). In a cell over several chips
+that is right for what these reductions give, which is per chip: an SPMD program runs
+the same operations on every chip, a scope's or a kernel's time is that chip's, and a
+flash call's shapes in its HLO text are already the chip's share of the batch and the
+heads. Busy seconds over all the chips are `lib/trace_reduce.py`'s.
 
 Where things sit in the file (looked at by hand, PERF.md §6, PR 24):
 
@@ -54,6 +60,26 @@ UNSCOPED = "(no scope)"
 SCOPES = ("embedding", "attn_norm", "attn", "mlp_norm", "mlp", "final_norm", "lm_head",
           "loss", "optimizer", "sample")
 KERNEL_PREFIXES = ("flash_fwd", "flash_bwd")  # operations whose HLO text is kept, for their shapes
+# Operations that move data between chips. A v5e's compiled step holds them in four forms
+# (looked at in the four-chip cell's trace and its HLO, PERF.md §6, PR 27): under their own
+# opcode and synchronous (`%all-reduce.66 = ... all-reduce(`, `%all-gather.327`,
+# `%all-to-all.1`); as a fusion the compiler made round one (`%fusion.447 = ... fusion(...),
+# kind=kCustom, calls=%all-reduce-scatter.6`: a reduce-scatter is an all-reduce fused with the
+# slice of the chip's own part); as the two halves of an asynchronous one under its opcode
+# (`%collective-permute-start.1`, `-done.1`); and as the two halves of an asynchronous one the
+# compiler fused, whose name alone says what it is (`%async-collective-start.11`,
+# `%async-collective-done.11`: here the all-gathers of the scanned layers' weights). A
+# synchronous one is on the `XLA Ops` line for as long as it takes: the core waits. Of an
+# asynchronous one that line has only the halves, some microseconds each: the issue, and at
+# the `-done` the wait for whatever has not landed yet. The transfer itself is a span from
+# start to done on another line of the first chip's plane, `Async XLA Ops`, beside the
+# compiler's own `copy-start.N` prefetches; it overlaps the operations line, is not device
+# time, and is not read here. A matmul fusion that carries a gather along with it
+# (`calls=%async_collective_fusion.449`) is computation, and is not counted.
+ASYNC_FUSED = "async-collective"
+COLLECTIVES = ("reduce-scatter", "all-gather", "all-reduce", "collective-permute", "all-to-all", ASYNC_FUSED)
+_COLLECTIVE_OPCODE = re.compile(r" (" + "|".join(COLLECTIVES[:5]) + r")(?:-start|-done)?\(")
+_COLLECTIVE_FUSION = re.compile(r" fusion\(.*calls=%?(all-reduce-scatter|" + "|".join(COLLECTIVES[:5]) + ")")
 
 
 # -- the wire format ----------------------------------------------------------------
@@ -190,6 +216,17 @@ def _event(buf, t0_ns, stat_names=None):
     return mid, t0_ns + offset_ps / 1e3, dur_ps / 1e3, stats
 
 
+def collective_kind(hlo: str):
+    """Which of COLLECTIVES an operation's HLO text is, or None."""
+    head = hlo.split(", metadata=", 1)[0]
+    if short_name(head).startswith(ASYNC_FUSED + "-"):
+        return ASYNC_FUSED
+    m = _COLLECTIVE_OPCODE.search(head) or _COLLECTIVE_FUSION.search(head)
+    if m is None:
+        return None
+    return "reduce-scatter" if m.group(1) == "all-reduce-scatter" else m.group(1)
+
+
 def program_name(module: str) -> str:
     """`jit_rt_decode(682187994369778556)` -> `jit_rt_decode`."""
     return module.split("(", 1)[0]
@@ -198,12 +235,13 @@ def program_name(module: str) -> str:
 def load_events(path: str) -> dict:
     """{"window": [lo, hi] or None, "spans": [[name, start, dur, attrs, thread]],
     "modules": [[program, start, dur]], "ops": [[op, scope path, start, dur]],
-    "hlo": {op: HLO text} for the kernels}: the first device's, and the host's `rt.*`
-    spans and `bench.window`."""
+    "hlo": {op: HLO text} for the kernels, "collectives": {op: kind} for the operations
+    that move data between chips}: the first device's, and the host's `rt.*` spans and
+    `bench.window`."""
     with open(path, "rb") as f:
         space = memoryview(f.read())
     planes = [_plane(val) for num, _, val in _fields(space) if num == 1]
-    out = {"window": None, "spans": [], "modules": [], "ops": [], "hlo": {}}
+    out = {"window": None, "spans": [], "modules": [], "ops": [], "hlo": {}, "collectives": {}}
     devices = sorted(p["name"] for p in planes if p["name"].startswith(DEVICE_PREFIX))
     for plane in planes:
         if plane["name"] == HOST_PLANE:
@@ -238,6 +276,10 @@ def load_events(path: str) -> dict:
                         out["ops"].append([op, stats.get("tf_op", ""), start, dur])
                         if op.startswith(KERNEL_PREFIXES):
                             out["hlo"][op] = hlo
+                        elif op not in out["collectives"]:
+                            kind = collective_kind(hlo)
+                            if kind is not None:
+                                out["collectives"][op] = kind
     out["spans"].sort(key=lambda e: e[1])
     out["modules"].sort(key=lambda e: e[1])
     out["ops"].sort(key=lambda e: e[2])
@@ -443,19 +485,42 @@ def decode_ms_per_step(events):
     return sum(m[2] for m in runs) / 1e6 / steps if steps else None
 
 
+def device_seconds_by_collective(events, with_scope: bool = False):
+    """Device self seconds in the window by kind of collective operation (COLLECTIVES), or
+    by kind and the scope it serves (`all-reduce in loss`): the time the core spends in
+    them or waiting for them, which is the part of the communication that no computation
+    hides. {} where the program has none (one chip), None without a window."""
+    w = window_of(events)
+    if w is None:
+        return None
+    kinds, out = events.get("collectives") or {}, {}
+    for op, path, ns in self_times(events["ops"], *w):
+        if op in kinds:
+            key = f"{kinds[op]} in {scope_of(path)}" if with_scope else kinds[op]
+            out[key] = out.get(key, 0.0) + ns / 1e9
+    return out
+
+
+def ms_per_step(events, seconds: float):
+    """`seconds` of the window as milliseconds a step: over the executions of the step
+    program in the window, those cut by its edges counting for their part. None where no
+    whole step lies inside."""
+    program, w = step_program(events), window_of(events)
+    if program is None:
+        return None
+    steps = sum(min(s + d, w[1]) - max(s, w[0]) for p, s, d in executions(events, program, whole=False) if p == program)
+    whole = [d for p, _, d in executions(events, program) if p == program]
+    if not whole:
+        return None
+    return 1e3 * seconds / (steps / (sum(whole) / len(whole)))
+
+
 def scope_ms_per_step(events, scopes):
     """Device self milliseconds a step under the given scopes: the window's total over
     the executions of the step program in it. None where no such operation ran."""
-    by_scope, program = device_seconds_by_scope(events), step_program(events)
-    if not by_scope or program is None:
-        return None
-    w = window_of(events)
-    steps = sum(min(s + d, w[1]) - max(s, w[0]) for p, s, d in executions(events, program, whole=False) if p == program)
-    whole = [d for p, _, d in executions(events, program) if p == program]
-    total = sum(by_scope.get(scope, 0.0) for scope in scopes)
-    if not whole or total <= 0:
-        return None
-    return 1e3 * total / (steps / (sum(whole) / len(whole)))
+    by_scope = device_seconds_by_scope(events)
+    total = sum(by_scope.get(scope, 0.0) for scope in scopes) if by_scope else 0.0
+    return ms_per_step(events, total) if total > 0 else None
 
 
 def kernel_ms_per_step(events, kernel: str):
@@ -531,6 +596,8 @@ def main(argv) -> int:
     for program, durs in sorted(runs.items(), key=lambda kv: -sum(kv[1])):
         print(f"  {len(durs):6d}  {sorted(durs)[len(durs) // 2]:9.3f} ms  {program}")
     table("device self seconds by scope (share of busy)", device_seconds_by_scope(events), busy_s)
+    table("device self seconds by collective operation and the scope it serves (share of busy)",
+          device_seconds_by_collective(events, with_scope=True), busy_s)
     for kernel in KERNEL_PREFIXES:
         calls = kernel_calls(events, kernel)
         if calls:

@@ -60,56 +60,64 @@ def _rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(q, k, v, q_block: int):
+def _attention(q, k, v, q_block: int, op):
     """q: [S, H, D]; k, v: [S, Hkv, D]. Causal, grouped-query, by einsum. Queries are
     taken `q_block` at a time only so that a 4096-token sequence's scores fit the chip
     beside a train state; the mathematics is the same for any block size."""
     S, H, D = q.shape
     g = H // k.shape[1]
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
+    k = op(jnp.repeat(k, g, axis=1))
+    v = op(jnp.repeat(v, g, axis=1))
     pos = jnp.arange(S)
     outs = []
     for s0 in range(0, S, q_block):
-        qb = q[s0:s0 + q_block]
+        qb = op(q[s0:s0 + q_block])
         scores = jnp.einsum("shd,thd->hst", qb, k) / math.sqrt(D)
         mask = pos[None, :] <= pos[s0:s0 + q_block, None]
         scores = jnp.where(mask[None], scores, -jnp.inf)
-        outs.append(jnp.einsum("hst,thd->shd", jax.nn.softmax(scores, axis=-1), v))
+        outs.append(jnp.einsum("hst,thd->shd", op(jax.nn.softmax(scores, axis=-1)), v))
     return jnp.concatenate(outs, axis=0)
 
 
-def forward(params, cfg: dict, tokens, q_block: int = 1024):
-    """tokens: [S] int32 -> logits [S, V] float32."""
+def forward(params, cfg: dict, tokens, q_block: int = 1024, operand=None):
+    """tokens: [S] int32 -> logits [S, V] float32. `operand`, where given, is applied to
+    both operands of every matrix product: the control of `tests/test_control.py` rounds
+    them to a narrower type there, to show that the comparison fails on it. The reference
+    gives none, and is then the same program as without the argument."""
     f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    op = operand or (lambda a: a)
     S = tokens.shape[0]
     positions = jnp.arange(S)
     h = f32(params["embedding"])[tokens]
     for i in range(cfg["n_layers"]):
         lp = _layer(params, i)
-        a = _rmsnorm(h, f32(lp["attn_norm"]["scale"]), cfg["norm_eps"])
-        q = jnp.einsum("se,ehd->shd", a, f32(lp["attn"]["q"]["kernel"]))
-        k = jnp.einsum("se,ehd->shd", a, f32(lp["attn"]["k"]["kernel"]))
-        v = jnp.einsum("se,ehd->shd", a, f32(lp["attn"]["v"]["kernel"]))
+        a = op(_rmsnorm(h, f32(lp["attn_norm"]["scale"]), cfg["norm_eps"]))
+        q = jnp.einsum("se,ehd->shd", a, op(f32(lp["attn"]["q"]["kernel"])))
+        k = jnp.einsum("se,ehd->shd", a, op(f32(lp["attn"]["k"]["kernel"])))
+        v = jnp.einsum("se,ehd->shd", a, op(f32(lp["attn"]["v"]["kernel"])))
         q = _rope(q, positions, cfg["rope_theta"])
         k = _rope(k, positions, cfg["rope_theta"])
-        o = _attention(q, k, v, q_block)
-        h = h + jnp.einsum("shd,hde->se", o, f32(lp["attn"]["o"]["kernel"]))
-        m = _rmsnorm(h, f32(lp["mlp_norm"]["scale"]), cfg["norm_eps"])
-        gate = m @ f32(lp["mlp"]["gate"]["kernel"])
-        up = m @ f32(lp["mlp"]["up"]["kernel"])
-        h = h + (jax.nn.silu(gate) * up) @ f32(lp["mlp"]["down"]["kernel"])
+        o = _attention(q, k, v, q_block, op)
+        h = h + jnp.einsum("shd,hde->se", op(o), op(f32(lp["attn"]["o"]["kernel"])))
+        m = op(_rmsnorm(h, f32(lp["mlp_norm"]["scale"]), cfg["norm_eps"]))
+        gate = m @ op(f32(lp["mlp"]["gate"]["kernel"]))
+        up = m @ op(f32(lp["mlp"]["up"]["kernel"]))
+        h = h + op(jax.nn.silu(gate) * up) @ op(f32(lp["mlp"]["down"]["kernel"]))
     h = _rmsnorm(h, f32(params["final_norm"]["scale"]), cfg["norm_eps"])
-    return h @ f32(params["lm_head"]["kernel"])
+    return op(h) @ op(f32(params["lm_head"]["kernel"]))
 
 
-def loss(params, cfg: dict, tokens, targets, q_block: int = 1024):
-    """Mean next-token cross-entropy of one sequence. tokens, targets: [S]."""
+def token_losses(params, cfg: dict, tokens, targets, q_block: int = 1024, operand=None):
+    """Next-token cross-entropy at every position of one sequence. tokens, targets: [S] -> [S]."""
     with jax.default_matmul_precision("highest"):
-        logits = forward(params, cfg, tokens, q_block)
-        logz = jax.nn.logsumexp(logits, axis=-1)
+        logits = forward(params, cfg, tokens, q_block, operand)
         gold = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
-        return jnp.mean(logz - gold)
+        return jax.nn.logsumexp(logits, axis=-1) - gold
+
+
+def loss(params, cfg: dict, tokens, targets, q_block: int = 1024, operand=None):
+    """Mean next-token cross-entropy of one sequence. tokens, targets: [S]."""
+    return jnp.mean(token_losses(params, cfg, tokens, targets, q_block, operand))
 
 
 def greedy(params, cfg: dict, prompt, n_new: int):
@@ -146,6 +154,21 @@ def greedy(params, cfg: dict, prompt, n_new: int):
 # 1.1e-2 at 8192 tokens. So 1.5e-3 absolute sits an order of magnitude above the
 # rounding and below a wrong function.
 LOSS_ABS_TOL = 1.5e-3
+
+# The train cells, beside it. A mean over thousands of tokens hides a precision: with both
+# operands of every matrix product rounded to float8 (the control, `tests/test_control.py`)
+# the mean loss moved by 2.6e-5, 1.6e-3 and 2.7e-3 on three seeds at Mistral's widths and by
+# 9.1e-4 to 3.7e-3 at InternLM2's, so LOSS_ABS_TOL passes it as often as not. What shows a
+# precision is a token's own loss. So the program's forward pass (`eval_logits_fn`, bfloat16
+# matmuls, float32 accumulation) is compared with the reference token by token, and the root
+# of the mean square of the difference is held to this limit. Readings (my chip run, PR 27):
+# sound runs 0.01460 to 0.01542 over 12 seeds of 4096 tokens at Mistral's widths, and 0.02155
+# to 0.02227 over 12 seeds of 16384 tokens at InternLM2's over four chips; the control 0.1523,
+# 0.1543, 0.1562 and 0.2108, 0.2114, 0.2138 on three seeds each (the reference with bfloat16
+# operands: 0.0101 to 0.0103 and 0.0141 to 0.0146). Steady from seed to seed on both sides,
+# a factor of seven apart at the nearest: 0.05 is 2.2 times the largest sound reading and a
+# third of the smallest control.
+TOKEN_LOSS_RMS_TOL = 5e-2
 
 # Cells 2 and 3. The engine multiplies in bfloat16 (float32 accumulation), so its
 # logits differ from the reference's, and with random weights the two largest of 92544

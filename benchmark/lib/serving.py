@@ -12,7 +12,7 @@ import time
 
 import numpy as np
 
-from lib import arrivals, reference
+from lib import arrivals, blocks
 from lib.rows import anchor
 
 PROBE_PROMPT_TOKENS = 64
@@ -38,6 +38,7 @@ def reference_probes(ctx, config, setup: dict) -> list:
 
     from ray_tpu.llm import load_model
 
+    reference = blocks.reference(ctx.config)
     t = time.perf_counter()
     _, params = load_model(config)
     params = reference.plain_tree(params)
@@ -79,7 +80,7 @@ def build(ctx) -> tuple:
 async def prepare(ctx, server, probes: list, setup: dict, notes: list, prompt_lens) -> bool:
     """The probes on the cold path, then the warm-up. Whether the reference agrees."""
     t = time.perf_counter()
-    ok_ref, note = await check_probes(server, probes)
+    ok_ref, note = await check_probes(server, probes, blocks.reference(ctx.config))
     notes.append(note)
     setup["probes_s"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -95,10 +96,11 @@ def note_compiles(ctx, setup: dict, c0: dict) -> None:
     setup["ramp_s"] = float(ctx.traffic["ramp_seconds"])
 
 
-async def check_probes(server, probes: list) -> tuple:
+async def check_probes(server, probes: list, reference) -> tuple:
     """The probes through `generate`, greedy, one at a time and before any other
     traffic: the cold prefill path. At least two, and on until enough positions of a
-    clear margin are compared. (agrees, note)."""
+    clear margin are compared, by the block's own `reference` module and its
+    tolerances. (agrees, note)."""
     compared, agrees, sent = 0, True, 0
     for p in probes:
         if sent >= 2 and compared >= reference.MIN_COMPARED_POSITIONS:

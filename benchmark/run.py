@@ -4,9 +4,11 @@
 
 Everything is found by name from `BENCHMARK.json`: the cell names a configuration
 (`benchmark/configs/<config>.json`) and a traffic mix (`benchmark/traffic/<mix>.json`);
-the mix names its driver (`benchmark/drivers/<driver>.py`); every metric has a reader
-(`benchmark/metrics/<metric>.py`). A later PR adds files and entries and edits nothing
-here. See `benchmark/README.md`.
+the mix names its driver (`benchmark/drivers/<driver>.py`) and, for a train step over
+several chips, its `mesh`; a driver says which kind of record it returns (`RECORD`, or
+its own name); a configuration may name its block (`lib/blocks.py`); every metric has a
+reader (`benchmark/metrics/<metric>.py`) that says whose records it reads. A later PR
+adds files and entries and edits nothing here. See `benchmark/README.md`.
 
 The last line of standard output is one JSON object: `correct`, `attempted`, `failed`,
 `metrics`, `device`, and with `--trace 1` also `breakdown`. Without a TPU whose
@@ -30,8 +32,9 @@ import sys  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-BENCH_FILE = os.path.join(ROOT, "BENCHMARK.json")  # the CPU test points these two at its own files
+BENCH_FILE = os.path.join(ROOT, "BENCHMARK.json")  # the CPU test points these three at its own files
 TRAFFIC_DIR = os.path.join(HERE, "traffic")
+DRIVERS_DIR = os.path.join(HERE, "drivers")
 for _p in (ROOT, HERE):
     if _p not in sys.path:
         sys.path.insert(0, _p)
@@ -49,9 +52,18 @@ def _read_json(path: str) -> dict:
         return json.load(f)
 
 
+def load_driver(name: str):
+    """The driver's module and the kind of record it returns: its `RECORD`, or its own
+    name. Readers bind to that kind (`DRIVERS`), so a new driver file that returns a
+    record of a kind that is there reports that kind's metrics with no reader edited."""
+    mod = _load_module(os.path.join(DRIVERS_DIR, name + ".py"), "benchmark_driver_" + name)
+    return mod, getattr(mod, "RECORD", name)
+
+
 def load_metric_readers() -> dict:
-    """Every file of `benchmark/metrics/` is one metric: NAME, UNIT, DRIVERS (whose
-    records it can read) and `read(record)`."""
+    """Every file of `benchmark/metrics/` is one metric: NAME, UNIT, DRIVERS (the kinds
+    of record it can read, each named after the driver that first returned it) and
+    `read(record)`."""
     out = {}
     mdir = os.path.join(HERE, "metrics")
     for fn in sorted(os.listdir(mdir)):
@@ -139,12 +151,12 @@ class Context:
 
 
 def device_block(devices, chips: int, trace_summary) -> dict:
-    peak = 0
-    for d in devices[:chips]:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    """`memory_peak_bytes` is the fullest chip's; `memory_peak_bytes_by_chip` every chip's
+    of those the cell uses."""
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices[:chips]]
     out = {"platform": devices[0].platform, "kind": devices[0].device_kind,
-           "count": len(devices), "memory_peak_bytes": peak}
+           "count": len(devices), "memory_peak_bytes": max(peaks),
+           "memory_peak_bytes_by_chip": peaks}
     if trace_summary is not None:
         out["busy_s"] = trace_summary["busy_s"]
         out["window_s"] = trace_summary["window_s"]
@@ -194,12 +206,12 @@ def main(argv=None) -> int:
         trace_dir=os.path.join(ROOT, ".bench_trace", args.workload),
     )
     shutil.rmtree(ctx.trace_dir, ignore_errors=True)
-    driver = _load_module(os.path.join(HERE, "drivers", traffic["driver"] + ".py"),
-                          "benchmark_driver_" + traffic["driver"])
+    driver, record_kind = load_driver(traffic["driver"])
     record = driver.run(ctx)
     record["setup"]["import_s"] = import_s
-    record.update(cell=cell["name"], driver=traffic["driver"], model=ctx.model,
-                  traffic=traffic, peaks=peaks, seed=args.seed)
+    record.update(cell=cell["name"], driver=traffic["driver"], record=record_kind, model=ctx.model,
+                  block=config.get("block"), chips=cell["chips"], traffic=traffic, peaks=peaks,
+                  seed=args.seed)
 
     trace_summary = None
     if ctx.trace:
@@ -215,7 +227,7 @@ def main(argv=None) -> int:
     metrics = {}
     for entry in metrics_of_cell(bench, cell["name"], kind):
         reader = readers.get(entry["name"])
-        applies = reader is not None and traffic["driver"] in reader.DRIVERS
+        applies = reader is not None and record_kind in reader.DRIVERS
         value = reader.read(record) if applies else None
         if value is not None:
             metrics[entry["name"]] = {"value": float(value), "unit": entry["unit"]}

@@ -10,3 +10,6 @@ for p in (ROOT, BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# two virtual devices, so that a train cell can build a mesh over more than one
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2").strip()

@@ -42,6 +42,24 @@ def test_costs_internlm2():
     assert costs.decode_step_bytes(m, 1000) == want
 
 
+def test_a_utilisation_is_taken_against_all_the_chips_of_the_cell():
+    import run as R
+
+    readers = R.load_metric_readers()
+    record = {"steps": 50, "tokens_per_step": 32768, "window_s": 51.0, "seq": 4096, "chips": 1,
+              "model": _model("internlm2-1.8b"), "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
+    one = readers["mfu.train"].read(record)
+    assert one == pytest.approx(100 * 50 * 32768 / 51.0 * costs.train_flops_per_token(record["model"], 4096) / 197e12)
+    assert readers["mfu.train"].read(dict(record, chips=4)) == pytest.approx(one / 4)
+    assert readers["mfu.train"].read(dict(record, block=None)) == one  # no block: lib/costs.py
+    # a serve record: one chip reads a share of its bandwidth, more than one reads nothing
+    serve = {"window_s": 10.0, "window": [0.0, 10.0], "chips": 1, "model": record["model"], "peaks": record["peaks"],
+             "rows": [dict(i=0, due=None, sent=1.0, prompt_len=100, max_tokens=50, n_out=50, ttft_s=0.1, latency_s=2.1,
+                           ok=True, rejected=False, interrupted=False)]}
+    assert 0 < readers["decode_hbm_util.serve"].read(serve) < 100
+    assert readers["decode_hbm_util.serve"].read(dict(serve, chips=4)) is None
+
+
 def test_arrivals_repeat_for_a_seed_and_differ_between_seeds():
     def draw(seed):
         rng = arrivals.rng_for(seed, 2)
@@ -134,3 +152,34 @@ def test_open_loop_schedule_is_one_cycle_that_the_seed_rotates():
     k = b.index(a[0])
     assert b[k:] + b[:k] == a                      # the same cycle from another phase
     assert ra[-1]["prompt"] != rb[-1]["prompt"]    # other token ids
+
+
+def test_hostwatch_counts_the_collectors_runs_and_ends_what_it_started():
+    """The note holds the collector's runs and the process's CPU time inside the watch;
+    stopping ends the watch's thread and takes its callback away."""
+    import gc
+    import json
+    import threading
+    import time
+
+    from lib import hostwatch
+
+    before = threading.active_count(), len(gc.callbacks)
+    watch = hostwatch.start()
+    time.sleep(0.1)
+    gc.collect()
+    stop_at = time.perf_counter() + 0.3
+    while time.perf_counter() < stop_at:
+        sum(range(100_000))
+    time.sleep(0.1)
+    note = watch.stop()
+    assert note.startswith("host in the window: ")
+    seen = json.loads(note.split(": ", 1)[1])
+    assert seen["gc_collections"][2] >= 1 and seen["gc_s"] > 0
+    assert 0.5 < seen["wall_s"] < 5 and seen["process_cpu_s"] > 0.2
+    assert 0 <= seen["machine_steal_share"] <= 1
+    assert "wake_worst_late_s" in seen
+    assert (threading.active_count(), len(gc.callbacks)) == before
+    quiet = hostwatch.start(ticker=False)  # a traced run's: no thread for the profiler to record
+    assert threading.active_count() == before[0]
+    assert "wake_worst_late_s" not in quiet.stop() and len(gc.callbacks) == before[1]

@@ -141,6 +141,39 @@ def test_self_time_by_scope_takes_nested_operations_out_of_their_parent():
     assert pt.scope_ms_per_step(events, ("lm_head", "loss")) is None  # no such operation: not 0
 
 
+def test_collective_operations_are_found_in_each_form_and_counted_by_self_time():
+    """The four forms a v5e's compiled step holds them in (`lib/program_trace.COLLECTIVES`), from
+    the four-chip cell's HLO; an operand or a metadata path that only mentions one is none."""
+    forms = {
+        '%all-gather.208 = bf16[1,2048,8,128]{1,3,2,0:T(8,128)(2,1)} all-gather(%param_0.1356), channel_id=75, replica_groups=[1,4]<=[4], dimensions={1}': "all-gather",
+        '%fusion.447 = bf16[528,8192]{1,0:T(8,128)(2,1)S(1)} fusion(%copy-done.24), kind=kCustom, calls=%all-reduce-scatter.6.clone.clone, metadata={op_name="jit(step)/mlp/up/dot_general"}': "reduce-scatter",
+        '%all-reduce.66 = bf16[2048,92544]{1,0} all-reduce(%convolution_bitcast_fusion.6), channel_id=22, to_apply=%add.1.clone': "all-reduce",
+        '%collective-permute-start.1 = (f32[8]{0}, f32[8]{0}) collective-permute-start(%x), source_target_pairs={{0,1}}': "collective-permute",
+        '%collective-permute-done.1 = f32[8]{0} collective-permute-done(%collective-permute-start.1)': "collective-permute",
+        '%all-to-all.1 = f32[4,8]{1,0} all-to-all(%y), replica_groups={{0,1,2,3}}': "all-to-all",
+        '%async-collective-start.11 = (bf16[1,512,8192]{2,1,0}, bf16[1,2048,8192]{2,1,0}, s32[2]{0}) fusion(%copy-done.15), kind=kCustom, calls=%fused_computation.385': "async-collective",
+        '%async-collective-done.11 = bf16[1,2048,8192]{2,1,0} fusion(%get-tuple-element.2337), kind=kCustom, calls=%fused_computation.386': "async-collective",
+        # a matmul that carries a gather along is computation: it is what hides the transfer
+        '%fusion.449 = (bf16[2,8,4096,128]{2,3,0,1}, bf16[1,512,8,128]{1,3,2,0}) fusion(%p.1, %p.2), kind=kCustom, calls=%async_collective_fusion.449': None,
+        '%fusion.9 = bf16[8,8]{1,0} fusion(%all-gather.208), kind=kOutput, calls=%fused_computation.9, metadata={op_name="jit(step)/all-gather"}': None,
+        '%flash_fwd.18 = (bf16[32,4096,128]{2,1,0}) custom-call(bf16[32,4096,128]{2,1,0} %q), custom_call_target="tpu_custom_call"': None,
+    }
+    assert {hlo: pt.collective_kind(hlo) for hlo in forms} == forms
+    # a step of 100 ms: a `while` of 80 holding a 10 ms gather (8 of it hidden under nothing here: it is
+    # on the operation line, so the core waits) and a 20 ms matmul; after it a 6 ms reduce-scatter fusion
+    events = {"window": [0, 200 * MS], "spans": [], "hlo": {}, "modules": [["jit_step", 0, 100 * MS], ["jit_step", 100 * MS, 100 * MS]],
+              "collectives": {"all-gather.2": "all-gather", "fusion.7": "reduce-scatter"}, "ops": [
+        ["while.1", "jit(step)/while:", 0, 80 * MS], ["all-gather.2", "jit(step)/while/body/mlp/dot_general:", 5 * MS, 10 * MS],
+        ["fusion.3", "jit(step)/while/body/mlp/dot_general:", 20 * MS, 20 * MS], ["fusion.7", "jit(step)/mlp/dot_general:", 85 * MS, 6 * MS],
+        ["while.1", "jit(step)/while:", 100 * MS, 80 * MS], ["all-gather.2", "jit(step)/while/body/mlp/dot_general:", 105 * MS, 10 * MS]]}
+    assert pt.device_seconds_by_collective(events) == pytest.approx({"all-gather": 0.020, "reduce-scatter": 0.006})
+    assert pt.device_seconds_by_collective(events, with_scope=True) == pytest.approx(
+        {"all-gather in mlp": 0.020, "reduce-scatter in mlp": 0.006})
+    assert pt.ms_per_step(events, 0.026) == pytest.approx(13.0)
+    assert pt.device_seconds_by_collective({**events, "collectives": {}}) == {}  # one chip: nothing, and the readers print nothing
+    assert pt.device_seconds_by_collective({**events, "window": None}) is None
+
+
 def test_steps_per_multi_step_execution_and_device_time_per_step():
     events = {"window": [0, 1000 * MS], "spans": [], "ops": [], "hlo": {}, "modules": [
         ["jit_rt_decode", 10 * MS, 30 * MS], ["jit_rt_decode_multi_n8", 50 * MS, 200 * MS],
@@ -229,11 +262,18 @@ def test_the_readers_on_a_recorded_trace(recorded, monkeypatch, piece):
     driver, config, want = RECORDED[piece]
     monkeypatch.setattr(pt, "for_record", lambda record: recorded[piece])
     with open(os.path.join(R.HERE, "configs", config + ".json")) as f:
-        record = {"cell": piece, "trace": {}, "model": json.load(f)["model"], "peaks": peaks.PEAKS["TPU v5 lite"]}
+        record = {"cell": piece, "trace": {}, "model": json.load(f)["model"], "peaks": peaks.PEAKS["TPU v5 lite"], "chips": 1}
     readers = {name: mod for name, mod in R.load_metric_readers().items() if hasattr(mod, "pt") and driver in mod.DRIVERS}
     got = {name: mod.read(record) for name, mod in readers.items()}
+    # a step on one chip moves no data between chips: those two read nothing, not 0
+    nothing = {"collective_dev_ms.train", "collective_dev_share.train"} & set(readers)
+    assert {name for name, v in got.items() if v is None} == nothing
+    got = {name: v for name, v in got.items() if v is not None}
     assert got == pytest.approx(want, rel=1e-4)
     assert all(v <= 100.0 for name, v in got.items() if readers[name].UNIT == "%")
+    # the same step's record from a server over four chips: no roofline against one chip's peak
+    if "decode_roofline.serve" in readers:
+        assert readers["decode_roofline.serve"].read(dict(record, chips=4)) is None
 
 
 def test_a_program_without_names_reads_as_nothing_not_zero(recorded, monkeypatch):
@@ -246,7 +286,7 @@ def test_a_program_without_names_reads_as_nothing_not_zero(recorded, monkeypatch
     piece = dict(recorded["chat"], spans=[], modules=[[m[0].replace("jit_rt_", "jit__"), m[1], m[2]] for m in recorded["chat"]["modules"]])
     monkeypatch.setattr(pt, "for_record", lambda record: piece)
     with open(os.path.join(R.HERE, "configs", "internlm2-1.8b.json")) as f:
-        record = {"cell": "chat", "trace": {}, "model": json.load(f)["model"], "peaks": peaks.PEAKS["TPU v5 lite"]}
+        record = {"cell": "chat", "trace": {}, "model": json.load(f)["model"], "peaks": peaks.PEAKS["TPU v5 lite"], "chips": 1}
     readers = R.load_metric_readers()
     assert {name: readers[name].read(record) for name in RECORDED["chat"][2]} == dict.fromkeys(RECORDED["chat"][2])
     train = dict(recorded["train"], hlo={}, ops=[[op.replace("flash_", "attn._flash_"), path.replace("optimizer", "").replace("loss", ""), s, d]
