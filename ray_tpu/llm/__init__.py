@@ -130,6 +130,14 @@ def load_model(config: "LLMConfig"):
     # test-tiny under another model's name would look like a working replica.
     cfg = config.model_config or get_config(config.model_id)
     cfg = dataclasses.replace(cfg, scan_layers=False, remat=False)
+    if cfg.block != "llama":
+        # A block the flax Transformer does not build brings its own tree
+        # (`_engine._block_module`), at seeded random weights in `param_dtype`.
+        if config.checkpoint_path:
+            raise NotImplementedError(f"no checkpoint loader for block {cfg.block!r} yet")
+        from ray_tpu.llm._engine import _block_module
+
+        return cfg, _block_module(cfg).init_params(cfg, jax.random.PRNGKey(config.seed))
     model = Transformer(cfg)
     if config.checkpoint_path:
         from ray_tpu import checkpoint as ckpt_lib
@@ -210,6 +218,13 @@ class LLMServer:
             tenant_quota=config.tenant_quota,
             tp=config.tp,
         )
+
+    def weights(self):
+        """(ModelConfig, parameter tree) this replica serves: the device arrays themselves,
+        not copies. For scoring what the replica generated against a reference forward
+        pass over the very weights it ran (the benchmark's `correct` for a block whose
+        tree fills most of a chip, where a second copy would not fit beside it)."""
+        return self._engine.cfg, self._engine.params
 
     async def load_lora(self, name: str, layer_weights: dict, alpha: float = 1.0) -> int:
         """Register a LoRA adapter on this replica (reference: LoRA checkpoints

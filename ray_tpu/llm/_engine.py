@@ -56,7 +56,8 @@ from ray_tpu.llm.tp import (
     single_device_shardings,
     tp_degree,
 )
-from ray_tpu.models.transformer import ModelConfig, _rope
+from ray_tpu.models import dots3
+from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope, require_llama_block
 from ray_tpu.util import xprof
 
 _NEG_INF = -1e30
@@ -86,20 +87,6 @@ class SamplingParams:
 
 
 # -- pure functional forward over the param tree ---------------------------
-
-
-def _dense(x, kernel):
-    return jax.lax.dot_general(
-        x, kernel.astype(x.dtype),
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
-
-
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (normed * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def _lora_delta(x, A, B_, scale):
@@ -238,6 +225,18 @@ def _forward_cached(params, cfg: ModelConfig, tokens, positions, caches, write_a
     return logits, new_caches
 
 
+def _block_module(cfg: ModelConfig):
+    """The module that runs a block other than the dense one: its `init_caches`,
+    `prefill` and `decode` take the place of the cache slab and of
+    `_forward_cached` in the engine's programs. None for the dense block, whose
+    programs are this module's own and do not change."""
+    if cfg.block == "llama":
+        return None
+    if cfg.block == "dots3":
+        return dots3
+    raise ValueError(f"unknown block {cfg.block!r}")
+
+
 def _rid(req: Request) -> str:
     """The id a request's `rt.engine.*` spans carry: its flight record's, so
     that a profiler trace and `request_timing()` name the request alike."""
@@ -307,6 +306,17 @@ class DecodeEngine:
         from ray_tpu.parallel.mesh import unbox
 
         self.cfg = cfg
+        # A block other than the dense one brings its own cache and forward
+        # (`_block_module`); what it cannot do yet is refused here, by name.
+        self._block = _block_module(cfg)
+        if self._block is not None:
+            for asked, what in ((lora_config, "LoRA (lora_config)"),
+                                (spec_config, "speculative decoding (spec_config)"),
+                                (tp != 1, "tensor parallelism (llm/tp.py)"),
+                                (prefix_cache, "the prefix cache (llm/kvcache/)")):
+                if asked:
+                    require_llama_block(cfg, what)
+            prefix_cache = False  # the default from the config flags is off for it too
         self.params = unbox(params)  # strip flax LogicallyPartitioned boxes
         self.B = num_slots
         self.T = max_seq or cfg.max_seq
@@ -354,7 +364,10 @@ class DecodeEngine:
             )
         self._adapter_ids = np.zeros((num_slots,), np.int32)
         kv_shape = (self.B, self.T, cfg.n_kv_heads, cfg.head_dim)
-        if self._mesh is not None:
+        if self._block is not None:
+            # per layer a tuple of [B, rows, width] arrays, by the layer's kind
+            self._caches = self._block.init_caches(cfg, self.B, self.T)
+        elif self._mesh is not None:
             # Mesh-resident per-slot KV pool: shards allocate at their
             # kv-head-split layout directly (never materialized whole on any
             # one device); freed by shutdown via the tracked pool handle.
@@ -407,9 +420,17 @@ class DecodeEngine:
 
         xprof.register_memory_owner(self._xprof_owner, _ledger_row)
         self._jit_prefill = {}
+        # A block's programs also return its expert layers' counts (one small
+        # array a dispatch), added on the device into one running sum that only
+        # scheduler_stats() reads. Its caches are donated: nothing but the next
+        # program reads them.
+        self._expert_acc = None
+        self._expert_seen = None  # the running sum as the last report read it
+        self._expert_totals = None
+        self._expert_lock = threading.Lock()  # reports come from any thread
         self._jit_decode = self._xprof.instrument(
             self._xprof_owner, ("decode",),
-            jax.jit(_named("rt_decode", self._decode_step)),
+            jax.jit(_named("rt_decode", self._decode_step), **self._donate(4)),
         )
         # Multi-step decode: N greedy tokens per dispatch (argmax on device,
         # lax.scan over decode steps) — one host round trip per CHUNK instead
@@ -670,6 +691,10 @@ class DecodeEngine:
         return None if self._adapters is None else self._adapters.stats()
 
     # -- jitted programs ---------------------------------------------------
+    def _donate(self, caches_arg: int) -> dict:
+        """`jax.jit` options of a program that takes the caches at that position."""
+        return {} if self._block is None else {"donate_argnums": (caches_arg,)}
+
     def _prefill_at(self, params, lora, tokens, caches, slot, offset,
                     total_len, adapter_id):
         """tokens: [1, Sbucket] right-padded, starting at row/position `offset`
@@ -680,6 +705,9 @@ class DecodeEngine:
         of any length mix reuses exactly these bucket programs. Slot lengths
         are host-side state (the dispatcher records total_len itself — no
         device lens write)."""
+        if self._block is not None:
+            return self._block.prefill(params, self.cfg, tokens, caches, slot,
+                                       offset, total_len)
         S = tokens.shape[1]
         positions = offset + jnp.arange(S)[None, :]
         # one-slot caches view
@@ -706,6 +734,10 @@ class DecodeEngine:
         with a stale lens, and an ungated write there would permanently
         corrupt rows its covering chunk already wrote (same hazard the
         spec-verify gate exists for)."""
+        if self._block is not None:
+            logits, new_caches, stats = self._block.decode(
+                params, self.cfg, last_token, caches, lens, gate)
+            return logits, new_caches, lens + 1, stats
         positions = lens[:, None]
         # key j visible iff j <= lens (the new token writes at index lens)
         kv_mask = (jnp.arange(self.T)[None, :] <= lens[:, None])[:, None, :]
@@ -722,17 +754,17 @@ class DecodeEngine:
 
         def step(carry, _):
             last, c, l = carry
-            logits, c, l = self._decode_step(
+            logits, c, l, *stats = self._decode_step(
                 params, lora, adapter_ids, last, c, l, gate
             )
             with jax.named_scope("sample"):
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (nxt, c, l), nxt
+            return (nxt, c, l), (nxt, *stats)
 
-        (last, caches, lens), toks = jax.lax.scan(
+        (last, caches, lens), (toks, *stats) = jax.lax.scan(
             step, (last_token, caches, lens), None, length=n
         )
-        return toks, caches, lens
+        return (toks, caches, lens, *(jnp.sum(s, axis=0) for s in stats))
 
     def _spec_verify_batched(self, params, lora, adapter_ids, tokens, caches,
                              lens, gate, constraint_mask):
@@ -1066,9 +1098,39 @@ class DecodeEngine:
         out["model"] = {
             "hidden": cfg.hidden, "n_layers": cfg.n_layers, "n_heads": cfg.n_heads,
             "n_kv_heads": cfg.n_kv_heads, "vocab_size": cfg.vocab_size,
-            "num_slots": self.B, "max_seq": self.T, "tp": self.tp,
+            "num_slots": self.B, "max_seq": self.T, "tp": self.tp, "block": cfg.block,
         }
+        if self._block is not None:
+            out["experts"] = self._expert_report()
         return out
+
+    def _note_expert_stats(self, stats: list) -> None:
+        """Add a dispatch's expert counts (none for the dense block) to the running sum
+        on the device: one small add, no readback. The sum is int32 and may wrap; a
+        report takes differences, which a wrap leaves right."""
+        for counts in stats:
+            self._expert_acc = counts if self._expert_acc is None else self._expert_acc + counts
+
+    def _expert_report(self) -> dict:
+        """The expert layers' counts (report path): token-expert pairs routed and pairs
+        whose expert this chip holds, since the engine started and over the window
+        since the last report, with the largest and the mean load of a held expert
+        there. One readback of the running sum."""
+        acc = self._expert_acc  # the stepper replaces it, never changes it
+        with self._expert_lock:
+            zeros = np.zeros((2 + self.cfg.n_routed_experts,), np.uint32)
+            now = zeros if acc is None else np.asarray(acc).astype(np.uint32)
+            seen = zeros if self._expert_seen is None else self._expert_seen
+            window = (now - seen).astype(np.int64)  # modulo 2**32: right across a wrap
+            self._expert_seen = now
+            total = self._expert_totals = window if self._expert_totals is None else self._expert_totals + window
+        return {
+            "held": self.cfg.n_routed_experts, "of": self.cfg.n_routed_experts_total,
+            "first": self.cfg.first_expert,
+            "pairs_routed": int(total[0]), "pairs_held": int(total[1]),
+            "window": {"pairs_routed": int(window[0]), "pairs_held": int(window[1]),
+                       "max_load": int(window[2:].max()), "mean_load": float(window[2:].mean())},
+        }
 
     def _flush_observability(self) -> dict:
         """Report-path export: queued completion summaries become
@@ -1165,7 +1227,7 @@ class DecodeEngine:
             per_device = per_device_byte_map(caches)
         elif caches:
             # .nbytes is shape metadata (rank * dtype arithmetic), not a pull
-            kv_bytes = sum(k.nbytes + v.nbytes for k, v in caches)
+            kv_bytes = sum(a.nbytes for layer in caches for a in layer)
         components["kv_slots"] = kv_bytes
         if self._adapters is not None:
             components["adapters"] = int(
@@ -1320,6 +1382,7 @@ class DecodeEngine:
         token_ids (optional, the prompt behind kv) lets the transferred
         prefix feed this engine's KV prefix cache AND keeps the slot
         spec-eligible (the draft catches up on the token history)."""
+        require_llama_block(self.cfg, "PD disaggregation (llm/pd_disagg.py)")
         self._check_alive()
         if prompt_len >= self.T:
             raise ValueError(
@@ -1492,6 +1555,7 @@ class DecodeEngine:
         evicted-and-reused between resolution and the dispatch capturing the
         table reference — after that, jax buffer immutability makes the
         captured table safe regardless."""
+        require_llama_block(self.cfg, "PD disaggregation (llm/pd_disagg.py)")
         prompt = list(token_ids)
         if len(prompt) > self.T - 1:
             raise ValueError(
@@ -1875,13 +1939,15 @@ class DecodeEngine:
         padded[0, : len(chunk.tokens)] = chunk.tokens
         prefill = self._program(
             self._jit_prefill, chunk.bucket,
-            lambda: jax.jit(_named(f"rt_prefill_b{chunk.bucket}", self._prefill_at)),
+            lambda: jax.jit(_named(f"rt_prefill_b{chunk.bucket}", self._prefill_at),
+                            **self._donate(3)),
         )
-        last_logits, self._caches = prefill(
+        last_logits, self._caches, *stats = prefill(
             self.params, self._lora_tables(), jnp.asarray(padded), self._caches,
             jnp.int32(slot), jnp.int32(offset),
             jnp.int32(req.prompt_len), jnp.int32(req.adapter_slot),
         )
+        self._note_expert_stats(stats)
         self._sched.chunk_done(chunk)
         if rec is not None:
             rec.span("prefill-chunk", t_chunk, time.time(),
@@ -2183,11 +2249,12 @@ class DecodeEngine:
                         rows=int(self._lens[decode_slots].sum())):
             gate = np.zeros((self.B,), bool)
             gate[decode_slots] = True
-            logits, self._caches, _ = self._jit_decode(
+            logits, self._caches, _, *stats = self._jit_decode(
                 self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
                 jnp.asarray(self._last_token), self._caches,
                 jnp.asarray(self._lens), jnp.asarray(gate),
             )
+            self._note_expert_stats(stats)
         # The step's ONE device->host pull: every active slot's next-token
         # logits arrive in a single [B, V] readback (sampling params can
         # differ per slot, so sampling itself is host-side).
@@ -2232,13 +2299,15 @@ class DecodeEngine:
             gate[decode_slots] = True
             decode_multi = self._program(
                 self._jit_decode_multi, ("decode_multi", n),
-                lambda: jax.jit(_named(f"rt_decode_multi_n{n}", self._decode_multi, n=n)),
+                lambda: jax.jit(_named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
+                                **self._donate(4)),
             )
-            toks_dev, self._caches, _ = decode_multi(
+            toks_dev, self._caches, _, *stats = decode_multi(
                 self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
                 jnp.asarray(self._last_token), self._caches,
                 jnp.asarray(self._lens), jnp.asarray(gate),
             )
+            self._note_expert_stats(stats)
         # The chunk's ONE device->host pull: n tokens x B slots per readback
         # (the whole point of multi-step decode).
         with xprof.span("rt.engine.readback", bytes=toks_dev.nbytes):

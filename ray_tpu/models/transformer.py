@@ -63,6 +63,45 @@ class ModelConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_coeff: float = 0.01
+    # Which block the layers are. "llama": the dense block of this module, which every
+    # path of the framework runs. "dots3": latent attention with a learned sparse
+    # indexer, windowed latent layers over a ring cache and sigmoid-routed experts
+    # beside a shared one (`models/dots3.py`), which only the serve engine runs; the
+    # fields below are that block's, under its published names where the two agree.
+    # `n_heads` and `rope_theta` are the full layers'; `mlp_dim` the leading dense layers'.
+    block: str = "llama"
+    layer_types: tuple = ()            # per layer: "full_attention" | "sliding_attention"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_rescale: bool = True           # latents scaled by sqrt(hidden / rank) after their norms
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    sliding_window: int = 0            # positions a sliding layer sees, the token itself counted
+    swa_n_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    first_k_dense: int = 1             # leading layers with a dense MLP
+    n_routed_experts_total: int = 0    # the router's width
+    n_routed_experts: int = 0          # routed experts this chip holds ...
+    first_expert: int = 0              # ... from this id on
+    n_shared_experts: int = 1
+    experts_per_token: int = 0
+    moe_mlp_dim: int = 0
+    routed_scaling_factor: float = 1.0
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))  # a JSON list, hashable
+        if self.block != "llama" and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"block {self.block!r} needs one of layer_types per layer: "
+                             f"{len(self.layer_types)} for n_layers={self.n_layers}")
 
     @property
     def head_dim(self) -> int:
@@ -144,6 +183,23 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary position embedding. x: [B, S, H, D]; positions: [B, S] or [S]."""
     cos, sin = _rope_angles(positions, x.shape[-1], theta)
     return _rope_apply(x, cos, sin)
+
+
+def _dense(x: jax.Array, kernel: jax.Array) -> jax.Array:
+    """x [..., M] @ kernel [M, N] in x's dtype, accumulated in float32: the serve
+    path's matrix product (the engine's own model and `models/dots3.py`)."""
+    return jax.lax.dot_general(
+        x, kernel.astype(x.dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(x.dtype)
+
+
+def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last axis in float32, returned in x's dtype (serve path)."""
+    x32 = x.astype(jnp.float32)
+    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (normed * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 class _HeadProj(nn.Module):
@@ -459,6 +515,7 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None, return_hidden=False):
         cfg = self.cfg
+        require_llama_block(cfg, "the flax Transformer (the train step)")
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :].astype(jnp.int32)
         embed = self.param(
@@ -623,6 +680,15 @@ def init_params(cfg: ModelConfig, rng=None, batch: int = 1, seq: int | None = No
     seq = seq or min(cfg.max_seq, 128)
     tokens = jnp.zeros((batch, seq), jnp.int32)
     return model, model.init(rng, tokens)
+
+
+def require_llama_block(cfg: ModelConfig, what: str) -> None:
+    """Paths that know only the dense block refuse another by name, rather than run
+    the dense code over a tree it cannot walk (PERF.md: what the system cannot run yet)."""
+    if cfg.block != "llama":
+        raise NotImplementedError(
+            f"{what} does not support block {cfg.block!r} yet: it runs the dense llama-family "
+            f"block only; block {cfg.block!r} is served by LLMServer / DecodeEngine on one device")
 
 
 def get_config(name: str, **overrides) -> ModelConfig:

@@ -103,3 +103,93 @@ class MoEMLP(nn.Module):
         # combine back: [T,E,C] x [E,C,M] -> [T,M]
         out = jnp.einsum("tec,ecm->tm", combine.astype(self.dtype), expert_out)
         return out.reshape(B, S, M), aux_loss.astype(jnp.float32)
+
+
+# -- dropless, sorted, grouped experts over the experts this chip holds -----------
+#
+# The serving path's expert product (`models/dots3.py`). The router chooses among
+# ALL of a layer's experts; this chip holds `E` of them, ids [first, first + E).
+# Token-expert pairs whose expert lives here are sorted by expert and laid out in
+# tiles of `tile` rows, every expert's group starting on a tile boundary, and one
+# loop runs a gated (three-matrix) expert over each tile that holds a pair: no
+# capacity, no dropped token, and an expert nobody chose costs nothing. Pairs of
+# absent experts add nothing here (their chips add them in a deployment). The
+# train step's `MoEMLP` above is the older capacity-dropping one-hot dispatch.
+
+
+def sigmoid_routing(h, router_kernel, router_bias, k: int, scaling: float = 1.0):
+    """`noaux_tc` routing: scores `sigmoid(h W_r)` in float32, the `k` experts of
+    largest score + bias chosen (the bias chooses and does not weigh), weights the
+    chosen scores over their sum times `scaling`. h: [N, D] -> (ids [N, k] int32,
+    weights [N, k] float32)."""
+    logits = jax.lax.dot_general(
+        h.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + router_bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+    return ids.astype(jnp.int32), weights
+
+
+def expert_tile_rows(pairs: int, experts: int) -> int:
+    """Rows a tile holds: near a held expert's fair share of the pairs, a power of
+    two from 16 (a bfloat16 sublane tile) to 128 (an MXU pass)."""
+    tile = 16
+    while tile < 128 and tile * experts < pairs:
+        tile *= 2
+    return tile
+
+
+def grouped_experts(x, ids, weights, valid, w_gate, w_up, w_down, first: int = 0,
+                    tile: Optional[int] = None):
+    """sum_k weights[n, k] * E_{ids[n, k]}(x[n]) over the pairs whose expert is held
+    here, E(h) = (silu(h Wg) * (h Wu)) Wd.
+
+    x: [N, D]; ids, weights: [N, K] (global expert ids); valid: [N] bool, rows that
+    are padding route nowhere; w_gate, w_up: [E, D, F]; w_down: [E, F, D].
+    Returns (y [N, D] in x's dtype, counts [E] int32: valid pairs per held expert)."""
+    N, D = x.shape
+    K = ids.shape[1]
+    E = w_gate.shape[0]
+    tile = tile or expert_tile_rows(N * K, E)
+    n_pairs = N * K
+    rows = -(-n_pairs // tile) * tile + E * tile  # every group may waste a tile's end
+
+    local = ids.reshape(-1) - first
+    held = (local >= 0) & (local < E) & jnp.repeat(valid, K)
+    key = jnp.where(held, local, E)  # pairs of absent experts sort to the end
+    counts = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    group_tiles = -(-counts // tile)
+    tiles_before = jnp.cumsum(group_tiles) - group_tiles
+    pairs_before = jnp.cumsum(counts) - counts
+    e_of = jnp.minimum(sorted_key, E - 1)
+    dest_sorted = jnp.where(
+        sorted_key < E,
+        tiles_before[e_of] * tile + jnp.arange(n_pairs) - pairs_before[e_of], rows)
+    dest = jnp.zeros((n_pairs,), jnp.int32).at[order].set(dest_sorted.astype(jnp.int32))
+
+    # each row's token by a gather (a scatter of whole rows is several times slower on the
+    # TPU): only the small inverse map, pair of a row, is scattered; empty rows read a zero row
+    pair_of_row = jnp.full((rows,), n_pairs, jnp.int32).at[dest].set(jnp.arange(n_pairs, dtype=jnp.int32), mode="drop")
+    xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[pair_of_row // K]
+    n_tiles = jnp.sum(group_tiles)
+    tile_expert = jnp.searchsorted(jnp.cumsum(group_tiles), jnp.arange(rows // tile), side="right")
+    tile_expert = jnp.minimum(tile_expert, E - 1).astype(jnp.int32)
+
+    def matmul(a, b):
+        return jax.lax.dot_general(a, b.astype(a.dtype), (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32).astype(a.dtype)
+
+    def one_tile(t, ys):
+        e = tile_expert[t]
+        xt = jax.lax.dynamic_slice(xs, (t * tile, 0), (tile, D))
+        h = jax.nn.silu(matmul(xt, w_gate[e])) * matmul(xt, w_up[e])
+        return jax.lax.dynamic_update_slice(ys, matmul(h, w_down[e]), (t * tile, 0))
+
+    ys = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((rows, D), x.dtype))
+    out = ys.at[dest].get(mode="fill", fill_value=0).reshape(N, K, D)
+    y = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), weights * held.reshape(N, K))
+    return y.astype(x.dtype), counts

@@ -67,7 +67,8 @@ def engines():
     finally:
         plain.shutdown()
         spec.shutdown()
-        CONFIG._cache.update(saved)
+        for k, v in saved.items():  # a flag never read before has no cached value to put back
+            CONFIG._cache.pop(k) if v is None else CONFIG._cache.update({k: v})
 
 
 def _engine_programs(plain, spec):
@@ -172,3 +173,64 @@ def test_the_train_step_names_model_loss_and_optimizer(fused_ce):
     bare = {n for n in op_names if n.startswith("jit(step)/")
             and not (set(_parts(n)) & (want | {"lm_head"}))}
     assert not any(re.search(r"log|exp|sqrt|dot_general", n.rsplit("/", 1)[-1]) for n in bare), sorted(bare)
+
+
+# -- the dots3 block: the same outer scopes, its mechanisms named inside them ------------
+
+DOTS3_INNER = {"prefill": {"indexer", "latent", "window", "router", "experts", "shared_expert"},
+               "decode": {"indexer", "select", "latent", "window", "router", "experts", "shared_expert"}}
+
+
+@pytest.fixture(scope="module")
+def dots3_engine():
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+    from ray_tpu.models import dots3
+    from tests.test_dots3 import tiny
+
+    cfg = tiny(n_routed_experts=4, first_expert=8)
+    params = dots3.init_params(cfg, jax.random.PRNGKey(2))
+    saved = CONFIG._cache.get("llm_prefill_bucket_min")
+    CONFIG._cache["llm_prefill_bucket_min"] = 4
+    engine = DecodeEngine(cfg, params, num_slots=2, max_seq=64, multi_step=4, token_budget=8)
+    try:
+        done = threading.Event()
+        engine.submit(list(range(1, 20)), SamplingParams(max_tokens=7), lambda tok, fin: fin and done.set())
+        assert done.wait(180), engine.error
+        yield engine
+    finally:
+        engine.shutdown()
+        CONFIG._cache.pop("llm_prefill_bucket_min") if saved is None else CONFIG._cache.update(llm_prefill_bucket_min=saved)
+
+
+def test_the_dots3_blocks_programs_keep_the_names_and_name_their_mechanisms(dots3_engine):
+    engine = dots3_engine
+    B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
+    step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
+    programs = [(engine._jit_decode, step)] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
+                 for k, p in engine._jit_prefill.items()]
+    names = dict(_lowered(prog, *args) for prog, args in programs)
+    assert {"jit_rt_decode", "jit_rt_prefill_b8"} <= set(names), sorted(names)
+    assert any(re.fullmatch(r"jit_rt_decode_multi_n\d+", n) for n in names)
+    for module, scopes in names.items():
+        assert set(MODEL_SCOPES) <= scopes, (module, set(MODEL_SCOPES) - scopes)
+        inner = DOTS3_INNER["prefill" if "prefill" in module else "decode"]
+        assert inner <= scopes, (module, inner - scopes)
+        assert ("select" in scopes) == ("prefill" not in module), module  # a chunk masks, a step gathers
+        assert ("sample" in scopes) == ("multi" in module), module
+
+
+def test_scheduler_stats_name_the_block_and_count_its_expert_pairs(dots3_engine):
+    stats = dots3_engine.scheduler_stats()
+    experts = stats["experts"]
+    assert stats["model"]["block"] == "dots3" and (experts["held"], experts["of"], experts["first"]) == (4, 16, 8)
+    # 19 prompt tokens and 6 fed-back tokens through 4 expert layers, 4 experts a token
+    assert experts["pairs_routed"] == (19 + 6) * 4 * 4 > experts["pairs_held"] > 0
+    assert set(experts["window"]) == {"pairs_routed", "pairs_held", "max_load", "mean_load"}
+    assert {"rt.engine.prefill", "rt.engine.dispatch", "rt.engine.iter"} <= set(stats["loop"])
+
+
+def test_the_dense_blocks_stats_say_so_and_have_no_expert_counts(engines):
+    stats = engines[0].scheduler_stats()
+    assert stats["model"]["block"] == "llama" and "experts" not in stats
