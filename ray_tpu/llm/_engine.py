@@ -155,16 +155,15 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
             cache_v, v.astype(cache_v.dtype), write_at, write_gate
         )
 
-    kk, vv = cache_k, cache_v
-    if cfg.n_kv_heads != cfg.n_heads:
-        rep = cfg.n_heads // cfg.n_kv_heads
-        kk = jnp.repeat(kk, rep, axis=2)
-        vv = jnp.repeat(vv, rep, axis=2)
+    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor
+    # of the split, and both products run against the slab as it lies: a copy of
+    # K or V repeated to H heads costs a third of a decode step (PERF.md §6, PR 29).
+    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    logits = jnp.einsum("bshd,bthd->bhst", q, kk.astype(q.dtype)) * scale
-    logits = jnp.where(kv_mask[:, None], logits.astype(jnp.float32), _NEG_INF)
+    logits = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(q.dtype)) * scale
+    logits = jnp.where(kv_mask[:, None, None], logits.astype(jnp.float32), _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", probs, vv.astype(q.dtype))
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, cache_v.astype(q.dtype))
     o_kernel = layer["o"]["kernel"].reshape(-1, cfg.hidden)
     proj = _dense(out.reshape(B, S, -1), o_kernel)
     return proj, cache_k, cache_v

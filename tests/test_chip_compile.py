@@ -1,10 +1,11 @@
-"""Compile the Pallas flash kernels for a described TPU v5e, without the chip.
+"""Compile the Pallas flash kernels, and one layer of the serve engine's cached
+attention, for a described TPU v5e, without the chip.
 
 The TPU's compiler is installed where the tests run, and compiles for a topology
 that is described, not attached (on-chip-measurement guide, section 2). It refuses
 what interpret mode lets through: a slice off the tiling, too much fast memory, a
-kernel it cannot place. Kernels only, at the shapes the repo really runs; whole-step
-compiles build a 152M-parameter model and stay scratch scripts.
+kernel it cannot place. Kernels and one layer only, at the shapes the repo really runs;
+whole-step compiles build a 152M-parameter model and stay scratch scripts.
 
 Everything that touches the topology lives in the module-scoped fixtures below:
 nothing here describes it at import, in a `skipif` or in `parametrize`, because the
@@ -20,6 +21,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu.llm._engine import _attn_cached
+from ray_tpu.models.transformer import ModelConfig
 from ray_tpu.ops.attention import _flash_backward, _flash_forward
 
 # (batch, heads, seq, head_dim) as the repo's configurations run the kernel, bf16.
@@ -90,3 +93,47 @@ def test_flash_backward_compiles_for_v5e(one_chip, model, layout):
     text = jax.jit(bwd).lower(x, x, x, x, lse, x).compile().as_text()
     assert "tpu_custom_call" in text
     assert re.search(r"%flash_bwd(\.\d+)? = ", text) and 'flash_bwd/pallas_call"' in text
+
+
+# InternLM2-1.8B's widths and the dense serve cells' 2048 rows a slot (PERF.md §4).
+_SERVE_CFG = ModelConfig(vocab_size=92544, hidden=2048, n_layers=24, n_heads=16, n_kv_heads=8,
+                         mlp_dim=8192, max_seq=2048, rope_theta=1e6)
+
+
+# (slots, query rows): those cells' decode step over all 12 slots, and a one-slot chunk of 128 tokens
+@pytest.mark.parametrize("slots,rows", [(12, 1), (1, 128)], ids=["decode_b12", "prefill_b128"])
+def test_cached_attention_copies_no_slab_for_v5e(one_chip, slots, rows):
+    """The grouped-query products read K and V where they lie: the compiled layer holds
+    no array of a slab repeated to all query heads. With `jnp.repeat` it held two
+    stand-alone `broadcast_in_dim` of that size, a third of a decode step (PERF.md §6, PR 29)."""
+    cfg, T = _SERVE_CFG, _SERVE_CFG.max_seq
+    H, Hkv, D, M = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.hidden
+    layer = {
+        name: {"kernel": _operand(shape, one_chip, jnp.float32)}
+        for name, shape in [("q", (M, H, D)), ("k", (M, Hkv, D)), ("v", (M, Hkv, D)),
+                            ("o", (H, D, M))]
+    }
+    slab = _operand((slots, T, Hkv, D), one_chip)
+
+    def attn(layer, x, positions, cache_k, cache_v, write_at, kv_mask):
+        return _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg)
+
+    text = jax.jit(attn).lower(
+        layer, _operand((slots, rows, M), one_chip),
+        _operand((slots, rows), one_chip, jnp.int32), slab, slab,
+        _operand((slots,), one_chip, jnp.int32),
+        _operand((slots, rows, T), one_chip, jnp.bool_),
+    ).compile().as_text()
+    assert f"bf16[{slots},{T},{Hkv},{D}]" in text  # the slabs themselves are there to find
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", text)}
+    G = H // Hkv
+    repeated = {(slots, T, H, D), (slots, T, Hkv, G, D)}
+    if slots == 1:
+        # without the unit axis; not (T, H, D), which at these widths is the q kernel too
+        repeated.add((T, Hkv, G, D))
+    else:
+        # and in any order of the axes ([B, Hkv, G, T, D], ...): with one query row no
+        # other array of the layer has a slab's rows and H heads' worth of elements
+        repeated |= {s for s in shapes if T in s and math.prod(s) == slots * T * H * D}
+    assert not repeated & shapes, sorted(repeated & shapes)

@@ -27,6 +27,136 @@ def _fresh_apps():
         serve.delete(app)
 
 
+def _attn_layer(cfg, key):
+    """One attention layer's kernels as the flax tree holds them, float32, drawn at
+    1 / sqrt(fan-in) so that outputs are of order 1 at every width."""
+    import jax
+
+    kq, kk, kv, ko = jax.random.split(key, 4)
+    H, Hkv, D, M = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.hidden
+    scale = M ** -0.5
+    return {
+        "q": {"kernel": scale * jax.random.normal(kq, (M, H, D))},
+        "k": {"kernel": scale * jax.random.normal(kk, (M, Hkv, D))},
+        "v": {"kernel": scale * jax.random.normal(kv, (M, Hkv, D))},
+        "o": {"kernel": scale * jax.random.normal(ko, (H, D, M))},
+    }
+
+
+def _attn_repeat_reference(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
+                           write_gate=None):
+    """`_attn_cached` spelled out slot by slot with every KV head copied to its
+    G query heads (`jnp.repeat`): what the grouped products have to equal."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import _rope
+
+    B, S, M = x.shape
+    H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = _rope((x @ layer["q"]["kernel"].reshape(M, -1)).reshape(B, S, H, D),
+              positions, cfg.rope_theta)
+    k = _rope((x @ layer["k"]["kernel"].reshape(M, -1)).reshape(B, S, Hkv, D),
+              positions, cfg.rope_theta)
+    v = (x @ layer["v"]["kernel"].reshape(M, -1)).reshape(B, S, Hkv, D)
+    outs = []
+    for b in range(B):
+        if write_gate is None or bool(write_gate[b]):
+            at = int(write_at[b])
+            cache_k = cache_k.at[b, at:at + S].set(k[b])
+            cache_v = cache_v.at[b, at:at + S].set(v[b])
+        kk = jnp.repeat(cache_k[b], H // Hkv, axis=1)  # [T, H, D]
+        vv = jnp.repeat(cache_v[b], H // Hkv, axis=1)
+        scores = jnp.einsum("shd,thd->hst", q[b], kk) / jnp.sqrt(float(D))
+        scores = jnp.where(kv_mask[b][None], scores, -1e30)
+        w = jnp.exp(scores - scores.max(-1, keepdims=True))
+        w = w / w.sum(-1, keepdims=True)
+        outs.append(jnp.einsum("hst,thd->shd", w, vv).reshape(S, H * D))
+    return jnp.stack(outs) @ layer["o"]["kernel"].reshape(-1, M), cache_k, cache_v
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "mixed_gate"])
+@pytest.mark.parametrize("S", [1, 5])
+@pytest.mark.parametrize("heads", [(16, 8), (8, 8), (32, 8), (4, 1)],
+                         ids=lambda hk: f"h{hk[0]}kv{hk[1]}")
+def test_attn_cached_equals_repeat_reference(heads, S, gated):
+    """Grouped-query products over the slab as it lies against an explicit copy of
+    every KV head to its query heads: output and both caches, float32, slots of
+    different lengths, the verify program's gated write among them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm._engine import _attn_cached
+    from ray_tpu.models.transformer import ModelConfig
+
+    H, Hkv = heads
+    D, B, T = 8, 3, 24
+    cfg = ModelConfig(hidden=H * D, n_heads=H, n_kv_heads=Hkv, dtype=jnp.float32,
+                      rope_theta=10000.0)
+    keys = jax.random.split(jax.random.PRNGKey(H * 100 + Hkv * 10 + S), 4)
+    layer = _attn_layer(cfg, keys[0])
+    x = jax.random.normal(keys[1], (B, S, cfg.hidden))
+    cache_k = jax.random.normal(keys[2], (B, T, Hkv, D))
+    cache_v = jax.random.normal(keys[3], (B, T, Hkv, D))
+    lens = jnp.asarray([0, 7, 17], jnp.int32)
+    positions = lens[:, None] + jnp.arange(S)[None]
+    # causal over the slot's own rows: query s sees rows 0 .. lens + s
+    kv_mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]
+    gate = jnp.asarray([True, False, True]) if gated else None
+
+    got = _attn_cached(layer, x, positions, cache_k, cache_v, lens, kv_mask, cfg,
+                       write_gate=gate)
+    want = _attn_repeat_reference(layer, x, positions, cache_k, cache_v, lens, kv_mask,
+                                  cfg, write_gate=gate)
+    for g, w, what in zip(got, want, ("out", "cache_k", "cache_v")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-5,
+                                   err_msg=what)
+    if gated:  # the slot whose gate is off keeps its rows
+        np.testing.assert_array_equal(np.asarray(got[1][1]), np.asarray(cache_k[1]))
+
+
+@pytest.mark.parametrize("side", ["keys", "values"])
+@pytest.mark.parametrize("heads", [(16, 8), (32, 8), (4, 1), (4, 2)],
+                         ids=lambda hk: f"h{hk[0]}kv{hk[1]}")
+def test_attn_cached_head_reads_its_own_kv_head(heads, side):
+    """Query head h reads KV head h // G and no other. Values: KV head k holds k + 1
+    in every row, so head h's output is h // G + 1 whatever it attends to. Keys: KV
+    head k's only non-zero key is row k and row t's value is t + 1 in every KV head,
+    so head h attends to row h // G alone and again reads h // G + 1."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm._engine import _attn_cached
+    from ray_tpu.models.transformer import ModelConfig
+
+    H, Hkv = heads
+    D, T = 8, 12
+    M = H * D
+    cfg = ModelConfig(hidden=M, n_heads=H, n_kv_heads=Hkv, dtype=jnp.float32)
+    layer = {
+        "q": {"kernel": jnp.full((M, H, D), 3.0 / M)},  # x of ones -> q of threes
+        "k": {"kernel": jnp.zeros((M, Hkv, D))},
+        "v": {"kernel": jnp.zeros((M, Hkv, D))},
+        "o": {"kernel": jnp.eye(M).reshape(H, D, M)},
+    }
+    if side == "values":
+        cache_k = jnp.zeros((1, T, Hkv, D))
+        per_kv_head = jnp.arange(1.0, Hkv + 1)[None, None, :, None]
+        cache_v = jnp.broadcast_to(per_kv_head, (1, T, Hkv, D))
+    else:
+        own_row = jnp.arange(T)[:, None] == jnp.arange(Hkv)[None, :]  # [T, Hkv]
+        cache_k = jnp.broadcast_to(3.0 * own_row[None, :, :, None], (1, T, Hkv, D))
+        per_row = jnp.arange(1.0, T + 1)[None, :, None, None]
+        cache_v = jnp.broadcast_to(per_row, (1, T, Hkv, D))
+    # positions 0 leave the rotation the identity; the gate keeps the slab as built
+    out, _, _ = _attn_cached(
+        layer, jnp.ones((1, 1, M)), jnp.zeros((1, 1), jnp.int32), cache_k, cache_v,
+        jnp.asarray([T - 1], jnp.int32), jnp.ones((1, 1, T), bool), cfg,
+        write_gate=jnp.asarray([False]))
+    want = np.repeat(np.arange(1.0, Hkv + 1), (H // Hkv) * D)
+    np.testing.assert_allclose(np.asarray(out[0, 0]), want, atol=1e-5)
+
+
 def test_engine_matches_full_forward():
     import jax
     import jax.numpy as jnp

@@ -74,7 +74,7 @@ import jax, jax.numpy as jnp
 from ray_tpu.models.transformer import Transformer, get_config
 from ray_tpu.llm._engine import DecodeEngine, SamplingParams
 
-cfg = get_config("test-tiny", scan_layers=False, remat=False, n_kv_heads=4)
+cfg = get_config("test-tiny", scan_layers=False, remat=False, n_kv_heads=__N_KV_HEADS__)
 model = Transformer(cfg)
 params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
 prompts = [[5, 9, 17, 3], [8, 2, 44, 7, 19, 21, 6], [5, 9, 17, 3]]
@@ -98,8 +98,36 @@ def program_count(e):
             pass
     return n
 
-out = {"devices": len(jax.devices()), "tokens": {}, "programs_flat": {}}
-for tp in (1, 2, 4):
+def attn_collectives(tp):
+    # one layer's cached attention, sharded as the engine shards it (heads and KV
+    # heads over tp): which collectives the partitioner puts into it
+    import re
+    from jax.sharding import NamedSharding
+    from ray_tpu.llm import tp as tp_plan
+    from ray_tpu.llm._engine import _attn_cached
+    mesh = tp_plan.build_tp_mesh(tp)
+    rep = tp_plan.replicated(mesh)
+    H, Hkv, D, M, B, T = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.hidden, 2, 64
+    def arg(shape, sharding=rep, dtype=cfg.dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    layer = {
+        name: {"kernel": arg(shape, NamedSharding(
+            mesh, tp_plan.param_spec(("layer_0", "attn", name, "kernel"), shape, mesh)))}
+        for name, shape in [("q", (M, H, D)), ("k", (M, Hkv, D)), ("v", (M, Hkv, D)),
+                            ("o", (H, D, M))]
+    }
+    slab = arg((B, T, Hkv, D), tp_plan.kv_cache_sharding(mesh, Hkv))
+    text = jax.jit(
+        lambda layer, x, pos, ck, cv, at, mask: _attn_cached(layer, x, pos, ck, cv, at, mask, cfg)
+    ).lower(layer, arg((B, 1, M)), arg((B, 1), dtype=jnp.int32), slab, slab,
+            arg((B,), dtype=jnp.int32), arg((B, 1, T), dtype=jnp.bool_)).compile().as_text()
+    return sorted(re.findall(
+        r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)(?:-start)?\(", text))
+
+out = {"devices": len(jax.devices()), "tokens": {}, "programs_flat": {}, "attn_collectives": {}}
+for tp in __TPS__:
+    if tp > 1:
+        out["attn_collectives"][str(tp)] = attn_collectives(tp)
     eng = DecodeEngine(cfg, params, num_slots=2, max_seq=64, tp=tp,
                        spec_config={"method": "ngram", "num_spec_tokens": 4})
     warm = [generate(eng, p) for p in prompts]   # warmup compiles everything
@@ -116,13 +144,21 @@ print("RESULT " + json.dumps(out))
 """
 
 
-def test_greedy_token_identity_across_tp_meshes(multi_device_run):
+@pytest.mark.parametrize("n_kv_heads,tps", [(4, (1, 2, 4)), (2, (1, 2))],
+                         ids=["mha_kv4", "grouped_kv2"])
+def test_greedy_token_identity_across_tp_meshes(multi_device_run, n_kv_heads, tps):
     """TP=1/2/4 greedy output bitwise token-identical, spec-verify included,
     program caches flat after warmup (zero mid-serve recompiles) — on the
-    subprocess-spawned 8-device CPU group, i.e. CI without TPUs."""
-    out = multi_device_run(_SWEEP_SNIPPET, timeout=900)
+    subprocess-spawned 8-device CPU group, i.e. CI without TPUs. With two KV
+    heads under four query heads each tp=2 shard holds one KV head and the two
+    query heads that read it: the grouped products split where the pool does,
+    and the layer's only collective stays the output projection's all-reduce."""
+    snippet = _SWEEP_SNIPPET.replace("__N_KV_HEADS__", str(n_kv_heads)).replace(
+        "__TPS__", repr(tps))
+    out = multi_device_run(snippet, timeout=900)
     assert out["devices"] >= 8, out["devices"]
-    assert out["tokens"]["1"] == out["tokens"]["2"] == out["tokens"]["4"], out
+    assert len({tuple(map(tuple, out["tokens"][str(tp)])) for tp in tps}) == 1, out
+    assert out["attn_collectives"] == {str(tp): ["all-reduce"] for tp in tps if tp > 1}, out
     for tp, (flat, n0, n1) in out["programs_flat"].items():
         assert flat, f"tp={tp}: program cache grew {n0} -> {n1} after warmup"
     # The spec phase really ran (the identity claim covers the verify path).
