@@ -260,7 +260,7 @@ class Raylet:
         self._obj_known: set[ObjectID] = set()  # flushed to GCS, not yet freed
         self._obj_flush_scheduled = False
         self.pull_manager = PullManager(CONFIG.pull_budget_bytes)
-        self._last_authoritative_views = 0.0  # composite-scheduling GCS probes
+        self._authoritative = (float("-inf"), {})  # (asked at, the GCS's node list): composite scheduling
         # pip runtime-env venvs (reference: runtime-env agent + env-keyed worker
         # pools, worker_pool.h:280): env key -> venv python path once built.
         self._venv_python: dict[str, str] = {}
@@ -1155,12 +1155,19 @@ class Raylet:
     async def _authoritative_views(self) -> dict:
         """Current cluster membership straight from the GCS: composite
         resolution must not miss a labeled node whose subscription update is
-        still in flight."""
-        try:
-            nodes = await self.gcs.call("get_nodes")
-            return {v["node_id"]: v for v in nodes}
-        except Exception:
-            return self.node_view
+        still in flight. Asked for at most once a second and kept between:
+        the dispatch loop comes here per queued task per pass and must not
+        head-of-line block on an RPC each time."""
+        asked, views = self._authoritative
+        now = time.monotonic()
+        if now - asked >= 1.0:
+            try:
+                nodes = await self.gcs.call("get_nodes")
+                views = {v["node_id"]: v for v in nodes}
+            except Exception:
+                views = self.node_view
+            self._authoritative = (now, views)
+        return views
 
     def _composite_choose(self, spec: dict, subs: list,
                           views: dict | None = None) -> dict | None:
@@ -1204,18 +1211,14 @@ class Raylet:
         strategy = spec.get("scheduling_strategy")
         views = None  # None => the subscribed node_view
         if strategy and strategy.get("composite"):
-            chosen = self._composite_choose(spec, strategy["composite"])
-            if chosen is None:
-                # The subscribed view may lag a just-registered labeled node:
-                # consult the GCS directly, but rate-limited — this loop runs
-                # per queued task per pass and must not head-of-line block on
-                # an RPC each time.
-                now = time.monotonic()
-                if now - self._last_authoritative_views < 1.0:
-                    return False
-                self._last_authoritative_views = now
+            subs = strategy["composite"]
+            chosen = self._composite_choose(spec, subs)
+            if chosen != (subs[0] or {}):
+                # None, or a later sub-strategy: an earlier one was turned down
+                # on the subscribed view, which may lag a just-registered
+                # labeled node. Resolve again on the GCS's own list.
                 views = await self._authoritative_views()
-                chosen = self._composite_choose(spec, strategy["composite"], views)
+                chosen = self._composite_choose(spec, subs, views)
                 if chosen is None:
                     return False  # nothing satisfiable yet: stay queued
             # chosen applies to THIS dispatch only (spec keeps the composite,

@@ -163,7 +163,7 @@ def get(ref: DeviceObjectRef, *, to_device: bool = False,
 
     Payloads below `devobj_stream_min_bytes` take the one-hop object-plane
     blob instead: a stream pays a control round-trip plus ring setup, which
-    only amortizes on multi-MB tensors (BENCH_PD.json). `_legacy=True`
+    only amortizes on multi-MB tensors. `_legacy=True`
     forces that path explicitly."""
     from ray_tpu._private.config import CONFIG
     from ray_tpu._private.worker import global_worker

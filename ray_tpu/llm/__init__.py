@@ -118,28 +118,26 @@ class LLMConfig:
     tp: Any = 1
 
 
+def model_config(config: "LLMConfig"):
+    """The ModelConfig a config serves. An unknown model_id with no model_config
+    raises (get_config): serving test-tiny under another model's name would look
+    like a working replica."""
+    from ray_tpu.models.transformer import get_config
+
+    return config.model_config or get_config(config.model_id)
+
+
 def load_model(config: "LLMConfig"):
     """Build (cfg, params) for a config — shared by monolithic and PD-disagg
-    deployments."""
+    deployments. Without a checkpoint the tree is the block's own, at seeded
+    random weights in `param_dtype`."""
     import jax
-    import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import Transformer, get_config
+    from ray_tpu import models
 
-    # An unknown model_id with no model_config raises (get_config): serving
-    # test-tiny under another model's name would look like a working replica.
-    cfg = config.model_config or get_config(config.model_id)
-    cfg = dataclasses.replace(cfg, scan_layers=False, remat=False)
-    if cfg.block != "llama":
-        # A block the flax Transformer does not build brings its own tree
-        # (`_engine._block_module`), at seeded random weights in `param_dtype`.
-        if config.checkpoint_path:
-            raise NotImplementedError(f"no checkpoint loader for block {cfg.block!r} yet")
-        from ray_tpu.llm._engine import _block_module
-
-        return cfg, _block_module(cfg).init_params(cfg, jax.random.PRNGKey(config.seed))
-    model = Transformer(cfg)
+    cfg = dataclasses.replace(model_config(config), scan_layers=False, remat=False)
     if config.checkpoint_path:
+        models.require(cfg, "checkpoint")
         from ray_tpu import checkpoint as ckpt_lib
 
         if ckpt_lib.is_sharded(config.checkpoint_path):
@@ -164,9 +162,7 @@ def load_model(config: "LLMConfig"):
             with open(os.path.join(config.checkpoint_path, "params.pkl"), "rb") as f:
                 params = pickle.load(f)
     else:
-        params = model.init(
-            jax.random.PRNGKey(config.seed), jnp.zeros((1, 8), jnp.int32)
-        )["params"]
+        params = models.block_module(cfg).init_params(cfg, jax.random.PRNGKey(config.seed))
     return cfg, params
 
 

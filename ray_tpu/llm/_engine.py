@@ -6,8 +6,11 @@ prefill + steady-state decode). Rebuilt TPU-first instead of wrapping a CUDA
 engine: static-shaped jitted prefill (per length bucket) and a single jitted
 decode step over B fixed slots with per-slot KV caches and length masks — no
 dynamic shapes anywhere, so XLA compiles exactly two core programs and the MXU
-stays on the batched matmul path. Weights are the flax Transformer's param tree
-(`ray_tpu/models/transformer.py`, scan_layers=False layout).
+stays on the batched matmul path. What the programs compute is the model
+block's: a module of pure functions over its parameter tree, looked up by
+`ModelConfig.block` (`ray_tpu/models/__init__.py`; `models/llama.py` is the
+dense block over the flax Transformer's tree in its scan_layers=False layout).
+This module holds no model code and knows no block by name.
 
 Control plane: the engine no longer schedules itself. An iteration-level
 `Scheduler` (`ray_tpu/llm/scheduler/`, docs/scheduler.md) owns the
@@ -24,7 +27,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import logging
-import math
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
@@ -56,9 +58,10 @@ from ray_tpu.llm.tp import (
     single_device_shardings,
     tp_degree,
 )
-from ray_tpu.models import dots3
-from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope, require_llama_block
+from ray_tpu import models
+from ray_tpu.models.transformer import ModelConfig
 from ray_tpu.util import xprof
+from ray_tpu.util.xprof import named
 
 _NEG_INF = -1e30
 
@@ -86,187 +89,10 @@ class SamplingParams:
     stop_token_id: Optional[int] = None
 
 
-# -- pure functional forward over the param tree ---------------------------
-
-
-def _lora_delta(x, A, B_, scale):
-    """Per-slot low-rank delta: x [B,S,M]; A [B,M,r]; B_ [B,r,O]; scale [B]."""
-    h = jnp.einsum("bsm,bmr->bsr", x, A.astype(x.dtype))
-    d = jnp.einsum("bsr,bro->bso", h, B_.astype(x.dtype))
-    return d * scale[:, None, None].astype(x.dtype)
-
-
-def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
-                 lora_layer=None, adapter_ids=None, write_gate=None):
-    """One attention layer against the KV cache.
-
-    x: [B, S, M]; positions: [B, S]; cache_k/v: [B, T, Hkv, D];
-    write_at: [B] start index per slot; kv_mask: [B, S, T] visibility.
-    lora_layer (optional): stacked adapters {"q_A": [A,M,r], "q_B": [A,r,H*D],
-    "v_A", "v_B", "scale": [A]} gathered per slot by adapter_ids [B] — the
-    multi-LoRA batching role of the reference's punica path, as plain gathers +
-    batched matmuls so one jitted program serves any adapter mix.
-    write_gate (optional): [B] bool — slots with a False gate leave their
-    cache rows untouched (the batched speculative-verify program runs every
-    slot through the forward but must only land KV for participants).
-    """
-    B, S, _ = x.shape
-    q = _dense(x, layer["q"]["kernel"].reshape(cfg.hidden, -1)).reshape(
-        B, S, cfg.n_heads, cfg.head_dim
-    )
-    k = _dense(x, layer["k"]["kernel"].reshape(cfg.hidden, -1)).reshape(
-        B, S, cfg.n_kv_heads, cfg.head_dim
-    )
-    v = _dense(x, layer["v"]["kernel"].reshape(cfg.hidden, -1)).reshape(
-        B, S, cfg.n_kv_heads, cfg.head_dim
-    )
-    if lora_layer is not None:
-        scale = lora_layer["scale"][adapter_ids]
-        dq = _lora_delta(
-            x, lora_layer["q_A"][adapter_ids], lora_layer["q_B"][adapter_ids], scale
-        )
-        q = q + dq.reshape(B, S, cfg.n_heads, cfg.head_dim)
-        dv = _lora_delta(
-            x, lora_layer["v_A"][adapter_ids], lora_layer["v_B"][adapter_ids], scale
-        )
-        v = v + dv.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-
-    if write_gate is None:
-        def put(slot_cache, slot_new, at):
-            return jax.lax.dynamic_update_slice(slot_cache, slot_new, (at, 0, 0))
-
-        cache_k = jax.vmap(put)(cache_k, k.astype(cache_k.dtype), write_at)
-        cache_v = jax.vmap(put)(cache_v, v.astype(cache_v.dtype), write_at)
-    else:
-        # Gated write: read the current rows and write them back unchanged
-        # when the gate is off. The read and write clamp identically at the
-        # cache end, so an off-gate slot is a no-op even at the boundary.
-        def put_gated(slot_cache, slot_new, at, gate):
-            cur = jax.lax.dynamic_slice(slot_cache, (at, 0, 0), slot_new.shape)
-            new = jnp.where(gate, slot_new, cur)
-            return jax.lax.dynamic_update_slice(slot_cache, new, (at, 0, 0))
-
-        cache_k = jax.vmap(put_gated)(
-            cache_k, k.astype(cache_k.dtype), write_at, write_gate
-        )
-        cache_v = jax.vmap(put_gated)(
-            cache_v, v.astype(cache_v.dtype), write_at, write_gate
-        )
-
-    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor
-    # of the split, and both products run against the slab as it lies: a copy of
-    # K or V repeated to H heads costs a third of a decode step (PERF.md §6, PR 29).
-    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    logits = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(q.dtype)) * scale
-    logits = jnp.where(kv_mask[:, None, None], logits.astype(jnp.float32), _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, cache_v.astype(q.dtype))
-    o_kernel = layer["o"]["kernel"].reshape(-1, cfg.hidden)
-    proj = _dense(out.reshape(B, S, -1), o_kernel)
-    return proj, cache_k, cache_v
-
-
-def _mlp(layer, x):
-    gate = _dense(x, layer["gate"]["kernel"])
-    up = _dense(x, layer["up"]["kernel"])
-    return _dense(jax.nn.silu(gate) * up, layer["down"]["kernel"])
-
-
-def _forward_cached(params, cfg: ModelConfig, tokens, positions, caches, write_at,
-                    kv_mask, lora=None, adapter_ids=None, write_gate=None):
-    """tokens: [B,S] -> logits [B,S,V]; updates caches in place (returned).
-
-    lora: the AdapterCache's STACKED tables ({"q_A": [L, S, M, r], ...}) —
-    per-layer views are extracted here inside the trace, so paging swaps the
-    whole table reference without touching program shapes.
-
-    The named scopes are the flax model's module names (`layer_<i>/attn`,
-    `mlp`, `attn_norm`, `mlp_norm`, `final_norm`, `lm_head`) plus `embedding`:
-    one list of scopes reads a device trace of either model (PERF.md §3).
-    They are metadata on the operations and change no program."""
-    embed = params["embedding"]
-    with jax.named_scope("embedding"):
-        x = embed[tokens].astype(cfg.dtype)
-    new_caches = []
-    for i in range(cfg.n_layers):
-        layer = params[f"layer_{i}"]
-        with jax.named_scope(f"layer_{i}"):
-            with jax.named_scope("attn_norm"):
-                normed = _rmsnorm(x, layer["attn_norm"]["scale"], cfg.norm_eps)
-            with jax.named_scope("attn"):
-                attn_out, ck, cv = _attn_cached(
-                    layer["attn"], normed, positions, caches[i][0], caches[i][1],
-                    write_at, kv_mask, cfg,
-                    lora_layer=None if lora is None else {k: v[i] for k, v in lora.items()},
-                    adapter_ids=adapter_ids,
-                    write_gate=write_gate,
-                )
-            new_caches.append((ck, cv))
-            x = x + attn_out
-            with jax.named_scope("mlp_norm"):
-                normed = _rmsnorm(x, layer["mlp_norm"]["scale"], cfg.norm_eps)
-            with jax.named_scope("mlp"):
-                x = x + _mlp(layer["mlp"], normed)
-    with jax.named_scope("final_norm"):
-        x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        if cfg.tie_embeddings:
-            logits = jax.lax.dot_general(
-                x.astype(cfg.dtype), embed.astype(cfg.dtype),
-                (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            )
-        else:
-            logits = _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
-        logits = logits.astype(jnp.float32)
-    return logits, new_caches
-
-
-def _block_module(cfg: ModelConfig):
-    """The module that runs a block other than the dense one: its `init_caches`,
-    `prefill` and `decode` take the place of the cache slab and of
-    `_forward_cached` in the engine's programs. None for the dense block, whose
-    programs are this module's own and do not change."""
-    if cfg.block == "llama":
-        return None
-    if cfg.block == "dots3":
-        return dots3
-    raise ValueError(f"unknown block {cfg.block!r}")
-
-
 def _rid(req: Request) -> str:
     """The id a request's `rt.engine.*` spans carry: its flight record's, so
     that a profiler trace and `request_timing()` name the request alike."""
     return req.rec.rid if req.rec is not None else (req.rid or "")
-
-
-def _named(name: str, fn, **static):
-    """`fn` (with `static` keyword arguments bound) under a `__name__` of its
-    own. jax names a compiled program after the function it traced
-    (`jit_<name>`), and that name is what a device trace shows: the engine's
-    programs are named here, by what they do and their static sizes, and not
-    by whatever the Python method happens to be called (PERF.md §3)."""
-
-    def program(*args):
-        return fn(*args, **static)
-
-    program.__name__ = program.__qualname__ = name
-    return program
-
-
-def _scatter_slot_caches(caches, new_slot, slot):
-    """Write a [1, T, ...] slot view back into the full [B, T, ...] caches."""
-    out = []
-    for (ck_full, cv_full), (ck, cv) in zip(caches, new_slot):
-        out.append((
-            jax.lax.dynamic_update_slice(ck_full, ck.astype(ck_full.dtype),
-                                         (slot, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(cv_full, cv.astype(cv_full.dtype),
-                                         (slot, 0, 0, 0)),
-        ))
-    return out
 
 
 def _sample_host(logits_row: np.ndarray, sampling: SamplingParams,
@@ -305,16 +131,15 @@ class DecodeEngine:
         from ray_tpu.parallel.mesh import unbox
 
         self.cfg = cfg
-        # A block other than the dense one brings its own cache and forward
-        # (`_block_module`); what it cannot do yet is refused here, by name.
-        self._block = _block_module(cfg)
-        if self._block is not None:
-            for asked, what in ((lora_config, "LoRA (lora_config)"),
-                                (spec_config, "speculative decoding (spec_config)"),
-                                (tp != 1, "tensor parallelism (llm/tp.py)"),
-                                (prefix_cache, "the prefix cache (llm/kvcache/)")):
-                if asked:
-                    require_llama_block(cfg, what)
+        # All the engine knows of the model is its block's module (`models/__init__.py`):
+        # the cache, the programs' bodies, what it counts and what it cannot take,
+        # which is refused here, by name.
+        self._block = models.block_module(cfg)
+        for asked, feature in ((lora_config, "lora"), (spec_config, "speculation"),
+                               (tp != 1, "tp"), (prefix_cache, "prefix_cache")):
+            if asked:
+                models.require(cfg, feature)
+        if "prefix_cache" not in self._block.SUPPORTS:
             prefix_cache = False  # the default from the config flags is off for it too
         self.params = unbox(params)  # strip flax LogicallyPartitioned boxes
         self.B = num_slots
@@ -362,25 +187,18 @@ class DecodeEngine:
                 mesh=self._mesh,
             )
         self._adapter_ids = np.zeros((num_slots,), np.int32)
-        kv_shape = (self.B, self.T, cfg.n_kv_heads, cfg.head_dim)
-        if self._block is not None:
-            # per layer a tuple of [B, rows, width] arrays, by the layer's kind
-            self._caches = self._block.init_caches(cfg, self.B, self.T)
-        elif self._mesh is not None:
+        if self._mesh is not None:
             # Mesh-resident per-slot KV pool: shards allocate at their
             # kv-head-split layout directly (never materialized whole on any
             # one device); freed by shutdown via the tracked pool handle.
             self._kv_pool = ShardedKVPool(
-                n_layers=cfg.n_layers, shape=kv_shape, dtype=cfg.dtype,
-                mesh=self._mesh, n_kv_heads=cfg.n_kv_heads,
+                n_layers=cfg.n_layers, shape=(self.B, self.T, cfg.n_kv_heads, cfg.head_dim),
+                dtype=cfg.dtype, mesh=self._mesh, n_kv_heads=cfg.n_kv_heads,
                 name=f"engine-{id(self):x}",
             )
             self._caches = self._kv_pool.take()
         else:
-            self._caches = [
-                (jnp.zeros(kv_shape, cfg.dtype), jnp.zeros(kv_shape, cfg.dtype))
-                for _ in range(cfg.n_layers)
-            ]
+            self._caches = self._block.init_caches(cfg, self.B, self.T)
         # Per-slot lengths and last tokens are HOST-native (numpy): the
         # stepper reads and writes them every step, and a device-canonical
         # copy would force a blocking device->host pull per step just to do
@@ -419,17 +237,17 @@ class DecodeEngine:
 
         xprof.register_memory_owner(self._xprof_owner, _ledger_row)
         self._jit_prefill = {}
-        # A block's programs also return its expert layers' counts (one small
-        # array a dispatch), added on the device into one running sum that only
-        # scheduler_stats() reads. Its caches are donated: nothing but the next
-        # program reads them.
-        self._expert_acc = None
-        self._expert_seen = None  # the running sum as the last report read it
-        self._expert_totals = None
-        self._expert_lock = threading.Lock()  # reports come from any thread
+        # Every program returns its block's counts last (small arrays, none where
+        # the block counts nothing), added on the device into one running sum
+        # that only scheduler_stats() reads.
+        self._stats_acc = self._block.init_stats(cfg)
+        # the running sum as the last report read it, and the reports' total
+        self._stats_seen = tuple(np.zeros(a.shape, np.uint32) for a in self._stats_acc)
+        self._stats_totals = tuple(np.zeros(a.shape, np.int64) for a in self._stats_acc)
+        self._stats_lock = threading.Lock()  # reports come from any thread
         self._jit_decode = self._xprof.instrument(
             self._xprof_owner, ("decode",),
-            jax.jit(_named("rt_decode", self._decode_step), **self._donate(4)),
+            jax.jit(named("rt_decode", self._decode_step), **self._donate(4)),
         )
         # Multi-step decode: N greedy tokens per dispatch (argmax on device,
         # lax.scan over decode steps) — one host round trip per CHUNK instead
@@ -692,64 +510,36 @@ class DecodeEngine:
     # -- jitted programs ---------------------------------------------------
     def _donate(self, caches_arg: int) -> dict:
         """`jax.jit` options of a program that takes the caches at that position."""
-        return {} if self._block is None else {"donate_argnums": (caches_arg,)}
+        return {"donate_argnums": (caches_arg,)} if self._block.DONATES_CACHES else {}
 
     def _prefill_at(self, params, lora, tokens, caches, slot, offset,
                     total_len, adapter_id):
-        """tokens: [1, Sbucket] right-padded, starting at row/position `offset`
-        (0 = whole-prompt prefill; >0 = a later CHUNK, or suffix-only prefill
-        behind a prefix cache hit whose KV was attached to rows [0, offset)).
-        Writes slot `slot`'s cache rows [offset, offset+S). One program per
+        """One chunk of one slot (the block's `prefill`). One program per
         bucket: offset and total_len are traced scalars — a chunked prefill
         of any length mix reuses exactly these bucket programs. Slot lengths
         are host-side state (the dispatcher records total_len itself — no
         device lens write)."""
-        if self._block is not None:
-            return self._block.prefill(params, self.cfg, tokens, caches, slot,
-                                       offset, total_len)
-        S = tokens.shape[1]
-        positions = offset + jnp.arange(S)[None, :]
-        # one-slot caches view
-        slot_caches = [
-            (c[0][slot][None], c[1][slot][None]) for c in caches
-        ]
-        # visibility: key row j <= global query position offset+i; attached
-        # prefix rows [0, offset) are all visible, pad rows beyond stay hidden
-        mask = (positions[0][:, None] >= jnp.arange(self.T)[None, :])[None]
-        logits, new_slot_caches = _forward_cached(
-            params, self.cfg, tokens, positions, slot_caches,
-            offset[None], mask,
-            lora=lora, adapter_ids=adapter_id[None],
-        )
-        out_caches = _scatter_slot_caches(caches, new_slot_caches, slot)
-        last = logits[0, total_len - 1 - offset]
-        return last, out_caches
+        last, caches, stats = self._block.prefill(
+            params, self.cfg, tokens, caches, slot, offset, total_len, lora, adapter_id)
+        return (last, caches, *stats)
 
     def _decode_step(self, params, lora, adapter_ids, last_token, caches, lens,
                      gate):
-        """One token for every slot. last_token: [B]; lens: [B] current
-        lengths; gate: [B] bool — only slots in the decode phase land their
-        KV row. A slot mid-chunked-prefill rides through the batched forward
-        with a stale lens, and an ungated write there would permanently
-        corrupt rows its covering chunk already wrote (same hazard the
-        spec-verify gate exists for)."""
-        if self._block is not None:
-            logits, new_caches, stats = self._block.decode(
-                params, self.cfg, last_token, caches, lens, gate)
-            return logits, new_caches, lens + 1, stats
-        positions = lens[:, None]
-        # key j visible iff j <= lens (the new token writes at index lens)
-        kv_mask = (jnp.arange(self.T)[None, :] <= lens[:, None])[:, None, :]
-        logits, new_caches = _forward_cached(
-            params, self.cfg, last_token[:, None], positions, caches, lens, kv_mask,
-            lora=lora, adapter_ids=adapter_ids, write_gate=gate,
-        )
-        return logits[:, 0], new_caches, lens + 1
+        """One token for every slot (the block's `decode`). last_token: [B];
+        lens: [B] current lengths; gate: [B] bool — only slots in the decode
+        phase land their KV row. A slot mid-chunked-prefill rides through the
+        batched forward with a stale lens, and an ungated write there would
+        permanently corrupt rows its covering chunk already wrote (same hazard
+        the spec-verify gate exists for)."""
+        logits, new_caches, stats = self._block.decode(
+            params, self.cfg, last_token, caches, lens, gate, lora, adapter_ids)
+        return (logits, new_caches, lens + 1, *stats)
 
     def _decode_multi(self, params, lora, adapter_ids, last_token, caches, lens,
                       gate, *, n):
         """n greedy tokens for every slot in ONE program: lax.scan over decode
-        steps with on-device argmax. Returns ([n, B] tokens, final caches/lens)."""
+        steps with on-device argmax. Returns ([n, B] tokens, final caches/lens,
+        the steps' stats summed)."""
 
         def step(carry, _):
             last, c, l = carry
@@ -767,11 +557,12 @@ class DecodeEngine:
 
     def _spec_verify_batched(self, params, lora, adapter_ids, tokens, caches,
                              lens, gate, constraint_mask):
-        """Target forward over [t0, d1..dk] for EVERY slot in one dispatch:
-        tokens [B, k+1] at positions lens..lens+k. Non-participating slots
-        (gate False) flow through the forward for batching but leave their
-        KV rows untouched — the canonical row for a plainly-decoding slot is
-        written by the decode dispatch that follows the verify phase.
+        """Target forward over [t0, d1..dk] for EVERY slot in one dispatch
+        (the block's `verify`): tokens [B, k+1] at positions lens..lens+k.
+        Non-participating slots (gate False) flow through the forward for
+        batching but leave their KV rows untouched — the canonical row for a
+        plainly-decoding slot is written by the decode dispatch that follows
+        the verify phase.
 
         constraint_mask [B, k+1, V] is the guided-decoding composition point
         (docs/generation.md): an ALWAYS-PASSED additive logits mask — all
@@ -786,16 +577,11 @@ class DecodeEngine:
 
         Returns on-device argmax [B, k+1] (the host needs k+1 ints per slot,
         not logits)."""
-        B, S = tokens.shape
-        positions = lens[:, None] + jnp.arange(S)[None, :]
-        kv_mask = jnp.arange(self.T)[None, None, :] <= positions[:, :, None]
-        logits, new_caches = _forward_cached(
-            params, self.cfg, tokens, positions, caches, lens, kv_mask,
-            lora=lora, adapter_ids=adapter_ids, write_gate=gate,
-        )
+        logits, new_caches, stats = self._block.verify(
+            params, self.cfg, tokens, caches, lens, gate, lora, adapter_ids)
         with jax.named_scope("sample"):
             greedy = jnp.argmax(logits + constraint_mask, axis=-1).astype(jnp.int32)
-        return greedy, new_caches
+        return (greedy, new_caches, *stats)
 
     # -- speculative phase --------------------------------------------------
     def _spec_round(self, plan: Plan):
@@ -834,13 +620,14 @@ class DecodeEngine:
                         rows=int(self._lens[plan.spec_slots].sum())) as dispatch:
             verify = self._program(
                 self._jit_spec_verify, ("verify", S),
-                lambda: jax.jit(_named(f"rt_verify_s{S}", self._spec_verify_batched)),
+                lambda: jax.jit(named(f"rt_verify_s{S}", self._spec_verify_batched)),
             )
-            greedy_dev, self._caches = verify(
+            greedy_dev, self._caches, *stats = verify(
                 self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
                 jnp.asarray(tokens), self._caches, jnp.asarray(self._lens),
                 jnp.asarray(gate), jnp.asarray(cmask),
             )
+            self._note_stats(stats)
         # The round's ONE acceptance sync: k+1 tokens per participating slot
         # arrive in a single batched pull — no per-token host round trip.
         with xprof.span("rt.engine.readback", bytes=greedy_dev.nbytes):
@@ -911,8 +698,8 @@ class DecodeEngine:
             gather = self._program(
                 self._jit_kv_gather, ("kv_gather", bucket),
                 lambda: jax.jit(
-                    _named(f"rt_kv_gather_b{bucket}", self._gather_slot_kv,
-                           rows=bucket),
+                    named(f"rt_kv_gather_b{bucket}", self._block.gather_rows,
+                          rows=bucket),
                     out_shardings=self.kv_transfer_sharding,
                 ),
             )
@@ -921,18 +708,6 @@ class DecodeEngine:
             self._kv_pending.append(_PendingKVInsert(prompt[:n], adapter, kv, rid))
             self._kv_counters["inserts_issued"] += 1
         self._kv_wake.set()
-
-    @staticmethod
-    def _gather_slot_kv(caches, slot, *, rows: int):
-        """Slot `slot`'s cache rows [0, rows) of every layer as one array in
-        the prefix pool's layout, [L, 2, rows, Hkv, D] in the caches' dtype.
-        The caches are read, not consumed: no donation."""
-
-        def take(c):
-            return jax.lax.dynamic_slice(
-                c, (slot, 0, 0, 0), (1, rows) + c.shape[2:])[0]
-
-        return jnp.stack([jnp.stack([take(ck), take(cv)]) for ck, cv in caches])
 
     def _finish_kv_inserts(self, keep: int = 0, worker: bool = False):
         """Hand pending inserts to the prefix pool, oldest first, until `keep`
@@ -1099,37 +874,27 @@ class DecodeEngine:
             "n_kv_heads": cfg.n_kv_heads, "vocab_size": cfg.vocab_size,
             "num_slots": self.B, "max_seq": self.T, "tp": self.tp, "block": cfg.block,
         }
-        if self._block is not None:
-            out["experts"] = self._expert_report()
+        out.update(self._block_report())
         return out
 
-    def _note_expert_stats(self, stats: list) -> None:
-        """Add a dispatch's expert counts (none for the dense block) to the running sum
-        on the device: one small add, no readback. The sum is int32 and may wrap; a
-        report takes differences, which a wrap leaves right."""
-        for counts in stats:
-            self._expert_acc = counts if self._expert_acc is None else self._expert_acc + counts
+    def _note_stats(self, stats) -> None:
+        """Add a dispatch's counts (none for a block that counts nothing) to the
+        running sum on the device: one small add each, no readback. The sum is
+        int32 and may wrap; a report takes differences, which a wrap leaves right."""
+        self._stats_acc = tuple(acc + counts for acc, counts in zip(self._stats_acc, stats))
 
-    def _expert_report(self) -> dict:
-        """The expert layers' counts (report path): token-expert pairs routed and pairs
-        whose expert this chip holds, since the engine started and over the window
-        since the last report, with the largest and the mean load of a held expert
-        there. One readback of the running sum."""
-        acc = self._expert_acc  # the stepper replaces it, never changes it
-        with self._expert_lock:
-            zeros = np.zeros((2 + self.cfg.n_routed_experts,), np.uint32)
-            now = zeros if acc is None else np.asarray(acc).astype(np.uint32)
-            seen = zeros if self._expert_seen is None else self._expert_seen
-            window = (now - seen).astype(np.int64)  # modulo 2**32: right across a wrap
-            self._expert_seen = now
-            total = self._expert_totals = window if self._expert_totals is None else self._expert_totals + window
-        return {
-            "held": self.cfg.n_routed_experts, "of": self.cfg.n_routed_experts_total,
-            "first": self.cfg.first_expert,
-            "pairs_routed": int(total[0]), "pairs_held": int(total[1]),
-            "window": {"pairs_routed": int(window[0]), "pairs_held": int(window[1]),
-                       "max_load": int(window[2:].max()), "mean_load": float(window[2:].mean())},
-        }
+    def _block_report(self) -> dict:
+        """What the block says of its counts (report path): one readback of the
+        running sum, handed to the block as the counts since the engine started
+        and over the window since the last report."""
+        acc = self._stats_acc  # the stepper replaces it, never changes it
+        with self._stats_lock:
+            now = tuple(np.asarray(a).astype(np.uint32) for a in acc)
+            # modulo 2**32: right across a wrap
+            window = tuple((n - seen).astype(np.int64) for n, seen in zip(now, self._stats_seen))
+            self._stats_seen = now
+            total = self._stats_totals = tuple(t + w for t, w in zip(self._stats_totals, window))
+        return self._block.report(self.cfg, total, window)
 
     def _flush_observability(self) -> dict:
         """Report-path export: queued completion summaries become
@@ -1256,20 +1021,6 @@ class DecodeEngine:
                 return kv
         return lease.kv()
 
-    def _attach_kv(self, caches, kv, slot):
-        """Write a transferred KV prefix into slot's cache rows [0, P).
-        kv: [L, 2, P, Hkv, D] (P = padded prefix bucket)."""
-        out = []
-        for i in range(self.cfg.n_layers):
-            ck = jax.lax.dynamic_update_slice(
-                caches[i][0], kv[i, 0][None].astype(caches[i][0].dtype), (slot, 0, 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                caches[i][1], kv[i, 1][None].astype(caches[i][1].dtype), (slot, 0, 0, 0)
-            )
-            out.append((ck, cv))
-        return out
-
     # -- public API --------------------------------------------------------
     def submit(self, token_ids: List[int], sampling: SamplingParams, callback,
                lora: str = "", tenant: Optional[str] = None,
@@ -1381,7 +1132,7 @@ class DecodeEngine:
         token_ids (optional, the prompt behind kv) lets the transferred
         prefix feed this engine's KV prefix cache AND keeps the slot
         spec-eligible (the draft catches up on the token history)."""
-        require_llama_block(self.cfg, "PD disaggregation (llm/pd_disagg.py)")
+        models.require(self.cfg, "pd")
         self._check_alive()
         if prompt_len >= self.T:
             raise ValueError(
@@ -1554,7 +1305,7 @@ class DecodeEngine:
         evicted-and-reused between resolution and the dispatch capturing the
         table reference — after that, jax buffer immutability makes the
         captured table safe regardless."""
-        require_llama_block(self.cfg, "PD disaggregation (llm/pd_disagg.py)")
+        models.require(self.cfg, "pd")
         prompt = list(token_ids)
         if len(prompt) > self.T - 1:
             raise ValueError(
@@ -1609,30 +1360,11 @@ class DecodeEngine:
                 padded[0, : len(prompt)] = prompt
 
                 def make_detached():
-                    cfg = self.cfg
-
                     def detached(params, lora_p, tokens, adapter_id):
-                        S = tokens.shape[1]
-                        positions = jnp.arange(S)[None, :]
-                        caches = [
-                            (
-                                jnp.zeros((1, S, cfg.n_kv_heads, cfg.head_dim), cfg.dtype),
-                                jnp.zeros((1, S, cfg.n_kv_heads, cfg.head_dim), cfg.dtype),
-                            )
-                            for _ in range(cfg.n_layers)
-                        ]
-                        mask = (jnp.arange(S)[:, None] >= jnp.arange(S)[None, :])[None]
-                        logits, new_caches = _forward_cached(
-                            params, cfg, tokens, positions, caches,
-                            jnp.zeros((1,), jnp.int32), mask,
-                            lora=lora_p, adapter_ids=adapter_id[None],
-                        )
-                        kv = jnp.stack(
-                            [jnp.stack([ck[0], cv[0]]) for ck, cv in new_caches]
-                        )  # [L, 2, S, Hkv, D]
-                        return logits[0], kv
+                        return self._block.prefill_detached(
+                            params, self.cfg, tokens, lora_p, adapter_id)
 
-                    return jax.jit(_named(f"rt_prefill_detached_b{bucket}", detached))
+                    return jax.jit(named(f"rt_prefill_detached_b{bucket}", detached))
 
                 prog = self._program(
                     self._jit_prefill, ("detached", bucket), make_detached
@@ -1705,43 +1437,12 @@ class DecodeEngine:
         padded[0, : len(suffix)] = suffix
 
         def make_detached_suffix():
-            cfg = self.cfg
-
             def detached_suffix(params, lora_p, prefix, tokens, off, adapter_id):
-                # cache layout: rows [0, mb) = attached prefix (valid [0, off)),
-                # rows [mb, mb+sb) = this pass's suffix writes.
-                caches = []
-                for i in range(cfg.n_layers):
-                    zeros = jnp.zeros(
-                        (1, sb, cfg.n_kv_heads, cfg.head_dim), cfg.dtype
-                    )
-                    caches.append((
-                        jnp.concatenate(
-                            [prefix[i, 0][None].astype(cfg.dtype), zeros], axis=1
-                        ),
-                        jnp.concatenate(
-                            [prefix[i, 1][None].astype(cfg.dtype), zeros], axis=1
-                        ),
-                    ))
-                positions = off + jnp.arange(sb)[None, :]
-                rows = jnp.arange(mb + sb)[None, :]
-                # visible: real prefix rows, plus suffix rows written so far
-                mask = (
-                    (rows < off)
-                    | ((rows >= mb) & (rows - mb <= jnp.arange(sb)[:, None]))
-                )[None]
-                logits, new_caches = _forward_cached(
-                    params, cfg, tokens, positions, caches,
-                    jnp.full((1,), mb, jnp.int32), mask,
-                    lora=lora_p, adapter_ids=adapter_id[None],
-                )
-                suffix_kv = jnp.stack([
-                    jnp.stack([ck[0, mb:], cv[0, mb:]]) for ck, cv in new_caches
-                ])  # [L, 2, sb, Hkv, D]
-                return logits[0], suffix_kv
+                return self._block.prefill_detached_suffix(
+                    params, self.cfg, prefix, tokens, off, lora_p, adapter_id)
 
-            return jax.jit(_named(f"rt_prefill_detached_suffix_b{mb}_{sb}",
-                                  detached_suffix))
+            return jax.jit(named(f"rt_prefill_detached_suffix_b{mb}_{sb}",
+                                 detached_suffix))
 
         prog = self._program(
             self._jit_prefill, ("detached_suffix", mb, sb), make_detached_suffix
@@ -1909,7 +1610,7 @@ class DecodeEngine:
                         prefix_kv = xp.concatenate([prefix_kv, pad], axis=2)
                     attach = self._program(
                         self._jit_prefill, ("attach", mb),
-                        lambda: jax.jit(_named(f"rt_attach_b{mb}", self._attach_kv)),
+                        lambda: jax.jit(named(f"rt_attach_b{mb}", self._block.attach_rows)),
                     )
                     self._caches = attach(
                         self._caches,
@@ -1938,7 +1639,7 @@ class DecodeEngine:
         padded[0, : len(chunk.tokens)] = chunk.tokens
         prefill = self._program(
             self._jit_prefill, chunk.bucket,
-            lambda: jax.jit(_named(f"rt_prefill_b{chunk.bucket}", self._prefill_at),
+            lambda: jax.jit(named(f"rt_prefill_b{chunk.bucket}", self._prefill_at),
                             **self._donate(3)),
         )
         last_logits, self._caches, *stats = prefill(
@@ -1946,7 +1647,7 @@ class DecodeEngine:
             jnp.int32(slot), jnp.int32(offset),
             jnp.int32(req.prompt_len), jnp.int32(req.adapter_slot),
         )
-        self._note_expert_stats(stats)
+        self._note_stats(stats)
         self._sched.chunk_done(chunk)
         if rec is not None:
             rec.span("prefill-chunk", t_chunk, time.time(),
@@ -2020,7 +1721,7 @@ class DecodeEngine:
                 kv = kv[:, :, :bucket]
             attach = self._program(
                 self._jit_prefill, ("attach", bucket),
-                lambda: jax.jit(_named(f"rt_attach_b{bucket}", self._attach_kv)),
+                lambda: jax.jit(named(f"rt_attach_b{bucket}", self._block.attach_rows)),
             )
             self._caches = attach(
                 self._caches, kv if on_device else jnp.asarray(kv), jnp.int32(slot)
@@ -2253,7 +1954,7 @@ class DecodeEngine:
                 jnp.asarray(self._last_token), self._caches,
                 jnp.asarray(self._lens), jnp.asarray(gate),
             )
-            self._note_expert_stats(stats)
+            self._note_stats(stats)
         # The step's ONE device->host pull: every active slot's next-token
         # logits arrive in a single [B, V] readback (sampling params can
         # differ per slot, so sampling itself is host-side).
@@ -2298,7 +1999,7 @@ class DecodeEngine:
             gate[decode_slots] = True
             decode_multi = self._program(
                 self._jit_decode_multi, ("decode_multi", n),
-                lambda: jax.jit(_named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
+                lambda: jax.jit(named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
                                 **self._donate(4)),
             )
             toks_dev, self._caches, _, *stats = decode_multi(
@@ -2306,7 +2007,7 @@ class DecodeEngine:
                 jnp.asarray(self._last_token), self._caches,
                 jnp.asarray(self._lens), jnp.asarray(gate),
             )
-            self._note_expert_stats(stats)
+            self._note_stats(stats)
         # The chunk's ONE device->host pull: n tokens x B slots per readback
         # (the whole point of multi-step decode).
         with xprof.span("rt.engine.readback", bytes=toks_dev.nbytes):

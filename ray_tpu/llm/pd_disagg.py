@@ -21,22 +21,18 @@ import time
 import uuid
 from typing import Any, List, Optional, Union
 
-from ray_tpu.llm import ByteTokenizer, LLMConfig, SamplingParams, load_model, resolve_tokenizer
+from ray_tpu import models
+from ray_tpu.llm import (
+    ByteTokenizer, LLMConfig, SamplingParams, load_model, model_config, resolve_tokenizer,
+)
 from ray_tpu.llm._engine import DecodeEngine
-from ray_tpu.models.transformer import require_llama_block
-
-
-def _refuse_other_blocks(config: LLMConfig) -> None:
-    """Before any weight is built: the KV hand-over here is the dense block's [L, 2, P, Hkv, D]."""
-    if config.model_config is not None:
-        require_llama_block(config.model_config, "PD disaggregation (llm/pd_disagg.py)")
 
 
 class PrefillServer:
     """Prefill-only replica: turns a prompt into (first_logits, KV prefix)."""
 
     def __init__(self, config: LLMConfig):
-        _refuse_other_blocks(config)
+        models.require(model_config(config), "pd")  # before any weight is built
         cfg, params = load_model(config)
         self._engine = DecodeEngine(
             cfg, params, num_slots=1,
@@ -176,7 +172,7 @@ class DecodeServer:
     """Decode-only replica: continues generation from a transferred KV prefix."""
 
     def __init__(self, config: LLMConfig):
-        _refuse_other_blocks(config)
+        models.require(model_config(config), "pd")  # before any weight is built
         cfg, params = load_model(config)
         self._tokenizer = resolve_tokenizer(config.tokenizer)
         self._engine = DecodeEngine(

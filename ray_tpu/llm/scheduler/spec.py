@@ -161,7 +161,7 @@ class ModelDraft(DraftProvider):
 
     def __init__(self, cfg, params, *, k: int, num_slots: int, max_seq: int,
                  program: Callable, bucket: Callable):
-        import jax.numpy as jnp
+        from ray_tpu.models import llama
 
         assert not cfg.scan_layers, "draft expects scan_layers=False layout"
         self.cfg = cfg
@@ -171,11 +171,7 @@ class ModelDraft(DraftProvider):
         self.T = max_seq
         self._program = program     # engine's capped get-or-build helper
         self._bucket = bucket
-        kv_shape = (self.B, self.T, cfg.n_kv_heads, cfg.head_dim)
-        self.caches = [
-            (jnp.zeros(kv_shape, cfg.dtype), jnp.zeros(kv_shape, cfg.dtype))
-            for _ in range(cfg.n_layers)
-        ]
+        self.caches = llama.init_caches(cfg, self.B, self.T)
         self._host_lens = np.zeros((self.B,), np.int32)
         self._ready = [False] * self.B
         # all-k-accepted leaves one proposed token's kv missing from the
@@ -196,7 +192,7 @@ class ModelDraft(DraftProvider):
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.llm import _engine as eng
+        from ray_tpu.models import llama
 
         dcfg = self.cfg
         slot_caches = [(c[0][slot][None], c[1][slot][None]) for c in caches]
@@ -205,7 +201,7 @@ class ModelDraft(DraftProvider):
         def step(carry, idx):
             tok, sc, pos = carry
             kv_mask = (jnp.arange(self.T)[None, :] <= pos)[None]
-            logits, new_sc = eng._forward_cached(
+            logits, new_sc = llama._forward_cached(
                 params, dcfg, tok[None, None], pos[None, None], sc,
                 pos[None], kv_mask, lora=None, adapter_ids=None,
             )
@@ -219,7 +215,7 @@ class ModelDraft(DraftProvider):
         )
         if catchup:
             toks = toks[1:]
-        return toks, eng._scatter_slot_caches(caches, out_slot, slot)
+        return toks, llama._scatter_slot_caches(caches, out_slot, slot)
 
     def _prefill_prog(self, params, caches, tokens, slot):
         """Prefill the DRAFT cache on the (padded) whole prompt: spec decode
@@ -228,17 +224,17 @@ class ModelDraft(DraftProvider):
         another engine's attached prefix rows."""
         import jax.numpy as jnp
 
-        from ray_tpu.llm import _engine as eng
+        from ray_tpu.models import llama
 
         S = tokens.shape[1]
         positions = jnp.arange(S)[None, :]
         slot_caches = [(c[0][slot][None], c[1][slot][None]) for c in caches]
         mask = (jnp.arange(S)[:, None] >= jnp.arange(self.T)[None, :])[None]
-        _logits, new_slot = eng._forward_cached(
+        _logits, new_slot = llama._forward_cached(
             params, self.cfg, tokens, positions, slot_caches,
             jnp.zeros((1,), jnp.int32), mask, lora=None, adapter_ids=None,
         )
-        return eng._scatter_slot_caches(caches, new_slot, slot)
+        return llama._scatter_slot_caches(caches, new_slot, slot)
 
     # -- DraftProvider ------------------------------------------------------
     def eligible(self, slot_idx: int, slot) -> bool:
@@ -255,11 +251,11 @@ class ModelDraft(DraftProvider):
         dlens = int(self._host_lens[slot_idx])
         pend = self._pending[slot_idx]
         catchup = pend is not None
-        from ray_tpu.llm._engine import _named
+        from ray_tpu.util.xprof import named
 
         prog = self._program(
             self._progs, ("propose", self.k, catchup),
-            lambda: jax.jit(_named(
+            lambda: jax.jit(named(
                 f"rt_draft_propose_k{self.k}" + ("_catchup" if catchup else ""),
                 self._propose_prog, k=self.k, catchup=catchup)),
         )
@@ -282,11 +278,11 @@ class ModelDraft(DraftProvider):
         bucket = self._bucket(len(prompt))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = prompt
-        from ray_tpu.llm._engine import _named
+        from ray_tpu.util.xprof import named
 
         prog = self._program(
             self._progs, ("dprefill", bucket),
-            lambda: jax.jit(_named(f"rt_draft_prefill_b{bucket}", self._prefill_prog)),
+            lambda: jax.jit(named(f"rt_draft_prefill_b{bucket}", self._prefill_prog)),
         )
         self.caches = prog(self.params, self.caches, jnp.asarray(padded),
                            jnp.int32(slot_idx))

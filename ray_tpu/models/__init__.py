@@ -1,0 +1,61 @@
+"""Which module runs a model's block, and what that block can take.
+
+A block's module is a set of pure functions over its parameter tree, and it is all the
+serve engine knows of a model (`llm/_engine.py` calls nothing else):
+
+    SUPPORTS          frozenset of FEATURES the block can take
+    DONATES_CACHES    whether its programs may consume the caches they are given
+    init_params(cfg, key)                  the tree served at random weights
+    init_caches(cfg, slots, max_seq)       per layer a tuple of [slots, ...] arrays
+    prefill(params, cfg, tokens, caches, slot, offset, total_len, lora, adapter_id)
+                                           one chunk of one slot -> (last logits [V], caches, stats)
+    decode(params, cfg, last_token, caches, lens, gate, lora, adapter_ids)
+                                           one token for every slot -> (logits [B, V], caches, stats)
+    init_stats(cfg)                        zeros shaped like `stats`, a tuple of int32 arrays
+                                           (empty where the block counts nothing)
+    report(cfg, total, window)             what scheduler_stats() says of those counts
+                                           since the start and since the last report
+
+and, with the feature that needs them: `verify` ("speculation"), `gather_rows` and
+`attach_rows` ("prefix_cache", "pd"), `prefill_detached` and `prefill_detached_suffix`
+("pd"); `models/llama.py` has them all. `lora` and the adapter ids are None and zeros
+for a block that does not list "lora".
+"""
+
+from __future__ import annotations
+
+import importlib
+
+# block name (`ModelConfig.block`) -> the module that runs it, imported when asked for
+# (`models/transformer.py` asks too, and the modules import it).
+BLOCKS = {
+    "llama": "ray_tpu.models.llama",
+    "dots3": "ray_tpu.models.dots3",
+}
+
+# What a caller may ask of a block, and how the refusal names the caller.
+FEATURES = {
+    "lora": "LoRA (lora_config)",
+    "speculation": "speculative decoding (spec_config)",
+    "tp": "tensor parallelism (llm/tp.py)",
+    "prefix_cache": "the prefix cache (llm/kvcache/)",
+    "pd": "PD disaggregation (llm/pd_disagg.py)",
+    "train": "the flax Transformer (the train step)",
+    "checkpoint": "loading a checkpoint (checkpoint_path)",
+}
+
+
+def block_module(cfg):
+    """The module that runs `cfg.block`."""
+    if cfg.block not in BLOCKS:
+        raise ValueError(f"unknown block {cfg.block!r}; known: {sorted(BLOCKS)}")
+    return importlib.import_module(BLOCKS[cfg.block])
+
+
+def require(cfg, feature: str) -> None:
+    """Refuse, by the block's name, a feature its module does not list, rather than run
+    one block's code over another's tree (PERF.md §7: what the system cannot run yet)."""
+    if feature not in block_module(cfg).SUPPORTS:
+        raise NotImplementedError(
+            f"{FEATURES[feature]} does not support block {cfg.block!r} yet: it runs the dense llama-family "
+            f"block only; block {cfg.block!r} is served by LLMServer / DecodeEngine on one device")

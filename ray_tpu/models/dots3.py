@@ -1,11 +1,13 @@
 """The `dots3` block on the serve path: latent attention with a learned sparse
 indexer, windowed latent layers over a ring cache, sigmoid-routed experts beside a
-shared one (`ModelConfig.block == "dots3"`).
+shared one (`ModelConfig(block="dots3")`).
 
-One set of pure functions over one parameter tree. The engine's prefill and decode
-programs call `prefill` and `decode`; `init_params` builds the tree `load_model`
-serves at random weights; `forward_plain` is the repo's plain reference (whole
-sequence, float32, no cache, no blocks) that the tests hold the cached paths to.
+One set of pure functions over one parameter tree, behind the seam every block is
+served through (`models/__init__.py`). The engine's prefill and decode programs call
+`prefill` and `decode`; `init_params` builds the tree `load_model` serves at random
+weights; `report` reads the expert layers' counts; `forward_plain` is the repo's
+plain reference (whole sequence, float32, no cache, no blocks) that the tests hold
+the cached paths to.
 
 Per layer i, h = RMSNorm(x) the sub-layer's input:
 
@@ -42,6 +44,12 @@ from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope
 from ray_tpu.ops.moe import grouped_experts, sigmoid_routing
 
 _NEG = -1e30
+
+# What the engine and the layers round it may ask of this block (`models.require`): served
+# by LLMServer / DecodeEngine on one device, and nothing else yet (PERF.md §7).
+SUPPORTS = frozenset()
+# Nothing but the next program reads the caches, so every program consumes them.
+DONATES_CACHES = True
 
 
 # -- sizes ---------------------------------------------------------------------------
@@ -191,6 +199,28 @@ def init_caches(cfg: ModelConfig, slots: int, max_seq: int) -> list:
         else:
             out.append((jnp.zeros((slots, cfg.sliding_window, width), cfg.dtype),))
     return out
+
+
+# -- the expert layers' counts ------------------------------------------------------
+
+
+def init_stats(cfg: ModelConfig) -> tuple:
+    """Zeros shaped like a program's stats: one int32 array [2 + E] (`_forward`)."""
+    return (jnp.zeros((2 + cfg.n_routed_experts,), jnp.int32),)
+
+
+def report(cfg: ModelConfig, total: tuple, window: tuple) -> dict:
+    """`scheduler_stats()["experts"]`: token-expert pairs routed and pairs whose expert
+    this chip holds, since the engine started and since the last report, with the
+    largest and the mean load of a held expert there."""
+    (total,), (window,) = total, window
+    return {"experts": {
+        "held": cfg.n_routed_experts, "of": cfg.n_routed_experts_total,
+        "first": cfg.first_expert,
+        "pairs_routed": int(total[0]), "pairs_held": int(total[1]),
+        "window": {"pairs_routed": int(window[0]), "pairs_held": int(window[1]),
+                   "max_load": int(window[2:].max()), "mean_load": float(window[2:].mean())},
+    }}
 
 
 # -- projections both paths share ----------------------------------------------------
@@ -503,7 +533,7 @@ def _head(params, x):
         return _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
 
 
-def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len):
+def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, lora=None, adapter_id=None):
     """The engine's prefill program for this block. tokens: [1, S] right-padded, the
     chunk at positions offset + [0, S) of a prompt of `total_len` tokens, into slot
     `slot`. Returns (logits of the prompt's last token if it is in this chunk, caches, stats)."""
@@ -520,10 +550,10 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len):
     caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
                     for a, b in zip(c, n)) for c, n in zip(caches, new)]
     last = jax.lax.dynamic_slice_in_dim(x[0], jnp.clip(total_len - 1 - offset, 0, S - 1), 1, axis=0)
-    return _head(params, last)[0], caches, stats
+    return _head(params, last)[0], caches, (stats,)
 
 
-def decode(params, cfg: ModelConfig, last_token, caches, lens, gate):
+def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, adapter_ids=None):
     """The engine's decode step for this block: one token for every slot; only slots
     with `gate` write their rows. Returns (logits [B, V], caches, stats)."""
 
@@ -533,7 +563,7 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate):
         return _window_attn_decode(p, normed, caches[i], lens, gate, cfg)
 
     x, new, stats = _forward(params, cfg, last_token[:, None], gate[:, None], attend)
-    return _head(params, x[:, 0]), new, stats
+    return _head(params, x[:, 0]), new, (stats,)
 
 
 # -- the plain reference -------------------------------------------------------------

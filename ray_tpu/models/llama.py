@@ -1,0 +1,351 @@
+"""The dense llama-family block on the serve path (`ModelConfig(block="llama")`):
+RMSNorm, rotary embeddings, grouped-query attention against a per-slot KV slab, a
+SwiGLU MLP, a tied or untied head.
+
+Pure functions over the flax Transformer's parameter tree (`models/transformer.py`,
+`scan_layers=False` layout), which the train step builds and checkpoints hold. The
+engine's programs call `prefill`, `decode` and `verify`; `gather_rows`, `attach_rows`
+and the two detached prefills are the slab's side of the prefix cache and of the PD
+hand-over, whose rows travel as one `[L, 2, rows, Hkv, D]` array; the draft model of
+`llm/scheduler/spec.py` runs `_forward_cached` over a slab of its own.
+
+The cache: per layer a pair (K, V) of `[slots, max_seq, Hkv, D]` arrays in `cfg.dtype`.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.transformer import ModelConfig, Transformer, _dense, _rmsnorm, _rope
+
+_NEG_INF = -1e30
+
+# What the engine and the layers round it may ask of this block (`models.require`).
+SUPPORTS = frozenset({"lora", "speculation", "tp", "prefix_cache", "pd", "train", "checkpoint"})
+# No program donates the slabs yet, so the compiler copies them in every one
+# (ROADMAP S5, which turns this on and deletes the fact).
+DONATES_CACHES = False
+
+
+def init_params(cfg: ModelConfig, key):
+    """The tree `load_model` serves at random weights: the flax model's own."""
+    return Transformer(cfg).init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def init_caches(cfg: ModelConfig, slots: int, max_seq: int) -> list:
+    kv_shape = (slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
+    return [
+        (jnp.zeros(kv_shape, cfg.dtype), jnp.zeros(kv_shape, cfg.dtype))
+        for _ in range(cfg.n_layers)
+    ]
+
+
+def init_stats(cfg: ModelConfig) -> tuple:
+    """The block counts nothing: its programs return no stats."""
+    return ()
+
+
+def report(cfg: ModelConfig, total: tuple, window: tuple) -> dict:
+    return {}
+
+
+# -- pure functional forward over the param tree ---------------------------
+
+
+def _lora_delta(x, A, B_, scale):
+    """Per-slot low-rank delta: x [B,S,M]; A [B,M,r]; B_ [B,r,O]; scale [B]."""
+    h = jnp.einsum("bsm,bmr->bsr", x, A.astype(x.dtype))
+    d = jnp.einsum("bsr,bro->bso", h, B_.astype(x.dtype))
+    return d * scale[:, None, None].astype(x.dtype)
+
+
+def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
+                 lora_layer=None, adapter_ids=None, write_gate=None):
+    """One attention layer against the KV cache.
+
+    x: [B, S, M]; positions: [B, S]; cache_k/v: [B, T, Hkv, D];
+    write_at: [B] start index per slot; kv_mask: [B, S, T] visibility.
+    lora_layer (optional): stacked adapters {"q_A": [A,M,r], "q_B": [A,r,H*D],
+    "v_A", "v_B", "scale": [A]} gathered per slot by adapter_ids [B] — the
+    multi-LoRA batching role of the reference's punica path, as plain gathers +
+    batched matmuls so one jitted program serves any adapter mix.
+    write_gate (optional): [B] bool — slots with a False gate leave their
+    cache rows untouched (the batched speculative-verify program runs every
+    slot through the forward but must only land KV for participants).
+    """
+    B, S, _ = x.shape
+    q = _dense(x, layer["q"]["kernel"].reshape(cfg.hidden, -1)).reshape(
+        B, S, cfg.n_heads, cfg.head_dim
+    )
+    k = _dense(x, layer["k"]["kernel"].reshape(cfg.hidden, -1)).reshape(
+        B, S, cfg.n_kv_heads, cfg.head_dim
+    )
+    v = _dense(x, layer["v"]["kernel"].reshape(cfg.hidden, -1)).reshape(
+        B, S, cfg.n_kv_heads, cfg.head_dim
+    )
+    if lora_layer is not None:
+        scale = lora_layer["scale"][adapter_ids]
+        dq = _lora_delta(
+            x, lora_layer["q_A"][adapter_ids], lora_layer["q_B"][adapter_ids], scale
+        )
+        q = q + dq.reshape(B, S, cfg.n_heads, cfg.head_dim)
+        dv = _lora_delta(
+            x, lora_layer["v_A"][adapter_ids], lora_layer["v_B"][adapter_ids], scale
+        )
+        v = v + dv.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    q = _rope(q, positions, cfg.rope_theta)
+    k = _rope(k, positions, cfg.rope_theta)
+
+    if write_gate is None:
+        def put(slot_cache, slot_new, at):
+            return jax.lax.dynamic_update_slice(slot_cache, slot_new, (at, 0, 0))
+
+        cache_k = jax.vmap(put)(cache_k, k.astype(cache_k.dtype), write_at)
+        cache_v = jax.vmap(put)(cache_v, v.astype(cache_v.dtype), write_at)
+    else:
+        # Gated write: read the current rows and write them back unchanged
+        # when the gate is off. The read and write clamp identically at the
+        # cache end, so an off-gate slot is a no-op even at the boundary.
+        def put_gated(slot_cache, slot_new, at, gate):
+            cur = jax.lax.dynamic_slice(slot_cache, (at, 0, 0), slot_new.shape)
+            new = jnp.where(gate, slot_new, cur)
+            return jax.lax.dynamic_update_slice(slot_cache, new, (at, 0, 0))
+
+        cache_k = jax.vmap(put_gated)(
+            cache_k, k.astype(cache_k.dtype), write_at, write_gate
+        )
+        cache_v = jax.vmap(put_gated)(
+            cache_v, v.astype(cache_v.dtype), write_at, write_gate
+        )
+
+    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor
+    # of the split, and both products run against the slab as it lies: a copy of
+    # K or V repeated to H heads costs a third of a decode step (PERF.md §6, PR 29).
+    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    logits = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(q.dtype)) * scale
+    logits = jnp.where(kv_mask[:, None, None], logits.astype(jnp.float32), _NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, cache_v.astype(q.dtype))
+    o_kernel = layer["o"]["kernel"].reshape(-1, cfg.hidden)
+    proj = _dense(out.reshape(B, S, -1), o_kernel)
+    return proj, cache_k, cache_v
+
+
+def _mlp(layer, x):
+    gate = _dense(x, layer["gate"]["kernel"])
+    up = _dense(x, layer["up"]["kernel"])
+    return _dense(jax.nn.silu(gate) * up, layer["down"]["kernel"])
+
+
+def _forward_cached(params, cfg: ModelConfig, tokens, positions, caches, write_at,
+                    kv_mask, lora=None, adapter_ids=None, write_gate=None):
+    """tokens: [B,S] -> logits [B,S,V]; updates caches in place (returned).
+
+    lora: the AdapterCache's STACKED tables ({"q_A": [L, S, M, r], ...}) —
+    per-layer views are extracted here inside the trace, so paging swaps the
+    whole table reference without touching program shapes.
+
+    The named scopes are the flax model's module names (`layer_<i>/attn`,
+    `mlp`, `attn_norm`, `mlp_norm`, `final_norm`, `lm_head`) plus `embedding`:
+    one list of scopes reads a device trace of either model (PERF.md §3).
+    They are metadata on the operations and change no program."""
+    embed = params["embedding"]
+    with jax.named_scope("embedding"):
+        x = embed[tokens].astype(cfg.dtype)
+    new_caches = []
+    for i in range(cfg.n_layers):
+        layer = params[f"layer_{i}"]
+        with jax.named_scope(f"layer_{i}"):
+            with jax.named_scope("attn_norm"):
+                normed = _rmsnorm(x, layer["attn_norm"]["scale"], cfg.norm_eps)
+            with jax.named_scope("attn"):
+                attn_out, ck, cv = _attn_cached(
+                    layer["attn"], normed, positions, caches[i][0], caches[i][1],
+                    write_at, kv_mask, cfg,
+                    lora_layer=None if lora is None else {k: v[i] for k, v in lora.items()},
+                    adapter_ids=adapter_ids,
+                    write_gate=write_gate,
+                )
+            new_caches.append((ck, cv))
+            x = x + attn_out
+            with jax.named_scope("mlp_norm"):
+                normed = _rmsnorm(x, layer["mlp_norm"]["scale"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                x = x + _mlp(layer["mlp"], normed)
+    with jax.named_scope("final_norm"):
+        x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = jax.lax.dot_general(
+                x.astype(cfg.dtype), embed.astype(cfg.dtype),
+                (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+        else:
+            logits = _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
+        logits = logits.astype(jnp.float32)
+    return logits, new_caches
+
+
+def _scatter_slot_caches(caches, new_slot, slot):
+    """Write a [1, T, ...] slot view back into the full [B, T, ...] caches."""
+    out = []
+    for (ck_full, cv_full), (ck, cv) in zip(caches, new_slot):
+        out.append((
+            jax.lax.dynamic_update_slice(ck_full, ck.astype(ck_full.dtype),
+                                         (slot, 0, 0, 0)),
+            jax.lax.dynamic_update_slice(cv_full, cv.astype(cv_full.dtype),
+                                         (slot, 0, 0, 0)),
+        ))
+    return out
+
+
+# -- what the engine's programs call ------------------------------------------
+
+
+def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len,
+            lora, adapter_id):
+    """tokens: [1, Sbucket] right-padded, starting at row/position `offset`
+    (0 = whole-prompt prefill; >0 = a later CHUNK, or suffix-only prefill
+    behind a prefix cache hit whose KV was attached to rows [0, offset)).
+    Writes slot `slot`'s cache rows [offset, offset+S). offset and total_len
+    are traced scalars: a chunked prefill of any length mix reuses one program
+    a bucket. Returns (logits of the prompt's last token, caches, no stats)."""
+    S = tokens.shape[1]
+    positions = offset + jnp.arange(S)[None, :]
+    # one-slot caches view
+    slot_caches = [
+        (c[0][slot][None], c[1][slot][None]) for c in caches
+    ]
+    # visibility: key row j <= global query position offset+i; attached
+    # prefix rows [0, offset) are all visible, pad rows beyond stay hidden
+    T = caches[0][0].shape[1]
+    mask = (positions[0][:, None] >= jnp.arange(T)[None, :])[None]
+    logits, new_slot_caches = _forward_cached(
+        params, cfg, tokens, positions, slot_caches,
+        offset[None], mask,
+        lora=lora, adapter_ids=adapter_id[None],
+    )
+    out_caches = _scatter_slot_caches(caches, new_slot_caches, slot)
+    last = logits[0, total_len - 1 - offset]
+    return last, out_caches, ()
+
+
+def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora, adapter_ids):
+    """One token for every slot. last_token: [B]; lens: [B] current lengths;
+    gate: [B] bool, only slots in the decode phase land their KV row.
+    Returns (logits [B, V], caches, no stats)."""
+    positions = lens[:, None]
+    # key j visible iff j <= lens (the new token writes at index lens)
+    T = caches[0][0].shape[1]
+    kv_mask = (jnp.arange(T)[None, :] <= lens[:, None])[:, None, :]
+    logits, new_caches = _forward_cached(
+        params, cfg, last_token[:, None], positions, caches, lens, kv_mask,
+        lora=lora, adapter_ids=adapter_ids, write_gate=gate,
+    )
+    return logits[:, 0], new_caches, ()
+
+
+def verify(params, cfg: ModelConfig, tokens, caches, lens, gate, lora, adapter_ids):
+    """The speculative phase's forward: tokens [B, k+1] at positions lens..lens+k
+    for EVERY slot; slots with a False gate flow through for batching and leave
+    their KV rows untouched. Returns (logits [B, k+1, V], caches, no stats)."""
+    B, S = tokens.shape
+    positions = lens[:, None] + jnp.arange(S)[None, :]
+    T = caches[0][0].shape[1]
+    kv_mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]
+    logits, new_caches = _forward_cached(
+        params, cfg, tokens, positions, caches, lens, kv_mask,
+        lora=lora, adapter_ids=adapter_ids, write_gate=gate,
+    )
+    return logits, new_caches, ()
+
+
+# -- the slab's rows on their way to and from the prefix pool and a PD peer ----
+
+
+def gather_rows(caches, slot, *, rows: int):
+    """Slot `slot`'s cache rows [0, rows) of every layer as one array in
+    the prefix pool's layout, [L, 2, rows, Hkv, D] in the caches' dtype.
+    The caches are read, not consumed: no donation."""
+
+    def take(c):
+        return jax.lax.dynamic_slice(
+            c, (slot, 0, 0, 0), (1, rows) + c.shape[2:])[0]
+
+    return jnp.stack([jnp.stack([take(ck), take(cv)]) for ck, cv in caches])
+
+
+def attach_rows(caches, kv, slot):
+    """Write a transferred KV prefix into slot's cache rows [0, P).
+    kv: [L, 2, P, Hkv, D] (P = padded prefix bucket)."""
+    out = []
+    for i in range(len(caches)):
+        ck = jax.lax.dynamic_update_slice(
+            caches[i][0], kv[i, 0][None].astype(caches[i][0].dtype), (slot, 0, 0, 0)
+        )
+        cv = jax.lax.dynamic_update_slice(
+            caches[i][1], kv[i, 1][None].astype(caches[i][1].dtype), (slot, 0, 0, 0)
+        )
+        out.append((ck, cv))
+    return out
+
+
+def prefill_detached(params, cfg: ModelConfig, tokens, lora, adapter_id):
+    """Prefill that occupies no slot (the PD prefill side). tokens: [1, S]
+    right-padded. Returns (logits [S, V], kv [L, 2, S, Hkv, D])."""
+    S = tokens.shape[1]
+    positions = jnp.arange(S)[None, :]
+    caches = init_caches(cfg, 1, S)
+    mask = (jnp.arange(S)[:, None] >= jnp.arange(S)[None, :])[None]
+    logits, new_caches = _forward_cached(
+        params, cfg, tokens, positions, caches,
+        jnp.zeros((1,), jnp.int32), mask,
+        lora=lora, adapter_ids=adapter_id[None],
+    )
+    kv = jnp.stack(
+        [jnp.stack([ck[0], cv[0]]) for ck, cv in new_caches]
+    )  # [L, 2, S, Hkv, D]
+    return logits[0], kv
+
+
+def prefill_detached_suffix(params, cfg: ModelConfig, prefix, tokens, off, lora,
+                            adapter_id):
+    """Detached prefill of a suffix (tokens [1, sb], right-padded) behind a cached
+    prefix [L, 2, mb, Hkv, D] of which rows [0, off) are valid.
+    Returns (logits [sb, V], the suffix's kv [L, 2, sb, Hkv, D])."""
+    mb, sb = prefix.shape[2], tokens.shape[1]
+    # cache layout: rows [0, mb) = attached prefix (valid [0, off)),
+    # rows [mb, mb+sb) = this pass's suffix writes.
+    caches = []
+    for i in range(cfg.n_layers):
+        zeros = jnp.zeros(
+            (1, sb, cfg.n_kv_heads, cfg.head_dim), cfg.dtype
+        )
+        caches.append((
+            jnp.concatenate(
+                [prefix[i, 0][None].astype(cfg.dtype), zeros], axis=1
+            ),
+            jnp.concatenate(
+                [prefix[i, 1][None].astype(cfg.dtype), zeros], axis=1
+            ),
+        ))
+    positions = off + jnp.arange(sb)[None, :]
+    rows = jnp.arange(mb + sb)[None, :]
+    # visible: real prefix rows, plus suffix rows written so far
+    mask = (
+        (rows < off)
+        | ((rows >= mb) & (rows - mb <= jnp.arange(sb)[:, None]))
+    )[None]
+    logits, new_caches = _forward_cached(
+        params, cfg, tokens, positions, caches,
+        jnp.full((1,), mb, jnp.int32), mask,
+        lora=lora, adapter_ids=adapter_id[None],
+    )
+    suffix_kv = jnp.stack([
+        jnp.stack([ck[0, mb:], cv[0, mb:]]) for ck, cv in new_caches
+    ])  # [L, 2, sb, Hkv, D]
+    return logits[0], suffix_kv

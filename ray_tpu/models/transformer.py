@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax._src.mesh import thread_resources  # what `with mesh:` sets; pjit reads it too
 from jax.sharding import PartitionSpec
 
-from ray_tpu.ops.attention import flash_attention, reference_attention
+from ray_tpu import models
+from ray_tpu.ops.attention import reference_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +54,6 @@ class ModelConfig:
     #             shape: the memory/speed point that fits an fsdp=8 v5e pod.
     remat_policy: str = "full"
     scan_layers: bool = True
-    fused_qkv: bool = False  # one projection matmul for q,k,v (and gate|up in the MLP);
-    # measured slower than separate projections on v5e at gpt2 scale — off by default
     attention: str = "flash"  # flash | reference | ring | ulysses
     sp_axis: str = "sp"
     # MoE: >0 replaces the dense MLP with that many experts (expert-parallel over
@@ -321,10 +320,10 @@ class Attention(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
-    def __call__(self, x, positions, rope=None, kv_cache=None):
+    def __call__(self, x, positions, rope=None):
         cfg = self.cfg
-        if cfg.attention == "flash" and kv_cache is None and not cfg.fused_qkv:
-            return self._flash_bhsd(x, positions, rope), None
+        if cfg.attention == "flash":
+            return self._flash_bhsd(x, positions, rope)
         dense = lambda features, names, name: nn.DenseGeneral(  # noqa: E731
             features,
             axis=-1,
@@ -336,16 +335,9 @@ class Attention(nn.Module):
             ),
             name=name,
         )
-        if cfg.fused_qkv:
-            total = cfg.n_heads + 2 * cfg.n_kv_heads
-            qkv = dense((total, cfg.head_dim), ("embed", "heads", "head_dim"), "qkv")(x)
-            q = qkv[..., : cfg.n_heads, :]
-            k = qkv[..., cfg.n_heads : cfg.n_heads + cfg.n_kv_heads, :]
-            v = qkv[..., cfg.n_heads + cfg.n_kv_heads :, :]
-        else:
-            q = dense((cfg.n_heads, cfg.head_dim), ("embed", "heads", "head_dim"), "q")(x)
-            k = dense((cfg.n_kv_heads, cfg.head_dim), ("embed", "kv_heads", "head_dim"), "k")(x)
-            v = dense((cfg.n_kv_heads, cfg.head_dim), ("embed", "kv_heads", "head_dim"), "v")(x)
+        q = dense((cfg.n_heads, cfg.head_dim), ("embed", "heads", "head_dim"), "q")(x)
+        k = dense((cfg.n_kv_heads, cfg.head_dim), ("embed", "kv_heads", "head_dim"), "k")(x)
+        v = dense((cfg.n_kv_heads, cfg.head_dim), ("embed", "kv_heads", "head_dim"), "v")(x)
         if rope is None:
             rope = _rope_angles(positions, cfg.head_dim, cfg.rope_theta)
         q = _rope_apply(q, *rope)
@@ -359,20 +351,7 @@ class Attention(nn.Module):
             k = checkpoint_name(k, "save")
             v = checkpoint_name(v, "save")
 
-        new_cache = None
-        if kv_cache is not None:
-            # Decode path: append to cache and attend over the full prefix.
-            cache_k, cache_v, cache_len = kv_cache
-            k = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype), (0, cache_len, 0, 0))
-            v = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype), (0, cache_len, 0, 0))
-            new_cache = (k, v, cache_len + x.shape[1])
-            t = jnp.arange(k.shape[1])
-            out = reference_attention(
-                q, k, v, causal=True,
-                positions_q=positions[0] if positions.ndim > 1 else positions,
-                positions_kv=t,
-            )
-        elif cfg.attention == "reference":
+        if cfg.attention == "reference":
             out = reference_attention(q, k, v, causal=True)
         elif cfg.attention == "ring":
             from ray_tpu.ops.ring_attention import ring_attention
@@ -383,8 +362,7 @@ class Attention(nn.Module):
 
             out = ulysses_attention(q, k, v, cfg.sp_axis, causal=True)
         else:
-            out = _flash_on_mesh(flash_attention, q, k, v,
-                                 ("batch", "seq", "heads", "head_dim"))
+            raise ValueError(f"unknown attention {cfg.attention!r}: flash | reference | ring | ulysses")
         if cfg.remat and cfg.remat_policy == "attn":
             from jax.ad_checkpoint import checkpoint_name
 
@@ -405,7 +383,7 @@ class Attention(nn.Module):
             ),
             name="o",
         )(out)
-        return proj, new_cache
+        return proj
 
     def _flash_bhsd(self, x, positions, rope):
         """Transpose-free train path: projections emit [B,H,S,D] directly,
@@ -461,12 +439,8 @@ class MLP(nn.Module):
             ),
             name=name,
         )
-        if cfg.fused_qkv:
-            gate_up = dense(2 * cfg.mlp_dim, ("embed", "mlp"), "gate_up")(x)
-            gate, up = jnp.split(gate_up, 2, axis=-1)
-        else:
-            gate = dense(cfg.mlp_dim, ("embed", "mlp"), "gate")(x)
-            up = dense(cfg.mlp_dim, ("embed", "mlp"), "up")(x)
+        gate = dense(cfg.mlp_dim, ("embed", "mlp"), "gate")(x)
+        up = dense(cfg.mlp_dim, ("embed", "mlp"), "up")(x)
         down = dense(cfg.hidden, ("mlp", "embed"), "down")(nn.silu(gate) * up)
         if cfg.remat and cfg.remat_policy == "selective":
             from jax.ad_checkpoint import checkpoint_name
@@ -481,11 +455,10 @@ class Block(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
-    def __call__(self, x, positions, rope=None, kv_cache=None):
+    def __call__(self, x, positions, rope=None):
         cfg = self.cfg
-        attn_out, new_cache = Attention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, rope,
-            kv_cache
+        attn_out = Attention(cfg, name="attn")(
+            RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions, rope
         )
         x = x + attn_out
         normed = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
@@ -506,16 +479,16 @@ class Block(nn.Module):
             mlp_out = MLP(cfg, name="mlp")(normed)
             aux = jnp.zeros((), jnp.float32)
         x = x + mlp_out
-        return x, (new_cache, aux)
+        return x, aux
 
 
 class Transformer(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, kv_caches=None, return_hidden=False):
+    def __call__(self, tokens, positions=None, return_hidden=False):
         cfg = self.cfg
-        require_llama_block(cfg, "the flax Transformer (the train step)")
+        models.require(cfg, "train")
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :].astype(jnp.int32)
         embed = self.param(
@@ -548,8 +521,7 @@ class Transformer(nn.Module):
                 policy = None
             return nn.remat(Block, prevent_cse=False, policy=policy)
 
-        new_caches = []
-        if cfg.scan_layers and kv_caches is None:
+        if cfg.scan_layers:
             block = Block
             if cfg.remat:
                 block = remat_block()
@@ -561,21 +533,15 @@ class Transformer(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
                 in_axes=(nn.broadcast, nn.broadcast),
             )
-            x, (_, aux_stack) = ScannedBlocks(cfg, name="layers")(
+            x, aux_stack = ScannedBlocks(cfg, name="layers")(
                 x, positions, rope
             )
             moe_aux = jnp.sum(aux_stack)
         else:
             moe_aux = jnp.zeros((), jnp.float32)
             for i in range(cfg.n_layers):
-                block_cls = Block
-                if cfg.remat and kv_caches is None:
-                    block_cls = remat_block()
-                cache = kv_caches[i] if kv_caches is not None else None
-                x, (new_cache, aux) = block_cls(cfg, name=f"layer_{i}")(
-                    x, positions, rope, cache
-                )
-                new_caches.append(new_cache)
+                block_cls = remat_block() if cfg.remat else Block
+                x, aux = block_cls(cfg, name=f"layer_{i}")(x, positions, rope)
                 moe_aux = moe_aux + aux
         if cfg.moe_experts > 0:
             # Reaches the training loss without changing the return signature:
@@ -607,10 +573,7 @@ class Transformer(nn.Module):
                 ),
                 name="lm_head",
             )(x).astype(jnp.float32)
-        logits = nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
-        if kv_caches is not None:
-            return logits, new_caches
-        return logits
+        return nn.with_logical_constraint(logits, ("batch", "seq", "vocab"))
 
 
 def cross_entropy_loss(logits, targets, mask=None):
@@ -680,15 +643,6 @@ def init_params(cfg: ModelConfig, rng=None, batch: int = 1, seq: int | None = No
     seq = seq or min(cfg.max_seq, 128)
     tokens = jnp.zeros((batch, seq), jnp.int32)
     return model, model.init(rng, tokens)
-
-
-def require_llama_block(cfg: ModelConfig, what: str) -> None:
-    """Paths that know only the dense block refuse another by name, rather than run
-    the dense code over a tree it cannot walk (PERF.md: what the system cannot run yet)."""
-    if cfg.block != "llama":
-        raise NotImplementedError(
-            f"{what} does not support block {cfg.block!r} yet: it runs the dense llama-family "
-            f"block only; block {cfg.block!r} is served by LLMServer / DecodeEngine on one device")
 
 
 def get_config(name: str, **overrides) -> ModelConfig:

@@ -349,7 +349,7 @@ def _flash_attention_fwd_impl(q, k, v, causal, scale):
         k_full = jnp.repeat(k, rep, axis=2)
         v_full = jnp.repeat(v, rep, axis=2)
     if _use_pallas():
-        # Defaults retuned round 5 (bench_profile.py attn, v5e, S=1024/D=64):
+        # Defaults retuned round 5 (v5e, S=1024/D=64; docs/perf.md):
         # BQ 256 + full-row BK measured 42.8 TFLOPS vs 27.3 at the old 512 —
         # the kernel is VPU-elementwise-bound, and smaller q blocks pipeline
         # the softmax work against the MXU better.
@@ -387,11 +387,10 @@ def _flash_bwd_rule(causal, scale, residuals, g):
     k_full = jnp.repeat(k, rep, axis=2) if rep > 1 else k
     v_full = jnp.repeat(v, rep, axis=2) if rep > 1 else v
 
-    if _use_pallas() and os.environ.get("RAY_TPU_FLASH_BWD", "pallas") == "pallas":
+    if _use_pallas():
         dq, dk, dv = _flash_backward(
             q, k_full, v_full, out, lse, g, causal=causal, scale=eff_scale,
-            block_q=int(os.environ.get("RAY_TPU_FLASH_BWD_BQ", "512")),
-            block_k=int(os.environ.get("RAY_TPU_FLASH_BWD_BK", "1024")),
+            block_q=512, block_k=1024,
             interpret=False,
         )
         if rep > 1:
@@ -505,11 +504,10 @@ def _flash_bhsd_bwd_rule(causal, scale, residuals, g):
     rep = H // Hkv
     k_full, v_full = _expand_kv_bhsd(k, v, H)
 
-    if _use_pallas() and os.environ.get("RAY_TPU_FLASH_BWD", "pallas") == "pallas":
+    if _use_pallas():
         dq, dk, dv = _flash_backward(
             q, k_full, v_full, out, lse, g, causal=causal, scale=eff_scale,
-            block_q=int(os.environ.get("RAY_TPU_FLASH_BWD_BQ", "512")),
-            block_k=int(os.environ.get("RAY_TPU_FLASH_BWD_BK", "1024")),
+            block_q=512, block_k=1024,
             interpret=False, layout="bhsd",
         )
         if rep > 1:

@@ -56,6 +56,7 @@ __all__ = [
     "capture",
     "device_memory_report",
     "is_resource_exhausted",
+    "named",
     "oom_snapshot",
     "register_memory_owner",
     "registry",
@@ -302,6 +303,21 @@ class span:
             row[0] += 1
             row[1] += self.t1 - self.t0
         return False
+
+
+def named(name: str, fn, **static):
+    """`fn` (with `static` keyword arguments bound) under a `__name__` of its
+    own. jax names a compiled program after the function it traced
+    (`jit_<name>`), and that name is what a device trace shows: the engine's
+    and the draft model's programs are named here, by what they do and their
+    static sizes, and not by whatever the Python function happens to be called
+    (PERF.md §3)."""
+
+    def program(*args):
+        return fn(*args, **static)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def span_totals() -> Dict[str, dict]:

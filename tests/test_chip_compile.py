@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.llm._engine import _attn_cached
+from ray_tpu.models.llama import _attn_cached
 from ray_tpu.models.transformer import ModelConfig
 from ray_tpu.ops.attention import _flash_backward, _flash_forward
 

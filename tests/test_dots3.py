@@ -256,15 +256,15 @@ def test_the_expert_counts_are_one_running_sum_that_may_wrap(engine):
     """The stepper adds each dispatch's counts into one device array; a report reads it once
     and takes the difference from the last reading, which an int32 wrap leaves right."""
     before = engine.scheduler_stats()["experts"]
-    acc = engine._expert_acc
+    (acc,) = engine._stats_acc
     assert acc.shape == (2 + 16,) and acc.dtype == jnp.int32
-    engine._note_expert_stats([jnp.full((18,), 2**31 - 5, jnp.int32)])
-    engine._note_expert_stats([jnp.full((18,), 2**31 - 5, jnp.int32)])  # past int32's largest
+    engine._note_stats((jnp.full((18,), 2**31 - 5, jnp.int32),))
+    engine._note_stats((jnp.full((18,), 2**31 - 5, jnp.int32),))  # past int32's largest
     ex = engine.scheduler_stats()["experts"]
     assert ex["window"]["pairs_routed"] == 2**32 - 10 == ex["pairs_routed"] - before["pairs_routed"]
-    engine._note_expert_stats([jnp.full((18,), 17, jnp.int32)])  # the running sum is past 2**32 now
+    engine._note_stats((jnp.full((18,), 17, jnp.int32),))  # the running sum is past 2**32 now
     assert engine.scheduler_stats()["experts"]["window"]["pairs_held"] == 17
-    engine._note_expert_stats([jnp.arange(18, dtype=jnp.int32)])
+    engine._note_stats((jnp.arange(18, dtype=jnp.int32),))
     assert engine.scheduler_stats()["experts"]["window"] == {
         "pairs_routed": 0, "pairs_held": 1, "max_load": 17, "mean_load": float(np.mean(np.arange(2, 18)))}
     assert engine.scheduler_stats()["experts"]["window"]["pairs_held"] == 0

@@ -234,38 +234,6 @@ def test_sharded_train_step_fsdp_tp():
     assert int(metrics["step"]) == 4
 
 
-def test_model_decode_with_kv_cache():
-    from ray_tpu.models.transformer import Transformer, get_config, init_params
-
-    cfg = get_config("test-tiny")
-    model, params = init_params(cfg, batch=1, seq=16)
-    tokens = jax.random.randint(jax.random.PRNGKey(5), (1, 16), 0, cfg.vocab_size)
-    full_logits = model.apply(params, tokens)
-
-    # Incremental decode must match the parallel forward.
-    caches = [
-        (
-            jnp.zeros((1, 32, cfg.n_kv_heads, cfg.head_dim), jnp.float32),
-            jnp.zeros((1, 32, cfg.n_kv_heads, cfg.head_dim), jnp.float32),
-            0,
-        )
-        for _ in range(cfg.n_layers)
-    ]
-    outs = []
-    for t in range(16):
-        logits, caches = model.apply(
-            params,
-            tokens[:, t : t + 1],
-            positions=jnp.array([[t]], jnp.int32),
-            kv_caches=caches,
-        )
-        outs.append(logits[:, 0])
-    inc = jnp.stack(outs, axis=1)
-    np.testing.assert_allclose(
-        np.asarray(inc), np.asarray(full_logits), atol=2e-3, rtol=2e-3
-    )
-
-
 def test_ulysses_attention_gqa_with_small_kv_heads():
     """GQA where kv-heads (2) < sp axis (4): the repeat fallback must kick in."""
     import numpy as np

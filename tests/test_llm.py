@@ -86,7 +86,7 @@ def test_attn_cached_equals_repeat_reference(heads, S, gated):
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm._engine import _attn_cached
+    from ray_tpu.models.llama import _attn_cached
     from ray_tpu.models.transformer import ModelConfig
 
     H, Hkv = heads
@@ -126,7 +126,7 @@ def test_attn_cached_head_reads_its_own_kv_head(heads, side):
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.llm._engine import _attn_cached
+    from ray_tpu.models.llama import _attn_cached
     from ray_tpu.models.transformer import ModelConfig
 
     H, Hkv = heads

@@ -104,7 +104,7 @@ def attn_collectives(tp):
     import re
     from jax.sharding import NamedSharding
     from ray_tpu.llm import tp as tp_plan
-    from ray_tpu.llm._engine import _attn_cached
+    from ray_tpu.models.llama import _attn_cached
     mesh = tp_plan.build_tp_mesh(tp)
     rep = tp_plan.replicated(mesh)
     H, Hkv, D, M, B, T = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.hidden, 2, 64

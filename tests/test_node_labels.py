@@ -107,3 +107,43 @@ def test_composite_prefers_matching_label(ray_start_cluster):
         return ray_tpu.get_runtime_context().get_node_id().hex()
 
     assert ray_tpu.get(where.remote(), timeout=240) == labeled.node_id_hex
+
+
+def test_composite_lands_on_a_labeled_node_the_subscribed_view_lacks():
+    """A head raylet's subscribed view may lag a node the GCS already lists. A
+    composite that settles on a later sub-strategy (or on none) is resolved again
+    on the GCS's own list before the task goes anywhere, and the GCS is asked at
+    most once a second however many tasks come."""
+    import asyncio
+
+    from ray_tpu._private.raylet import Raylet, ResourceManager
+
+    labeled = {"node_id": b"labeled", "alive": True, "labels": {"accelerator": "tpu-v9"},
+               "resources_total": {"CPU": 1}, "resources_available": {"CPU": 1}}
+    asked, forwarded = [], []
+
+    class Gcs:
+        async def call(self, verb):
+            asked.append(verb)
+            return [labeled]
+
+    async def forward(spec, node_id, method="submit_task"):
+        forwarded.append(node_id)
+        return True
+
+    raylet = Raylet.__new__(Raylet)  # the dispatch decision alone: no sockets, no workers
+    raylet.node_id, raylet.labels = b"head", {}
+    raylet.resources = ResourceManager({"CPU": 1})
+    raylet.node_view = {}  # the labeled node's registration has not arrived here
+    raylet.gcs, raylet._authoritative = Gcs(), (float("-inf"), {})
+    raylet._forward_to_peer = forward
+    composite = CompositeSchedulingStrategy(any_of=[
+        NodeLabelSchedulingStrategy(hard={"accelerator": "tpu-v9"}), None]).to_spec()
+    spec = {"task_id": b"t", "resources": {"CPU": 1}, "scheduling_strategy": composite}
+
+    async def dispatch_twice():
+        return [await raylet._try_dispatch(dict(spec)) for _ in range(2)]
+
+    assert asyncio.run(dispatch_twice()) == [True, True]
+    assert forwarded == [b"labeled", b"labeled"] and asked == ["get_nodes"]
+    assert spec["scheduling_strategy"] == composite  # a forwarded peer resolves it again
