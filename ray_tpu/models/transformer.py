@@ -13,6 +13,7 @@ tied output head; bfloat16 activations with float32 RMSNorm accumulation (MXU-fr
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Optional
 
@@ -257,6 +258,20 @@ class _OutProjBhsd(nn.Module):
         )
 
 
+def _mesh_to_split_over():
+    """The mesh of the enclosing `with mesh:`; None outside any, on one device, or
+    already inside a `shard_map`."""
+    mesh = thread_resources.env.physical_mesh
+    if mesh.empty or mesh.size == 1 or not jax.sharding.get_abstract_mesh().empty:
+        return None
+    return mesh
+
+
+def _axis_tuple(a) -> tuple:
+    """One entry of `nn.logical_to_mesh_axes` (a name, a tuple of names or None) as a tuple."""
+    return (a,) if isinstance(a, str) else tuple(a or ())
+
+
 def _flash_on_mesh(flash, q, k, v, names: tuple):
     """Call a flash-attention entry point under the mesh of the enclosing `with mesh:`.
 
@@ -270,14 +285,14 @@ def _flash_on_mesh(flash, q, k, v, names: tuple):
 
     `names` are q's logical axis names, e.g. ("batch", "heads", "seq", "head_dim").
     """
-    mesh = thread_resources.env.physical_mesh
-    if mesh.empty or mesh.size == 1 or not jax.sharding.get_abstract_mesh().empty:
-        return flash(q, k, v, True, None)  # one device, or already inside a shard_map
+    mesh = _mesh_to_split_over()
+    if mesh is None:
+        return flash(q, k, v, True, None)
     axes = dict(zip(names, nn.logical_to_mesh_axes(names)))
     kv_axes = nn.logical_to_mesh_axes(("kv_heads",))[0]
 
     def ways(a) -> int:
-        return math.prod(mesh.shape[x] for x in ((a,) if isinstance(a, str) else a or ()))
+        return math.prod(mesh.shape[x] for x in _axis_tuple(a))
 
     i_b, i_h = names.index("batch"), names.index("heads")
     batch = axes["batch"] if q.shape[i_b] % ways(axes["batch"]) == 0 else None
@@ -590,51 +605,175 @@ def fused_cross_entropy_loss(hidden, table, targets, mask=None, *, chunk=256,
                              contract_dim=1, compute_dtype=jnp.bfloat16):
     """Chunked head-matmul + cross-entropy that never materializes full logits.
 
-    HBM-bound at GPT-2 vocab sizes: [B,S,V] float32 logits are ~1.6 GB at
-    B=8/S=1024/V=50257, written and re-read in forward and again as the softmax
-    gradient in backward. Computing logits per sequence chunk under
-    jax.checkpoint bounds live logits to [B,chunk,V] in both passes (backward
-    recomputes each chunk's logits), trading a second head matmul for ~3 GB of
-    HBM traffic per step — a net win on TPU where HBM bandwidth, not MXU FLOPs,
-    limits this model size.
+    [B,S,V] float32 logits are written and re-read in the forward pass and again as
+    the softmax's gradient in the backward pass. Here a chunk of the sequence at a
+    time is multiplied by the head, so at most [B,chunk,V] logits are live in either
+    pass; the backward pass computes each chunk's logits again (a second head matmul)
+    rather than keep them. `build_train_step` takes this path once whole logits
+    would pass 2 GB.
 
     hidden: [B,S,E] (pre-head, post-final-norm); table: the tied embedding
     [V,E] (contract_dim=1) or an untied lm_head kernel [E,V] (contract_dim=0);
     targets: [B,S] int32. Matches cross_entropy_loss numerically (same bf16
     matmul with f32 accumulation as the model head).
+
+    On a mesh. Called under `with mesh:` and `nn.logical_axis_rules`, the function
+    reads which mesh axes the rules give `batch`. Where axes of more than one device
+    split the batch, both chunk loops run on each device's own sequences against
+    the whole table (a `shard_map` over those axes; `tp` and `sp` stay the
+    compiler's): the cast table is gathered once on its way in, each device sums its
+    partial gradient of the whole table over the chunks in float32, and those sums
+    meet once, in the compute dtype, on their way out. No loop's body holds a
+    collective of the table's size, whatever the number of chunks; left to the
+    compiler, each chunk gathers the table twice and all-reduces its gradient
+    (PERF.md §6, PR 31). The mask is a constant there: it gets no gradient. With no
+    mesh or no rules, on one device, with no axis that splits the batch (`tp` or
+    `sp` alone), with a batch the axes do not divide, or inside another
+    `shard_map`, the scan runs as it is and its collectives are the compiler's.
     """
-    import math as _math
+    split = _loss_split(hidden.shape)
+    if split is None:
+        total, count = _chunk_sums_scan(hidden, table, targets, mask, chunk,
+                                        contract_dim, compute_dtype)
+    else:
+        total, count = _chunk_sums_on_mesh(hidden, table.astype(compute_dtype), targets,
+                                           mask, chunk, contract_dim, *split)
+    return total / jnp.maximum(count, 1.0)
 
+
+def _loss_split(hidden_shape):
+    """(mesh, the mesh axes of more than one device that split the batch) for
+    `fused_cross_entropy_loss`, or None where the loop is left to the compiler. Read
+    from the mesh of the enclosing `with mesh:` and the logical rules in force, as
+    `_flash_on_mesh` reads them."""
+    mesh = _mesh_to_split_over()
+    if mesh is None:
+        return None
+    batch = tuple(x for x in _axis_tuple(nn.logical_to_mesh_axes(("batch",))[0])
+                  if mesh.shape[x] > 1)
+    if not batch or hidden_shape[0] % math.prod(mesh.shape[x] for x in batch):
+        return None
+    return mesh, batch
+
+
+def _in_chunks(hidden, targets, mask, chunk):
+    """Sequence chunks first, for a scan: ([n,B,c,E], [n,B,c], the mask's [n,B,c] or None)."""
     B, S, E = hidden.shape
-    c = _math.gcd(S, chunk)
+    c = math.gcd(S, chunk)
     n = S // c
-    hs = hidden.reshape(B, n, c, E).swapaxes(0, 1)  # [n,B,c,E]
-    ts = targets.reshape(B, n, c).swapaxes(0, 1)  # [n,B,c]
+    hs = hidden.reshape(B, n, c, E).swapaxes(0, 1)
+    ts = targets.reshape(B, n, c).swapaxes(0, 1)
     ms = None if mask is None else mask.reshape(B, n, c).swapaxes(0, 1)
+    return hs, ts, ms
 
-    @jax.checkpoint
-    def chunk_sums(h, t, m):
-        logits = jax.lax.dot_general(
-            h.astype(compute_dtype), table.astype(compute_dtype),
-            (((2,), (contract_dim,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [B,c,V]
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
-        nll = logz - gold
-        if m is not None:
-            return jnp.sum(nll * m), jnp.sum(m)
-        return jnp.sum(nll), jnp.asarray(nll.size, jnp.float32)
 
-    def body(carry, xs):
-        h, t, m = xs if ms is not None else (*xs, None)
-        s, cnt = chunk_sums(h, t, m)
+def _head_logits(h, w, contract_dim):
+    """[B,c,E] x the head -> [B,c,V] float32; operands in w's dtype, as the model's head."""
+    return jax.lax.dot_general(
+        h.astype(w.dtype), w, (((2,), (contract_dim,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _chunk_loss_sums(h, w, t, m, contract_dim):
+    """One chunk's (sum of the tokens' losses, their count)."""
+    logits = _head_logits(h, w, contract_dim)  # [B,c,V]
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
+    nll = logz - gold
+    if m is not None:
+        return jnp.sum(nll * m), jnp.sum(m)
+    return jnp.sum(nll), jnp.asarray(nll.size, jnp.float32)
+
+
+def _scan_sums(chunk_sums, xs):
+    """Add `chunk_sums(h, t, m)` over the chunks `xs` (of `_in_chunks`)."""
+    def body(carry, x):
+        s, cnt = chunk_sums(*x)
         return (carry[0] + s, carry[1] + cnt), None
 
     init = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-    xs = (hs, ts, ms) if ms is not None else (hs, ts)
-    (total, count), _ = jax.lax.scan(body, init, xs)
-    return total / jnp.maximum(count, 1.0)
+    return jax.lax.scan(body, init, xs)[0]
+
+
+def _chunk_sums_scan(hidden, table, targets, mask, chunk, contract_dim, compute_dtype):
+    """(sum of the tokens' losses, their count): a scan over sequence chunks whose body,
+    under `jax.checkpoint`, casts the table and multiplies; autodiff makes the backward
+    loop. Every collective a split table or batch needs is the compiler's, in the body."""
+    @jax.checkpoint
+    def chunk_sums(h, t, m):
+        return _chunk_loss_sums(h, table.astype(compute_dtype), t, m, contract_dim)
+
+    return _scan_sums(chunk_sums, _in_chunks(hidden, targets, mask, chunk))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _chunk_sums_on_mesh(hidden, w, targets, mask, chunk, contract_dim, mesh, batch_axes):
+    """`_chunk_sums_scan` against the cast table `w`, with both loops inside a
+    `shard_map` over the axes that split the batch: the same sums from the same
+    products, each device on its own sequences against the whole of `w`. What crosses
+    the devices does so where a `shard_map` begins or ends, once: `w` is gathered on
+    its way in, and the devices' partial gradients of it are summed on their way out.
+    The backward loop is written out because the scan's own transpose would sum the
+    whole table's cotangent over the chunks in w's dtype; here that sum is float32."""
+    return _on_mesh_fwd(hidden, w, targets, mask, chunk, contract_dim, mesh, batch_axes)[0]
+
+
+def _per_device(f, mesh, batch_axes, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=frozenset(batch_axes), check_vma=False)
+
+
+def _on_mesh_fwd(hidden, w, targets, mask, chunk, contract_dim, mesh, batch_axes):
+    rows, whole = PartitionSpec(batch_axes), PartitionSpec()
+
+    def device_sums(hidden, w, targets, mask):
+        sums = _scan_sums(lambda h, t, m: _chunk_loss_sums(h, w, t, m, contract_dim),
+                          _in_chunks(hidden, targets, mask, chunk))
+        return jax.lax.psum(sums, batch_axes)
+
+    sums = _per_device(device_sums, mesh, batch_axes,
+                       (rows, whole, rows, None if mask is None else rows), whole,
+                       )(hidden, w, targets, mask)
+    return sums, (hidden, w, targets, mask)
+
+
+def _on_mesh_bwd(chunk, contract_dim, mesh, batch_axes, saved, cts):
+    hidden, w, targets, mask = saved
+    rows, whole = PartitionSpec(batch_axes), PartitionSpec()
+
+    def device_grads(g, hidden, w, targets, mask):
+        def body(dw, x):
+            h, t, m = x
+            p = jax.nn.softmax(_head_logits(h, w, contract_dim), axis=-1)
+            scale = g if m is None else g * m[..., None]
+            dlogits = ((p - jax.nn.one_hot(t, p.shape[-1], dtype=p.dtype)) * scale).astype(w.dtype)
+            dh = jax.lax.dot_general(
+                dlogits, w, (((2,), (1 - contract_dim,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(h.dtype)
+            hc = h.astype(w.dtype)
+            dw = dw + jax.lax.dot_general(
+                *((hc, dlogits) if contract_dim == 0 else (dlogits, hc)),
+                (((0, 1), (0, 1)), ((), ())), preferred_element_type=jnp.float32)
+            return dw, dh
+
+        dw, dhs = jax.lax.scan(body, jnp.zeros(w.shape, jnp.float32),
+                               _in_chunks(hidden, targets, mask, chunk))
+        # this device's float32 sum over the chunks leaves in w's dtype, one of a stack
+        # of as many as there are devices along the batch
+        return dhs.swapaxes(0, 1).reshape(hidden.shape), dw.astype(w.dtype)[None]
+
+    dhidden, dws = _per_device(device_grads, mesh, batch_axes,
+                               (whole, rows, whole, rows, None if mask is None else rows),
+                               (rows, rows))(cts[0], hidden, w, targets, mask)
+    # The stack's sum is the one reduction of the table's size, made in the compute
+    # dtype as the layers' gradients are (jnp.sum would make it in float32).
+    dw = jax.lax.reduce(dws, jnp.zeros((), dws.dtype), jax.lax.add, (0,))
+    return dhidden, dw, None, None
+
+
+_chunk_sums_on_mesh.defvjp(_on_mesh_fwd, _on_mesh_bwd)
 
 
 def init_params(cfg: ModelConfig, rng=None, batch: int = 1, seq: int | None = None):

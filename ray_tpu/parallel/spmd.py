@@ -109,9 +109,13 @@ def build_train_step(model, optimizer, mesh: Mesh, rules=None,
     """One jitted SPMD train step: (state, batch{tokens,targets,mask?}) -> (state, metrics).
 
     fused_ce (default: auto): compute the LM head + cross-entropy in sequence
-    chunks so [B,S,V] logits are never materialized (fused_cross_entropy_loss)
-    — the HBM-bandwidth win that puts this step ahead of the A100-FSDP MFU bar.
-    Auto-enabled for Transformer models when no custom loss_fn is supplied.
+    chunks so [B,S,V] logits are never materialized (fused_cross_entropy_loss).
+    Auto: for Transformer models with no custom loss_fn, once whole float32
+    logits would pass 2 GB; under that the plain loss over whole logits runs.
+    The fused loss is called under this step's logical rules and, as the step
+    is, under the caller's `with mesh:`. From them it reads which mesh axes
+    split the batch, and where some do it runs its chunk loop on each device's
+    own sequences and moves the head once a step (its docstring has the rest).
     """
     from ray_tpu.models.transformer import (
         Transformer,
@@ -150,7 +154,8 @@ def build_train_step(model, optimizer, mesh: Mesh, rules=None,
             aux = sum(jnp.sum(v) for v in jax.tree_util.tree_leaves(extra))
             # flax names the model's operations by module; `loss` and `optimizer`
             # name the rest of the step in a device trace (PERF.md §3).
-            with jax.named_scope("loss"):
+            # The fused loss reads the rules too: on a mesh it splits its loop by them.
+            with jax.named_scope("loss"), nn.logical_axis_rules(rules_list):
                 if use_fused:
                     if model.cfg.tie_embeddings:
                         table, cdim = params["embedding"], 1

@@ -1,5 +1,5 @@
-"""Compile the Pallas flash kernels, and one layer of the serve engine's cached
-attention, for a described TPU v5e, without the chip.
+"""Compile the Pallas flash kernels, one layer of the serve engine's cached attention
+and the fused cross-entropy on four chips, for a described TPU v5e, without the chip.
 
 The TPU's compiler is installed where the tests run, and compiles for a topology
 that is described, not attached (on-chip-measurement guide, section 2). It refuses
@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models.llama import _attn_cached
-from ray_tpu.models.transformer import ModelConfig
+from ray_tpu.models.transformer import ModelConfig, fused_cross_entropy_loss
 from ray_tpu.ops.attention import _flash_backward, _flash_forward
 
 # (batch, heads, seq, head_dim) as the repo's configurations run the kernel, bf16.
@@ -34,10 +34,10 @@ SHAPES = {
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A sharding on one device of a described v5e:2x2. The persistent compilation
-    cache is off while this module runs: a compile for a described chip is written
-    to it but cannot be read back without the chip."""
+def v5e_2x2():
+    """A described v5e:2x2. The persistent compilation cache is off while this module
+    runs: a compile for a described chip is written to it but cannot be read back
+    without the chip."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -48,9 +48,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """A sharding on one device of the described v5e:2x2."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _operand(shape, sharding, dtype=jnp.bfloat16):
@@ -137,3 +143,38 @@ def test_cached_attention_copies_no_slab_for_v5e(one_chip, slots, rows):
         # other array of the layer has a slab's rows and H heads' worth of elements
         repeated |= {s for s in shapes if T in s and math.prod(s) == slots * T * H * D}
     assert not repeated & shapes, sorted(repeated & shapes)
+
+
+def test_fused_loss_moves_the_head_once_a_step_on_v5e_2x2(v5e_2x2):
+    """`internlm2-1.8b.train-fsdp4`'s loss (8 x 4096 tokens, a 2048 x 92544 head split four
+    ways on `embed`, 16 chunks): value_and_grad compiled for the four described chips
+    holds one gather of the cast head and one reduction of its gradient, neither inside
+    a loop. Left to the compiler the two loops held two gathers and an all-reduce of the
+    whole head in every chunk, a quarter of the step (PERF.md §6, PR 31)."""
+    import flax.linen as nn
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.parallel import mesh as mesh_lib
+    from ray_tpu.parallel.spmd import _rules_list
+    from tests.test_fused_loss_mesh import _collectives
+
+    B, S, E, V = 8, 4096, _SERVE_CFG.hidden, _SERVE_CFG.vocab_size
+    mesh = mesh_lib.create_mesh({"fsdp": 4}, devices=v5e_2x2.devices)
+
+    def placed(shape, dtype, names):
+        return _operand(shape, NamedSharding(mesh, mesh_lib.logical_to_spec(names)), dtype)
+
+    def loss(hidden, table, targets):
+        with nn.logical_axis_rules(_rules_list(None)):
+            return fused_cross_entropy_loss(hidden, table, targets, contract_dim=0)
+
+    with mesh:
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            placed((B, S, E), jnp.bfloat16, ("batch", "seq", None)),
+            placed((E, V), jnp.float32, ("embed", "vocab")),
+            placed((B, S), jnp.int32, ("batch", "seq")),
+        ).compile().as_text()
+    assert len(re.findall(r"body=", text)) >= 2  # the forward and the backward chunk loop
+    whole_head = sorted((kind, inside) for kind, size, inside in _collectives(text) if size == E * V)
+    assert whole_head in ([("all-gather", False), ("all-reduce", False)],
+                          [("all-gather", False), ("reduce-scatter", False)]), whole_head
