@@ -834,13 +834,18 @@ class CoreWorker:
             )
             direct_port = self._direct_server.port
             self._direct_bind_host = bind
-        reply = self.io.run(
-            self.raylet.call(
+        async def register():
+            reply = await self.raylet.call(
                 "register_worker", self.worker_id, self.mode, os.getpid(), direct_port,
                 self._direct_bind_host,
             )
-        )
-        self.node_id = reply["node_id"]
+            # On the io loop, before it reads the raylet's next message: a task pushed right
+            # behind the reply runs on another thread and may ask for the node id before this
+            # thread wakes (tests/test_node_labels.py saw None under load).
+            self.node_id = reply["node_id"]
+            return reply
+
+        reply = self.io.run(register())
         # Native-store direct data plane: with the arena name in hand, put/get
         # run entirely in shared memory (alloc/write/seal and lookup/read under
         # the arena's process-shared mutex) — no raylet RPC on the hot path.

@@ -873,6 +873,8 @@ class DecodeEngine:
             "hidden": cfg.hidden, "n_layers": cfg.n_layers, "n_heads": cfg.n_heads,
             "n_kv_heads": cfg.n_kv_heads, "vocab_size": cfg.vocab_size,
             "num_slots": self.B, "max_seq": self.T, "tp": self.tp, "block": cfg.block,
+            # every array a slot keeps, rows and recurrent state alike (shape arithmetic, no pull)
+            "cache_bytes": sum(a.nbytes for layer in self._caches or () for a in layer),
         }
         out.update(self._block_report())
         return out

@@ -63,7 +63,7 @@ def _lora_delta(x, A, B_, scale):
 
 
 def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
-                 lora_layer=None, adapter_ids=None, write_gate=None):
+                 lora_layer=None, adapter_ids=None, write_gate=None, score_scale=None, rotate=True):
     """One attention layer against the KV cache.
 
     x: [B, S, M]; positions: [B, S]; cache_k/v: [B, T, Hkv, D];
@@ -75,6 +75,10 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
     write_gate (optional): [B] bool — slots with a False gate leave their
     cache rows untouched (the batched speculative-verify program runs every
     slot through the forward but must only land KV for participants).
+    score_scale: what the scores are multiplied by, 1 / sqrt(head_dim) where None; rotate: whether
+    queries and keys take rotary positions (`models/granite_hybrid.py` serves its position-free
+    attention layers through here with a scale of its own; the scope `kv_attn` holds the
+    slab's write and the two products against it, for every model).
     """
     B, S, _ = x.shape
     q = _dense(x, layer["q"]["kernel"].reshape(cfg.hidden, -1)).reshape(
@@ -96,9 +100,23 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
             x, lora_layer["v_A"][adapter_ids], lora_layer["v_B"][adapter_ids], scale
         )
         v = v + dv.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if rotate:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    if score_scale is None:
+        score_scale = 1.0 / math.sqrt(cfg.head_dim)
+    with jax.named_scope("kv_attn"):
+        out, cache_k, cache_v = _cached_products(q, k, v, cache_k, cache_v, write_at, kv_mask, cfg,
+                                                 write_gate, score_scale)
+    o_kernel = layer["o"]["kernel"].reshape(-1, cfg.hidden)
+    proj = _dense(out.reshape(B, S, -1), o_kernel)
+    return proj, cache_k, cache_v
 
+
+def _cached_products(q, k, v, cache_k, cache_v, write_at, kv_mask, cfg, write_gate, scale):
+    """The new rows into the slab, then scores and values against it. q: [B, S, H, D];
+    k, v: [B, S, Hkv, D] -> (out [B, S, Hkv, G, D], cache_k, cache_v)."""
+    B, S = q.shape[:2]
     if write_gate is None:
         def put(slot_cache, slot_new, at):
             return jax.lax.dynamic_update_slice(slot_cache, slot_new, (at, 0, 0))
@@ -125,14 +143,11 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
     # of the split, and both products run against the slab as it lies: a copy of
     # K or V repeated to H heads costs a third of a decode step (PERF.md §6, PR 29).
     qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(q.dtype)) * scale
     logits = jnp.where(kv_mask[:, None, None], logits.astype(jnp.float32), _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, cache_v.astype(q.dtype))
-    o_kernel = layer["o"]["kernel"].reshape(-1, cfg.hidden)
-    proj = _dense(out.reshape(B, S, -1), o_kernel)
-    return proj, cache_k, cache_v
+    return out, cache_k, cache_v
 
 
 def _mlp(layer, x):

@@ -69,8 +69,12 @@ class ModelConfig:
     # beside a shared one (`models/dots3.py`), which only the serve engine runs; the
     # fields below are that block's, under its published names where the two agree.
     # `n_heads` and `rope_theta` are the full layers'; `mlp_dim` the leading dense layers'.
+    # "granite_hybrid": Mamba-2 layers whose recurrent state lives in the engine's slots
+    # beside the KV rows of a few position-free GQA layers (`models/granite_hybrid.py`,
+    # served only); its fields are the last group.
     block: str = "llama"
-    layer_types: tuple = ()            # per layer: "full_attention" | "sliding_attention"
+    layer_types: tuple = ()            # per layer; dots3: "full_attention" | "sliding_attention";
+                                       # granite_hybrid: "mamba" | "attention"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -96,6 +100,16 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_mlp_dim: int = 0
     routed_scaling_factor: float = 1.0
+    mamba_n_heads: int = 0             # heads of a mamba layer's recurrence ...
+    mamba_d_head: int = 0              # ... channels a head (n_heads x d_head = the inner width)
+    mamba_d_state: int = 0             # state a channel; B and C are this wide (one group)
+    mamba_d_conv: int = 4              # taps of the causal depthwise convolution
+    mamba_chunk_size: int = 256        # positions a block of the prefill scan takes at once
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # the attention layers' score scale
+    logits_scaling: float = 1.0        # logits are divided by it
+    position_embedding_type: str = "rope"  # "nope": the attention layers rotate nothing
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))  # a JSON list, hashable

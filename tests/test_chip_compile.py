@@ -145,6 +145,45 @@ def test_cached_attention_copies_no_slab_for_v5e(one_chip, slots, rows):
     assert not repeated & shapes, sorted(repeated & shapes)
 
 
+@pytest.mark.parametrize("steps", [1, 4], ids=["decode", "multi-step"])
+def test_a_decode_step_updates_the_recurrent_state_in_place_for_v5e(one_chip, steps):
+    """`granite-4.0-h-micro.serve-sessions48`'s decode programs at the published widths and 48
+    slots, cut to one mamba and one attention layer: every array of the donated cache is aliased
+    to an output (48 slots of state are 3.6 GB at full depth, which a copy would double), and no
+    copy of a recurrent state is in the compiled text: the update reads it once and writes it once."""
+    from ray_tpu.models import granite_hybrid as gh
+
+    cfg = ModelConfig(block="granite_hybrid", vocab_size=100352, hidden=2048, n_layers=2, n_heads=32, n_kv_heads=8,
+                      mlp_dim=8192, max_seq=4096, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, scan_layers=False,
+                      remat=False, tie_embeddings=True, layer_types=("mamba", "attention"), mamba_n_heads=64,
+                      mamba_d_head=64, mamba_d_state=128, embedding_multiplier=12.0, residual_multiplier=0.22,
+                      attention_multiplier=0.015625, logits_scaling=8.0, position_embedding_type="nope")
+    slots = 48
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda a: _operand(a.shape, one_chip, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda k: gh.init_params(cfg, k), jax.random.PRNGKey(0)))
+    caches = shaped(jax.eval_shape(lambda: gh.init_caches(cfg, slots, cfg.max_seq)))
+    vec = _operand((slots,), one_chip, jnp.int32)
+
+    def run(params, last, caches, lens, gate):
+        def step(carry, _):
+            last, caches, lens = carry
+            logits, caches, _ = gh.decode(params, cfg, last, caches, lens, gate)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), caches, lens + 1), None
+
+        return jax.lax.scan(step, (last, caches, lens), None, length=steps)[0]
+
+    compiled = jax.jit(run, donate_argnums=(2,)).lower(
+        params, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_)).compile()
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes == held
+    state = f"f32[{slots},{cfg.mamba_n_heads},{cfg.mamba_d_head},{cfg.mamba_d_state}]"
+    text = compiled.as_text()
+    assert state in text and not re.search(re.escape(state) + r"\S* copy\(", text)
+
+
 def test_fused_loss_moves_the_head_once_a_step_on_v5e_2x2(v5e_2x2):
     """`internlm2-1.8b.train-fsdp4`'s loss (8 x 4096 tokens, a 2048 x 92544 head split four
     ways on `embed`, 16 chunks): value_and_grad compiled for the four described chips

@@ -234,3 +234,65 @@ def test_scheduler_stats_name_the_block_and_count_its_expert_pairs(dots3_engine)
 def test_the_dense_blocks_stats_say_so_and_have_no_expert_counts(engines):
     stats = engines[0].scheduler_stats()
     assert stats["model"]["block"] == "llama" and "experts" not in stats
+
+
+# -- the granite_hybrid block: a state's scopes inside `attn`, and its counts -------------------
+
+GRANITE_INNER = {"in_proj", "conv", "ssm", "gate_norm", "out_proj", "kv_attn"}
+
+
+@pytest.fixture(scope="module")
+def granite_engine():
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+    from ray_tpu.models import granite_hybrid
+    from tests.test_granite_hybrid import tiny
+
+    cfg = tiny()
+    params = granite_hybrid.init_params(cfg, jax.random.PRNGKey(2))
+    saved = CONFIG._cache.get("llm_prefill_bucket_min")
+    CONFIG._cache["llm_prefill_bucket_min"] = 4
+    engine = DecodeEngine(cfg, params, num_slots=2, max_seq=64, multi_step=4, token_budget=8)
+    try:
+        done = threading.Event()
+        engine.submit(list(range(1, 20)), SamplingParams(max_tokens=7), lambda tok, fin: fin and done.set())
+        assert done.wait(180), engine.error
+        yield engine
+    finally:
+        engine.shutdown()
+        CONFIG._cache.pop("llm_prefill_bucket_min") if saved is None else CONFIG._cache.update(llm_prefill_bucket_min=saved)
+
+
+def test_the_granite_hybrid_blocks_programs_keep_the_names_and_name_a_layers_parts(granite_engine):
+    engine = granite_engine
+    B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
+    step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
+    programs = [(engine._jit_decode, step)] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
+                 for k, p in engine._jit_prefill.items()]
+    names = dict(_lowered(prog, *args) for prog, args in programs)
+    assert {"jit_rt_decode", "jit_rt_prefill_b8"} <= set(names), sorted(names)
+    assert any(re.fullmatch(r"jit_rt_decode_multi_n\d+", n) for n in names)
+    for module, scopes in names.items():
+        assert set(MODEL_SCOPES) <= scopes, (module, set(MODEL_SCOPES) - scopes)
+        assert GRANITE_INNER <= scopes, (module, GRANITE_INNER - scopes)
+        assert ("sample" in scopes) == ("multi" in module), module
+
+
+def test_scheduler_stats_count_a_states_positions_padding_resets_and_steps(granite_engine):
+    stats = granite_engine.scheduler_stats()
+    state = stats["state"]
+    assert stats["model"]["block"] == "granite_hybrid" and stats["model"]["cache_bytes"] > 0 and "experts" not in stats
+    # 19 prompt tokens in chunks of 8, 8 and 3 (bucket 4): one reset; 6 fed-back tokens on one slot
+    assert {k: state[k] for k in ("prefill_positions", "prefill_padding", "states_reset", "decode_slot_steps")} == {
+        "prefill_positions": 20, "prefill_padding": 1, "states_reset": 1, "decode_slot_steps": 6}
+    assert set(state["window"]) == {"prefill_positions", "prefill_padding", "states_reset", "decode_slot_steps"}
+    assert state["bytes_per_slot"] > 0
+
+
+def test_the_dense_blocks_cached_products_are_named_too(engines):
+    """`kv_attn` is written by `llama._attn_cached`, which both blocks' attention layers run."""
+    plain, _ = engines
+    vec = np.zeros((plain.B,), np.int32)
+    _, scopes = _lowered(plain._jit_decode, plain.params, None, vec, vec, plain._caches, vec, np.ones((plain.B,), bool))
+    assert "kv_attn" in scopes and not (GRANITE_INNER - {"kv_attn"}) & scopes
