@@ -427,10 +427,11 @@ def multichip_phase(model="llama3-1b", overrides=None, n_layers=2, n_devices=4, 
 
     def probe(engine):
         """Next-token logits from the engine's own decode program, over the KV that
-        generation left behind. The write gate is closed: nothing changes."""
+        generation left behind. The write gate is closed: nothing changes, but the program
+        consumes the caches it is given, so the engine takes the returned ones (its stepper is idle)."""
         lens = np.full((engine.B,), min(prompt_lens), np.int32)
         last = np.arange(engine.B, dtype=np.int32) + 7
-        logits, _, _ = engine._jit_decode(
+        logits, engine._caches, _ = engine._jit_decode(
             engine.params, engine._lora_tables(), jnp.asarray(engine._adapter_ids),
             jnp.asarray(last), engine._caches, jnp.asarray(lens),
             jnp.zeros((engine.B,), bool))

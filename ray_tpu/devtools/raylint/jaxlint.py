@@ -94,7 +94,7 @@ def _is_jit_ctor(node: ast.expr) -> bool:
     return last in _JIT_NAMES
 
 
-def _donated_argnums(node: ast.Call) -> tuple:
+def _argnums_donated(node: ast.Call) -> tuple:
     """Positional donate indices of a jit ctor call (donate_argnums only —
     donate_argnames needs kw callsites, matched separately)."""
     for kw in node.keywords:
@@ -232,7 +232,7 @@ class _Prepass(ast.NodeVisitor):
     def visit_Assign(self, node):
         value = node.value
         if _is_jit_ctor(value):
-            donated = _donated_argnums(value)
+            donated = _argnums_donated(value)
             for t in node.targets:
                 if isinstance(t, ast.Name):
                     if not self._scope:
@@ -545,7 +545,7 @@ class _JaxChecker(ast.NodeVisitor):
         for t in node.targets:
             self.visit(t)
         if _is_jit_ctor(value):
-            donated = _donated_argnums(value)
+            donated = _argnums_donated(value)
             for t in node.targets:
                 if isinstance(t, ast.Name) and self._local_jit:
                     self._local_jit[-1][t.id] = donated

@@ -53,7 +53,6 @@ from ray_tpu.llm.tp import (
     checkpoint_shardings,
     kv_prefix_sharding,
     mesh_signature,
-    per_device_byte_map,
     shard_decode_params,
     single_device_shardings,
     tp_degree,
@@ -247,7 +246,7 @@ class DecodeEngine:
         self._stats_lock = threading.Lock()  # reports come from any thread
         self._jit_decode = self._xprof.instrument(
             self._xprof_owner, ("decode",),
-            jax.jit(named("rt_decode", self._decode_step), **self._donate(4)),
+            jax.jit(named("rt_decode", self._decode_step), donate_argnums=(4,)),
         )
         # Multi-step decode: N greedy tokens per dispatch (argmax on device,
         # lax.scan over decode steps) — one host round trip per CHUNK instead
@@ -508,10 +507,6 @@ class DecodeEngine:
         return None if self._adapters is None else self._adapters.stats()
 
     # -- jitted programs ---------------------------------------------------
-    def _donate(self, caches_arg: int) -> dict:
-        """`jax.jit` options of a program that takes the caches at that position."""
-        return {"donate_argnums": (caches_arg,)} if self._block.DONATES_CACHES else {}
-
     def _prefill_at(self, params, lora, tokens, caches, slot, offset,
                     total_len, adapter_id):
         """One chunk of one slot (the block's `prefill`). One program per
@@ -620,7 +615,8 @@ class DecodeEngine:
                         rows=int(self._lens[plan.spec_slots].sum())) as dispatch:
             verify = self._program(
                 self._jit_spec_verify, ("verify", S),
-                lambda: jax.jit(named(f"rt_verify_s{S}", self._spec_verify_batched)),
+                lambda: jax.jit(named(f"rt_verify_s{S}", self._spec_verify_batched),
+                                donate_argnums=(4,)),
             )
             greedy_dev, self._caches, *stats = verify(
                 self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
@@ -682,9 +678,11 @@ class DecodeEngine:
         pool's layout, and one asynchronous copy takes it to the host. The
         rows beyond the prompt's whole blocks are padding that the pool's
         insert ignores; the already-cached prefix rides along (the radix walk
-        dedups it without copying). The device runs the gather before the
-        next decode dispatch (stream order) and nothing rewrites rows [0, n)
-        of a running slot, so the copy holds the prompt's bytes. The worker
+        dedups it without copying). The gather reads the caches and returns a
+        new array; the very next program consumes the buffers it read
+        (`donate_argnums`), and the runtime holds that donation back until the
+        reads already enqueued are done, so the copy holds the prompt's bytes
+        (tests/test_llm_engine_hotpath.py holds this). The worker
         thread hands them to the pool when the copy has landed; a lookup that
         comes sooner finishes the insert itself (`_finish_kv_inserts`), and a
         third pending insert first waits for the oldest."""
@@ -987,12 +985,14 @@ class DecodeEngine:
         components: Dict[str, int] = {}
         per_device: Dict[str, int] = {}
         kv_bytes = 0
+        # The stepper's next program consumes these arrays while this thread looks:
+        # only what a deleted array still answers (`nbytes` is shape arithmetic) is
+        # read here, and a mesh's per-device split comes from the pool's books.
         caches = self._caches
         if self._kv_pool is not None and caches:
             kv_bytes = self._kv_pool.total_bytes
-            per_device = per_device_byte_map(caches)
+            per_device = dict(self._kv_pool.per_device)
         elif caches:
-            # .nbytes is shape metadata (rank * dtype arithmetic), not a pull
             kv_bytes = sum(a.nbytes for layer in caches for a in layer)
         components["kv_slots"] = kv_bytes
         if self._adapters is not None:
@@ -1612,7 +1612,8 @@ class DecodeEngine:
                         prefix_kv = xp.concatenate([prefix_kv, pad], axis=2)
                     attach = self._program(
                         self._jit_prefill, ("attach", mb),
-                        lambda: jax.jit(named(f"rt_attach_b{mb}", self._block.attach_rows)),
+                        lambda: jax.jit(named(f"rt_attach_b{mb}", self._block.attach_rows),
+                                        donate_argnums=(0,)),
                     )
                     self._caches = attach(
                         self._caches,
@@ -1642,7 +1643,7 @@ class DecodeEngine:
         prefill = self._program(
             self._jit_prefill, chunk.bucket,
             lambda: jax.jit(named(f"rt_prefill_b{chunk.bucket}", self._prefill_at),
-                            **self._donate(3)),
+                            donate_argnums=(3,)),
         )
         last_logits, self._caches, *stats = prefill(
             self.params, self._lora_tables(), jnp.asarray(padded), self._caches,
@@ -1723,7 +1724,8 @@ class DecodeEngine:
                 kv = kv[:, :, :bucket]
             attach = self._program(
                 self._jit_prefill, ("attach", bucket),
-                lambda: jax.jit(named(f"rt_attach_b{bucket}", self._block.attach_rows)),
+                lambda: jax.jit(named(f"rt_attach_b{bucket}", self._block.attach_rows),
+                                donate_argnums=(0,)),
             )
             self._caches = attach(
                 self._caches, kv if on_device else jnp.asarray(kv), jnp.int32(slot)
@@ -1873,7 +1875,10 @@ class DecodeEngine:
                 self._recorder.note_oom(xprof.oom_snapshot())
             self.error = e
             # Callers blocked on per-request callbacks would otherwise hang
-            # forever: fail every active/queued request loudly.
+            # forever: fail every active/queued request loudly. A program that
+            # raised after consuming its caches leaves `self._caches` naming
+            # deleted buffers: nothing dispatches on them again, because this
+            # thread ends here and `_check_alive` refuses every later request.
             for slot in self._sched.slots:
                 self._release_slot_pin(slot)
                 self._release_slot_constraint(slot)
@@ -2002,7 +2007,7 @@ class DecodeEngine:
             decode_multi = self._program(
                 self._jit_decode_multi, ("decode_multi", n),
                 lambda: jax.jit(named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
-                                **self._donate(4)),
+                                donate_argnums=(4,)),
             )
             toks_dev, self._caches, _, *stats = decode_multi(
                 self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),

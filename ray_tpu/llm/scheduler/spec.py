@@ -181,7 +181,8 @@ class ModelDraft(DraftProvider):
 
     # -- jitted draft programs ---------------------------------------------
     # Params and caches are explicit arguments (never closed over): a traced
-    # closure would bake them into the compiled program as constants.
+    # closure would bake them into the compiled program as constants. Both
+    # programs consume the draft's caches and return them written in place.
     def _propose_prog(self, params, caches, first_tok, t0, l, slot, *, k,
                       catchup):
         """Draft k greedy tokens in ONE program (lax.scan): the whole
@@ -257,7 +258,7 @@ class ModelDraft(DraftProvider):
             self._progs, ("propose", self.k, catchup),
             lambda: jax.jit(named(
                 f"rt_draft_propose_k{self.k}" + ("_catchup" if catchup else ""),
-                self._propose_prog, k=self.k, catchup=catchup)),
+                self._propose_prog, k=self.k, catchup=catchup), donate_argnums=(1,)),
         )
         toks_dev, self.caches = prog(
             self.params, self.caches,
@@ -282,7 +283,8 @@ class ModelDraft(DraftProvider):
 
         prog = self._program(
             self._progs, ("dprefill", bucket),
-            lambda: jax.jit(named(f"rt_draft_prefill_b{bucket}", self._prefill_prog)),
+            lambda: jax.jit(named(f"rt_draft_prefill_b{bucket}", self._prefill_prog),
+                            donate_argnums=(1,)),
         )
         self.caches = prog(self.params, self.caches, jnp.asarray(padded),
                            jnp.int32(slot_idx))

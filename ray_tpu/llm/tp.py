@@ -299,9 +299,10 @@ class ShardedKVPool:
     shard's buffer reference — a forgotten pool is `tp * layers * 2`
     stranded HBM buffers that no host object names.
 
-    The caches themselves are immutable jax arrays the engine swaps per
-    dispatch (functional updates); the pool tracks the ALLOCATION lifetime,
-    not any single buffer generation.
+    The engine's programs consume the caches they are given and return the next
+    generation in the same buffers; the pool tracks the ALLOCATION lifetime,
+    not any single buffer generation, and keeps the numbers a report needs
+    (`total_bytes`, `per_device`) so that no report touches an array.
     """
 
     def __init__(self, *, n_layers: int, shape, dtype, mesh, n_kv_heads: int,
@@ -321,6 +322,8 @@ class ShardedKVPool:
             2 * self.n_layers * int(np.prod(self.shape)) * itemsize
         )
         self.shard_count = 2 * self.n_layers * max(1, tp_degree(mesh))
+        # device id -> bytes, read once while the zeroth generation is alive
+        self.per_device = per_device_byte_map(self.caches)
         _leaksan.track(
             "kv_shard_pool", token=self.name,
             detail=f"{self.shard_count} shards / {self.total_bytes} B",
@@ -328,8 +331,7 @@ class ShardedKVPool:
 
     def take(self):
         """Hand the initial buffer generation to the owning engine and drop
-        the pool's own references — the engine swaps generations per dispatch
-        and the pool must not pin the zeroth one for its whole life."""
+        the pool's own references: the engine's first program consumes it."""
         caches, self.caches = self.caches, None
         return caches
 

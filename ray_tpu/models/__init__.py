@@ -4,7 +4,6 @@ A block's module is a set of pure functions over its parameter tree, and it is a
 serve engine knows of a model (`llm/_engine.py` calls nothing else):
 
     SUPPORTS          frozenset of FEATURES the block can take
-    DONATES_CACHES    whether its programs may consume the caches they are given
     init_params(cfg, key)                  the tree served at random weights
     init_caches(cfg, slots, max_seq)       per layer a tuple of [slots, ...] arrays
     prefill(params, cfg, tokens, caches, slot, offset, total_len, lora, adapter_id)

@@ -48,8 +48,8 @@ _NEG = -1e30
 # What the engine and the layers round it may ask of this block (`models.require`): served
 # by LLMServer / DecodeEngine on one device, and nothing else yet (PERF.md §7).
 SUPPORTS = frozenset()
-# Nothing but the next program reads the caches, so every program consumes them.
-DONATES_CACHES = True
+# Nothing but the next program reads the caches: every program of the engine consumes
+# them (`donate_argnums`), so the compiler writes each one in place.
 
 
 # -- sizes ---------------------------------------------------------------------------

@@ -47,8 +47,8 @@ from ray_tpu.ops.ssd import ssd_chunked, ssd_step
 # Served by LLMServer / DecodeEngine on one device, and nothing else yet (PERF.md §7): a prefix
 # hit needs a snapshot of the state at a block boundary, a rejected draft a state that rolls back.
 SUPPORTS = frozenset()
-# Nothing but the next program reads the caches; undonated, every program would copy the states.
-DONATES_CACHES = True
+# Nothing but the next program reads the caches: every program of the engine consumes them
+# (`donate_argnums`); undonated, every program would copy the states.
 
 # What a program counts (`init_stats`), in this order.
 COUNTS = ("prefill_positions", "prefill_padding", "states_reset", "decode_slot_steps")

@@ -25,9 +25,6 @@ _NEG_INF = -1e30
 
 # What the engine and the layers round it may ask of this block (`models.require`).
 SUPPORTS = frozenset({"lora", "speculation", "tp", "prefix_cache", "pd", "train", "checkpoint"})
-# No program donates the slabs yet, so the compiler copies them in every one
-# (ROADMAP S5, which turns this on and deletes the fact).
-DONATES_CACHES = False
 
 
 def init_params(cfg: ModelConfig, key):

@@ -22,7 +22,6 @@ from ray_tpu.models.transformer import ModelConfig, Transformer, get_config
 # -- the block: what `models/__init__.py` says a block's module offers -----------------
 
 SUPPORTS = frozenset()
-DONATES_CACHES = True
 
 
 def init_params(cfg, key):

@@ -63,6 +63,11 @@ def _operand(shape, sharding, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _shaped(tree, sharding):
+    """The tree's arrays as operands on that device."""
+    return jax.tree_util.tree_map(lambda a: _operand(a.shape, sharding, a.dtype), tree)
+
+
 def _laid_out(model: str, layout: str):
     b, h, s, d = SHAPES[model]
     return ((b, h, s, d) if layout == "bhsd" else (b, s, h, d)), (b, h, s), d
@@ -160,11 +165,8 @@ def test_a_decode_step_updates_the_recurrent_state_in_place_for_v5e(one_chip, st
                       attention_multiplier=0.015625, logits_scaling=8.0, position_embedding_type="nope")
     slots = 48
 
-    def shaped(tree):
-        return jax.tree_util.tree_map(lambda a: _operand(a.shape, one_chip, a.dtype), tree)
-
-    params = shaped(jax.eval_shape(lambda k: gh.init_params(cfg, k), jax.random.PRNGKey(0)))
-    caches = shaped(jax.eval_shape(lambda: gh.init_caches(cfg, slots, cfg.max_seq)))
+    params = _shaped(jax.eval_shape(lambda k: gh.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: gh.init_caches(cfg, slots, cfg.max_seq)), one_chip)
     vec = _operand((slots,), one_chip, jnp.int32)
 
     def run(params, last, caches, lens, gate):
@@ -182,6 +184,48 @@ def test_a_decode_step_updates_the_recurrent_state_in_place_for_v5e(one_chip, st
     state = f"f32[{slots},{cfg.mamba_n_heads},{cfg.mamba_d_head},{cfg.mamba_d_state}]"
     text = compiled.as_text()
     assert state in text and not re.search(re.escape(state) + r"\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b128"])
+def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program):
+    """The dense serve cells' three programs (`DecodeEngine`'s own bodies over `models/llama.py`)
+    at InternLM2-1.8B's widths, 12 slots of 2048 rows and float32 weights, cut to two layers, with
+    the caches donated as the engine donates them: every slab is aliased to its output, and the
+    compiled text holds no copy of one into its own layout. Undonated, each program first copied
+    all 48 slabs of the whole depth (`copy(%caches_...)`, four here), 6.9 ms of a 25.5 ms decode
+    step and 6.7 of a 17.3 ms chunk (PERF.md §6, PR 33). The decode programs' text also holds, donated
+    or not, a `copy` a slab into `{3,1,2,0}` (rows before heads, scope `kv_attn`): those are operands
+    fused into the two products, and the chip's trace shows no operation that writes a slab for them."""
+    import dataclasses
+    import functools
+    import types
+
+    from ray_tpu.llm._engine import DecodeEngine
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import unbox
+
+    cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
+    slots, T = 12, cfg.max_seq
+    engine = types.SimpleNamespace(cfg=cfg, _block=llama)  # all that the three bodies read of an engine
+    engine._decode_step = functools.partial(DecodeEngine._decode_step, engine)
+
+    params = _shaped(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), one_chip)
+    vec, i32 = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
+    step = (params, None, vec, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
+    body, donated, args = {
+        "rt_decode": (engine._decode_step, 4, step),
+        "rt_decode_multi_n8": (functools.partial(DecodeEngine._decode_multi, engine, n=8), 4, step),
+        "rt_prefill_b128": (functools.partial(DecodeEngine._prefill_at, engine), 3,
+                            (params, None, _operand((1, 128), one_chip, jnp.int32), caches, i32, i32, i32, i32)),
+    }[program]
+    compiled = jax.jit(body, donate_argnums=(donated,)).lower(*args).compile()
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes == held
+    slab = f"bf16[{slots},{T},{cfg.n_kv_heads},{cfg.head_dim}]"
+    text = compiled.as_text()
+    assert slab + "{3,2,1,0" in text  # the slab as the engine holds it: row-major
+    assert not re.search(re.escape(slab) + r"\{3,2,1,0[^}]*\} copy\(", text)
 
 
 def test_fused_loss_moves_the_head_once_a_step_on_v5e_2x2(v5e_2x2):

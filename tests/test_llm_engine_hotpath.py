@@ -2,6 +2,7 @@
 host-native decode loop (the two compute-plane fixes jaxlint RL602/RL603
 gate — see docs/raylint.md "writing jit-safe hot paths")."""
 
+import contextlib
 import threading
 
 import numpy as np
@@ -23,9 +24,8 @@ def _tiny_engine(**kwargs):
     return DecodeEngine(cfg, params, **kwargs)
 
 
-def _generate(engine, prompt, lora="", **sp):
-    from ray_tpu.llm import SamplingParams
-
+def _collect(engine, submit):
+    """The tokens a request's callback got, `submit(callback)` having sent it."""
     acc, done = [], threading.Event()
 
     def cb(tok, fin):
@@ -33,9 +33,15 @@ def _generate(engine, prompt, lora="", **sp):
         if fin:
             done.set()
 
-    engine.submit(prompt, SamplingParams(**sp), cb, lora=lora)
+    submit(cb)
     assert done.wait(180), engine.error
     return acc
+
+
+def _generate(engine, prompt, lora="", **sp):
+    from ray_tpu.llm import SamplingParams
+
+    return _collect(engine, lambda cb: engine.submit(prompt, SamplingParams(**sp), cb, lora=lora))
 
 
 def test_jit_program_cache_bounded_under_adversarial_length_mix(monkeypatch):
@@ -624,5 +630,274 @@ def test_scheduler_stats_reports_the_loop_table_with_distsan_silent():
         nested = loop.get("rt.engine.attach", {"seconds": 0})["seconds"]  # inside prefill, as kv_insert is
         assert loop["rt.engine.iter"]["seconds"] >= 0.5 * (children - nested)
         assert distsan.violations() == []
+    finally:
+        engine.shutdown()
+
+
+# -- every program consumes the caches it is given (PERF.md §6, PR 33) ------------------
+
+
+@contextlib.contextmanager
+def _undonated():
+    """A context in which `jax.jit` drops `donate_argnums`: engines built and driven
+    inside it run the programs the engine ran before it donated, the reference here."""
+    import jax
+
+    real = jax.jit
+
+    def jit(fn, **options):
+        options.pop("donate_argnums", None)
+        return real(fn, **options)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "jit", jit)
+        yield
+
+
+def _checking_programs(monkeypatch, seen):
+    """Wrap every program an engine (or its draft) builds: after each call, note under
+    the program's key whether the caches it was given are deleted."""
+    import jax
+
+    from ray_tpu.llm import DecodeEngine
+
+    def caches_at(key):
+        if isinstance(key, int):
+            return 3                                   # rt_prefill_b<bucket>
+        return {"decode": 4, "decode_multi": 4, "verify": 4, "attach": 0, "kv_gather": 0,
+                "propose": 1, "dprefill": 1}.get(key[0])
+
+    def checking(key, prog):
+        at = caches_at(key)
+        if at is None:
+            return prog
+
+        def call(*args):
+            out = prog(*args)
+            given = jax.tree_util.tree_leaves(args[at])
+            seen.setdefault("prefill" if isinstance(key, int) else key[0], []).append(
+                all(a.is_deleted() for a in given))
+            return out
+
+        return call
+
+    real_program = DecodeEngine._program
+
+    def program(self, cache, key, make):
+        return checking(key, real_program(self, cache, key, make))
+
+    monkeypatch.setattr(DecodeEngine, "_program", program)
+    return checking
+
+
+@pytest.fixture(scope="module")
+def consumed():
+    """Drive every program that takes the caches once or more, and return, by program,
+    whether each call left the caches it was given deleted."""
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    seen = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+        checking = _checking_programs(patch, seen)
+        prompt = list(range(1, 14))
+        # prefill, decode, the multi-step scan, the insert's gather and a hit's attach
+        engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=4,
+                              prefix_cache=PrefixCacheManager(4, 1 << 20, name="consumed"))
+        engine._jit_decode = checking(("decode",), engine._jit_decode)
+        try:
+            _generate(engine, prompt, max_tokens=6)
+            _generate(engine, prompt, max_tokens=6)
+            assert engine.last_attach["cached_tokens"] == 12
+            _generate(engine, prompt[:5], max_tokens=3, temperature=0.7)  # sampled: single steps
+        finally:
+            engine.shutdown()
+        # the verify round and the draft model's two programs over its own caches
+        engine = _tiny_engine(num_slots=2, max_seq=64, prefix_cache=False,
+                              spec_config={"num_spec_tokens": 3})
+        try:
+            _generate(engine, prompt, max_tokens=9)
+        finally:
+            engine.shutdown()
+    return seen
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_multi", "prefill", "attach", "verify",
+                                     "propose", "dprefill"])
+def test_a_program_leaves_the_caches_it_was_given_deleted(consumed, program):
+    """The donation was taken: after `rt_decode`, `rt_decode_multi_n<n>`, `rt_prefill_b<b>`,
+    `rt_attach_b<b>`, `rt_verify_s<S>` and the draft's `rt_draft_propose_k<k>` and
+    `rt_draft_prefill_b<b>`, the previous generation of the caches is gone. A program
+    that copied them would leave them alive."""
+    assert consumed.get(program), sorted(consumed)
+    assert all(consumed[program]), consumed[program]
+
+
+def test_the_gather_reads_the_caches_and_leaves_them(consumed):
+    """`rt_kv_gather_b<b>` returns a new array and consumes nothing."""
+    assert consumed.get("kv_gather") and not any(consumed["kv_gather"]), consumed.get("kv_gather")
+
+
+@pytest.mark.parametrize("round_", ["decode", "multi_step"])
+def test_an_insert_issued_just_before_a_consuming_step_holds_the_rows_it_saw(monkeypatch, round_):
+    """`_insert_prompt_kv` dispatches the gather on the caches and the very next program
+    consumes those buffers and writes into them. The runtime orders the donation after
+    the read already enqueued: the rows the pool gets are the rows of a reference gather
+    taken from a host copy beforehand, whatever the step then wrote."""
+    import jax.numpy as jnp
+
+    engine = _cache_engine(monkeypatch, decode_loop=False)
+    rng = np.random.default_rng(7)
+    try:
+        for turn in range(4):
+            host = [(rng.standard_normal(ck.shape).astype(np.float32), rng.standard_normal(cv.shape).astype(np.float32))
+                    for ck, cv in engine._caches]
+            engine._caches = [(jnp.asarray(k, ck.dtype), jnp.asarray(v, cv.dtype))
+                              for (k, v), (ck, cv) in zip(host, engine._caches)]
+            given = [a for layer in engine._caches for a in layer]
+            want = np.stack([np.stack([np.asarray(ck)[1, :12], np.asarray(cv)[1, :12]])
+                             for ck, cv in engine._caches])
+            prompt = [100 * turn + i for i in range(1, 14)]   # 12 rows of whole blocks, bucket 16
+            engine._lens[:] = 3                               # the step writes rows inside [0, 12)
+            engine._insert_prompt_kv(1, prompt, 0, 0)
+            if round_ == "decode":
+                engine._decode_round([0, 1])
+            else:
+                engine._multi_round([0, 1], 4)
+            assert all(a.is_deleted() for a in given)
+            wrote = np.asarray(engine._caches[0][0])[1, 3]
+            assert not np.array_equal(wrote, want[0, 0, 3])   # and the step did write there
+            engine._finish_kv_inserts()
+            lease = engine._prefix_cache.lookup(prompt)
+            try:
+                assert lease is not None and lease.matched_tokens == 12
+                np.testing.assert_array_equal(lease.kv(), want)
+            finally:
+                lease.release()
+    finally:
+        engine.shutdown()
+
+
+def _greedy_ids(path):
+    """The greedy ids of one path that hands rows to, or verifies against, consumed caches."""
+    from ray_tpu.llm import SamplingParams
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    prompt = list(range(1, 14))
+    if path == "prefix_attach":
+        engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=1,
+                              prefix_cache=PrefixCacheManager(4, 1 << 20, name="ids"))
+        try:
+            first = _generate(engine, prompt, max_tokens=8)
+            second = _generate(engine, prompt, max_tokens=8)
+            assert engine.last_attach["cached_tokens"] == 12
+            return first + second
+        finally:
+            engine.shutdown()
+    if path == "spec_verify":
+        engine = _tiny_engine(num_slots=2, max_seq=64, prefix_cache=False,
+                              spec_config={"num_spec_tokens": 3})
+        try:
+            out = _generate(engine, prompt, max_tokens=12)
+            assert engine.scheduler_stats()["spec"]["rounds"] > 0
+            return out
+        finally:
+            engine.shutdown()
+    assert path == "pd_attach"
+    prefiller = _tiny_engine(num_slots=1, max_seq=64, decode_loop=False, prefix_cache=False)
+    decoder = _tiny_engine(num_slots=2, max_seq=64, prefix_cache=False)
+    try:
+        first_logits, kv, plen = prefiller.prefill_detached(prompt)
+        return _collect(decoder, lambda cb: decoder.submit_prefilled(
+            kv, plen, first_logits, SamplingParams(max_tokens=8), cb))
+    finally:
+        prefiller.shutdown()
+        decoder.shutdown()
+
+
+@pytest.mark.parametrize("path", ["prefix_attach", "pd_attach", "spec_verify"])
+def test_attached_and_verified_rows_give_the_ids_of_an_undonated_run(monkeypatch, path):
+    """A prefix-cache attach, a PD attach and a speculative verify round each write into
+    caches the program consumed: the greedy ids are those of the same engine built with
+    no `donate_argnums` anywhere."""
+    from ray_tpu._private.config import CONFIG
+
+    monkeypatch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+    with _undonated():
+        want = _greedy_ids(path)
+    got = _greedy_ids(path)
+    assert got == want and len(got) >= 8
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_reports_from_another_thread_never_touch_a_consumed_array(monkeypatch, tp):
+    """`_memory_owner_report` (the memory ledger's callback) and `scheduler_stats()` run on
+    report threads while the stepper's programs consume one generation of the caches after
+    another, under a mesh too, where the per-device split used to walk the arrays' shards."""
+    import sys
+
+    import jax
+
+    if tp > len(jax.devices()):
+        pytest.skip("needs 2 devices")
+    engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=2, prefix_cache=False, tp=tp)
+    errors, stop, reports = [], threading.Event(), [0]
+
+    def report():
+        while not stop.is_set():
+            try:
+                row = engine._memory_owner_report()
+                stats = engine.scheduler_stats()
+                assert row["components"]["kv_slots"] == stats["model"]["cache_bytes"] > 0
+                if tp > 1:
+                    assert sum(row["per_device"].values()) == row["components"]["kv_slots"]
+                reports[0] += 1
+            except Exception as e:  # noqa: BLE001 - whatever a consumed array raises
+                errors.append(e)
+                return
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    thread = threading.Thread(target=report, daemon=True)
+    thread.start()
+    try:
+        for start in (1, 21, 41):
+            _generate(engine, list(range(start, start + 9)), max_tokens=12)
+    finally:
+        stop.set()
+        thread.join(30)
+        sys.setswitchinterval(switch)
+        engine.shutdown()
+    assert not errors, errors
+    assert reports[0] > 0
+
+
+def test_a_program_that_raises_after_consuming_the_caches_ends_the_engine(monkeypatch):
+    """What the stepper does when a program fails once its inputs are gone (for every
+    block): the request in flight ends with the error, the engine refuses further work
+    and never dispatches on the deleted arrays again, and the reports still answer."""
+    from ray_tpu.llm import SamplingParams
+
+    engine = _tiny_engine(num_slots=2, max_seq=64, multi_step=1, prefix_cache=False)
+    real = engine._jit_decode
+    given = []
+
+    def failing(*args):
+        real(*args)
+        given.extend(a for layer in args[4] for a in layer)
+        raise RuntimeError("RESOURCE_EXHAUSTED: planted")
+
+    engine._jit_decode = failing
+    try:
+        acc = _generate(engine, list(range(1, 10)), max_tokens=8)
+        engine._thread.join(30)
+        assert acc[-1] == -1 and isinstance(engine.error, RuntimeError)
+        assert given and all(a.is_deleted() for a in given)
+        assert all(a.is_deleted() for layer in engine._caches for a in layer)
+        with pytest.raises(RuntimeError, match="stepper died"):
+            engine.submit([1, 2, 3], SamplingParams(max_tokens=2), lambda tok, fin: None)
+        assert engine.scheduler_stats()["model"]["cache_bytes"] > 0
+        assert engine._memory_owner_report()["components"]["kv_slots"] > 0
     finally:
         engine.shutdown()
