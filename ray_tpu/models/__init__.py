@@ -31,6 +31,7 @@ BLOCKS = {
     "llama": "ray_tpu.models.llama",
     "dots3": "ray_tpu.models.dots3",
     "granite_hybrid": "ray_tpu.models.granite_hybrid",
+    "lfm2": "ray_tpu.models.lfm2",
 }
 
 # What a caller may ask of a block, and how the refusal names the caller.

@@ -60,7 +60,8 @@ def _lora_delta(x, A, B_, scale):
 
 
 def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
-                 lora_layer=None, adapter_ids=None, write_gate=None, score_scale=None, rotate=True):
+                 lora_layer=None, adapter_ids=None, write_gate=None, score_scale=None, rotate=True,
+                 qk_norm=None):
     """One attention layer against the KV cache.
 
     x: [B, S, M]; positions: [B, S]; cache_k/v: [B, T, Hkv, D];
@@ -76,6 +77,8 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
     queries and keys take rotary positions (`models/granite_hybrid.py` serves its position-free
     attention layers through here with a scale of its own; the scope `kv_attn` holds the
     slab's write and the two products against it, for every model).
+    qk_norm: (q scale, k scale), each [head_dim], of an RMSNorm over every head of q and of k
+    before the rotation (`models/lfm2.py`; scope `qk_norm`); None: no norm and no operation more.
     """
     B, S, _ = x.shape
     q = _dense(x, layer["q"]["kernel"].reshape(cfg.hidden, -1)).reshape(
@@ -97,6 +100,10 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
             x, lora_layer["v_A"][adapter_ids], lora_layer["v_B"][adapter_ids], scale
         )
         v = v + dv.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    if qk_norm is not None:
+        with jax.named_scope("qk_norm"):
+            q = _rmsnorm(q, qk_norm[0], cfg.norm_eps)
+            k = _rmsnorm(k, qk_norm[1], cfg.norm_eps)
     if rotate:
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)

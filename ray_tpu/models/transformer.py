@@ -71,10 +71,13 @@ class ModelConfig:
     # `n_heads` and `rope_theta` are the full layers'; `mlp_dim` the leading dense layers'.
     # "granite_hybrid": Mamba-2 layers whose recurrent state lives in the engine's slots
     # beside the KV rows of a few position-free GQA layers (`models/granite_hybrid.py`,
-    # served only); its fields are the last group.
+    # served only); its fields are the group after dots3's. "lfm2": gated short convolutions whose last
+    # inputs live in the engine's slots, GQA layers with q/k norms and rotary, all of a layer's
+    # sigmoid-routed experts held (`models/lfm2.py`, served only): dots3's expert fields
+    # (`first_k_dense`, `n_routed_experts*`, `experts_per_token`, `moe_mlp_dim`) and `conv_L_cache`.
     block: str = "llama"
     layer_types: tuple = ()            # per layer; dots3: "full_attention" | "sliding_attention";
-                                       # granite_hybrid: "mamba" | "attention"
+                                       # granite_hybrid: "mamba" | "attention"; lfm2: "conv" | "full_attention"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -110,6 +113,7 @@ class ModelConfig:
     attention_multiplier: float = 0.0  # the attention layers' score scale
     logits_scaling: float = 1.0        # logits are divided by it
     position_embedding_type: str = "rope"  # "nope": the attention layers rotate nothing
+    conv_L_cache: int = 3              # lfm2: taps of a conv layer's causal depthwise convolution
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))  # a JSON list, hashable

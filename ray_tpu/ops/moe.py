@@ -1,6 +1,7 @@
-"""Mixture-of-Experts layer with expert parallelism over the "ep" mesh axis.
+"""Mixture-of-Experts layers: the train step's and the serve path's.
 
-The reference framework has no native expert parallelism (SURVEY.md §2.3: vLLM
+Train (`MoEMLP`, `top_k_routing`): expert parallelism over the "ep" mesh axis. The
+reference framework has no native expert parallelism (SURVEY.md §2.3: vLLM
 kwargs pass-through only); here it is a library op. Design is the standard TPU
 MoE recipe: top-k router → capacity-bounded dispatch (dense einsum with a
 one-hot dispatch mask keeps everything static-shaped for XLA) → experts as a
@@ -10,6 +11,10 @@ all-to-alls over ICI — no hand-written collectives needed.
 
 Shapes (E experts, C capacity per expert, k top-k):
     tokens  [B, S, M]  →  dispatch [B, S, E, C]  →  expert in [E, B*C', M] ...
+
+Serve (`sigmoid_routing`, `grouped_experts`; called by `models/dots3.py` and
+`models/lfm2.py`): sigmoid scores with a selection bias and renormalised top-k,
+then a dropless product over the held experts' sorted, tiled pairs (below).
 """
 
 from __future__ import annotations
@@ -117,18 +122,19 @@ class MoEMLP(nn.Module):
 # train step's `MoEMLP` above is the older capacity-dropping one-hot dispatch.
 
 
-def sigmoid_routing(h, router_kernel, router_bias, k: int, scaling: float = 1.0):
+def sigmoid_routing(h, router_kernel, router_bias, k: int, scaling: float = 1.0, eps: float = 0.0):
     """`noaux_tc` routing: scores `sigmoid(h W_r)` in float32, the `k` experts of
     largest score + bias chosen (the bias chooses and does not weigh), weights the
-    chosen scores over their sum times `scaling`. h: [N, D] -> (ids [N, k] int32,
-    weights [N, k] float32)."""
+    chosen scores over their sum (plus `eps`, where a model's code adds one) times
+    `scaling`. h: [N, D] -> (ids [N, k] int32, weights [N, k] float32)."""
     logits = jax.lax.dot_general(
         h.astype(jnp.float32), router_kernel.astype(jnp.float32),
         (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, ids = jax.lax.top_k(scores + router_bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    weights = chosen / (total + eps if eps else total) * scaling
     return ids.astype(jnp.int32), weights
 
 

@@ -261,3 +261,53 @@ def test_fused_loss_moves_the_head_once_a_step_on_v5e_2x2(v5e_2x2):
     whole_head = sorted((kind, inside) for kind, size, inside in _collectives(text) if size == E * V)
     assert whole_head in ([("all-gather", False), ("all-reduce", False)],
                           [("all-gather", False), ("reduce-scatter", False)]), whole_head
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b512"])
+def test_the_lfm2_cells_programs_fit_the_chip_and_read_each_expert_in_place_for_v5e(one_chip, program):
+    """`lfm2-24b-a2b.serve-decode64`'s three programs at the published widths, all 9 layers of the
+    cut and 64 slots of 4096 rows, the caches donated as the engine donates them: the plan
+    (arguments + outputs + temporaries - aliased) stays under the chip's 15.75 GiB with 10.36 GB of
+    weights held, every cache array is aliased to its output, and the loop over the experts' tiles
+    reads a tile's expert where it lies: its `[2048, 1536]` matrices are a `dynamic-slice` of the
+    stack fused into the product, and no operation of the compiled text copies one out first
+    (that would read every expert twice and write it once: PERF.md §6, PR 34)."""
+    import json
+    import os
+
+    from ray_tpu.models import lfm2
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "lfm2-24b-a2b.json")) as f:
+        model = json.load(f)["model"]
+    cfg = ModelConfig(**{k: getattr(jnp, v) if k in ("dtype", "param_dtype") else v for k, v in model.items()})
+    slots = 64
+    params = _shaped(jax.eval_shape(lambda k: lfm2.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: lfm2.init_caches(cfg, slots, cfg.max_seq)), one_chip)
+    vec, scalar = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
+
+    def steps(n):
+        def run(params, last, caches, lens, gate):
+            def step(carry, _):
+                last, caches, lens = carry
+                logits, caches, stats = lfm2.decode(params, cfg, last, caches, lens, gate)
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32), caches, lens + 1), stats
+
+            return jax.lax.scan(step, (last, caches, lens), None, length=n)
+        return jax.jit(run, donate_argnums=(2,)).lower(params, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
+
+    if program == "rt_prefill_b512":
+        lowered = jax.jit(lambda p, t, c, s, o, n: lfm2.prefill(p, cfg, t, c, s, o, n), donate_argnums=(2,)).lower(
+            params, _operand((1, 512), one_chip, jnp.int32), caches, scalar, scalar, scalar)
+    else:
+        lowered = steps(8 if program.endswith("n8") else 1)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    plan = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert m.alias_size_in_bytes == held == 64 * (57344 + 4096 * 4096)
+    assert 2 * lfm2.num_params(cfg) + held < plan < 15.75 * 2**30, plan / 2**30
+    text = compiled.as_text()
+    assert "bf16[64,2048,1536]" in text and re.search(r"bf16\[1,2048,1536\]\S* dynamic-slice\(", text)
+    for matrix in ("bf16[2048,1536]", "bf16[1536,2048]", "bf16[1,2048,1536]", "bf16[1,1536,2048]", "bf16[64,2048,1536]"):
+        assert not re.search(re.escape(matrix) + r"\S* copy\(", text), matrix

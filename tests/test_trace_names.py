@@ -296,3 +296,65 @@ def test_the_dense_blocks_cached_products_are_named_too(engines):
     vec = np.zeros((plain.B,), np.int32)
     _, scopes = _lowered(plain._jit_decode, plain.params, None, vec, vec, plain._caches, vec, np.ones((plain.B,), bool))
     assert "kv_attn" in scopes and not (GRANITE_INNER - {"kv_attn"}) & scopes
+
+
+# -- the lfm2 block: a conv layer's and an attention layer's scopes, the experts', both counts ----
+
+LFM2_INNER = {"in_proj", "conv", "out_proj", "qk_norm", "kv_attn", "router", "experts"}
+
+
+@pytest.fixture(scope="module")
+def lfm2_engine():
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+    from ray_tpu.models import lfm2
+    from tests.test_lfm2 import tiny
+
+    cfg = tiny()
+    params = lfm2.init_params(cfg, jax.random.PRNGKey(2))
+    saved = CONFIG._cache.get("llm_prefill_bucket_min")
+    CONFIG._cache["llm_prefill_bucket_min"] = 4
+    engine = DecodeEngine(cfg, params, num_slots=2, max_seq=64, multi_step=4, token_budget=8)
+    try:
+        done = threading.Event()
+        engine.submit(list(range(1, 20)), SamplingParams(max_tokens=7), lambda tok, fin: fin and done.set())
+        assert done.wait(180), engine.error
+        yield engine
+    finally:
+        engine.shutdown()
+        CONFIG._cache.pop("llm_prefill_bucket_min") if saved is None else CONFIG._cache.update(llm_prefill_bucket_min=saved)
+
+
+def test_the_lfm2_blocks_programs_keep_the_names_and_name_a_layers_parts(lfm2_engine):
+    """granite's names in a conv layer (`lib/scope_trace_state.py` reads them), dots3's in an expert
+    layer (`lib/scope_trace.py`), `qk_norm` and `kv_attn` in an attention layer, under `layer_<i>`."""
+    engine = lfm2_engine
+    B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
+    step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
+    programs = [(engine._jit_decode, step)] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
+                 for k, p in engine._jit_prefill.items()]
+    names = dict(_lowered(prog, *args) for prog, args in programs)
+    assert {"jit_rt_decode", "jit_rt_prefill_b8"} <= set(names), sorted(names)
+    assert any(re.fullmatch(r"jit_rt_decode_multi_n\d+", n) for n in names)
+    for module, scopes in names.items():
+        assert set(MODEL_SCOPES) <= scopes, (module, set(MODEL_SCOPES) - scopes)
+        assert LFM2_INNER <= scopes, (module, LFM2_INNER - scopes)
+        assert {f"layer_{i}" for i in range(6)} <= scopes and not {"ssm", "gate_norm", "shared_expert"} & scopes
+        assert ("sample" in scopes) == ("multi" in module), module
+
+
+def test_scheduler_stats_count_the_lfm2_blocks_experts_and_its_state(lfm2_engine):
+    stats = lfm2_engine.scheduler_stats()
+    experts, state = stats["experts"], stats["state"]
+    assert stats["model"]["block"] == "lfm2" and (experts["held"], experts["of"], experts["first"]) == (8, 8, 0)
+    # 19 prompt tokens and 6 fed-back tokens through 5 expert layers, 2 experts a token, every pair held
+    assert experts["pairs_routed"] == experts["pairs_held"] == (19 + 6) * 5 * 2
+    counted = {"pairs_routed", "pairs_held", "experts_hit", "tiles_run", "layer_steps", "decode_experts_hit", "decode_layer_steps"}
+    assert counted <= set(experts) and set(experts["window"]) == counted | {"max_load", "mean_load"}
+    assert experts["decode_layer_steps"] == 6 * 5 and experts["decode_experts_hit"] == 6 * 5 * 2 < experts["experts_hit"] <= experts["tiles_run"]
+    # chunks of 8, 8 and 3 (bucket 4): one reset; 6 fed-back tokens on one slot
+    assert {k: state[k] for k in ("prefill_positions", "prefill_padding", "states_reset", "decode_slot_steps")} == {
+        "prefill_positions": 20, "prefill_padding": 1, "states_reset": 1, "decode_slot_steps": 6}
+    assert set(state["window"]) == {"prefill_positions", "prefill_padding", "states_reset", "decode_slot_steps"}
+    assert state["bytes_per_slot"] == 4 * 2 * 64 * 4
