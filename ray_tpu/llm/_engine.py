@@ -25,6 +25,7 @@ prefill adds ZERO new compiled programs.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import logging
 import threading
@@ -107,6 +108,14 @@ def _sample_host(logits_row: np.ndarray, sampling: SamplingParams,
     probs = np.exp(scaled)
     probs /= probs.sum()
     return int(rng.choice(len(probs), p=probs))
+
+
+def _traced_on(mesh):
+    """What a program's body traces its block's call under: the TP engine's mesh, so that
+    the block can see it (`models/llama.py` runs its attention kernel inside a `shard_map`
+    over it; everything else is GSPMD's, from the arguments' shardings), and nothing on one
+    device."""
+    return contextlib.nullcontext() if mesh is None else mesh
 
 
 class DecodeEngine:
@@ -526,8 +535,9 @@ class DecodeEngine:
         batched forward with a stale lens, and an ungated write there would
         permanently corrupt rows its covering chunk already wrote (same hazard
         the spec-verify gate exists for)."""
-        logits, new_caches, stats = self._block.decode(
-            params, self.cfg, last_token, caches, lens, gate, lora, adapter_ids)
+        with _traced_on(self._mesh):
+            logits, new_caches, stats = self._block.decode(
+                params, self.cfg, last_token, caches, lens, gate, lora, adapter_ids)
         return (logits, new_caches, lens + 1, *stats)
 
     def _decode_multi(self, params, lora, adapter_ids, last_token, caches, lens,
@@ -572,8 +582,9 @@ class DecodeEngine:
 
         Returns on-device argmax [B, k+1] (the host needs k+1 ints per slot,
         not logits)."""
-        logits, new_caches, stats = self._block.verify(
-            params, self.cfg, tokens, caches, lens, gate, lora, adapter_ids)
+        with _traced_on(self._mesh):
+            logits, new_caches, stats = self._block.verify(
+                params, self.cfg, tokens, caches, lens, gate, lora, adapter_ids)
         with jax.named_scope("sample"):
             greedy = jnp.argmax(logits + constraint_mask, axis=-1).astype(jnp.int32)
         return (greedy, new_caches, *stats)

@@ -201,10 +201,9 @@ class ModelDraft(DraftProvider):
 
         def step(carry, idx):
             tok, sc, pos = carry
-            kv_mask = (jnp.arange(self.T)[None, :] <= pos)[None]
             logits, new_sc = llama._forward_cached(
                 params, dcfg, tok[None, None], pos[None, None], sc,
-                pos[None], kv_mask, lora=None, adapter_ids=None,
+                pos[None], lora=None, adapter_ids=None,
             )
             nxt = jnp.argmax(logits[0, 0]).astype(jnp.int32)
             if catchup:
@@ -230,10 +229,9 @@ class ModelDraft(DraftProvider):
         S = tokens.shape[1]
         positions = jnp.arange(S)[None, :]
         slot_caches = [(c[0][slot][None], c[1][slot][None]) for c in caches]
-        mask = (jnp.arange(S)[:, None] >= jnp.arange(self.T)[None, :])[None]
         _logits, new_slot = llama._forward_cached(
             params, self.cfg, tokens, positions, slot_caches,
-            jnp.zeros((1,), jnp.int32), mask, lora=None, adapter_ids=None,
+            jnp.zeros((1,), jnp.int32), lora=None, adapter_ids=None,
         )
         return llama._scatter_slot_caches(caches, new_slot, slot)
 

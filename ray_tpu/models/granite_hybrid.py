@@ -174,7 +174,7 @@ def init_params(cfg: ModelConfig, key):
 
 def init_caches(cfg: ModelConfig, slots: int, max_seq: int) -> list:
     H, P, N, _, W, _ = mamba_dims(cfg)
-    kv = (slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
+    kv = llama.kv_slab_shape(cfg, slots, max_seq)  # heads of 64: two to a row of 128 lanes
     return [
         (jnp.zeros((slots, cfg.mamba_d_conv - 1, W), cfg.dtype), jnp.zeros((slots, H, P, N), jnp.float32))
         if _is_mamba(cfg, i) else (jnp.zeros(kv, cfg.dtype), jnp.zeros(kv, cfg.dtype))
@@ -273,9 +273,9 @@ def _mamba_decode(p, u, cache, gate, cfg: ModelConfig):
 # -- the layers round the mixers ------------------------------------------------------
 
 
-def _attention(p, normed, positions, cache, write_at, kv_mask, gate, cfg: ModelConfig):
+def _attention(p, normed, positions, cache, write_at, gate, cfg: ModelConfig):
     out, k, v = llama._attn_cached(
-        p, normed, positions, cache[0], cache[1], write_at, kv_mask, cfg, write_gate=gate,
+        p, normed, positions, cache[0], cache[1], write_at, cfg, write_gate=gate,
         score_scale=cfg.attention_multiplier, rotate=cfg.position_embedding_type != "nope")
     return out, (k, v)
 
@@ -316,11 +316,6 @@ def _head(params, cfg: ModelConfig, x):
         return logits / cfg.logits_scaling
 
 
-def _kv_rows(cfg: ModelConfig, caches) -> int:
-    """Rows of the attention layers' slabs."""
-    return next(c[0].shape[1] for i, c in enumerate(caches) if not _is_mamba(cfg, i))
-
-
 def _counts(**named):
     return (jnp.stack([jnp.asarray(named.get(name, 0), jnp.int32) for name in COUNTS]),)
 
@@ -336,13 +331,13 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     n_valid = jnp.minimum(S, total_len - offset)
     view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
     positions = offset + jnp.arange(S)[None, :]
-    # a query sees the rows up to its own position: the earlier chunks' and this chunk's
-    kv_mask = (positions[0][:, None] >= jnp.arange(_kv_rows(cfg, caches))[None, :])[None]
+    # a query sees the rows up to its own position, the earlier chunks' and this chunk's:
+    # `_attn_cached` reads that from the slot's length, `offset`
 
     def mix(i, p, normed):
         if _is_mamba(cfg, i):
             return _mamba_prefill(p, normed, view[i], offset, n_valid, cfg)
-        return _attention(p, normed, positions, view[i], offset[None], kv_mask, None, cfg)
+        return _attention(p, normed, positions, view[i], offset[None], None, cfg)
 
     x, new = _forward(params, cfg, tokens, mix)
     caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
@@ -356,12 +351,11 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
     """The engine's decode step for this block: one token for every slot; only slots with
     `gate` advance their state and write their rows. Returns (logits [B, V], caches, stats)."""
     positions = lens[:, None]
-    kv_mask = (jnp.arange(_kv_rows(cfg, caches))[None, :] <= lens[:, None])[:, None, :]
 
     def mix(i, p, normed):
         if _is_mamba(cfg, i):
             return _mamba_decode(p, normed, caches[i], gate, cfg)
-        return _attention(p, normed, positions, caches[i], lens, kv_mask, gate, cfg)
+        return _attention(p, normed, positions, caches[i], lens, gate, cfg)
 
     x, new = _forward(params, cfg, last_token[:, None], mix)
     return _head(params, cfg, x[:, 0]), new, _counts(decode_slot_steps=jnp.sum(gate))

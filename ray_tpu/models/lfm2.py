@@ -175,7 +175,7 @@ def init_params(cfg: ModelConfig, key):
 
 
 def init_caches(cfg: ModelConfig, slots: int, max_seq: int) -> list:
-    kv = (slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
+    kv = llama.kv_slab_shape(cfg, slots, max_seq)  # heads of 64: two to a row of 128 lanes
     return [
         (jnp.zeros((slots, cfg.conv_L_cache - 1, cfg.hidden), cfg.dtype),)
         if _is_conv(cfg, i) else (jnp.zeros(kv, cfg.dtype), jnp.zeros(kv, cfg.dtype))
@@ -263,8 +263,8 @@ def _conv_decode(p, h, cache, gate, cfg: ModelConfig):
 # -- the layers round the operators ----------------------------------------------------
 
 
-def _attention(p, normed, positions, cache, write_at, kv_mask, gate, cfg: ModelConfig):
-    out, k, v = llama._attn_cached(p, normed, positions, cache[0], cache[1], write_at, kv_mask, cfg, write_gate=gate,
+def _attention(p, normed, positions, cache, write_at, gate, cfg: ModelConfig):
+    out, k, v = llama._attn_cached(p, normed, positions, cache[0], cache[1], write_at, cfg, write_gate=gate,
                                    qk_norm=(p["q_norm"]["scale"], p["k_norm"]["scale"]))
     return out, (k, v)
 
@@ -325,11 +325,6 @@ def _head(params, cfg: ModelConfig, x):
                                    preferred_element_type=jnp.float32)
 
 
-def _kv_rows(cfg: ModelConfig, caches) -> int:
-    """Rows of the attention layers' slabs."""
-    return next(c[0].shape[1] for i, c in enumerate(caches) if not _is_conv(cfg, i))
-
-
 def _counts(names: tuple, **named):
     """One int32 array in the order of `names`, 0 where a program counts nothing under a name."""
     return jnp.stack([jnp.asarray(named.get(name, 0), jnp.int32) for name in names])
@@ -346,13 +341,13 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     n_valid = jnp.minimum(S, total_len - offset)
     view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
     positions = offset + jnp.arange(S)[None, :]
-    # a query sees the rows up to its own position: the earlier chunks' and this chunk's
-    kv_mask = (positions[0][:, None] >= jnp.arange(_kv_rows(cfg, caches))[None, :])[None]
+    # a query sees the rows up to its own position, the earlier chunks' and this chunk's:
+    # `_attn_cached` reads that from the slot's length, `offset`
 
     def mix(i, p, normed):
         if _is_conv(cfg, i):
             return _conv_prefill(p, normed, view[i], offset, n_valid, cfg)
-        return _attention(p, normed, positions, view[i], offset[None], kv_mask, None, cfg)
+        return _attention(p, normed, positions, view[i], offset[None], None, cfg)
 
     x, new, experts = _forward(params, cfg, tokens, jnp.arange(S)[None, :] < n_valid, mix, decoding=False)
     caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
@@ -366,12 +361,11 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
     """The engine's decode step for this block: one token for every slot; only slots with `gate`
     advance their state, write their rows and are routed. Returns (logits [B, V], caches, stats)."""
     positions = lens[:, None]
-    kv_mask = (jnp.arange(_kv_rows(cfg, caches))[None, :] <= lens[:, None])[:, None, :]
 
     def mix(i, p, normed):
         if _is_conv(cfg, i):
             return _conv_decode(p, normed, caches[i], gate, cfg)
-        return _attention(p, normed, positions, caches[i], lens, kv_mask, gate, cfg)
+        return _attention(p, normed, positions, caches[i], lens, gate, cfg)
 
     x, new, experts = _forward(params, cfg, last_token[:, None], gate[:, None], mix, decoding=True)
     return _head(params, cfg, x[:, 0]), new, (experts, _counts(STATE_COUNTS, decode_slot_steps=jnp.sum(gate)))

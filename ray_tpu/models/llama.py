@@ -19,9 +19,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import ModelConfig, Transformer, _dense, _rmsnorm, _rope
-
-_NEG_INF = -1e30
+from ray_tpu.models.transformer import ModelConfig, Transformer, _dense, _mesh_to_split_over, _rmsnorm, _rope
+from ray_tpu.ops import attention
 
 # What the engine and the layers round it may ask of this block (`models.require`).
 SUPPORTS = frozenset({"lora", "speculation", "tp", "prefix_cache", "pd", "train", "checkpoint"})
@@ -30,6 +29,21 @@ SUPPORTS = frozenset({"lora", "speculation", "tp", "prefix_cache", "pd", "train"
 def init_params(cfg: ModelConfig, key):
     """The tree `load_model` serves at random weights: the flax model's own."""
     return Transformer(cfg).init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def kv_slab_shape(cfg: ModelConfig, slots: int, max_seq: int) -> tuple:
+    """One K or V slab of heads under 128 wide, kept whole rows of 128 lanes: the row-major
+    elements of `[slots, max_seq, Hkv, D]` as `[slots, max_seq, Hkv * D // 128, 128]`. An
+    array whose last axis is under 128 wide is not stored row-major on the TPU (its rows
+    become the lanes), and every program that reads it a row at a time first copies all of
+    it into another layout (PERF.md §6, PR 35). `_attn_cached` takes either form; the blocks
+    whose engine paths take a slab only whole (`granite_hybrid`, `lfm2`) keep theirs so.
+    This block's own (`init_caches`) stay a head a row: its prefix gather, attach and TP
+    sharding address axis 2 as the KV heads (ROADMAP S5: the follow-up that ends the fork)."""
+    width = cfg.n_kv_heads * cfg.head_dim
+    if cfg.head_dim < 128 and 128 % cfg.head_dim == 0 and width % 128 == 0:
+        return (slots, max_seq, width // 128, 128)
+    return (slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
 
 
 def init_caches(cfg: ModelConfig, slots: int, max_seq: int) -> list:
@@ -59,24 +73,31 @@ def _lora_delta(x, A, B_, scale):
     return d * scale[:, None, None].astype(x.dtype)
 
 
-def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
+def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, cfg,
                  lora_layer=None, adapter_ids=None, write_gate=None, score_scale=None, rotate=True,
                  qk_norm=None):
     """One attention layer against the KV cache.
 
-    x: [B, S, M]; positions: [B, S]; cache_k/v: [B, T, Hkv, D];
-    write_at: [B] start index per slot; kv_mask: [B, S, T] visibility.
+    x: [B, S, M]; positions: [B, S]; cache_k/v: [B, T, Hkv, D] (or [B, T, Hkv * D // 128,
+    128], `kv_slab_shape`); write_at: [B], the slot's length: the S new rows are written
+    at rows write_at + [0, S), and query i sees the rows j <= write_at + i. That one
+    number a slot is all the visibility there is, so the rows past it are not read
+    (`_cached_products`).
     lora_layer (optional): stacked adapters {"q_A": [A,M,r], "q_B": [A,r,H*D],
     "v_A", "v_B", "scale": [A]} gathered per slot by adapter_ids [B] — the
     multi-LoRA batching role of the reference's punica path, as plain gathers +
     batched matmuls so one jitted program serves any adapter mix.
     write_gate (optional): [B] bool — slots with a False gate leave their
     cache rows untouched (the batched speculative-verify program runs every
-    slot through the forward but must only land KV for participants).
+    slot through the forward but must only land KV for participants). The
+    programs that step every slot at once hand one in (decode, verify); a
+    one-slot view (prefill) has none, and that is what routes the attention:
+    the kernel over the live rows for the first, the products for the second
+    (`_cached_products`).
     score_scale: what the scores are multiplied by, 1 / sqrt(head_dim) where None; rotate: whether
     queries and keys take rotary positions (`models/granite_hybrid.py` serves its position-free
     attention layers through here with a scale of its own; the scope `kv_attn` holds the
-    slab's write and the two products against it, for every model).
+    slab's write and the attention against it, for every model).
     qk_norm: (q scale, k scale), each [head_dim], of an RMSNorm over every head of q and of k
     before the rotation (`models/lfm2.py`; scope `qk_norm`); None: no norm and no operation more.
     """
@@ -110,48 +131,76 @@ def _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg,
     if score_scale is None:
         score_scale = 1.0 / math.sqrt(cfg.head_dim)
     with jax.named_scope("kv_attn"):
-        out, cache_k, cache_v = _cached_products(q, k, v, cache_k, cache_v, write_at, kv_mask, cfg,
+        out, cache_k, cache_v = _cached_products(q, k, v, cache_k, cache_v, write_at, cfg,
                                                  write_gate, score_scale)
     o_kernel = layer["o"]["kernel"].reshape(-1, cfg.hidden)
     proj = _dense(out.reshape(B, S, -1), o_kernel)
     return proj, cache_k, cache_v
 
 
-def _cached_products(q, k, v, cache_k, cache_v, write_at, kv_mask, cfg, write_gate, scale):
-    """The new rows into the slab, then scores and values against it. q: [B, S, H, D];
+def _cached_products(q, k, v, cache_k, cache_v, write_at, cfg, write_gate, scale):
+    """The new rows into the slab, then attention against it. q: [B, S, H, D];
     k, v: [B, S, Hkv, D] -> (out [B, S, Hkv, G, D], cache_k, cache_v)."""
     B, S = q.shape[:2]
+    origin = (0,) * (cache_k.ndim - 2)
+    new_k = k.astype(cache_k.dtype).reshape((B, S) + cache_k.shape[2:])
+    new_v = v.astype(cache_v.dtype).reshape((B, S) + cache_v.shape[2:])
     if write_gate is None:
         def put(slot_cache, slot_new, at):
-            return jax.lax.dynamic_update_slice(slot_cache, slot_new, (at, 0, 0))
+            return jax.lax.dynamic_update_slice(slot_cache, slot_new, (at,) + origin)
 
-        cache_k = jax.vmap(put)(cache_k, k.astype(cache_k.dtype), write_at)
-        cache_v = jax.vmap(put)(cache_v, v.astype(cache_v.dtype), write_at)
+        cache_k = jax.vmap(put)(cache_k, new_k, write_at)
+        cache_v = jax.vmap(put)(cache_v, new_v, write_at)
     else:
         # Gated write: read the current rows and write them back unchanged
         # when the gate is off. The read and write clamp identically at the
         # cache end, so an off-gate slot is a no-op even at the boundary.
         def put_gated(slot_cache, slot_new, at, gate):
-            cur = jax.lax.dynamic_slice(slot_cache, (at, 0, 0), slot_new.shape)
+            cur = jax.lax.dynamic_slice(slot_cache, (at,) + origin, slot_new.shape)
             new = jnp.where(gate, slot_new, cur)
-            return jax.lax.dynamic_update_slice(slot_cache, new, (at, 0, 0))
+            return jax.lax.dynamic_update_slice(slot_cache, new, (at,) + origin)
 
-        cache_k = jax.vmap(put_gated)(
-            cache_k, k.astype(cache_k.dtype), write_at, write_gate
-        )
-        cache_v = jax.vmap(put_gated)(
-            cache_v, v.astype(cache_v.dtype), write_at, write_gate
-        )
+        cache_k = jax.vmap(put_gated)(cache_k, new_k, write_at, write_gate)
+        cache_v = jax.vmap(put_gated)(cache_v, new_v, write_at, write_gate)
 
-    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor
-    # of the split, and both products run against the slab as it lies: a copy of
-    # K or V repeated to H heads costs a third of a decode step (PERF.md §6, PR 29).
+    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor of the split.
+    # The programs that step every slot of the engine at once (decode, multi-step, verify:
+    # the ones that gate their writes) go, on the TPU, to the kernel that reads of each slot
+    # the row blocks up to its last visible row and nothing past it: the rows no slot holds
+    # were most of a decode step (PERF.md §6, PR 35). Their callers trace them under the
+    # engine's mesh (`llm/_engine.py:_traced_on`). A one-slot view has no gate (a prefill
+    # chunk of any bucket, a detached prefill, the draft's own steps): its queries are many
+    # or its rows few, and it takes the two products over the whole slab, with the mask
+    # built from the same lengths; so do a slab of heads under 128 wide kept a head a row,
+    # which is not row-major on the TPU, and every other backend, which has no kernel.
     qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
-    logits = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(q.dtype)) * scale
-    logits = jnp.where(kv_mask[:, None, None], logits.astype(jnp.float32), _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, cache_v.astype(q.dtype))
+    if write_gate is not None and attention._use_pallas() and attention.cached_attention_takes(cache_k.shape[-1]):
+        out = _cached_attention_on_mesh(qg, cache_k, cache_v, write_at, scale)
+    else:
+        out = attention.cached_attention_xla(qg, cache_k, cache_v, write_at, scale=scale)
     return out, cache_k, cache_v
+
+
+def _cached_attention_on_mesh(qg, cache_k, cache_v, lens, scale, interpret: bool = False):
+    """The kernel, on one device or under the mesh of the enclosing `with mesh:` (the TP
+    engine traces its programs inside one). A `pallas_call` has no partitioning rule, and
+    attention is independent per KV head: under a mesh whose `tp` axis splits the slabs'
+    heads the call runs inside a `shard_map` over that axis; where `tp` does not divide
+    them the slabs are whole on every device (`llm/tp.py:kv_cache_sharding`) and so is the call."""
+    mesh = _mesh_to_split_over()
+
+    def run(qg, cache_k, cache_v, lens):
+        return attention.cached_attention(qg, cache_k, cache_v, lens, scale=scale, interpret=interpret)
+
+    if mesh is None:
+        return run(qg, cache_k, cache_v, lens)
+    from jax.sharding import PartitionSpec as P
+
+    tp = mesh.shape.get("tp", 1)
+    heads = "tp" if tp > 1 and qg.shape[2] % tp == 0 and cache_k.shape[2] % tp == 0 else None
+    slab = P(None, None, heads)
+    return jax.shard_map(run, mesh=mesh, in_specs=(slab, slab, slab, P()), out_specs=slab,
+                         check_vma=False)(qg, cache_k, cache_v, lens)
 
 
 def _mlp(layer, x):
@@ -161,8 +210,9 @@ def _mlp(layer, x):
 
 
 def _forward_cached(params, cfg: ModelConfig, tokens, positions, caches, write_at,
-                    kv_mask, lora=None, adapter_ids=None, write_gate=None):
-    """tokens: [B,S] -> logits [B,S,V]; updates caches in place (returned).
+                    lora=None, adapter_ids=None, write_gate=None):
+    """tokens: [B,S] -> logits [B,S,V]; updates caches in place (returned). write_at: [B],
+    each slot's length before these tokens (`_attn_cached`).
 
     lora: the AdapterCache's STACKED tables ({"q_A": [L, S, M, r], ...}) —
     per-layer views are extracted here inside the trace, so paging swaps the
@@ -184,7 +234,7 @@ def _forward_cached(params, cfg: ModelConfig, tokens, positions, caches, write_a
             with jax.named_scope("attn"):
                 attn_out, ck, cv = _attn_cached(
                     layer["attn"], normed, positions, caches[i][0], caches[i][1],
-                    write_at, kv_mask, cfg,
+                    write_at, cfg,
                     lora_layer=None if lora is None else {k: v[i] for k, v in lora.items()},
                     adapter_ids=adapter_ids,
                     write_gate=write_gate,
@@ -239,13 +289,11 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len,
     slot_caches = [
         (c[0][slot][None], c[1][slot][None]) for c in caches
     ]
-    # visibility: key row j <= global query position offset+i; attached
-    # prefix rows [0, offset) are all visible, pad rows beyond stay hidden
-    T = caches[0][0].shape[1]
-    mask = (positions[0][:, None] >= jnp.arange(T)[None, :])[None]
+    # visibility follows from the slot's length, `offset`: key row j <= global query
+    # position offset+i; attached prefix rows [0, offset) are all visible, pad rows
+    # beyond stay hidden
     logits, new_slot_caches = _forward_cached(
-        params, cfg, tokens, positions, slot_caches,
-        offset[None], mask,
+        params, cfg, tokens, positions, slot_caches, offset[None],
         lora=lora, adapter_ids=adapter_id[None],
     )
     out_caches = _scatter_slot_caches(caches, new_slot_caches, slot)
@@ -259,10 +307,8 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora, adapt
     Returns (logits [B, V], caches, no stats)."""
     positions = lens[:, None]
     # key j visible iff j <= lens (the new token writes at index lens)
-    T = caches[0][0].shape[1]
-    kv_mask = (jnp.arange(T)[None, :] <= lens[:, None])[:, None, :]
     logits, new_caches = _forward_cached(
-        params, cfg, last_token[:, None], positions, caches, lens, kv_mask,
+        params, cfg, last_token[:, None], positions, caches, lens,
         lora=lora, adapter_ids=adapter_ids, write_gate=gate,
     )
     return logits[:, 0], new_caches, ()
@@ -274,10 +320,8 @@ def verify(params, cfg: ModelConfig, tokens, caches, lens, gate, lora, adapter_i
     their KV rows untouched. Returns (logits [B, k+1, V], caches, no stats)."""
     B, S = tokens.shape
     positions = lens[:, None] + jnp.arange(S)[None, :]
-    T = caches[0][0].shape[1]
-    kv_mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]
     logits, new_caches = _forward_cached(
-        params, cfg, tokens, positions, caches, lens, kv_mask,
+        params, cfg, tokens, positions, caches, lens,
         lora=lora, adapter_ids=adapter_ids, write_gate=gate,
     )
     return logits, new_caches, ()
@@ -319,10 +363,8 @@ def prefill_detached(params, cfg: ModelConfig, tokens, lora, adapter_id):
     S = tokens.shape[1]
     positions = jnp.arange(S)[None, :]
     caches = init_caches(cfg, 1, S)
-    mask = (jnp.arange(S)[:, None] >= jnp.arange(S)[None, :])[None]
     logits, new_caches = _forward_cached(
-        params, cfg, tokens, positions, caches,
-        jnp.zeros((1,), jnp.int32), mask,
+        params, cfg, tokens, positions, caches, jnp.zeros((1,), jnp.int32),
         lora=lora, adapter_ids=adapter_id[None],
     )
     kv = jnp.stack(
@@ -337,8 +379,9 @@ def prefill_detached_suffix(params, cfg: ModelConfig, prefix, tokens, off, lora,
     prefix [L, 2, mb, Hkv, D] of which rows [0, off) are valid.
     Returns (logits [sb, V], the suffix's kv [L, 2, sb, Hkv, D])."""
     mb, sb = prefix.shape[2], tokens.shape[1]
-    # cache layout: rows [0, mb) = attached prefix (valid [0, off)),
-    # rows [mb, mb+sb) = this pass's suffix writes.
+    # cache layout: rows [0, off) = the attached prefix's valid rows, rows [off, off+sb) =
+    # this pass's suffix writes, over the prefix's padding: the rows a query sees are then
+    # the rows up to its own, as in every other program (`_attn_cached`).
     caches = []
     for i in range(cfg.n_layers):
         zeros = jnp.zeros(
@@ -353,18 +396,12 @@ def prefill_detached_suffix(params, cfg: ModelConfig, prefix, tokens, off, lora,
             ),
         ))
     positions = off + jnp.arange(sb)[None, :]
-    rows = jnp.arange(mb + sb)[None, :]
-    # visible: real prefix rows, plus suffix rows written so far
-    mask = (
-        (rows < off)
-        | ((rows >= mb) & (rows - mb <= jnp.arange(sb)[:, None]))
-    )[None]
     logits, new_caches = _forward_cached(
-        params, cfg, tokens, positions, caches,
-        jnp.full((1,), mb, jnp.int32), mask,
+        params, cfg, tokens, positions, caches, off[None].astype(jnp.int32),
         lora=lora, adapter_ids=adapter_id[None],
     )
     suffix_kv = jnp.stack([
-        jnp.stack([ck[0, mb:], cv[0, mb:]]) for ck, cv in new_caches
+        jnp.stack([jax.lax.dynamic_slice_in_dim(ck[0], off, sb), jax.lax.dynamic_slice_in_dim(cv[0], off, sb)])
+        for ck, cv in new_caches
     ])  # [L, 2, sb, Hkv, D]
     return logits[0], suffix_kv

@@ -527,3 +527,165 @@ def _flash_bhsd_bwd_rule(causal, scale, residuals, g):
 
 
 flash_attention_bhsd.defvjp(_flash_bhsd_fwd_rule, _flash_bhsd_bwd_rule)
+
+
+# ------------------------------------------------ cached attention (the serve path)
+#
+# One query block a slot against the slot's rows of the KV slab, as the engine holds it:
+# `[B, T, *minor]`, where `minor` is `(Hkv, D)` or any row-major regrouping of it whose
+# last axis holds whole heads (`(Hkv * D // 128, 128)` for heads of 64: the blocks with
+# narrow heads keep their slabs so, because a TPU array whose last axis is under 128
+# lanes is not stored row-major). Query i of slot b sees rows j <= lens[b] + i.
+
+
+def cached_attention_xla(q, cache_k, cache_v, lens, *, scale):
+    """The plain form: two products over every row of the slab and a mask. q: [B, S, Hkv,
+    G, D]; cache_k/v: [B, T, *minor]; lens: [B] -> [B, S, Hkv, G, D] in q's type. Scores in
+    float32, the weights cast to q's type before the values' product."""
+    B, S, Hkv, _, D = q.shape
+    T = cache_k.shape[1]
+    k = cache_k.reshape(B, T, Hkv, D).astype(q.dtype)
+    v = cache_v.reshape(B, T, Hkv, D).astype(q.dtype)
+    # Grouped queries: head h reads KV head h // G, so both products run against the slab
+    # as it lies: a copy of K or V repeated to H heads costs a third of a decode step
+    # (PERF.md §6, PR 29).
+    logits = jnp.einsum("bskgd,btkd->bkgst", q, k) * scale
+    visible = jnp.arange(T)[None, None, :] <= lens[:, None, None] + jnp.arange(S)[None, :, None]
+    logits = jnp.where(visible[:, None, None], logits.astype(jnp.float32), _NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgst,btkd->bskgd", probs, v)
+
+
+def cached_attention_takes(lanes: int) -> bool:
+    """Whether the kernel can read a slab whose last axis is `lanes` wide: its rows must be
+    whole rows of 128 lanes. A narrower last axis (a dense model's heads of 64 kept a head a
+    row) is not stored row-major on the TPU, so the kernel's copies cannot address it and the
+    compiler would copy the whole slab for it first, as it does for the products."""
+    return lanes % 128 == 0
+
+
+def _cached_block_rows(T: int, groups: int) -> int:
+    """Cache rows a block of the kernel reads: about 1024 rows of the flattened slab (256 KB
+    each of K and V in bfloat16: the chip's fastest of 512 to 8192, PERF.md §6, PR 35), a
+    divisor of T."""
+    want = max(8, 1024 // groups)
+    while want > 8 and T % want:
+        want //= 2
+    return want if T % want == 0 else T
+
+
+def _cached_attn_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
+                        scale: float, S: int, G: int, groups: int, per_group: int, block: int, T: int):
+    """Grid (slot,): online softmax over the slot's live row blocks, which the kernel copies in
+    itself, two buffers deep, so that a slot costs no step for a block it does not hold.
+
+    q_ref: [1, R, W], R = Hkv * S * G rows ordered (lane group, head in the group, position,
+    query of the head), a row's head in its own D of the W lanes and zeros in the others.
+    k_hbm, v_hbm: the whole flattened slabs [B, T * groups, W], left where they are; a block is
+    `block` cache rows, each as `groups` rows of W lanes. A score is live where the column's
+    lane group is the row's and its cache row is visible; the zeros of q make a group's other
+    heads add nothing."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    lens = lens_ref[b]
+    live_blocks = jnp.minimum(lens + S - 1, T - 1) // block + 1
+    width = block * groups
+
+    def copies(j, buf):
+        rows = pl.ds(j * width, width)
+        return (pltpu.make_async_copy(k_hbm.at[b, rows], k_buf.at[buf], sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[b, rows], v_buf.at[buf], sems.at[1, buf]))
+
+    for copy in copies(0, 0):
+        copy.start()
+    q = q_ref[0]
+    sg = S * G
+
+    def one_block(j, carry):
+        m_prev, l_prev, acc = carry
+        buf = j % 2
+
+        @pl.when(j + 1 < live_blocks)
+        def _next():
+            for copy in copies(j + 1, 1 - buf):
+                copy.start()
+
+        for copy in copies(j, buf):
+            copy.wait()
+        k = k_buf[buf].astype(q.dtype)
+        v = v_buf[buf].astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [R, block * groups]
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * width
+        # column c is lane group c % groups of cache row c // groups; every row sees cache
+        # row 0, so no row of the first block is all masked and m is finite from there on
+        live = (col % groups == row // (per_group * sg)) & (col < (lens + 1 + (row % sg) // G) * groups)
+        s = jnp.where(live, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(q.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        return m_new, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), acc
+
+    rows = q.shape[0]
+    _, l, acc = jax.lax.fori_loop(0, live_blocks, one_block, (
+        jnp.full((rows, 1), _NEG_INF, jnp.float32), jnp.zeros((rows, 1), jnp.float32), jnp.zeros(q.shape, jnp.float32)))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def cached_attention(q, cache_k, cache_v, lens, *, scale, interpret: bool = False):
+    """The length-aware kernel: as `cached_attention_xla`, reading of each slot only the row
+    blocks up to its last visible row. The slab goes in as it lies: its rows and the axes
+    between them and the last are flattened, which moves nothing. Jitted, so that a program
+    of 24 layers traces the kernel and lowers it to Mosaic once and calls that 24 times:
+    traced in line, each layer's call costs 0.09 s of every start, warm or cold (PERF.md §6, PR 35)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, Hkv, G, D = q.shape
+    T, W = cache_k.shape[1], cache_k.shape[-1]
+    groups, per_group = Hkv * D // W, W // D
+    assert per_group * D == W and groups * per_group == Hkv, (q.shape, cache_k.shape)
+    rows = Hkv * S * G
+    qf = jnp.transpose(q, (0, 2, 1, 3, 4)).reshape(B, groups, per_group, S * G, D)
+    if per_group > 1:
+        # a head's D values into its own lanes of the group, zeros in the other heads'
+        own = jnp.eye(per_group, dtype=q.dtype)[None, None, :, None, :, None]
+        qf = qf[:, :, :, :, None, :] * own
+    qf = qf.reshape(B, rows, W)
+    block = _cached_block_rows(T, groups)
+    flat = (B, T * groups, W)
+    kernel = functools.partial(_cached_attn_kernel, scale=float(scale), S=S, G=G, groups=groups,
+                               per_group=per_group, block=block, T=T)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, rows, W), lambda b, lens: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, rows, W), lambda b, lens: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block * groups, W), cache_k.dtype),
+                pltpu.VMEM((2, block * groups, W), cache_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, rows, W), q.dtype),
+        interpret=interpret,
+        name="cached_attn",
+    )(lens.astype(jnp.int32), qf, cache_k.reshape(flat), cache_v.reshape(flat))
+    out = out.reshape(B, groups, per_group, S * G, per_group, D)
+    if per_group > 1:
+        out = jnp.stack([out[:, :, u, :, u] for u in range(per_group)], axis=2)
+    return jnp.transpose(out.reshape(B, Hkv, S, G, D), (0, 2, 1, 3, 4))

@@ -59,6 +59,16 @@ def one_chip(v5e_2x2):
     return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
+@pytest.fixture(autouse=True)
+def as_on_the_chip(monkeypatch):
+    """`ops.attention._use_pallas()` asks for the backend and sees the CPU here: every program of
+    this file is compiled as the chip's process would trace it, the cached attention through its
+    kernel (on-chip-measurement guide, section 2: steer such code in the test)."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+
+
 def _operand(shape, sharding, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -111,12 +121,31 @@ _SERVE_CFG = ModelConfig(vocab_size=92544, hidden=2048, n_layers=24, n_heads=16,
                          mlp_dim=8192, max_seq=2048, rope_theta=1e6)
 
 
+def _granite_cfg():
+    return ModelConfig(block="granite_hybrid", vocab_size=100352, hidden=2048, n_layers=2, n_heads=32, n_kv_heads=8,
+                       mlp_dim=8192, max_seq=4096, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, scan_layers=False,
+                       remat=False, tie_embeddings=True, layer_types=("mamba", "attention"), mamba_n_heads=64,
+                       mamba_d_head=64, mamba_d_state=128, embedding_multiplier=12.0, residual_multiplier=0.22,
+                       attention_multiplier=0.015625, logits_scaling=8.0, position_embedding_type="nope")
+
+
+def _lfm2_cfg():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "lfm2-24b-a2b.json")) as f:
+        model = json.load(f)["model"]
+    return ModelConfig(**{k: getattr(jnp, v) if k in ("dtype", "param_dtype") else v for k, v in model.items()})
+
+
 # (slots, query rows): those cells' decode step over all 12 slots, and a one-slot chunk of 128 tokens
 @pytest.mark.parametrize("slots,rows", [(12, 1), (1, 128)], ids=["decode_b12", "prefill_b128"])
 def test_cached_attention_copies_no_slab_for_v5e(one_chip, slots, rows):
-    """The grouped-query products read K and V where they lie: the compiled layer holds
-    no array of a slab repeated to all query heads. With `jnp.repeat` it held two
-    stand-alone `broadcast_in_dim` of that size, a third of a decode step (PERF.md §6, PR 29)."""
+    """A decode step's attention (the kernel) and a chunk's (the grouped-query products) read K
+    and V where they lie: the compiled layer holds no array of a slab repeated to all query
+    heads. With `jnp.repeat` it held two stand-alone `broadcast_in_dim` of that size, a third
+    of a decode step (PERF.md §6, PR 29)."""
     cfg, T = _SERVE_CFG, _SERVE_CFG.max_seq
     H, Hkv, D, M = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.hidden
     layer = {
@@ -126,16 +155,17 @@ def test_cached_attention_copies_no_slab_for_v5e(one_chip, slots, rows):
     }
     slab = _operand((slots, T, Hkv, D), one_chip)
 
-    def attn(layer, x, positions, cache_k, cache_v, write_at, kv_mask):
-        return _attn_cached(layer, x, positions, cache_k, cache_v, write_at, kv_mask, cfg)
+    def attn(layer, x, positions, cache_k, cache_v, write_at, gate):
+        # the decode step gates its writes; the one-slot chunk has no gate
+        return _attn_cached(layer, x, positions, cache_k, cache_v, write_at, cfg, write_gate=gate if rows == 1 else None)
 
     text = jax.jit(attn).lower(
         layer, _operand((slots, rows, M), one_chip),
         _operand((slots, rows), one_chip, jnp.int32), slab, slab,
-        _operand((slots,), one_chip, jnp.int32),
-        _operand((slots, rows, T), one_chip, jnp.bool_),
+        _operand((slots,), one_chip, jnp.int32), _operand((slots,), one_chip, jnp.bool_),
     ).compile().as_text()
     assert f"bf16[{slots},{T},{Hkv},{D}]" in text  # the slabs themselves are there to find
+    assert ("cached_attn" in text) == (rows == 1)  # a one-slot view keeps the products (PERF.md §6, PR 35)
     shapes = {tuple(int(n) for n in dims.split(","))
               for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", text)}
     G = H // Hkv
@@ -158,12 +188,7 @@ def test_a_decode_step_updates_the_recurrent_state_in_place_for_v5e(one_chip, st
     copy of a recurrent state is in the compiled text: the update reads it once and writes it once."""
     from ray_tpu.models import granite_hybrid as gh
 
-    cfg = ModelConfig(block="granite_hybrid", vocab_size=100352, hidden=2048, n_layers=2, n_heads=32, n_kv_heads=8,
-                      mlp_dim=8192, max_seq=4096, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, scan_layers=False,
-                      remat=False, tie_embeddings=True, layer_types=("mamba", "attention"), mamba_n_heads=64,
-                      mamba_d_head=64, mamba_d_state=128, embedding_multiplier=12.0, residual_multiplier=0.22,
-                      attention_multiplier=0.015625, logits_scaling=8.0, position_embedding_type="nope")
-    slots = 48
+    cfg, slots = _granite_cfg(), 48
 
     params = _shaped(jax.eval_shape(lambda k: gh.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
     caches = _shaped(jax.eval_shape(lambda: gh.init_caches(cfg, slots, cfg.max_seq)), one_chip)
@@ -193,9 +218,9 @@ def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program
     the caches donated as the engine donates them: every slab is aliased to its output, and the
     compiled text holds no copy of one into its own layout. Undonated, each program first copied
     all 48 slabs of the whole depth (`copy(%caches_...)`, four here), 6.9 ms of a 25.5 ms decode
-    step and 6.7 of a 17.3 ms chunk (PERF.md §6, PR 33). The decode programs' text also holds, donated
-    or not, a `copy` a slab into `{3,1,2,0}` (rows before heads, scope `kv_attn`): those are operands
-    fused into the two products, and the chip's trace shows no operation that writes a slab for them."""
+    step and 6.7 of a 17.3 ms chunk (PERF.md §6, PR 33). The decode programs no longer hold a slab
+    in any second layout (`test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e`);
+    the chunk's two products read theirs as fused operands."""
     import dataclasses
     import functools
     import types
@@ -206,7 +231,7 @@ def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program
 
     cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
     slots, T = 12, cfg.max_seq
-    engine = types.SimpleNamespace(cfg=cfg, _block=llama)  # all that the three bodies read of an engine
+    engine = types.SimpleNamespace(cfg=cfg, _block=llama, _mesh=None)  # all that the three bodies read of an engine
     engine._decode_step = functools.partial(DecodeEngine._decode_step, engine)
 
     params = _shaped(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
@@ -226,6 +251,113 @@ def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program
     text = compiled.as_text()
     assert slab + "{3,2,1,0" in text  # the slab as the engine holds it: row-major
     assert not re.search(re.escape(slab) + r"\{3,2,1,0[^}]*\} copy\(", text)
+
+
+@pytest.mark.parametrize("steps", [1, 8], ids=["rt_decode", "rt_decode_multi_n8"])
+@pytest.mark.parametrize("block", ["llama", "granite_hybrid", "lfm2"])
+def test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e(one_chip, block, steps):
+    """The four serve cells' decode programs (the dense block at InternLM2-1.8B's widths and 12
+    slots of 2048 rows, cut to two layers; `granite_hybrid` at 48 slots of 4096 rows, one mamba and
+    one attention layer; `lfm2`'s cut of 9 layers at 64 slots), the caches donated as the engine
+    donates them: the text holds the `cached_attn` call, every cache array is aliased to its
+    output, no operation copies an array of a slab's size (the parent's programs copied every
+    head-64 slab into another layout each step, `jit_rt_decode:copy.24` and its like, a quarter of
+    a `granite_hybrid` step: PERF.md §6, PR 35), and no `[B, Hkv, G, S, T]` array of scores over
+    every row of every slot is left."""
+    from ray_tpu import models
+    from ray_tpu.parallel.mesh import unbox
+
+    import dataclasses
+
+    cfg, slots = {"llama": (dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False), 12),
+                  "granite_hybrid": (_granite_cfg(), 48), "lfm2": (_lfm2_cfg(), 64)}[block]
+    module, T = models.block_module(cfg), cfg.max_seq
+    params = _shaped(unbox(jax.eval_shape(lambda k: module.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: module.init_caches(cfg, slots, T)), one_chip)
+    vec = _operand((slots,), one_chip, jnp.int32)
+
+    def run(params, last, caches, lens, gate):
+        def step(carry, _):
+            last, caches, lens = carry
+            logits, caches, stats = module.decode(params, cfg, last, caches, lens, gate, None, None)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), caches, lens + 1), stats
+
+        return jax.lax.scan(step, (last, caches, lens), None, length=steps)
+
+    compiled = jax.jit(run, donate_argnums=(2,)).lower(params, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_)).compile()
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes == held
+    text = compiled.as_text()
+    assert re.search(r"%cached_attn(\.\d+)? = ", text) and re.search(r'kv_attn/[^"]*cached_attn/pallas_call"', text)
+    slab = math.prod(module.init_caches(cfg, 1, 8)[-1][0].shape[2:]) * slots * T  # elements of one K or V slab
+    sized = [line.strip()[:160] for line in text.splitlines()
+             if (m := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)) and math.prod(int(n) for n in m.group(1).split(",")) >= slab]
+    assert not sized, sized
+    G = cfg.n_heads // cfg.n_kv_heads
+    shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
+    assert not {s for s in shapes if T in s and math.prod(s) == slots * cfg.n_kv_heads * G * T}, "scores over every row"
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_spec_verify_k4", "rt_prefill_b16", "rt_prefill_b128",
+                                     "prefill_detached_b16", "draft_propose"])
+def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
+    """The TP=4 engine's programs over the dense block at InternLM2-1.8B's widths (cut to two
+    layers), params and slabs split over `tp` as `llm/tp.py` splits them, each traced as the
+    engine traces it: decode and verify under the mesh (`_engine.py:_traced_on`), where the
+    kernel runs inside a `shard_map` over the KV heads, two calls and the row-parallel
+    all-reduces; a prefill chunk (the smallest bucket: 16 tokens x 16 heads), a detached prefill
+    and the draft's own steps outside any mesh, where a `pallas_call` cannot be lowered
+    (`Mosaic kernels cannot be automatically partitioned`): those hold no kernel and compile."""
+    import dataclasses
+    import functools
+    import types
+
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.llm import tp as tp_plan
+    from ray_tpu.llm._engine import DecodeEngine
+    from ray_tpu.llm.scheduler.spec import ModelDraft
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import unbox
+
+    cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
+    slots, T = 12, cfg.max_seq
+    mesh = tp_plan.build_tp_mesh(4, devices=list(v5e_2x2.devices))
+    whole = tp_plan.replicated(mesh)
+    engine = types.SimpleNamespace(cfg=cfg, _block=llama, _mesh=mesh)
+    engine._decode_step = functools.partial(DecodeEngine._decode_step, engine)
+
+    def on_mesh(tree, path=()):
+        if isinstance(tree, dict):
+            return {k: on_mesh(v, path + (k,)) for k, v in tree.items()}
+        return _operand(tree.shape, NamedSharding(mesh, tp_plan.param_spec(path, tuple(tree.shape), mesh)), tree.dtype)
+
+    params = on_mesh(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))))
+    caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), tp_plan.kv_cache_sharding(mesh, cfg.n_kv_heads))
+    vec, i32 = _operand((slots,), whole, jnp.int32), _operand((), whole, jnp.int32)
+    gate = _operand((slots,), whole, jnp.bool_)
+    draft = types.SimpleNamespace(cfg=cfg, T=T)  # all that the draft's program reads of its provider
+    body, args = {
+        "rt_decode": (engine._decode_step, (params, None, vec, vec, caches, vec, gate)),
+        "rt_spec_verify_k4": (functools.partial(DecodeEngine._spec_verify_batched, engine),
+                              (params, None, vec, _operand((slots, 5), whole, jnp.int32), caches, vec, gate,
+                               _operand((slots, 5, cfg.vocab_size), whole, jnp.float32))),
+        "rt_prefill_b16": (functools.partial(DecodeEngine._prefill_at, engine),
+                           (params, None, _operand((1, 16), whole, jnp.int32), caches, i32, i32, i32, i32)),
+        "rt_prefill_b128": (functools.partial(DecodeEngine._prefill_at, engine),
+                            (params, None, _operand((1, 128), whole, jnp.int32), caches, i32, i32, i32, i32)),
+        "prefill_detached_b16": (lambda params, tokens, adapter: llama.prefill_detached(params, cfg, tokens, None, adapter),
+                                 (params, _operand((1, 16), whole, jnp.int32), i32)),
+        "draft_propose": (functools.partial(ModelDraft._propose_prog, draft, k=4, catchup=False),
+                          (params, caches, i32, i32, i32, i32)),
+    }[program]
+    text = jax.jit(body).lower(*args).compile().as_text()
+    kernels = len(re.findall(r"%cached_attn(\.\d+)? = ", text))
+    if program in ("rt_decode", "rt_spec_verify_k4"):
+        assert kernels == cfg.n_layers and " all-reduce" in text
+        assert f"bf16[{slots},{T},{cfg.n_kv_heads // 4},{cfg.head_dim}]" in text  # a device's heads of the slab
+    else:
+        assert kernels == 0 and "tpu_custom_call" not in text
 
 
 def test_fused_loss_moves_the_head_once_a_step_on_v5e_2x2(v5e_2x2):
@@ -272,16 +404,9 @@ def test_the_lfm2_cells_programs_fit_the_chip_and_read_each_expert_in_place_for_
     reads a tile's expert where it lies: its `[2048, 1536]` matrices are a `dynamic-slice` of the
     stack fused into the product, and no operation of the compiled text copies one out first
     (that would read every expert twice and write it once: PERF.md §6, PR 34)."""
-    import json
-    import os
-
     from ray_tpu.models import lfm2
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "lfm2-24b-a2b.json")) as f:
-        model = json.load(f)["model"]
-    cfg = ModelConfig(**{k: getattr(jnp, v) if k in ("dtype", "param_dtype") else v for k, v in model.items()})
-    slots = 64
+    cfg, slots = _lfm2_cfg(), 64
     params = _shaped(jax.eval_shape(lambda k: lfm2.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
     caches = _shaped(jax.eval_shape(lambda: lfm2.init_caches(cfg, slots, cfg.max_seq)), one_chip)
     vec, scalar = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)

@@ -259,8 +259,7 @@ def test_attn_cached_with_norm_scales_is_the_references_layer_and_without_them_t
     p["k_norm"] = {"scale": jnp.linspace(1.2, 0.7, 16)}
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 16, cfg.hidden))
     k0 = jnp.zeros((1, 16, cfg.n_kv_heads, cfg.head_dim))
-    mask = jnp.tril(jnp.ones((16, 16), bool))[None]
-    args = (p, x, jnp.arange(16)[None], k0, k0, jnp.zeros((1,), jnp.int32), mask, cfg)
+    args = (p, x, jnp.arange(16)[None], k0, k0, jnp.zeros((1,), jnp.int32), cfg)  # a slot of 0 rows: causal
     out, _, _ = llama._attn_cached(*args, qk_norm=(p["q_norm"]["scale"], p["k_norm"]["scale"]))
     with jax.default_matmul_precision("highest"):
         want = reference._attention(p, x[0], _model_dict(cfg), 16, lambda a: a)

@@ -100,11 +100,12 @@ def test_attn_cached_equals_repeat_reference(heads, S, gated):
     cache_v = jax.random.normal(keys[3], (B, T, Hkv, D))
     lens = jnp.asarray([0, 7, 17], jnp.int32)
     positions = lens[:, None] + jnp.arange(S)[None]
-    # causal over the slot's own rows: query s sees rows 0 .. lens + s
+    # causal over the slot's own rows: query s sees rows 0 .. lens + s, which `_attn_cached`
+    # reads from `lens`; the reference takes it spelled out
     kv_mask = jnp.arange(T)[None, None, :] <= positions[:, :, None]
     gate = jnp.asarray([True, False, True]) if gated else None
 
-    got = _attn_cached(layer, x, positions, cache_k, cache_v, lens, kv_mask, cfg,
+    got = _attn_cached(layer, x, positions, cache_k, cache_v, lens, cfg,
                        write_gate=gate)
     want = _attn_repeat_reference(layer, x, positions, cache_k, cache_v, lens, kv_mask,
                                   cfg, write_gate=gate)
@@ -151,7 +152,7 @@ def test_attn_cached_head_reads_its_own_kv_head(heads, side):
     # positions 0 leave the rotation the identity; the gate keeps the slab as built
     out, _, _ = _attn_cached(
         layer, jnp.ones((1, 1, M)), jnp.zeros((1, 1), jnp.int32), cache_k, cache_v,
-        jnp.asarray([T - 1], jnp.int32), jnp.ones((1, 1, T), bool), cfg,
+        jnp.asarray([T - 1], jnp.int32), cfg,  # a slot of T - 1 rows: the query sees all T
         write_gate=jnp.asarray([False]))
     want = np.repeat(np.arange(1.0, Hkv + 1), (H // Hkv) * D)
     np.testing.assert_allclose(np.asarray(out[0, 0]), want, atol=1e-5)

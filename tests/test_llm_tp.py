@@ -118,9 +118,9 @@ def attn_collectives(tp):
     }
     slab = arg((B, T, Hkv, D), tp_plan.kv_cache_sharding(mesh, Hkv))
     text = jax.jit(
-        lambda layer, x, pos, ck, cv, at, mask: _attn_cached(layer, x, pos, ck, cv, at, mask, cfg)
+        lambda layer, x, pos, ck, cv, at: _attn_cached(layer, x, pos, ck, cv, at, cfg)
     ).lower(layer, arg((B, 1, M)), arg((B, 1), dtype=jnp.int32), slab, slab,
-            arg((B,), dtype=jnp.int32), arg((B, 1, T), dtype=jnp.bool_)).compile().as_text()
+            arg((B,), dtype=jnp.int32)).compile().as_text()
     return sorted(re.findall(
         r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)(?:-start)?\(", text))
 

@@ -298,6 +298,41 @@ def test_the_dense_blocks_cached_products_are_named_too(engines):
     assert "kv_attn" in scopes and not (GRANITE_INNER - {"kv_attn"}) & scopes
 
 
+def test_the_cached_attention_kernel_is_named_under_kv_attn(monkeypatch):
+    """As the TPU's process traces a decode program, the attention against the slab is the Pallas
+    kernel `cached_attn` (`pallas_call(name=...)`) inside the scope `kv_attn` of every layer: the
+    scope readers (`kv_attn_dev_ms_per_step.sessions`, `kv_attn_roofline.serve`) sum the kernel's
+    time with the write's by that scope, and a trace's own listing names the kernel. Heads of
+    128, as the kernel asks of a slab kept a head a row (`cached_attention_takes`)."""
+    from ray_tpu.models import llama
+    from ray_tpu.models.transformer import ModelConfig
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import unbox
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    cfg = ModelConfig(vocab_size=64, hidden=256, n_layers=2, n_heads=2, n_kv_heads=1, mlp_dim=64,
+                      scan_layers=False, remat=False)
+    params = unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0)))
+    caches = jax.eval_shape(lambda: llama.init_caches(cfg, 2, 64))
+    vec = jax.ShapeDtypeStruct((2,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, last, c, lens, gate: llama.decode(p, cfg, last, c, lens, gate, None, None))(
+        params, vec, caches, vec, jax.ShapeDtypeStruct((2,), jnp.bool_))
+
+    def kernels(jaxpr, outer):
+        """(kernel name, scopes) of every `pallas_call`, through the jitted functions that hold them."""
+        for eqn in jaxpr.eqns:
+            scopes = outer + [part for part in str(eqn.source_info.name_stack).split("/") if part]
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], scopes
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from kernels(sub, scopes)
+
+    calls = list(kernels(jaxpr.jaxpr, []))
+    assert len(calls) == cfg.n_layers, calls
+    for i, (name, scopes) in enumerate(calls):
+        assert name == "cached_attn" and scopes[-1] == "cached_attn" and "kv_attn" in scopes and f"layer_{i}" in scopes, (name, scopes)
+
+
 # -- the lfm2 block: a conv layer's and an attention layer's scopes, the experts', both counts ----
 
 LFM2_INNER = {"in_proj", "conv", "out_proj", "qk_norm", "kv_attn", "router", "experts"}
