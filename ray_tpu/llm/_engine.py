@@ -624,25 +624,28 @@ class DecodeEngine:
                 cmask[i, :len(rows)] = rows
         with xprof.span("rt.engine.dispatch", steps=1, slots=len(plan.spec_slots),
                         rows=int(self._lens[plan.spec_slots].sum())) as dispatch:
-            verify = self._program(
-                self._jit_spec_verify, ("verify", S),
-                lambda: jax.jit(named(f"rt_verify_s{S}", self._spec_verify_batched),
-                                donate_argnums=(4,)),
-            )
-            greedy_dev, self._caches, *stats = verify(
-                self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
-                jnp.asarray(tokens), self._caches, jnp.asarray(self._lens),
-                jnp.asarray(gate), jnp.asarray(cmask),
-            )
-            self._note_stats(stats)
+            with xprof.span("rt.engine.dispatch.args"):
+                lora, adapter_ids = self._lora_tables(), jnp.asarray(self._adapter_ids)
+                tokens_dev, lens = jnp.asarray(tokens), jnp.asarray(self._lens)
+                gate_dev, cmask_dev = jnp.asarray(gate), jnp.asarray(cmask)
+            with xprof.span("rt.engine.dispatch.call"):
+                verify = self._program(
+                    self._jit_spec_verify, ("verify", S),
+                    lambda: jax.jit(named(f"rt_verify_s{S}", self._spec_verify_batched),
+                                    donate_argnums=(4,)),
+                )
+                greedy_dev, self._caches, *stats = verify(
+                    self.params, lora, adapter_ids, tokens_dev, self._caches,
+                    lens, gate_dev, cmask_dev)
+                self._note_stats(stats)
         # The round's ONE acceptance sync: k+1 tokens per participating slot
         # arrive in a single batched pull — no per-token host round trip.
-        with xprof.span("rt.engine.readback", bytes=greedy_dev.nbytes):
-            greedy = np.asarray(greedy_dev)  # raylint: disable=RL603 (per-round batched acceptance sync)
+        greedy = self._readback(greedy_dev)
         c = self._spec_counters
         c["rounds"] += 1
         round_proposed = round_accepted = 0
-        with xprof.span("rt.engine.sample", slots=len(plan.spec_slots)):
+        with xprof.span("rt.engine.sample", slots=len(plan.spec_slots)), \
+                xprof.span("rt.engine.sample.emit"):
             for i in plan.spec_slots:
                 s = self._sched.slots[i]
                 p = plan.proposals[i]
@@ -976,6 +979,7 @@ class DecodeEngine:
         return {
             "request_id": summary["rid"],
             "queue_s": summary["queue_s"],
+            "prefill_wait_s": summary["prefill_wait_s"],
             "ttft_s": summary["ttft_s"],
             "tpot_s": summary["tpot_s"],
             "e2e_s": summary["e2e_s"],
@@ -1682,8 +1686,7 @@ class DecodeEngine:
         # The admission sync: the request's FIRST token must be sampled
         # host-side before the slot can join the decode batch — one
         # [V]-row pull per admitted request, not per step or per chunk.
-        with xprof.span("rt.engine.readback", bytes=last_logits.nbytes):
-            first_row = np.asarray(last_logits)  # raylint: disable=RL603 (one per-admission pull)
+        first_row = self._readback(last_logits)
         with xprof.span("rt.engine.sample", slots=1):
             if req.constraint is not None:
                 first_row = first_row + req.constraint.mask(
@@ -1746,8 +1749,8 @@ class DecodeEngine:
             req.rec.span("pd-attach", attach_span.t0, attach_span.t1,
                          prompt_len=prompt_len, bucket=bucket,
                          on_device=on_device)
+        first_row = self._readback(req.first_logits)
         with xprof.span("rt.engine.sample", slots=1):
-            first_row = np.asarray(req.first_logits)
             if req.constraint is not None:
                 # Guided PD decode: the transferred first-logits row gets the
                 # same start-state mask a local prefill's first sample would.
@@ -1809,6 +1812,22 @@ class DecodeEngine:
         summary = self._recorder.finish(rec, status=status)
         if summary is not None:
             self._serve_metrics.record(summary)
+            with xprof.span("rt.engine.finish", rid=rec.rid,
+                            tokens=summary["tokens"], status=status):
+                pass
+
+    @staticmethod
+    def _note_first_token(rec):
+        """A request's first token as an instant of a profiler trace, its
+        durations the record's own (the stamp just made, `admitted`, the first
+        `prefill-chunk`), so that trace and recorder cannot disagree."""
+        attrs = {"ttft_us": int((rec.token_times[0] - rec.t_submit) * 1e6),
+                 "chunks": sum(1 for e in rec.events if e[0] == "prefill-chunk")}
+        wait = rec.prefill_wait_s()
+        if wait is not None:  # a transferred prefix ran no chunk
+            attrs["prefill_wait_us"] = int(wait * 1e6)
+        with xprof.span("rt.engine.first_token", rid=rec.rid, **attrs):
+            pass
 
     def _emit(self, slot: int, token: int):
         s = self._sched.slots[slot]
@@ -1830,6 +1849,8 @@ class DecodeEngine:
         self._sched.note_emitted(slot)  # per-tenant decode-token metering
         if s.rec is not None:
             s.rec.token()  # host timestamp append; TTFT/TPOT derive from these
+            if len(s.rec.token_times) == 1:
+                self._note_first_token(s.rec)
             if done:
                 # Retire the record BEFORE the callback observes
                 # finished=True: a caller reading request_timing() the
@@ -1932,9 +1953,17 @@ class DecodeEngine:
                 # The `rt.engine.*` spans (docs/observability.md "compute
                 # plane") put this loop's phases into any profiler trace, on
                 # the device's clock, and into scheduler_stats()["loop"].
+                # `limit` says what held `steps` under `steps_max`
+                # (`Plan.limit`); `unix_us` is this instant on the clock of
+                # the flight records, so that any trace holds the offset
+                # between that clock and the profiler's.
                 with xprof.span("rt.engine.iter", chunks=len(plan.chunks),
                                 decode_slots=len(plan.decode_slots),
-                                steps=plan.multi_step):
+                                steps=plan.multi_step, steps_max=plan.steps_max,
+                                limit=plan.limit,
+                                waiting=self._sched.queue_depth(),
+                                prefilling=self._sched.prefilling(),
+                                unix_us=int(time.time() * 1e6)):
                     self._exec_plan(plan)
 
     def _exec_plan(self, plan: Plan):
@@ -1957,55 +1986,91 @@ class DecodeEngine:
                     # (The ngram draft is stateless here: no-op.)
                     self._draft.on_plain_decode(i)
 
+    def _step_args(self, decode_slots: List[int]):
+        """What a decode or multi-step program takes besides the parameters and
+        the caches: (lora, adapter_ids, last_token, lens, gate).
+        lens/last_token/adapter_ids ride host->device per dispatch (an async
+        copy of a few int32s); the returned device lens is discarded — the
+        host mirrors are canonical. The write gate restricts KV writes to
+        exactly the slots whose lens advances after the step: idle and
+        mid-prefill slots pass through write-free."""
+        gate = np.zeros((self.B,), bool)
+        gate[decode_slots] = True
+        return (self._lora_tables(), jnp.asarray(self._adapter_ids),
+                jnp.asarray(self._last_token), jnp.asarray(self._lens),
+                jnp.asarray(gate))
+
+    def _readback(self, x) -> np.ndarray:
+        """A program's result on the host: the dispatch's one device->host pull,
+        in the two parts a trace has to tell apart. `.wait` ends when the host
+        is back from waiting for the device (the step's rest, then the wake-up
+        of this thread: the device's idle time inside it is the wake-up);
+        `.copy` is the copy out of a finished buffer (the bytes). No second
+        pull and no program: `block_until_ready` moves nothing, and the copy
+        is asked for before the wait, so that it follows the step on the
+        device as it does under `np.asarray` alone (waiting first and asking
+        then costs a second round trip, 0.12 ms a pull: PERF.md §6, PR 36)."""
+        with xprof.span("rt.engine.readback", bytes=x.nbytes):
+            on_device = isinstance(x, jax.Array)  # a PD transfer's first logits may be numpy
+            if on_device:
+                x.copy_to_host_async()
+            with xprof.span("rt.engine.readback.wait"):
+                if on_device:
+                    x.block_until_ready()  # raylint: disable=RL603 (the same pull's wait, named apart from its copy)
+            with xprof.span("rt.engine.readback.copy"):
+                return np.asarray(x)  # raylint: disable=RL603 (the per-dispatch batched readback)
+
     def _decode_round(self, decode_slots: List[int]):
-        # lens/last_token/adapter_ids ride host->device per dispatch (an
-        # async copy of a few int32s); the returned device lens is
-        # discarded — the host mirrors below are canonical. The write gate
-        # restricts KV writes to exactly the slots whose lens advances
-        # below: idle and mid-prefill slots pass through write-free.
         with xprof.span("rt.engine.dispatch", steps=1, slots=len(decode_slots),
                         rows=int(self._lens[decode_slots].sum())):
-            gate = np.zeros((self.B,), bool)
-            gate[decode_slots] = True
-            logits, self._caches, _, *stats = self._jit_decode(
-                self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
-                jnp.asarray(self._last_token), self._caches,
-                jnp.asarray(self._lens), jnp.asarray(gate),
-            )
-            self._note_stats(stats)
+            with xprof.span("rt.engine.dispatch.args"):
+                lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
+            with xprof.span("rt.engine.dispatch.call"):
+                logits, self._caches, _, *stats = self._jit_decode(
+                    self.params, lora, adapter_ids, last_token, self._caches, lens, gate)
+                self._note_stats(stats)
         # The step's ONE device->host pull: every active slot's next-token
         # logits arrive in a single [B, V] readback (sampling params can
         # differ per slot, so sampling itself is host-side).
-        with xprof.span("rt.engine.readback", bytes=logits.nbytes):
-            logits_np = np.asarray(logits)  # raylint: disable=RL603 (the per-dispatch batched readback)
+        logits_np = self._readback(logits)
+        # Two passes, so that a trace says what the draws cost and what the
+        # emission costs (`rt.engine.sample.draw`, `.emit`): a slot's draw
+        # reads its own state alone and each slot is in the round once, so
+        # drawing every row first gives the tokens of draw-then-emit row by
+        # row, and the generator is asked in the same order.
         with xprof.span("rt.engine.sample", slots=len(decode_slots)):
-            for i in decode_slots:
-                s = self._sched.slots[i]
-                self._lens[i] += 1  # the decode step wrote this slot's kv row
-                if not s.active:
-                    continue
-                row = logits_np[i]
-                if s.constraint is not None:
-                    # Guided composition point (docs/generation.md): one cached
-                    # [V] mask row + one numpy add on the already-pulled logits
-                    # — strictly host-side, zero new compiled programs. When the
-                    # unconstrained argmax is already legal the mask cannot
-                    # change it, so guided greedy output is token-identical to
-                    # unconstrained greedy except where the constraint binds.
-                    # budget= steers onto a completable path once remaining
-                    # max_tokens gets tight (an unbounded quantifier must not
-                    # eat the budget and truncate mid-pattern).
-                    row = row + s.constraint.mask(
-                        s.params.stop_token_id,
-                        budget=s.params.max_tokens - s.generated,
-                    )
-                token = _sample_host(row, s.params, self._np_rng)
-                s.generated += 1
-                s.host_len += 1
-                s.tokens.append(token)
-                s.history.append(token)
-                self._last_token[i] = token
-                self._emit(i, token)
+            drawn = []
+            with xprof.span("rt.engine.sample.draw"):
+                for i in decode_slots:
+                    s = self._sched.slots[i]
+                    if not s.active:
+                        continue
+                    row = logits_np[i]
+                    if s.constraint is not None:
+                        # Guided composition point (docs/generation.md): one cached
+                        # [V] mask row + one numpy add on the already-pulled logits
+                        # — strictly host-side, zero new compiled programs. When the
+                        # unconstrained argmax is already legal the mask cannot
+                        # change it, so guided greedy output is token-identical to
+                        # unconstrained greedy except where the constraint binds.
+                        # budget= steers onto a completable path once remaining
+                        # max_tokens gets tight (an unbounded quantifier must not
+                        # eat the budget and truncate mid-pattern).
+                        row = row + s.constraint.mask(
+                            s.params.stop_token_id,
+                            budget=s.params.max_tokens - s.generated,
+                        )
+                    drawn.append((i, _sample_host(row, s.params, self._np_rng)))
+            with xprof.span("rt.engine.sample.emit"):
+                self._lens[decode_slots] += 1  # the decode step wrote these slots' kv rows
+                for i, token in drawn:
+                    s = self._sched.slots[i]
+                    s.generated += 1
+                    s.host_len += 1
+                    s.tokens.append(token)
+                    s.history.append(token)
+                    self._last_token[i] = token
+                    self._emit(i, token)
 
     def _multi_round(self, decode_slots: List[int], n: int):
         """One multi-token dispatch + host-side emission with rollback for
@@ -2013,24 +2078,24 @@ class DecodeEngine:
         corrected back to what was actually consumed."""
         with xprof.span("rt.engine.dispatch", steps=n, slots=len(decode_slots),
                         rows=int(self._lens[decode_slots].sum())):
-            gate = np.zeros((self.B,), bool)
-            gate[decode_slots] = True
-            decode_multi = self._program(
-                self._jit_decode_multi, ("decode_multi", n),
-                lambda: jax.jit(named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
-                                donate_argnums=(4,)),
-            )
-            toks_dev, self._caches, _, *stats = decode_multi(
-                self.params, self._lora_tables(), jnp.asarray(self._adapter_ids),
-                jnp.asarray(self._last_token), self._caches,
-                jnp.asarray(self._lens), jnp.asarray(gate),
-            )
-            self._note_stats(stats)
+            with xprof.span("rt.engine.dispatch.args"):
+                lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
+            with xprof.span("rt.engine.dispatch.call"):
+                decode_multi = self._program(
+                    self._jit_decode_multi, ("decode_multi", n),
+                    lambda: jax.jit(named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
+                                    donate_argnums=(4,)),
+                )
+                toks_dev, self._caches, _, *stats = decode_multi(
+                    self.params, lora, adapter_ids, last_token, self._caches, lens, gate)
+                self._note_stats(stats)
         # The chunk's ONE device->host pull: n tokens x B slots per readback
         # (the whole point of multi-step decode).
-        with xprof.span("rt.engine.readback", bytes=toks_dev.nbytes):
-            toks = np.asarray(toks_dev)  # raylint: disable=RL603 (the per-chunk batched readback)
-        with xprof.span("rt.engine.sample", slots=len(decode_slots)):
+        toks = self._readback(toks_dev)
+        # Nothing is drawn on the host here (the program took the argmax): the
+        # round's `rt.engine.sample` is emission alone, and says so.
+        with xprof.span("rt.engine.sample", slots=len(decode_slots)), \
+                xprof.span("rt.engine.sample.emit"):
             for i in decode_slots:
                 s = self._sched.slots[i]
                 self._lens[i] += n  # device wrote n kv rows for this slot

@@ -81,10 +81,12 @@ class RequestRecord:
         self.meta = meta
 
     # -- recording (any thread; never blocks, never touches a device) ------
-    def mark(self, name: str, **attrs):
-        """Instant event (rendered as a zero-duration span)."""
+    def mark(self, name: str, **attrs) -> float:
+        """Instant event (rendered as a zero-duration span); returns its
+        stamp, for a caller that names the same instant in a profiler trace."""
         t = time.time()
         self.span(name, t, t, **attrs)
+        return t
 
     def span(self, name: str, t0: float, t1: float, **attrs):
         if len(self.events) >= _MAX_EVENTS:
@@ -98,6 +100,18 @@ class RequestRecord:
             self.token_times.append(time.time())
 
     # -- summarization ------------------------------------------------------
+    def _first(self, name: str) -> Optional[float]:
+        """Start of the first event of that name, or None."""
+        return next((t0 for n, t0, _t1, _a in self.events if n == name), None)
+
+    def prefill_wait_s(self) -> Optional[float]:
+        """Seconds a request spent among the admitted before its own first
+        chunk ran: the first `prefill-chunk`'s start less `admitted` (the
+        chunks of those ahead of it, one an iteration). None where either
+        event is absent (not admitted yet; a transferred prefix has no chunk)."""
+        admitted, chunk = self._first("admitted"), self._first("prefill-chunk")
+        return None if admitted is None or chunk is None else chunk - admitted
+
     def summary(self, status: str = "ok") -> dict:
         """The completion record that feeds the ring, the SLO metrics, and
         the response-metadata timing breakdown."""
@@ -111,10 +125,7 @@ class RequestRecord:
             p = phases.setdefault(name, {"count": 0, "seconds": 0.0})
             p["count"] += 1
             p["seconds"] += max(0.0, t1 - t0)
-        admitted = next(
-            (t0 for name, t0, _t1, _a in self.events if name == "admitted"),
-            None,
-        )
+        admitted = self._first("admitted")
         return {
             "rid": self.rid,
             "status": status,
@@ -127,6 +138,7 @@ class RequestRecord:
             "t_end": t_end,
             "e2e_s": t_end - self.t_submit,
             "queue_s": (admitted - self.t_submit) if admitted else None,
+            "prefill_wait_s": self.prefill_wait_s(),
             "ttft_s": ttft,
             "tpot_s": tpot,
             "tokens": len(tt),

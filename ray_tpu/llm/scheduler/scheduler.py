@@ -42,6 +42,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from ray_tpu.llm.kvcache.manager import PrefixLease
+from ray_tpu.util import xprof
 
 
 class EngineOverloadedError(RuntimeError):
@@ -150,8 +151,8 @@ class Plan:
     """
 
     __slots__ = ("chunks", "decode_slots", "spec_slots", "proposals",
-                 "multi_step", "prefill_tokens", "decode_tokens",
-                 "verify_tokens", "idle")
+                 "multi_step", "steps_max", "limit", "prefill_tokens",
+                 "decode_tokens", "verify_tokens", "idle")
 
     def __init__(self):
         self.chunks: List[ScheduledChunk] = []
@@ -159,10 +160,22 @@ class Plan:
         self.spec_slots: List[int] = []
         self.proposals: Dict[int, np.ndarray] = {}
         self.multi_step = 1
+        self.steps_max = 1          # the scheduler's multi_step
+        self.limit = "off"          # why multi_step < steps_max: one of LIMITS
         self.prefill_tokens = 0
         self.decode_tokens = 0
         self.verify_tokens = 0
         self.idle = True
+
+
+# What held a plan's decode phase to `multi_step` steps, in the order
+# `Scheduler._decide_steps` tests them (the first that holds is the plan's
+# `limit`; docs/scheduler.md): multi-step switched off; no slot decoding; a
+# prefill chunk (or attach) in this plan; a speculative phase; a request
+# admitted whose chunks are not in this plan; a non-empty queue; a slot that
+# samples or is guided; a slot with fewer than `steps_max` tokens left; none.
+LIMITS = ("off", "no_decode", "chunk", "spec", "prefilling", "queue",
+          "sampling", "tail", "none")
 
 
 class _TenantState:
@@ -287,6 +300,13 @@ class Scheduler:
             "prefill_chunks": 0, "admitted": 0, "spec_rounds": 0,
             "rejected": 0, "resident_preferred": 0,
         }
+        # Per `Plan.limit`, since process start: plans run, decode tokens they
+        # planned, and what `decode_slots x multi_step` would have planned.
+        self._by_limit = {
+            limit: {"iterations": 0, "decode_tokens": 0,
+                    "decode_tokens_possible": 0}
+            for limit in LIMITS
+        }
 
     # -- cross-thread API ---------------------------------------------------
     def _tenant(self, name: str) -> _TenantState:
@@ -351,6 +371,10 @@ class Scheduler:
     def queue_depth(self) -> int:
         with self._lock:
             return self._depth
+
+    def prefilling(self) -> int:
+        """Requests admitted (a slot assigned) whose chunks are still to run."""
+        return len(self._prefilling)
 
     def drain(self) -> List[Request]:
         """Remove every queued and in-prefill request (stepper death and
@@ -535,8 +559,15 @@ class Scheduler:
                     req.prefilled = lease.matched_tokens
             if req.rec is not None:
                 # Queue phase ends here: slot assigned, cache lease resolved.
-                req.rec.mark("admitted", slot=req.slot,
-                             cached_tokens=req.cached_offset)
+                # The same instant in a profiler trace, on the device's
+                # clock, its duration from the record's own stamps.
+                t = req.rec.mark("admitted", slot=req.slot,
+                                 cached_tokens=req.cached_offset)
+                with xprof.span("rt.sched.admit", rid=req.rec.rid,
+                                slot=req.slot, cached_tokens=req.cached_offset,
+                                queue_us=int((t - req.rec.t_submit) * 1e6),
+                                waiting=self._depth):
+                    pass
             self._prefilling.append(req)
             admitted += 1
         if admitted:
@@ -612,11 +643,9 @@ class Scheduler:
             plan.prefill_tokens += bucket
 
         # -- multi-step decode: only when the engine is otherwise idle -----
-        if (self.multi_step > 1 and plan.decode_slots and not plan.chunks
-                and not plan.spec_slots and not self._prefilling
-                and self.queue_depth() == 0):
-            plan.multi_step = self._choose_multi_step(plan.decode_slots)
-            plan.decode_tokens = len(plan.decode_slots) * plan.multi_step
+        plan.steps_max = self.multi_step
+        plan.multi_step, plan.limit = self._decide_steps(plan)
+        plan.decode_tokens = len(plan.decode_slots) * plan.multi_step
 
         plan.idle = not (plan.chunks or plan.decode_slots or plan.spec_slots)
         if not plan.idle:
@@ -642,17 +671,36 @@ class Scheduler:
                 best = b
         return best
 
-    def _choose_multi_step(self, decode_slots: List[int]) -> int:
-        """Tokens per decode dispatch: >1 only when every active slot is
-        greedy (on-device argmax is exact then), capped at the smallest
-        remaining budget and power-of-two bucketed to bound the jit cache."""
+    def _decide_steps(self, plan: Plan):
+        """(decode steps of this plan, what held them under `multi_step`):
+        more than one step only in a plan with nothing else to run. The
+        tests in the order of LIMITS; the first that holds names the plan."""
+        if self.multi_step <= 1:
+            return 1, "off"
+        if not plan.decode_slots:
+            return 1, "no_decode"
+        if plan.chunks:
+            return 1, "chunk"
+        if plan.spec_slots:
+            return 1, "spec"
+        if self._prefilling:
+            return 1, "prefilling"
+        if self.queue_depth() > 0:
+            return 1, "queue"
+        return self._choose_multi_step(plan.decode_slots)
+
+    def _choose_multi_step(self, decode_slots: List[int]):
+        """Tokens per decode dispatch, and why not `multi_step` of them: >1
+        only when every active slot is greedy (on-device argmax is exact
+        then), capped at the smallest remaining budget and power-of-two
+        bucketed to bound the jit cache."""
         if any(self.slots[i].params.temperature > 0
                or self.slots[i].constraint is not None
                for i in decode_slots):
             # Sampling slots need host-side sampling; GUIDED slots need the
             # host-side constraint mask before each argmax — the on-device
             # multi-token argmax chain can honor neither.
-            return 1
+            return 1, "sampling"
         remaining = min(
             self.slots[i].params.max_tokens - self.slots[i].generated
             for i in decode_slots
@@ -661,7 +709,7 @@ class Scheduler:
         bucket = 1
         while bucket * 2 <= n:
             bucket *= 2
-        return bucket
+        return bucket, ("tail" if remaining < self.multi_step else "none")
 
     # -- state transitions (engine-driven) ----------------------------------
     def chunk_done(self, chunk: ScheduledChunk):
@@ -708,11 +756,15 @@ class Scheduler:
     def stats(self) -> dict:
         out = dict(self._counters)
         out["queue_depth"] = self.queue_depth()
-        out["prefilling"] = len(self._prefilling)
+        out["prefilling"] = self.prefilling()
         out["running"] = sum(1 for s in self.slots if s.active)
         out["token_budget"] = self.token_budget
         out["wfq"] = self.wfq
         out["tenant_quota"] = self._tenant_quota
+        out["plans"] = {
+            "steps_max": self.multi_step,
+            "by_limit": {k: dict(v) for k, v in self._by_limit.items()},
+        }
         tenants = {}
         with self._lock:
             for name, t in self._tenants.items():
@@ -772,6 +824,10 @@ class Scheduler:
             c["spec_rounds"] += 1
         if plan.prefill_tokens and (plan.decode_slots or plan.spec_slots):
             c["interleaved_iterations"] += 1
+        row = self._by_limit[plan.limit]
+        row["iterations"] += 1
+        row["decode_tokens"] += plan.decode_tokens
+        row["decode_tokens_possible"] += len(plan.decode_slots) * plan.steps_max
         # Plain tuple only: the occupancy GAUGES export from stats() — a
         # Metric mutation here would ride every planner iteration (RL901).
         self._last_plan_tokens = (

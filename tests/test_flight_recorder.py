@@ -141,6 +141,26 @@ def test_serve_metrics_slo_classification_and_burn():
     assert m.burn_rate("") == pytest.approx((1 / 4) / 0.1)
 
 
+@pytest.mark.parametrize("events, want", [
+    ((("queued", 0.0), ("admitted", 1.0), ("prefill-chunk", 1.25), ("prefill-chunk", 1.5)), 0.25),
+    ((("queued", 0.0), ("admitted", 1.0), ("cache-attach", 1.1), ("prefill-chunk", 1.4)), 0.4),
+    ((("queued", 0.0), ("admitted", 1.0), ("pd-attach", 1.2)), None),   # a transferred prefix runs no chunk
+    ((("queued", 0.0),), None),                                         # not admitted yet
+], ids=["two_chunks", "behind_an_attach", "no_chunk", "not_admitted"])
+def test_prefill_wait_is_the_first_chunks_start_less_admitted(events, want):
+    """What a request waits after it has a slot, for the chunks of those ahead of it: from a
+    record's own events, in its summary beside `queue_s`; None where either event is absent."""
+    from ray_tpu.llm import flight_recorder as fr
+
+    rec = fr.RequestRecord("r")
+    for name, t0 in events:
+        rec.span(name, rec.t_submit + t0, rec.t_submit + t0 + 0.05)
+    summary = rec.summary()
+    assert summary["prefill_wait_s"] == (None if want is None else pytest.approx(want))
+    assert rec.prefill_wait_s() == summary["prefill_wait_s"]
+    assert summary["queue_s"] == (pytest.approx(1.0) if len(events) > 1 else None)
+
+
 # -- engine integration -------------------------------------------------------
 
 
@@ -152,6 +172,7 @@ def test_engine_timing_breakdown_and_phases():
         t = engine.request_timing("req-tb")
         assert t is not None and t["tokens"] == 6
         assert t["queue_s"] is not None and t["queue_s"] >= 0
+        assert 0 <= t["prefill_wait_s"] <= t["ttft_s"] - t["queue_s"]
         assert t["ttft_s"] > 0 and t["e2e_s"] >= t["ttft_s"]
         assert "prefill-chunk" in t["phases"] and "decode" in t["phases"]
         rec = engine._recorder.records()[-1]

@@ -496,13 +496,18 @@ def test_shutdown_with_an_insert_in_flight_leaves_no_thread_and_no_buffer(monkey
 _ITER_CHILDREN = ("rt.engine.prefill", "rt.engine.attach", "rt.engine.kv_insert",
                   "rt.engine.dispatch", "rt.engine.readback", "rt.engine.sample")
 _ENGINE_SPANS = ("rt.engine.iter", "rt.engine.idle", "rt.engine.plan") + _ITER_CHILDREN
+# the parts of the three coarse spans (child -> parent), and a request's three instants
+_PARTS = {"rt.engine.readback.wait": "rt.engine.readback", "rt.engine.readback.copy": "rt.engine.readback",
+          "rt.engine.dispatch.args": "rt.engine.dispatch", "rt.engine.dispatch.call": "rt.engine.dispatch",
+          "rt.engine.sample.draw": "rt.engine.sample", "rt.engine.sample.emit": "rt.engine.sample"}
+_REQUEST_EVENTS = ("rt.sched.admit", "rt.engine.first_token", "rt.engine.finish")
 
 
 def _loop_counts():
     from ray_tpu.util import xprof
 
     totals = xprof.span_totals()
-    return {name: totals.get(name, {"count": 0})["count"] for name in _ENGINE_SPANS}
+    return {name: totals.get(name, {"count": 0})["count"] for name in _ENGINE_SPANS + tuple(_PARTS)}
 
 
 def test_spans_add_zero_pulls_and_zero_programs_on_a_warm_engine(monkeypatch):
@@ -538,6 +543,11 @@ def test_spans_add_zero_pulls_and_zero_programs_on_a_warm_engine(monkeypatch):
         assert after["rt.engine.dispatch"] == before["rt.engine.dispatch"] + 5
         assert after["rt.engine.readback"] == before["rt.engine.readback"] + 6
         assert after["rt.engine.sample"] == before["rt.engine.sample"] + 6
+        # the split readback is still one pull a dispatch (counted above): a wait and a copy each
+        for part, n in (("rt.engine.readback.wait", 6), ("rt.engine.readback.copy", 6),
+                        ("rt.engine.dispatch.args", 5), ("rt.engine.dispatch.call", 5),
+                        ("rt.engine.sample.draw", 5), ("rt.engine.sample.emit", 5)):
+            assert after[part] == before[part] + n, part
     finally:
         engine.shutdown()
 
@@ -546,7 +556,9 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
     """Prefix-cache insert, a cache-hit attach, chunked prefill, single and
     multi-step decode and an idle loop, under a CPU profiler capture: every
     span of the table is an event of the host plane, every child lies inside an
-    `rt.engine.iter`, and a request's engine spans carry its flight-record id."""
+    `rt.engine.iter`, and a request's engine spans carry its flight-record id. The
+    three coarse spans' parts each lie inside their parent, and a request's admission,
+    first token and end are instants that carry its record's id and its record's times."""
     import glob
     import os
     import time
@@ -577,8 +589,16 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
         finally:
             cap.stop_capture()
         assert engine.last_attach is not None, "the second prompt did not hit the prefix cache"
+        records = {r["rid"]: r for r in engine._recorder.records()}
+        stats = engine.scheduler_stats()
     finally:
         engine.shutdown()
+    # the whole run's plans by what held them: every iteration and every decode token under one limit
+    by_limit = stats["plans"]["by_limit"]
+    assert stats["plans"]["steps_max"] == 4 and sum(row["iterations"] for row in by_limit.values()) == stats["iterations"]
+    assert sum(row["decode_tokens"] for row in by_limit.values()) == stats["decode_tokens"] > 0
+    assert {k for k, row in by_limit.items() if row["iterations"]} == {"no_decode", "none", "tail", "sampling"}
+    assert by_limit["tail"]["decode_tokens_possible"] == 4 * by_limit["tail"]["decode_tokens"] == 4
     (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
     events = {}
     for plane in ProfileData.from_file(path).planes:
@@ -586,10 +606,45 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith("rt.engine."):
+                if e.name.startswith(("rt.engine.", "rt.sched.")):
                     events.setdefault(e.name, []).append((e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    parts = {name: events.pop(name) for name in _PARTS}
+    instants = {name: events.pop(name) for name in _REQUEST_EVENTS}
     assert set(events) - {"rt.engine.kv_copy"} == set(_ENGINE_SPANS), sorted(set(_ENGINE_SPANS) ^ set(events))
     iters = events["rt.engine.iter"]
+    # every part inside a span of its parent; a readback is a wait and a copy, a dispatch its
+    # arguments and its call, and a round's sample an emission after (where the host draws) a draw
+    for name, parent in _PARTS.items():
+        for a, b, _ in parts[name]:
+            assert any(p0 <= a and b <= p1 for p0, p1, _ in events[parent]), name
+    for name in ("rt.engine.readback.wait", "rt.engine.readback.copy", "rt.engine.dispatch.args", "rt.engine.dispatch.call"):
+        assert len(parts[name]) == len(events[_PARTS[name]]), name
+    rounds = len(events["rt.engine.dispatch"])                       # one `sample` a round, one a first token
+    assert len(parts["rt.engine.sample.emit"]) == rounds == len(events["rt.engine.sample"]) - 2
+    assert 0 < len(parts["rt.engine.sample.draw"]) < rounds          # the multi-step rounds draw nothing
+    # a request's three instants: its record's id, and its record's own times
+    for name in _REQUEST_EVENTS:
+        assert {str(s["rid"]) for _, _, s in instants[name]} == set(records) and len(records) == 2, name
+    for _, _, s in instants["rt.sched.admit"]:
+        rec = records[str(s["rid"])]
+        assert int(s["queue_us"]) == int(rec["queue_s"] * 1e6) and int(s["slot"]) in (0, 1)
+        assert int(s["cached_tokens"]) == (12 if s["rid"] == "req-hit" else 0)
+    for _, _, s in instants["rt.engine.first_token"]:
+        rec = records[str(s["rid"])]
+        assert int(s["ttft_us"]) == int(rec["ttft_s"] * 1e6)
+        assert int(s["prefill_wait_us"]) == int(rec["prefill_wait_s"] * 1e6) >= 0
+        assert int(s["chunks"]) == rec["phases"]["prefill-chunk"]["count"]
+    assert {(str(s["rid"]), int(s["tokens"]), str(s["status"])) for _, _, s in instants["rt.engine.finish"]} == {
+        (rid, rec["tokens"], "ok") for rid, rec in records.items()}
+    # an iteration says why its plan ran the steps it ran, and what time it was on the records' clock
+    # (prompts one at a time: a chunk's plan has no slot decoding; the 5 tokens after the first are
+    # a full run of 4 and a last one alone)
+    assert {str(s["limit"]) for _, _, s in iters} == {"no_decode", "none", "tail", "sampling"}
+    assert all(int(s["steps_max"]) == 4 for _, _, s in iters)
+    assert [(str(s["limit"]), int(s["steps"])) for _, _, s in iters if s["limit"] in ("none", "tail")] == [("none", 4), ("tail", 1)]
+    assert all({"waiting", "prefilling", "unix_us"} <= set(s) for _, _, s in iters)
+    t_lo = min(r["t_submit"] for r in records.values())
+    assert all(t_lo - 1 < int(s["unix_us"]) / 1e6 < t_lo + 600 for _, _, s in iters)
     # An insert is the gather's dispatch, a `rt.engine.kv_insert` inside the prompt's last
     # chunk, and its hand-over to the pool: `rt.engine.kv_copy` on the worker thread, or a
     # second `rt.engine.kv_insert` inside the `rt.engine.plan` of a lookup that came first.

@@ -157,6 +157,96 @@ def test_scheduler_queue_cap_and_drain():
     assert sched.queue_depth() == 0
 
 
+# -- a plan says what held it under `multi_step` (docs/scheduler.md) ---------
+
+
+class _OneSlotDraft:
+    """A draft that proposes two tokens for slot 0 and nothing for any other."""
+
+    k = 2
+
+    def eligible(self, i, s):
+        return i == 0
+
+    def propose(self, i, s):
+        return [11, 12]
+
+
+def _limit_case(limit):
+    """(scheduler in a crafted state, draft, slots its plan decodes, steps, its chunks as
+    (tokens, bucket)): one state that gives a plan that `limit`."""
+    from ray_tpu.llm import SamplingParams
+    from ray_tpu.llm.scheduler import Request
+
+    def prompt_request(n=40):
+        return Request("prompt", prompt=list(range(1, n + 1)), sampling=SamplingParams(max_tokens=4),
+                       callback=lambda *a: None)
+
+    sched = _unit_sched(multi_step=1 if limit == "off" else 8)
+    draft = None
+    if limit != "no_decode":
+        _fake_running(sched, 0)
+    if limit in ("none", "tail", "spec", "queue"):
+        _fake_running(sched, 1, max_tokens=7 if limit == "tail" else 1000)  # 6 left: under 8, and 4 a bucket
+    if limit == "sampling":
+        sched.slots[0].params = SamplingParams(max_tokens=1000, temperature=0.7)
+    if limit in ("no_decode", "chunk", "queue"):
+        sched.submit(prompt_request())  # admitted to a free slot, or left queued where both decode
+    if limit == "prefilling":
+        done = prompt_request()        # admitted, every row cached: nothing of it left to run
+        done.slot, done.prefilled = 1, done.prompt_len
+        sched._prefilling.append(done)
+    if limit == "spec":
+        draft = _OneSlotDraft()
+    decode_slots = {"no_decode": [], "spec": [1], "none": [0, 1], "tail": [0, 1], "queue": [0, 1]}.get(limit, [0])
+    steps = {"none": 8, "tail": 4}.get(limit, 1)
+    # a 40-token prompt under a budget of 64: whole in a bucket of 64, or 32 of it beside a decoding slot
+    chunks = {"no_decode": [(40, 64)], "chunk": [(32, 32)]}.get(limit, [])
+    return sched, draft, decode_slots, steps, chunks
+
+
+def _the_parents_steps(sched, plan):
+    """`next_plan`'s multi-step decision as it stood before a plan carried its reason."""
+    if not (sched.multi_step > 1 and plan.decode_slots and not plan.chunks and not plan.spec_slots
+            and not sched._prefilling and sched.queue_depth() == 0):
+        return 1
+    slots = [sched.slots[i] for i in plan.decode_slots]
+    if any(s.params.temperature > 0 or s.constraint is not None for s in slots):
+        return 1
+    n = max(1, min(sched.multi_step, min(s.params.max_tokens - s.generated for s in slots)))
+    return 1 << (n.bit_length() - 1)
+
+
+@pytest.mark.parametrize("limit", ["none", "off", "no_decode", "spec", "chunk", "prefilling",
+                                   "queue", "sampling", "tail"])
+def test_a_plan_says_what_held_it_under_multi_step(limit):
+    """One crafted state a `Plan.limit` value: the plan names it, runs the steps the
+    parent's rule ran (every other field what that state always planned), and
+    `stats()["plans"]` counts the iteration, its decode tokens and what
+    `decode_slots x multi_step` would have been under that limit and no other."""
+    from ray_tpu.llm.scheduler.scheduler import LIMITS
+
+    sched, draft, decode_slots, steps, chunks = _limit_case(limit)
+    plan = sched.next_plan(draft=draft)
+    assert (plan.limit, plan.steps_max) == (limit, sched.multi_step) and limit in LIMITS
+    assert plan.multi_step == steps == _the_parents_steps(sched, plan)
+    assert plan.decode_slots == decode_slots and plan.decode_tokens == len(decode_slots) * steps
+    assert plan.spec_slots == ([0] if limit == "spec" else []) and plan.verify_tokens == (3 if limit == "spec" else 0)
+    assert [(len(c.tokens), c.bucket) for c in plan.chunks] == chunks and not plan.idle
+    assert plan.prefill_tokens == sum(bucket for _, bucket in chunks)
+    stats = sched.stats()
+    assert stats["plans"]["steps_max"] == sched.multi_step and set(stats["plans"]["by_limit"]) == set(LIMITS)
+    for name, row in stats["plans"]["by_limit"].items():
+        assert row == ({"iterations": 1, "decode_tokens": plan.decode_tokens,
+                        "decode_tokens_possible": len(decode_slots) * sched.multi_step} if name == limit
+                       else {"iterations": 0, "decode_tokens": 0, "decode_tokens_possible": 0}), name
+    assert (stats["iterations"], stats["decode_tokens"]) == (1, plan.decode_tokens)
+    assert (stats["spec_rounds"], stats["verify_tokens"]) == ((1, 3) if limit == "spec" else (0, 0))
+    assert (stats["queue_depth"], stats["prefilling"]) == (int(limit == "queue"), len(chunks) + int(limit == "prefilling"))
+    # the configuration a replica runs under, echoed for its operator (docs/scheduler.md)
+    assert (stats["token_budget"], stats["wfq"], stats["tenant_quota"]) == (64, True, sched._tenant_quota)
+
+
 # -- token-identity across scheduling shapes -------------------------------
 
 

@@ -7,6 +7,7 @@ size on the CPU; the Pallas kernels' names are checked where the kernels compile
 
 import re
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -393,3 +394,104 @@ def test_scheduler_stats_count_the_lfm2_blocks_experts_and_its_state(lfm2_engine
         "prefill_positions": 20, "prefill_padding": 1, "states_reset": 1, "decode_slot_steps": 6}
     assert set(state["window"]) == {"prefill_positions", "prefill_padding", "states_reset", "decode_slot_steps"}
     assert state["bytes_per_slot"] == 4 * 2 * 64 * 4
+
+
+# -- the engine loop says why: a seeded run through the two-pass sample, and the readers of the new names ----
+
+
+def _parents_decode_round(self, decode_slots):
+    """`DecodeEngine._decode_round` as it was before a round drew every row and then emitted
+    every token: a row is drawn and its token emitted before the next row is looked at."""
+    from ray_tpu.llm._engine import _sample_host
+
+    lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
+    logits, self._caches, _, *stats = self._jit_decode(self.params, lora, adapter_ids, last_token, self._caches, lens, gate)
+    self._note_stats(stats)
+    logits_np = np.asarray(logits)
+    for i in decode_slots:
+        s = self._sched.slots[i]
+        self._lens[i] += 1
+        if not s.active:
+            continue
+        token = _sample_host(logits_np[i], s.params, self._np_rng)
+        s.generated += 1
+        s.host_len += 1
+        s.tokens.append(token)
+        s.history.append(token)
+        self._last_token[i] = token
+        self._emit(i, token)
+
+
+def test_a_seeded_temperature_run_emits_the_tokens_of_draw_then_emit_row_by_row(engines, monkeypatch):
+    """Two slots at a temperature share the engine's generator; one ends three tokens before the
+    other. Drawing a round's rows and then emitting them asks the generator in the order that
+    drawing and emitting row by row did, so a seeded run gives the parent's tokens."""
+    import types
+
+    from ray_tpu.llm import SamplingParams
+
+    plain, _ = engines
+
+    def run(seed):
+        """Both requests submitted while the stepper is held at its next plan, so that every run plans alike."""
+        gate, plans = threading.Event(), plain._sched.next_plan
+        monkeypatch.setattr(plain._sched, "next_plan", lambda **kw: gate.wait(60) and plans(**kw))
+        time.sleep(0.05)  # the stepper is inside the patched call, or will enter it: either way it waits
+        plain._np_rng = np.random.default_rng(seed)
+        out, done = {"a": [], "b": []}, {"a": threading.Event(), "b": threading.Event()}
+        for key, prompt, n in (("a", [5, 9, 17], 4), ("b", [3, 8, 2], 7)):  # under a block of 4: never cached
+            plain.submit(prompt, SamplingParams(max_tokens=n, temperature=0.9),
+                         lambda tok, fin, key=key: (out[key].append(tok), fin and done[key].set()))
+        gate.set()
+        assert done["a"].wait(120) and done["b"].wait(120), plain.error
+        monkeypatch.setattr(plain._sched, "next_plan", plans)
+        return out
+
+    two_pass = run(11)
+    monkeypatch.setattr(plain, "_decode_round", types.MethodType(_parents_decode_round, plain))
+    row_by_row = run(11)
+    monkeypatch.undo()
+    assert two_pass == row_by_row and [len(two_pass[k]) for k in "ab"] == [4, 7]
+    assert run(12) != two_pass  # the seed, not the prompts, decided the tokens
+
+
+LOOP_METRICS = ("plan_full_steps_share.decode", "plan_held_by_prefill_share.decode", "plan_held_by_tail_share.decode",
+                "readback_wake_ms_p50.serve", "readback_copy_ms_p50.serve", "dispatch_args_ms_p50.serve",
+                "dispatch_call_ms_p50.serve", "emit_ms_p50.serve")
+
+
+@pytest.fixture
+def loop_trace_cases(monkeypatch):
+    """`benchmark/tests/test_loop_trace.py`, for its hand-made events and its loader of a reader,
+    with `benchmark/` importable as `benchmark/run.py` makes it."""
+    import importlib.util
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    monkeypatch.syspath_prepend(bench)
+    for name in [n for n in sys.modules if n == "lib" or n.startswith("lib.")]:
+        monkeypatch.delitem(sys.modules, name)
+    spec = importlib.util.spec_from_file_location("bench_test_loop_trace", os.path.join(bench, "tests", "test_loop_trace.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    yield module
+    for name in [n for n in sys.modules if n == "lib" or n.startswith("lib.")]:
+        sys.modules.pop(name)
+
+
+@pytest.mark.parametrize("metric", LOOP_METRICS)
+def test_a_loop_reader_reads_the_new_names_and_nothing_on_a_parents_trace(loop_trace_cases, monkeypatch, metric):
+    """Three decode iterations (a chunk beside a single step that waits 3 ms and copies 4.4 MB in 1.5 ms;
+    eight steps; four under a slot's tail) and one that ran a chunk alone: a third of the decode
+    iterations each way, the medians of the rounds' parts, and the pull that ends the chunk left out.
+    The parent's trace has the older spans and attributes alone: every reader None, none raises."""
+    cases = loop_trace_cases
+    reader = cases._reader(metric)
+    want = {"readback_wake_ms_p50.serve": 3.0, "readback_copy_ms_p50.serve": 0.1, "dispatch_args_ms_p50.serve": 1.0,
+            "dispatch_call_ms_p50.serve": 2.0, "emit_ms_p50.serve": 1.0}.get(metric, 100 / 3)
+    for parts, expected in ((True, pytest.approx(want)), (False, None)):
+        monkeypatch.setattr(cases.pt, "for_record", lambda record, parts=parts: cases.events_of(parts=parts))
+        assert reader.read({"cell": "c", "trace": {}}) == expected, (metric, parts)
+    monkeypatch.setattr(cases.pt, "for_record", lambda record: None)  # an untraced run
+    assert reader.read({"cell": "c"}) is None
