@@ -431,10 +431,10 @@ def multichip_phase(model="llama3-1b", overrides=None, n_layers=2, n_devices=4, 
         consumes the caches it is given, so the engine takes the returned ones (its stepper is idle)."""
         lens = np.full((engine.B,), min(prompt_lens), np.int32)
         last = np.arange(engine.B, dtype=np.int32) + 7
-        logits, engine._caches, _ = engine._jit_decode(
+        _, logits, engine._caches, _, engine._sample_key = engine._jit_decode(
             engine.params, engine._lora_tables(), jnp.asarray(engine._adapter_ids),
             jnp.asarray(last), engine._caches, jnp.asarray(lens),
-            jnp.zeros((engine.B,), bool))
+            jnp.zeros((engine.B,), bool), engine._temps_dev, engine._sample_key)
         return np.asarray(logits, np.float32)
 
     out = {}
@@ -451,7 +451,7 @@ def multichip_phase(model="llama3-1b", overrides=None, n_layers=2, n_devices=4, 
                 text = engine._jit_decode.lower(
                     engine.params, engine._lora_tables(), jnp.asarray(engine._adapter_ids),
                     jnp.asarray(engine._last_token), engine._caches, jnp.asarray(engine._lens),
-                    jnp.zeros((engine.B,), bool)).compile().as_text()
+                    jnp.zeros((engine.B,), bool), engine._temps_dev, engine._sample_key).compile().as_text()
                 check("all-reduce" in text, "the TP decode program holds an all-reduce")
         finally:
             engine.shutdown()

@@ -54,6 +54,7 @@ from ray_tpu.llm.tp import (
     checkpoint_shardings,
     kv_prefix_sharding,
     mesh_signature,
+    replicated,
     shard_decode_params,
     single_device_shardings,
     tp_degree,
@@ -97,7 +98,8 @@ def _rid(req: Request) -> str:
 
 def _sample_host(logits_row: np.ndarray, sampling: SamplingParams,
                  rng: np.random.Generator) -> int:
-    """Per-slot host-side sampling: slots may carry different sampling params."""
+    """One row drawn on the host: a request's first token (one pull a prefill),
+    and in a decode round the rows `_host_drawn` names."""
     if sampling.temperature <= 0:
         return int(np.argmax(logits_row))
     scaled = logits_row / sampling.temperature
@@ -108,6 +110,46 @@ def _sample_host(logits_row: np.ndarray, sampling: SamplingParams,
     probs = np.exp(scaled)
     probs /= probs.sum()
     return int(rng.choice(len(probs), p=probs))
+
+
+def _host_drawn(sampling: SamplingParams, constraint) -> bool:
+    """Whether a slot's decode rounds draw its row on the host, from what the slot
+    carries: a guided slot's mask is a host automaton's `[V]` row a step, and a
+    top-k filter at a temperature is a sort of the row, which the decode program's
+    sampler does not hold (at temperature 0 `_sample_host` ignores `top_k`, and so
+    does the device)."""
+    return constraint is not None or (sampling.temperature > 0 and sampling.top_k > 0)
+
+
+def _sample_device(logits, temps, gate, key):
+    """One token a slot from `[B, V]` float32 logits, in the decode program: the
+    row's first maximum at temperature 0 (what `np.argmax` of the pulled row
+    gives), and at T > 0 a draw from `softmax(logits / T)` by Gumbel-max, one
+    pass over the row and no sort. Returns (tokens [B] int32, the key to carry).
+    A round none of whose stepping rows has a temperature takes the other branch
+    of the `cond`: no noise is generated and the key stands. The draw goes a row
+    at a time, in a scan: as one `[B, V]` fusion it costs a third of that (42 us
+    for 142 at 12 x 92544), but with it in the program the TPU's compiler starts
+    every layer's prefetch of its norm scales too late to hide, in either branch,
+    and the dense block's step is 0.3 ms longer (PERF.md §6, PR 37)."""
+    hot = temps > 0
+
+    def draw(key):
+        key, sub = jax.random.split(key)
+
+        def row(_, x):
+            row_logits, temp, row_hot, row_key = x
+            noise = jax.random.gumbel(row_key, row_logits.shape, row_logits.dtype)
+            scaled = row_logits / jnp.where(row_hot, temp, 1.0) + noise
+            return None, jnp.argmax(jnp.where(row_hot, scaled, row_logits))
+
+        _, tokens = jax.lax.scan(
+            row, None, (logits, temps, hot, jax.random.split(sub, logits.shape[0])))
+        return tokens, key
+
+    tokens, key = jax.lax.cond(
+        jnp.any(hot & gate), draw, lambda key: (jnp.argmax(logits, axis=-1), key), key)
+    return tokens.astype(jnp.int32), key
 
 
 def _traced_on(mesh):
@@ -152,6 +194,9 @@ class DecodeEngine:
         self.params = unbox(params)  # strip flax LogicallyPartitioned boxes
         self.B = num_slots
         self.T = max_seq or cfg.max_seq
+        # Two generators from the one seed: this one draws a request's first token
+        # and the rows `_host_drawn` names; `_sample_key` is the decode program's
+        # sampler's, carried on the device from round to round (`_decode_sample`).
         self._np_rng = np.random.default_rng(seed)
         # Tensor parallelism (docs/serving_tp.md): tp > 1 (or a mesh-axes
         # dict) shards the WHOLE decode plane — params, per-slot KV pool,
@@ -214,6 +259,15 @@ class DecodeEngine:
         # host->device per call (a few async bytes, off the critical path).
         self._lens = np.zeros((self.B,), np.int32)
         self._last_token = np.zeros((self.B,), np.int32)
+        # Per-slot temperature of the decode program's sampler (0: the argmax;
+        # also 0 for a slot whose rows the host draws). It changes at admission
+        # only (`_start_slot`), so the device copy is made there and a dispatch
+        # hands on an array that is already on the device.
+        self._temps = np.zeros((self.B,), np.float32)
+        self._temps_dev = self._resident(self._temps)
+        self._sample_key = self._resident(jax.random.PRNGKey(seed))
+        # rows the single-step rounds drew, by where (scheduler_stats())
+        self._rows_sampled = {"device": 0, "host": 0}
         self._stop = False
         # Cross-thread cancel plane (docs/generation.md): cancel() resolves
         # still-QUEUED requests synchronously under the scheduler's
@@ -255,7 +309,7 @@ class DecodeEngine:
         self._stats_lock = threading.Lock()  # reports come from any thread
         self._jit_decode = self._xprof.instrument(
             self._xprof_owner, ("decode",),
-            jax.jit(named("rt_decode", self._decode_step), donate_argnums=(4,)),
+            jax.jit(named("rt_decode", self._decode_sample), donate_argnums=(4,)),
         )
         # Multi-step decode: N greedy tokens per dispatch (argmax on device,
         # lax.scan over decode steps) — one host round trip per CHUNK instead
@@ -515,6 +569,15 @@ class DecodeEngine:
         no lora_config). See docs/multitenancy.md."""
         return None if self._adapters is None else self._adapters.stats()
 
+    def _resident(self, x):
+        """A small array that stays on the device between dispatches (the sampler's
+        key, the slots' temperatures): under a TP mesh placed where the decode program
+        returns and reads it, so that no dispatch moves it and the program's second
+        call finds the first's."""
+        if self._mesh is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, replicated(self._mesh))
+
     # -- jitted programs ---------------------------------------------------
     def _prefill_at(self, params, lora, tokens, caches, slot, offset,
                     total_len, adapter_id):
@@ -539,6 +602,19 @@ class DecodeEngine:
             logits, new_caches, stats = self._block.decode(
                 params, self.cfg, last_token, caches, lens, gate, lora, adapter_ids)
         return (logits, new_caches, lens + 1, *stats)
+
+    def _decode_sample(self, params, lora, adapter_ids, last_token, caches, lens,
+                       gate, temps, key):
+        """The single-step program (`rt_decode`): `_decode_step`, then one token
+        a slot from its logits and the slots' temperatures (`_sample_device`),
+        under the scope `sample`. Returns (tokens [B], logits [B, V], caches,
+        lens + 1, the sampler's next key, *stats): a round pulls the tokens, and
+        the logits stay on the device unless a slot's row is the host's to draw."""
+        logits, new_caches, lens, *stats = self._decode_step(
+            params, lora, adapter_ids, last_token, caches, lens, gate)
+        with jax.named_scope("sample"):
+            tokens, key = _sample_device(logits, temps, gate, key)
+        return (tokens, logits, new_caches, lens, key, *stats)
 
     def _decode_multi(self, params, lora, adapter_ids, last_token, caches, lens,
                       gate, *, n):
@@ -869,6 +945,10 @@ class DecodeEngine:
                     self._spec_metrics["accept_rate"].set(spec["accept_rate"])
                 except Exception:
                     pass  # metrics must never break the serving path
+        # Rows the single-step decode rounds drew, by where: in the program, or
+        # on the host from pulled logits (guided slots; top-k at a temperature).
+        out["rows_sampled_device"] = self._rows_sampled["device"]
+        out["rows_sampled_host"] = self._rows_sampled["host"]
         out["recorder"] = self._flush_observability()
         # Compute-plane report (same report-path contract): this engine's
         # compiled-program rows + the process-wide device-memory ledger.
@@ -1793,6 +1873,11 @@ class DecodeEngine:
         # request finishes) keeps this row valid for the whole generation.
         self._adapter_ids[slot] = req.adapter_slot
         self._last_token[slot] = first
+        s = self._sched.slots[slot]
+        temp = np.float32(0.0 if _host_drawn(s.params, s.constraint) else s.params.temperature)
+        if temp != self._temps[slot]:
+            self._temps[slot] = temp
+            self._temps_dev = self._resident(self._temps)
         self._emit(slot, first)
 
     def _finish_record(self, s, status: str = "ok"):
@@ -2021,56 +2106,69 @@ class DecodeEngine:
                 return np.asarray(x)  # raylint: disable=RL603 (the per-dispatch batched readback)
 
     def _decode_round(self, decode_slots: List[int]):
+        """One single-step round: the program steps every slot and draws its
+        token (`_decode_sample`: the argmax at temperature 0, a draw from
+        `softmax(logits / T)` otherwise), and the round pulls `[B]` token ids.
+        The logits stay on the device unless a slot in the round is the host's
+        to draw (`_host_drawn`: a guided slot, whose mask is a host automaton's
+        row a step, or a top-k filter at a temperature): then they are pulled
+        too, and those rows alone go through `_sample_host`."""
+        slots = self._sched.slots
+        host_rows = [i for i in decode_slots
+                     if slots[i].active and _host_drawn(slots[i].params, slots[i].constraint)]
         with xprof.span("rt.engine.dispatch", steps=1, slots=len(decode_slots),
                         rows=int(self._lens[decode_slots].sum())):
             with xprof.span("rt.engine.dispatch.args"):
                 lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
             with xprof.span("rt.engine.dispatch.call"):
-                logits, self._caches, _, *stats = self._jit_decode(
-                    self.params, lora, adapter_ids, last_token, self._caches, lens, gate)
+                tokens_dev, logits, self._caches, _, self._sample_key, *stats = self._jit_decode(
+                    self.params, lora, adapter_ids, last_token, self._caches, lens, gate,
+                    self._temps_dev, self._sample_key)
                 self._note_stats(stats)
-        # The step's ONE device->host pull: every active slot's next-token
-        # logits arrive in a single [B, V] readback (sampling params can
-        # differ per slot, so sampling itself is host-side).
-        logits_np = self._readback(logits)
-        # Two passes, so that a trace says what the draws cost and what the
-        # emission costs (`rt.engine.sample.draw`, `.emit`): a slot's draw
-        # reads its own state alone and each slot is in the round once, so
-        # drawing every row first gives the tokens of draw-then-emit row by
-        # row, and the generator is asked in the same order.
-        with xprof.span("rt.engine.sample", slots=len(decode_slots)):
-            drawn = []
-            with xprof.span("rt.engine.sample.draw"):
-                for i in decode_slots:
-                    s = self._sched.slots[i]
-                    if not s.active:
-                        continue
-                    row = logits_np[i]
-                    if s.constraint is not None:
-                        # Guided composition point (docs/generation.md): one cached
-                        # [V] mask row + one numpy add on the already-pulled logits
-                        # — strictly host-side, zero new compiled programs. When the
-                        # unconstrained argmax is already legal the mask cannot
-                        # change it, so guided greedy output is token-identical to
-                        # unconstrained greedy except where the constraint binds.
-                        # budget= steers onto a completable path once remaining
-                        # max_tokens gets tight (an unbounded quantifier must not
-                        # eat the budget and truncate mid-pattern).
-                        row = row + s.constraint.mask(
-                            s.params.stop_token_id,
-                            budget=s.params.max_tokens - s.generated,
-                        )
-                    drawn.append((i, _sample_host(row, s.params, self._np_rng)))
+            if host_rows:
+                logits.copy_to_host_async()  # behind the step, as the tokens' copy is
+        # The step's device->host pull: 4 bytes a slot.
+        tokens = self._readback(tokens_dev).tolist()
+        with xprof.span("rt.engine.sample", slots=len(decode_slots), host_rows=len(host_rows)):
+            drawn = {}
+            if host_rows:
+                logits_np = self._readback(logits)
+                with xprof.span("rt.engine.sample.draw"):
+                    for i in host_rows:
+                        s = slots[i]
+                        row = logits_np[i]
+                        if s.constraint is not None:
+                            # Guided composition point (docs/generation.md): one cached
+                            # [V] mask row + one numpy add on the pulled logits, on the
+                            # host and with no program of its own. When the
+                            # unconstrained argmax is already legal the mask cannot
+                            # change it, so guided greedy output is token-identical to
+                            # unconstrained greedy except where the constraint binds.
+                            # budget= steers onto a completable path once remaining
+                            # max_tokens gets tight (an unbounded quantifier must not
+                            # eat the budget and truncate mid-pattern).
+                            row = row + s.constraint.mask(
+                                s.params.stop_token_id,
+                                budget=s.params.max_tokens - s.generated,
+                            )
+                        drawn[i] = _sample_host(row, s.params, self._np_rng)
             with xprof.span("rt.engine.sample.emit"):
                 self._lens[decode_slots] += 1  # the decode step wrote these slots' kv rows
-                for i, token in drawn:
-                    s = self._sched.slots[i]
+                emitted = 0
+                for i in decode_slots:
+                    s = slots[i]
+                    if not s.active:
+                        continue
+                    token = drawn.get(i, tokens[i])
                     s.generated += 1
                     s.host_len += 1
                     s.tokens.append(token)
                     s.history.append(token)
                     self._last_token[i] = token
                     self._emit(i, token)
+                    emitted += 1
+                self._rows_sampled["host"] += len(drawn)
+                self._rows_sampled["device"] += emitted - len(drawn)
 
     def _multi_round(self, decode_slots: List[int], n: int):
         """One multi-token dispatch + host-side emission with rollback for

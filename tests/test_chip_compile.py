@@ -211,6 +211,11 @@ def test_a_decode_step_updates_the_recurrent_state_in_place_for_v5e(one_chip, st
     assert state in text and not re.search(re.escape(state) + r"\S* copy\(", text)
 
 
+def _sampler_operands(slots, sharding):
+    """What `rt_decode` takes after the gate: the slots' temperatures and the sampler's key."""
+    return (_operand((slots,), sharding, jnp.float32), _operand((2,), sharding, jnp.uint32))
+
+
 @pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b128"])
 def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program):
     """The dense serve cells' three programs (`DecodeEngine`'s own bodies over `models/llama.py`)
@@ -239,7 +244,7 @@ def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program
     vec, i32 = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
     step = (params, None, vec, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
     body, donated, args = {
-        "rt_decode": (engine._decode_step, 4, step),
+        "rt_decode": (functools.partial(DecodeEngine._decode_sample, engine), 4, step + _sampler_operands(slots, one_chip)),
         "rt_decode_multi_n8": (functools.partial(DecodeEngine._decode_multi, engine, n=8), 4, step),
         "rt_prefill_b128": (functools.partial(DecodeEngine._prefill_at, engine), 3,
                             (params, None, _operand((1, 128), one_chip, jnp.int32), caches, i32, i32, i32, i32)),
@@ -298,6 +303,52 @@ def test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e(one_chip,
     assert not {s for s in shapes if T in s and math.prod(s) == slots * cfg.n_kv_heads * G * T}, "scores over every row"
 
 
+def test_the_decode_program_ends_in_a_sampler_that_sorts_nothing_for_v5e(one_chip):
+    """`rt_decode` at the chat cell's widths (12 slots, 92544 tokens of vocabulary, two layers):
+    the compiled text holds operations under the scope `sample`, both branches of its `cond`
+    among them (a round of greedy rows generates no noise), and no `sort` there or anywhere
+    else in the program (a top-k filter at a temperature stays the host's: `_host_drawn`); the
+    logits leave the program beside the tokens, `f32[12,92544]` and `s32[12]`; and the block's
+    own prefetches keep their place in the schedule."""
+    import dataclasses
+    import functools
+    import types
+
+    from ray_tpu.llm._engine import DecodeEngine
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import unbox
+
+    cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
+    slots, T = 12, cfg.max_seq
+    engine = types.SimpleNamespace(cfg=cfg, _block=llama, _mesh=None)
+    engine._decode_step = functools.partial(DecodeEngine._decode_step, engine)
+    params = _shaped(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), one_chip)
+    vec = _operand((slots,), one_chip, jnp.int32)
+    args = (params, None, vec, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_)) + _sampler_operands(slots, one_chip)
+    compiled = jax.jit(functools.partial(DecodeEngine._decode_sample, engine), donate_argnums=(4,)).lower(*args).compile()
+    text = compiled.as_text()
+    sampled = [line for line in text.splitlines() if re.search(r'op_name="jit\([^"]*/sample/', line)]
+    assert sampled and any("branch_0_fun" in line for line in sampled) and any("branch_1_fun" in line for line in sampled)
+    assert not re.search(r"\bsort\(", text)
+    assert any("random_bits" in line or "threefry" in line for line in sampled)  # the noise is the sampler's, in its scope
+    # The sampler leaves the block's schedule alone: every layer's norm scales are fetched behind a
+    # product, as without it. Drawn as one `[B, V]` fusion the compiler started those fetches after
+    # the product before them and each `copy-done` waited: 0.3 ms a step on the chip (PERF.md §6, PR 37).
+    entry = text[text.index("ENTRY "):].splitlines()
+    at = {m.group(1): i for i, line in enumerate(entry) if (m := re.match(r"\s*%([\w.\-]+) = ", line))}
+    fetched = 0
+    for i, line in enumerate(entry):
+        done = re.match(r"\s*%copy-done[\w.\-]* = .*copy-done\(%([\w.\-]+)\)", line)
+        if done and re.search(r"copy-start\(%params__layer_\d+____\w+_norm", entry[at[done.group(1)]]):
+            fetched += 1
+            between = entry[at[done.group(1)]:i]
+            assert any(" fusion(" in op and "dot_general" in op and "kind=kOutput" in op for op in between), line.strip()[:120]
+    assert fetched == 2 * cfg.n_layers
+    out = jax.eval_shape(functools.partial(DecodeEngine._decode_sample, engine), *args)
+    assert (out[0].shape, out[0].dtype, out[1].shape, out[1].dtype) == ((slots,), jnp.int32, (slots, cfg.vocab_size), jnp.float32)
+
+
 @pytest.mark.parametrize("program", ["rt_decode", "rt_spec_verify_k4", "rt_prefill_b16", "rt_prefill_b128",
                                      "prefill_detached_b16", "draft_propose"])
 def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
@@ -338,7 +389,8 @@ def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
     gate = _operand((slots,), whole, jnp.bool_)
     draft = types.SimpleNamespace(cfg=cfg, T=T)  # all that the draft's program reads of its provider
     body, args = {
-        "rt_decode": (engine._decode_step, (params, None, vec, vec, caches, vec, gate)),
+        "rt_decode": (functools.partial(DecodeEngine._decode_sample, engine),
+                      (params, None, vec, vec, caches, vec, gate) + _sampler_operands(slots, whole)),
         "rt_spec_verify_k4": (functools.partial(DecodeEngine._spec_verify_batched, engine),
                               (params, None, vec, _operand((slots, 5), whole, jnp.int32), caches, vec, gate,
                                _operand((slots, 5, cfg.vocab_size), whole, jnp.float32))),

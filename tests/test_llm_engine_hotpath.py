@@ -546,7 +546,7 @@ def test_spans_add_zero_pulls_and_zero_programs_on_a_warm_engine(monkeypatch):
         # the split readback is still one pull a dispatch (counted above): a wait and a copy each
         for part, n in (("rt.engine.readback.wait", 6), ("rt.engine.readback.copy", 6),
                         ("rt.engine.dispatch.args", 5), ("rt.engine.dispatch.call", 5),
-                        ("rt.engine.sample.draw", 5), ("rt.engine.sample.emit", 5)):
+                        ("rt.engine.sample.draw", 0), ("rt.engine.sample.emit", 5)):  # greedy rows: the device's
             assert after[part] == before[part] + n, part
     finally:
         engine.shutdown()
@@ -579,8 +579,8 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
         try:
             _generate(engine, prompt, max_tokens=6)                  # chunks, kv_insert, multi-step
             done = []
-            engine.submit(prompt + [40, 41], SamplingParams(max_tokens=3, temperature=0.8),
-                          lambda tok, fin: done.append(fin), request_id="req-hit")  # attach, one step at a time
+            engine.submit(prompt + [40, 41], SamplingParams(max_tokens=3, temperature=0.8, top_k=8),
+                          lambda tok, fin: done.append(fin), request_id="req-hit")  # attach, one step at a time, drawn on the host
             deadline = time.time() + 120
             while not (done and done[-1]) and time.time() < deadline:
                 time.sleep(0.01)
@@ -621,7 +621,10 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
         assert len(parts[name]) == len(events[_PARTS[name]]), name
     rounds = len(events["rt.engine.dispatch"])                       # one `sample` a round, one a first token
     assert len(parts["rt.engine.sample.emit"]) == rounds == len(events["rt.engine.sample"]) - 2
-    assert 0 < len(parts["rt.engine.sample.draw"]) < rounds          # the multi-step rounds draw nothing
+    assert 0 < len(parts["rt.engine.sample.draw"]) < rounds          # the multi-step rounds draw nothing on the host
+    # the single-step rounds say how many rows: the greedy tail's on the device, the top-k request's two on the host
+    assert sorted(int(s["host_rows"]) for _, _, s in events["rt.engine.sample"] if "host_rows" in s) == [0, 1, 1]
+    assert len(parts["rt.engine.sample.draw"]) == 2
     # a request's three instants: its record's id, and its record's own times
     for name in _REQUEST_EVENTS:
         assert {str(s["rid"]) for _, _, s in instants[name]} == set(records) and len(records) == 2, name

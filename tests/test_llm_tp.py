@@ -124,7 +124,18 @@ def attn_collectives(tp):
     return sorted(re.findall(
         r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)(?:-start)?\(", text))
 
-out = {"devices": len(jax.devices()), "tokens": {}, "programs_flat": {}, "attn_collectives": {}}
+def sampler_collectives(e):
+    # the decode program's sampler over a head that leaves its logits split by vocabulary:
+    # (kind, elements) of every collective under the scope `sample`
+    import math, re
+    vec = jnp.zeros((e.B,), jnp.int32)
+    text = e._jit_decode.lower(e.params, None, vec, vec, e._caches, vec, jnp.ones((e.B,), bool),
+                               e._temps_dev, e._sample_key).compile().as_text()
+    found = re.findall(r"= \(?\w+\[([\d,]*)\]\S* (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+                       r"(?:-start)?\([^\n]*op_name=\"[^\"]*/sample/", text)
+    return sorted((kind, math.prod(int(n) for n in dims.split(",") if n)) for dims, kind in found)
+
+out = {"devices": len(jax.devices()), "tokens": {}, "programs_flat": {}, "attn_collectives": {}, "sampler_collectives": {}}
 for tp in __TPS__:
     if tp > 1:
         out["attn_collectives"][str(tp)] = attn_collectives(tp)
@@ -139,6 +150,8 @@ for tp in __TPS__:
     out["programs_flat"][str(tp)] = (n0 == n1, n0, n1)
     spec = eng.scheduler_stats().get("spec", {})
     out.setdefault("spec_rounds", {})[str(tp)] = spec.get("rounds", 0)
+    if tp > 1:
+        out["sampler_collectives"][str(tp)] = sampler_collectives(eng)
     eng.shutdown()
 print("RESULT " + json.dumps(out))
 """
@@ -163,6 +176,11 @@ def test_greedy_token_identity_across_tp_meshes(multi_device_run, n_kv_heads, tp
         assert flat, f"tp={tp}: program cache grew {n0} -> {n1} after warmup"
     # The spec phase really ran (the identity claim covers the verify path).
     assert all(r > 0 for r in out["spec_rounds"].values()), out["spec_rounds"]
+    # The decode program's sampler moves no logits between chips: the head leaves them split
+    # by vocabulary, each chip reduces its own columns, and what crosses is a maximum and an
+    # index a slot a chip (two slots: `[B, tp]`), as in the multi-step program's argmax.
+    for tp, found in out["sampler_collectives"].items():
+        assert found and all(kind == "all-gather" and n <= 2 * int(tp) for kind, n in found), (tp, found)
 
 
 # -- sharding plan ------------------------------------------------------------

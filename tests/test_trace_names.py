@@ -72,13 +72,18 @@ def engines():
             CONFIG._cache.pop(k) if v is None else CONFIG._cache.update({k: v})
 
 
+def _sampler_args(engine):
+    """What `rt_decode` takes after the gate: the slots' temperatures and the sampler's key."""
+    return (np.zeros((engine.B,), np.float32), np.zeros((2,), np.uint32))
+
+
 def _engine_programs(plain, spec):
     """(program, its arguments) for every program the two engines built."""
     cfg, B, T = plain.cfg, plain.B, plain.T
     kv = lambda rows: np.zeros((cfg.n_layers, 2, rows, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)  # noqa: E731
     i32, vec = np.int32(0), np.zeros((B,), np.int32)
     step = (plain.params, None, vec, vec, plain._caches, vec, np.ones((B,), bool))
-    out = [(plain._jit_decode, step)]
+    out = [(plain._jit_decode, step + _sampler_args(plain))]
     out += [(prog, step) for prog in plain._jit_decode_multi.values()]
     for key, prog in plain._jit_prefill.items():
         if isinstance(key, int):
@@ -119,8 +124,8 @@ def test_every_engine_program_has_a_name_of_its_own_and_the_models_scopes(engine
         found = [n for n in names if re.fullmatch(pattern, n)]
         assert len(found) >= at_least, (pattern, sorted(names))
     assert all(any(re.fullmatch(p, n) for p in patterns) for n in names), sorted(names)
-    # a program that runs the model carries every scope of the list; the multi-step and
-    # verify programs also `sample` on the device; an attach and the prefix-cache
+    # a program that runs the model carries every scope of the list; the decode, multi-step
+    # and verify programs also `sample` on the device; an attach and the prefix-cache
     # insert's gather run no model
     for module, scopes in names.items():
         if "attach" in module or "kv_gather" in module:
@@ -130,7 +135,7 @@ def test_every_engine_program_has_a_name_of_its_own_and_the_models_scopes(engine
             want -= {"final_norm", "lm_head"}  # it keeps the KV rows and drops the logits
         assert want <= scopes, (module, want - scopes)
         assert {"layer_0", "layer_1"} <= scopes, module
-        assert ("sample" in scopes) == bool(re.search(r"multi|verify", module)), module
+        assert ("sample" in scopes) == bool(re.search(r"rt_decode|verify", module)), module
     # the steps of a multi-step program are in its name
     assert {f"jit_rt_decode_multi_n{key[-1]}" for key in plain._jit_decode_multi} <= set(names)
 
@@ -208,7 +213,7 @@ def test_the_dots3_blocks_programs_keep_the_names_and_name_their_mechanisms(dots
     engine = dots3_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step)] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)
@@ -219,7 +224,7 @@ def test_the_dots3_blocks_programs_keep_the_names_and_name_their_mechanisms(dots
         inner = DOTS3_INNER["prefill" if "prefill" in module else "decode"]
         assert inner <= scopes, (module, inner - scopes)
         assert ("select" in scopes) == ("prefill" not in module), module  # a chunk masks, a step gathers
-        assert ("sample" in scopes) == ("multi" in module), module
+        assert ("sample" in scopes) == ("decode" in module), module  # the single step draws on the device too
 
 
 def test_scheduler_stats_name_the_block_and_count_its_expert_pairs(dots3_engine):
@@ -268,7 +273,7 @@ def test_the_granite_hybrid_blocks_programs_keep_the_names_and_name_a_layers_par
     engine = granite_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step)] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)
@@ -277,7 +282,7 @@ def test_the_granite_hybrid_blocks_programs_keep_the_names_and_name_a_layers_par
     for module, scopes in names.items():
         assert set(MODEL_SCOPES) <= scopes, (module, set(MODEL_SCOPES) - scopes)
         assert GRANITE_INNER <= scopes, (module, GRANITE_INNER - scopes)
-        assert ("sample" in scopes) == ("multi" in module), module
+        assert ("sample" in scopes) == ("decode" in module), module  # the single step draws on the device too
 
 
 def test_scheduler_stats_count_a_states_positions_padding_resets_and_steps(granite_engine):
@@ -295,7 +300,8 @@ def test_the_dense_blocks_cached_products_are_named_too(engines):
     """`kv_attn` is written by `llama._attn_cached`, which both blocks' attention layers run."""
     plain, _ = engines
     vec = np.zeros((plain.B,), np.int32)
-    _, scopes = _lowered(plain._jit_decode, plain.params, None, vec, vec, plain._caches, vec, np.ones((plain.B,), bool))
+    _, scopes = _lowered(plain._jit_decode, plain.params, None, vec, vec, plain._caches, vec, np.ones((plain.B,), bool),
+                         *_sampler_args(plain))
     assert "kv_attn" in scopes and not (GRANITE_INNER - {"kv_attn"}) & scopes
 
 
@@ -367,7 +373,7 @@ def test_the_lfm2_blocks_programs_keep_the_names_and_name_a_layers_parts(lfm2_en
     engine = lfm2_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step)] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)
@@ -377,7 +383,7 @@ def test_the_lfm2_blocks_programs_keep_the_names_and_name_a_layers_parts(lfm2_en
         assert set(MODEL_SCOPES) <= scopes, (module, set(MODEL_SCOPES) - scopes)
         assert LFM2_INNER <= scopes, (module, LFM2_INNER - scopes)
         assert {f"layer_{i}" for i in range(6)} <= scopes and not {"ssm", "gate_norm", "shared_expert"} & scopes
-        assert ("sample" in scopes) == ("multi" in module), module
+        assert ("sample" in scopes) == ("decode" in module), module  # the single step draws on the device too
 
 
 def test_scheduler_stats_count_the_lfm2_blocks_experts_and_its_state(lfm2_engine):
@@ -399,13 +405,15 @@ def test_scheduler_stats_count_the_lfm2_blocks_experts_and_its_state(lfm2_engine
 # -- the engine loop says why: a seeded run through the two-pass sample, and the readers of the new names ----
 
 
-def _parents_decode_round(self, decode_slots):
+def _row_by_row_decode_round(self, decode_slots):
     """`DecodeEngine._decode_round` as it was before a round drew every row and then emitted
-    every token: a row is drawn and its token emitted before the next row is looked at."""
+    every token, over the rows the host still draws: a row is drawn from the pulled logits and
+    its token emitted before the next row is looked at."""
     from ray_tpu.llm._engine import _sample_host
 
     lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
-    logits, self._caches, _, *stats = self._jit_decode(self.params, lora, adapter_ids, last_token, self._caches, lens, gate)
+    _, logits, self._caches, _, self._sample_key, *stats = self._jit_decode(
+        self.params, lora, adapter_ids, last_token, self._caches, lens, gate, self._temps_dev, self._sample_key)
     self._note_stats(stats)
     logits_np = np.asarray(logits)
     for i in decode_slots:
@@ -423,9 +431,11 @@ def _parents_decode_round(self, decode_slots):
 
 
 def test_a_seeded_temperature_run_emits_the_tokens_of_draw_then_emit_row_by_row(engines, monkeypatch):
-    """Two slots at a temperature share the engine's generator; one ends three tokens before the
-    other. Drawing a round's rows and then emitting them asks the generator in the order that
-    drawing and emitting row by row did, so a seeded run gives the parent's tokens."""
+    """The host's fallback keeps its generator's order. Two slots with a top-k filter at a
+    temperature (rows the decode program's sampler does not draw: `_host_drawn`) share the
+    engine's generator; one ends three tokens before the other. Drawing a round's host rows
+    and then emitting every token asks the generator in the order that drawing and emitting
+    row by row did, so a seeded run gives those tokens."""
     import types
 
     from ray_tpu.llm import SamplingParams
@@ -440,15 +450,17 @@ def test_a_seeded_temperature_run_emits_the_tokens_of_draw_then_emit_row_by_row(
         plain._np_rng = np.random.default_rng(seed)
         out, done = {"a": [], "b": []}, {"a": threading.Event(), "b": threading.Event()}
         for key, prompt, n in (("a", [5, 9, 17], 4), ("b", [3, 8, 2], 7)):  # under a block of 4: never cached
-            plain.submit(prompt, SamplingParams(max_tokens=n, temperature=0.9),
+            plain.submit(prompt, SamplingParams(max_tokens=n, temperature=0.9, top_k=40),
                          lambda tok, fin, key=key: (out[key].append(tok), fin and done[key].set()))
         gate.set()
         assert done["a"].wait(120) and done["b"].wait(120), plain.error
         monkeypatch.setattr(plain._sched, "next_plan", plans)
         return out
 
+    host = plain.scheduler_stats()["rows_sampled_host"]
     two_pass = run(11)
-    monkeypatch.setattr(plain, "_decode_round", types.MethodType(_parents_decode_round, plain))
+    assert plain.scheduler_stats()["rows_sampled_host"] == host + 3 + 6  # every row after a first token
+    monkeypatch.setattr(plain, "_decode_round", types.MethodType(_row_by_row_decode_round, plain))
     row_by_row = run(11)
     monkeypatch.undo()
     assert two_pass == row_by_row and [len(two_pass[k]) for k in "ab"] == [4, 7]
