@@ -15,6 +15,9 @@ serve engine knows of a model (`llm/_engine.py` calls nothing else):
     report(cfg, total, window)             what scheduler_stats() says of those counts
                                            since the start and since the last report
 
+    LAYER_TYPES       () where every layer is of one kind; a block without it names each
+                      layer's kind in `ModelConfig.layer_types`, one per layer
+
 and, with the feature that needs them: `verify` ("speculation"), `gather_rows` and
 `attach_rows` ("prefix_cache", "pd"), `prefill_detached` and `prefill_detached_suffix`
 ("pd"); `models/llama.py` has them all. `lora` and the adapter ids are None and zeros
@@ -32,6 +35,7 @@ BLOCKS = {
     "dots3": "ray_tpu.models.dots3",
     "granite_hybrid": "ray_tpu.models.granite_hybrid",
     "lfm2": "ray_tpu.models.lfm2",
+    "pangu_moe": "ray_tpu.models.pangu_moe",
 }
 
 # What a caller may ask of a block, and how the refusal names the caller.
@@ -51,6 +55,12 @@ def block_module(cfg):
     if cfg.block not in BLOCKS:
         raise ValueError(f"unknown block {cfg.block!r}; known: {sorted(BLOCKS)}")
     return importlib.import_module(BLOCKS[cfg.block])
+
+
+def names_its_layers(cfg) -> bool:
+    """Whether `cfg.layer_types` has to name each layer's kind: every block's does, but one
+    whose module says its layers are all of one kind (`LAYER_TYPES = ()`)."""
+    return getattr(block_module(cfg), "LAYER_TYPES", None) != ()
 
 
 def require(cfg, feature: str) -> None:

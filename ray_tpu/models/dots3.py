@@ -144,16 +144,22 @@ def num_params(cfg: ModelConfig) -> int:
 
 
 def init_params(cfg: ModelConfig, key):
-    """The tree at seeded random weights in `cfg.param_dtype`, made on the device one
-    top-level group (a layer, the embedding, the head) a program, so that layers of one
-    kind share theirs. The router's correction bias is drawn at a scale (0.1) that
-    changes some of the choices the scores alone would make."""
+    """The tree at seeded random weights in `cfg.param_dtype` (`tree_from_shapes`). The
+    router's correction bias is drawn at a scale (0.1) that changes some of the choices
+    the scores alone would make."""
+    return tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
+
+
+def tree_from_shapes(shapes: dict, key, dtype):
+    """A tree of seeded random leaves from {path tuple: (shape, fan_in)} (`param_shapes`),
+    made on the device one top-level group (a layer, the embedding, the head) a program,
+    so that layers of one kind share theirs."""
     groups: dict = {}
-    for path, spec in param_shapes(cfg).items():
+    for path, spec in shapes.items():
         groups.setdefault(path[0], {})[path[1:]] = spec
     tree = {}
     for n, (name, leaves) in enumerate(groups.items()):
-        made = _init_group(jax.random.fold_in(key, n), tuple(leaves.items()), cfg.param_dtype)
+        made = _init_group(jax.random.fold_in(key, n), tuple(leaves.items()), dtype)
         for path, leaf in zip(leaves, made):
             node = tree
             for part in (name,) + path[:-1]:

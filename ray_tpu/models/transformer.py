@@ -75,6 +75,9 @@ class ModelConfig:
     # inputs live in the engine's slots, GQA layers with q/k norms and rotary, all of a layer's
     # sigmoid-routed experts held (`models/lfm2.py`, served only): dots3's expert fields
     # (`first_k_dense`, `n_routed_experts*`, `experts_per_token`, `moe_mlp_dim`) and `conv_L_cache`.
+    # "pangu_moe": dense latent attention over the whole cache (dots3's full-layer latent fields, `mla_rescale`
+    # off), a norm after every sub-layer as well as before it, dots3's expert fields (`models/pangu_moe.py`,
+    # served only); every layer is of one kind, so it takes no `layer_types`.
     block: str = "llama"
     layer_types: tuple = ()            # per layer; dots3: "full_attention" | "sliding_attention";
                                        # granite_hybrid: "mamba" | "attention"; lfm2: "conv" | "full_attention"
@@ -117,7 +120,8 @@ class ModelConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))  # a JSON list, hashable
-        if self.block != "llama" and len(self.layer_types) != self.n_layers:
+        if (self.block != "llama" and len(self.layer_types) != self.n_layers
+                and models.names_its_layers(self)):
             raise ValueError(f"block {self.block!r} needs one of layer_types per layer: "
                              f"{len(self.layer_types)} for n_layers={self.n_layers}")
 

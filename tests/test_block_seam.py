@@ -150,7 +150,7 @@ def test_what_the_unseen_block_does_not_list_is_refused_by_its_name(onerow, what
 
 
 @pytest.mark.parametrize("feature", sorted(models.FEATURES))
-@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "onerow"])
+@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "pangu_moe", "onerow"])
 def test_one_function_refuses_what_a_block_does_not_list(onerow, block, feature):
     cfg = get_config("test-tiny") if block == "llama" else _cfg(block)
     if feature in models.block_module(cfg).SUPPORTS:
@@ -161,5 +161,5 @@ def test_one_function_refuses_what_a_block_does_not_list(onerow, block, feature)
 
 
 def test_an_unknown_block_is_named_with_those_known():
-    with pytest.raises(ValueError, match=r"unknown block 'nosuch'; known: \['dots3', 'granite_hybrid', 'lfm2', 'llama'\]"):
+    with pytest.raises(ValueError, match=r"unknown block 'nosuch'; known: \['dots3', 'granite_hybrid', 'lfm2', 'llama', 'pangu_moe'\]"):
         models.block_module(_cfg("nosuch"))

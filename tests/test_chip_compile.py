@@ -488,3 +488,66 @@ def test_the_lfm2_cells_programs_fit_the_chip_and_read_each_expert_in_place_for_
     assert "bf16[64,2048,1536]" in text and re.search(r"bf16\[1,2048,1536\]\S* dynamic-slice\(", text)
     for matrix in ("bf16[2048,1536]", "bf16[1536,2048]", "bf16[1,2048,1536]", "bf16[1,1536,2048]", "bf16[64,2048,1536]"):
         assert not re.search(re.escape(matrix) + r"\S* copy\(", text), matrix
+
+
+def _pangu_cfg():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "openpangu-ultra-moe-718b.json")) as f:
+        model = json.load(f)["model"]
+    return ModelConfig(**{k: getattr(jnp, v) if k in ("dtype", "param_dtype") else v for k, v in model.items()})
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b1024"])
+def test_the_pangu_moe_cells_programs_fit_the_chip_and_copy_no_latent_slab_for_v5e(one_chip, program):
+    """`openpangu-ultra-moe-718b.serve-longctx-mla`'s programs at the published widths, all 6 layers
+    of the cut and 16 slots of 32768 rows, the caches donated as the engine donates them: the latent
+    slab is `bf16[16,32768,640]` row-major in the program's own layout (576 values in five whole rows
+    of 128 lanes), every slab is aliased to its output, no operation copies an array of a slab's size
+    (a 576-wide slab was copied whole four times a decode step: PERF.md §7, PR 35), the plan stays
+    under the chip's 15.75 GiB with 8.07 GB of weights and 4.03 GB of cache held, a decode step reads
+    the slab through the kernel `latent_attn` and holds no array of scores over every row of every
+    slot, and W_qb is multiplied where it lies (kept `[1536, 128, 192]` it was copied into the
+    product's shape every step: a last axis of 192 is a row and a half of lanes)."""
+    from ray_tpu.models import pangu_moe
+
+    cfg, slots = _pangu_cfg(), 16
+    T = cfg.max_seq
+    params = _shaped(jax.eval_shape(lambda k: pangu_moe.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: pangu_moe.init_caches(cfg, slots, T)), one_chip)
+    vec, scalar = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
+
+    def steps(n):
+        def run(params, last, caches, lens, gate):
+            def step(carry, _):
+                last, caches, lens = carry
+                logits, caches, stats = pangu_moe.decode(params, cfg, last, caches, lens, gate)
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32), caches, lens + 1), stats
+
+            return jax.lax.scan(step, (last, caches, lens), None, length=n)
+        return jax.jit(run, donate_argnums=(2,)).lower(params, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
+
+    if program == "rt_prefill_b1024":
+        lowered = jax.jit(lambda p, t, c, s, o, n: pangu_moe.prefill(p, cfg, t, c, s, o, n), donate_argnums=(2,)).lower(
+            params, _operand((1, 1024), one_chip, jnp.int32), caches, scalar, scalar, scalar)
+    else:
+        lowered = steps(8 if program.endswith("n8") else 1)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    plan = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert m.alias_size_in_bytes == held == 6 * 16 * 32768 * 640 * 2
+    assert 2 * pangu_moe.num_params(cfg) + held < plan < 15.75 * 2**30, plan / 2**30
+    text = compiled.as_text()
+    layout = next(line for line in text.splitlines() if "entry_computation_layout" in line)
+    assert "bf16[16,32768,640]{2,1,0:T(8,128)(2,1)}" in layout and "bf16[16,32768,576]" not in text
+    copied = [tuple(int(n) for n in mm.group(1).split(",")) for line in text.splitlines()
+              if (mm := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))]
+    # no copy of a slab, nor of one slot's rows of it (a chunk's view)
+    assert not [s for s in copied if T in s or math.prod(s) >= slots * T * 640], copied
+    if program != "rt_prefill_b1024":
+        assert re.search(r"%latent_attn(\.\d+)? = ", text) and re.search(r'latent/[^"]*latent_attn/pallas_call"', text)
+        shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
+        assert not {s for s in shapes if T in s and math.prod(s) >= slots * cfg.n_heads * T}, "scores over every row"

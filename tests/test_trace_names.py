@@ -507,3 +507,62 @@ def test_a_loop_reader_reads_the_new_names_and_nothing_on_a_parents_trace(loop_t
         assert reader.read({"cell": "c", "trace": {}}) == expected, (metric, parts)
     monkeypatch.setattr(cases.pt, "for_record", lambda record: None)  # an untraced run
     assert reader.read({"cell": "c"}) is None
+
+
+# -- the pangu_moe block: dots3's names where the mathematics is dots3's, the post-norms' own ----
+
+PANGU_INNER = {"latent", "attn_post_norm", "router", "experts", "shared_expert", "mlp_post_norm"}
+
+
+@pytest.fixture(scope="module")
+def pangu_engine():
+    from ray_tpu._private.config import CONFIG
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+    from ray_tpu.models import pangu_moe
+    from tests.test_pangu_moe import tiny
+
+    cfg = tiny(n_routed_experts=8, first_expert=8)
+    params = pangu_moe.init_params(cfg, jax.random.PRNGKey(2))
+    saved = CONFIG._cache.get("llm_prefill_bucket_min")
+    CONFIG._cache["llm_prefill_bucket_min"] = 4
+    engine = DecodeEngine(cfg, params, num_slots=2, max_seq=64, multi_step=4, token_budget=8)
+    try:
+        done = threading.Event()
+        engine.submit(list(range(1, 20)), SamplingParams(max_tokens=7), lambda tok, fin: fin and done.set())
+        assert done.wait(180), engine.error
+        yield engine
+    finally:
+        engine.shutdown()
+        CONFIG._cache.pop("llm_prefill_bucket_min") if saved is None else CONFIG._cache.update(llm_prefill_bucket_min=saved)
+
+
+def test_the_pangu_moe_blocks_programs_keep_the_names_and_name_their_mechanisms(pangu_engine):
+    """`latent` inside `attn` and `router`, `experts`, `shared_expert` inside `mlp` are the names
+    `lib/scope_trace.py` reads for `dots3`; the two post-norms are this block's, each inside its
+    sub-layer's scope; nothing selects, indexes or slides."""
+    engine = pangu_engine
+    B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
+    step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
+                 for k, p in engine._jit_prefill.items()]
+    names = dict(_lowered(prog, *args) for prog, args in programs)
+    assert {"jit_rt_decode", "jit_rt_prefill_b8"} <= set(names), sorted(names)
+    assert any(re.fullmatch(r"jit_rt_decode_multi_n\d+", n) for n in names)
+    for module, scopes in names.items():
+        assert set(MODEL_SCOPES) <= scopes, (module, set(MODEL_SCOPES) - scopes)
+        assert PANGU_INNER <= scopes, (module, PANGU_INNER - scopes)
+        assert {"layer_0", "layer_1", "layer_2"} <= scopes and not {"indexer", "select", "window", "kv_attn"} & scopes
+        assert ("sample" in scopes) == ("decode" in module), module  # the single step draws on the device too
+
+
+def test_scheduler_stats_count_the_pangu_moe_blocks_pairs_and_its_slabs_rows(pangu_engine):
+    stats = pangu_engine.scheduler_stats()
+    experts, latent = stats["experts"], stats["latent"]
+    assert stats["model"]["block"] == "pangu_moe" and (experts["held"], experts["of"], experts["first"]) == (8, 64, 8)
+    # 19 prompt tokens and 6 fed-back tokens through 2 expert layers, 8 experts a token, an eighth of them held
+    assert experts["pairs_routed"] == (19 + 6) * 2 * 8 > experts["pairs_held"] > 0
+    assert set(experts["window"]) == {"pairs_routed", "pairs_held", "max_load", "mean_load"}  # `dots3`'s keys alone
+    # six decode steps of one slot at lengths 19 to 24: rows 20 to 25 visible, both slots' 64 rows read
+    assert latent["rows_visible"] == sum(range(20, 26)) and latent["rows_read"] == 6 * 2 * 64
+    assert set(latent["window"]) == {"rows_visible", "rows_read"} and latent["bytes_per_row"] == 128 * 4
