@@ -222,7 +222,7 @@ class EngineStage:
     def __init__(self, config: EngineProcessorConfig):
         import os
 
-        from ray_tpu.llm import LLMConfig, load_model
+        from ray_tpu.llm import LLMConfig, engine_config, load_model
         from ray_tpu.llm._engine import DecodeEngine
 
         self._config = config
@@ -241,10 +241,10 @@ class EngineStage:
             tokenizer=config.tokenizer,
             seed=int(kwargs.pop("seed", 0)),
         )
-        cfg, params = load_model(llm_cfg)
+        cfg = engine_config(llm_cfg)
         self._engine = DecodeEngine(
             cfg,
-            params,
+            lambda: load_model(llm_cfg)[1],
             num_slots=int(kwargs.pop("num_slots", 4)),
             max_seq=kwargs.pop("max_seq", None) or min(cfg.max_seq, 2048),
             seed=llm_cfg.seed,

@@ -127,15 +127,23 @@ def model_config(config: "LLMConfig"):
     return config.model_config or get_config(config.model_id)
 
 
+def engine_config(config: "LLMConfig"):
+    """The ModelConfig a replica of `config` runs: the named one in the layout the engine's
+    programs take (no scan over the layers, no remat)."""
+    return dataclasses.replace(model_config(config), scan_layers=False, remat=False)
+
+
 def load_model(config: "LLMConfig"):
     """Build (cfg, params) for a config — shared by monolithic and PD-disagg
     deployments. Without a checkpoint the tree is the block's own, at seeded
-    random weights in `param_dtype`."""
+    random weights in `param_dtype`. A replica serves it in `dtype` (the engine casts
+    what its programs multiply in `dtype`), so it hands the engine this call as a
+    function (`lambda: load_model(config)[1]`) and keeps no tree of its own."""
     import jax
 
     from ray_tpu import models
 
-    cfg = dataclasses.replace(model_config(config), scan_layers=False, remat=False)
+    cfg = engine_config(config)
     if config.checkpoint_path:
         models.require(cfg, "checkpoint")
         from ray_tpu import checkpoint as ckpt_lib
@@ -193,7 +201,7 @@ class LLMServer:
     """One TPU replica: engine + tokenizer. Parity: llm_server.py LLMServer."""
 
     def __init__(self, config: LLMConfig):
-        cfg, params = load_model(config)
+        cfg = engine_config(config)
         self._cfg = cfg
         self._config = config
         self._tokenizer = resolve_tokenizer(config.tokenizer)
@@ -206,7 +214,7 @@ class LLMServer:
             self._tokenizer, cfg.vocab_size
         )
         self._engine = DecodeEngine(
-            cfg, params, num_slots=config.num_slots,
+            cfg, lambda: load_model(config)[1], num_slots=config.num_slots,
             max_seq=config.max_seq or min(cfg.max_seq, 2048), seed=config.seed,
             lora_config=config.lora_config,
             spec_config=config.spec_config,
@@ -217,9 +225,11 @@ class LLMServer:
 
     def weights(self):
         """(ModelConfig, parameter tree) this replica serves: the device arrays themselves,
-        not copies. For scoring what the replica generated against a reference forward
-        pass over the very weights it ran (the benchmark's `correct` for a block whose
-        tree fills most of a chip, where a second copy would not fit beside it)."""
+        not copies, in the types the engine holds them (`cfg.dtype` wherever its programs
+        multiply in it, whatever `load_model` gave). For scoring what the replica generated
+        against a reference forward pass over the very weights it ran (the benchmark's
+        `correct` for a block whose tree fills most of a chip, where a second copy would not
+        fit beside it)."""
         return self._engine.cfg, self._engine.params
 
     async def load_lora(self, name: str, layer_weights: dict, alpha: float = 1.0) -> int:

@@ -162,7 +162,13 @@ def _traced_on(mesh):
 
 class DecodeEngine:
     """B-slot continuous-batching engine. Thread-safe submit(); a background
-    stepper thread executes the scheduler's per-iteration plans."""
+    stepper thread executes the scheduler's per-iteration plans.
+
+    `params` is the model's tree, in whatever type it was trained or saved, or a function of
+    no arguments that returns it. The engine keeps `self.params`, the tree as its block serves
+    it (`models/__init__.py`: `serving_params`), in `cfg.dtype` wherever the programs
+    multiply in it. Given the function, nothing else holds the tree that came in, and each of
+    its leaves is freed as it is cast."""
 
     def __init__(self, cfg: ModelConfig, params, *, num_slots: int = 4,
                  max_seq: Optional[int] = None, seed: int = 0,
@@ -191,7 +197,18 @@ class DecodeEngine:
                 models.require(cfg, feature)
         if "prefix_cache" not in self._block.SUPPORTS:
             prefix_cache = False  # the default from the config flags is off for it too
-        self.params = unbox(params)  # strip flax LogicallyPartitioned boxes
+        # The tree as the block's programs read it (`serving_params`: for the dense block the
+        # kernels and the table in `cfg.dtype`, cast once here and not in every program). A
+        # caller that hands over a function holds no tree itself, so each wider leaf is free
+        # as soon as it is cast: start-up never holds both trees beside the slabs.
+        tree = unbox(params() if callable(params) else params)  # strips flax's boxes; the dicts are new
+        del params
+        given = [leaf.dtype for leaf in jax.tree_util.tree_leaves(tree)]
+        self.params = self._block.serving_params(cfg, tree)
+        served = jax.tree_util.tree_leaves(self.params)
+        # scheduler_stats()["model"]: the tree's bytes, and those of them cast here
+        self._weight_bytes = sum(leaf.nbytes for leaf in served)
+        self._weight_bytes_cast = sum(leaf.nbytes for was, leaf in zip(given, served) if leaf.dtype != was)
         self.B = num_slots
         self.T = max_seq or cfg.max_seq
         # Two generators from the one seed: this one draws a request's first token
@@ -523,12 +540,15 @@ class DecodeEngine:
         from ray_tpu.checkpoint import restore
 
         mesh = build_tp_mesh(tp)
-        if mesh is not None:
-            tree = restore(path, shardings=checkpoint_shardings(path, mesh))
-        else:
-            tree = restore(path, shardings=single_device_shardings())
-        params = tree.get("params", tree) if isinstance(tree, dict) else tree
-        return cls(cfg, params, tp=tp, **kwargs)
+
+        def restored():
+            if mesh is not None:
+                tree = restore(path, shardings=checkpoint_shardings(path, mesh))
+            else:
+                tree = restore(path, shardings=single_device_shardings())
+            return tree.get("params", tree) if isinstance(tree, dict) else tree
+
+        return cls(cfg, restored, tp=tp, **kwargs)
 
     # -- lora registry -----------------------------------------------------
     def add_lora(self, name: str, layer_weights: Dict[int, Dict[str, np.ndarray]],
@@ -967,6 +987,9 @@ class DecodeEngine:
             "num_slots": self.B, "max_seq": self.T, "tp": self.tp, "block": cfg.block,
             # every array a slot keeps, rows and recurrent state alike (shape arithmetic, no pull)
             "cache_bytes": sum(a.nbytes for layer in self._caches or () for a in layer),
+            # the tree the programs read, and the bytes of it that construction cast from a
+            # wider tree (0 where it came in the served type)
+            "weight_bytes": self._weight_bytes, "weight_bytes_cast": self._weight_bytes_cast,
         }
         out.update(self._block_report())
         return out

@@ -23,7 +23,7 @@ from typing import Any, List, Optional, Union
 
 from ray_tpu import models
 from ray_tpu.llm import (
-    ByteTokenizer, LLMConfig, SamplingParams, load_model, model_config, resolve_tokenizer,
+    ByteTokenizer, LLMConfig, SamplingParams, engine_config, load_model, model_config, resolve_tokenizer,
 )
 from ray_tpu.llm._engine import DecodeEngine
 
@@ -33,9 +33,9 @@ class PrefillServer:
 
     def __init__(self, config: LLMConfig):
         models.require(model_config(config), "pd")  # before any weight is built
-        cfg, params = load_model(config)
+        cfg = engine_config(config)
         self._engine = DecodeEngine(
-            cfg, params, num_slots=1,
+            cfg, lambda: load_model(config)[1], num_slots=1,
             max_seq=config.max_seq or min(cfg.max_seq, 2048), seed=config.seed,
             lora_config=config.lora_config, decode_loop=False,
             tp=config.tp,
@@ -173,10 +173,10 @@ class DecodeServer:
 
     def __init__(self, config: LLMConfig):
         models.require(model_config(config), "pd")  # before any weight is built
-        cfg, params = load_model(config)
+        cfg = engine_config(config)
         self._tokenizer = resolve_tokenizer(config.tokenizer)
         self._engine = DecodeEngine(
-            cfg, params, num_slots=config.num_slots,
+            cfg, lambda: load_model(config)[1], num_slots=config.num_slots,
             max_seq=config.max_seq or min(cfg.max_seq, 2048), seed=config.seed,
             lora_config=config.lora_config,
             # Transferred prefixes arrive with token_ids, so decode-side spec

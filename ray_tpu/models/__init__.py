@@ -5,6 +5,12 @@ serve engine knows of a model (`llm/_engine.py` calls nothing else):
 
     SUPPORTS          frozenset of FEATURES the block can take
     init_params(cfg, key)                  the tree served at random weights
+    serving_params(cfg, params)            the tree the engine holds: `params` with every leaf that the
+                                           block's programs read only through a cast to `cfg.dtype` held
+                                           in `cfg.dtype` (`cast_leaves`), so that no program reads or
+                                           converts the wider bytes; the tree it was given where
+                                           `init_params` already draws in the served type. The dicts
+                                           of `params` are the engine's own and may be written into
     init_caches(cfg, slots, max_seq)       per layer a tuple of [slots, ...] arrays
     prefill(params, cfg, tokens, caches, slot, offset, total_len, lora, adapter_id)
                                            one chunk of one slot -> (last logits [V], caches, stats)
@@ -48,6 +54,26 @@ FEATURES = {
     "train": "the flax Transformer (the train step)",
     "checkpoint": "loading a checkpoint (checkpoint_path)",
 }
+
+
+def cast_leaves(tree: dict, dtype, cast_on_read) -> dict:
+    """`tree` (nested dicts of arrays, the engine's own: `parallel.mesh.unbox` builds them) with
+    every leaf whose path `cast_on_read(path)` names held in `dtype`: what a block's
+    `serving_params` is made of. Written into `tree`'s own dicts, one leaf at a time and each
+    waited for, so that where nothing else holds the wider tree each wide leaf is free before the
+    next is cast and start-up never holds both trees whole. A leaf already in `dtype` stays the
+    very array."""
+    import jax
+
+    def walk(node, path):
+        for key, leaf in node.items():
+            if isinstance(leaf, dict):
+                walk(leaf, path + (key,))
+            elif leaf.dtype != dtype and cast_on_read(path + (key,)):
+                node[key] = jax.block_until_ready(leaf.astype(dtype))  # raylint: disable=RL603 (start-up, a wait a leaf: the wide leaf must be free before the next cast)
+
+    walk(tree, ())
+    return tree
 
 
 def block_module(cfg):

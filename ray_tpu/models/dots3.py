@@ -143,6 +143,12 @@ def num_params(cfg: ModelConfig) -> int:
     return sum(math.prod(shape) for shape, _ in param_shapes(cfg).values())
 
 
+def serving_params(cfg: ModelConfig, params):
+    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
+    with `param_dtype` the served type."""
+    return params
+
+
 def init_params(cfg: ModelConfig, key):
     """The tree at seeded random weights in `cfg.param_dtype` (`tree_from_shapes`). The
     router's correction bias is drawn at a scale (0.1) that changes some of the choices

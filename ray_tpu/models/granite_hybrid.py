@@ -148,6 +148,13 @@ def _init_leaves(key, leaves: tuple, dtype):
 _init_group = jax.jit(_init_leaves, static_argnums=(1, 2))
 
 
+def serving_params(cfg: ModelConfig, params):
+    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
+    with `param_dtype` the served type, and `A_log` and `dt_bias` stay float32, as the recurrence
+    reads them."""
+    return params
+
+
 def init_params(cfg: ModelConfig, key):
     """The tree at seeded random weights in `cfg.param_dtype` (A_log and dt_bias in float32),
     made on the device one top-level group (a layer, the embedding) a program, so that

@@ -150,6 +150,12 @@ def _init_leaves(key, leaves: tuple, dtype):
 _init_group = jax.jit(_init_leaves, static_argnums=(1, 2))
 
 
+def serving_params(cfg: ModelConfig, params):
+    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
+    with `param_dtype` the served type."""
+    return params
+
+
 def init_params(cfg: ModelConfig, key):
     """The tree at seeded random weights in `cfg.param_dtype`, made on the device one top-level
     group (a layer, the embedding) a program, so that layers of one kind share theirs and no

@@ -19,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ray_tpu import models
 from ray_tpu.models.transformer import ModelConfig, Transformer, _dense, _mesh_to_split_over, _rmsnorm, _rope
 from ray_tpu.ops import attention
 
@@ -29,6 +30,22 @@ SUPPORTS = frozenset({"lora", "speculation", "tp", "prefix_cache", "pd", "train"
 def init_params(cfg: ModelConfig, key):
     """The tree `load_model` serves at random weights: the flax model's own."""
     return Transformer(cfg).init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+# The leaves `_forward_cached` reads only through a cast to `cfg.dtype`: every kernel (`_dense`
+# casts it to its input's type) and the table (`embed[tokens].astype`: a cast commutes with a
+# gather, and the tied head casts the table too). The norms read their scales in float32.
+_CAST_ON_READ = frozenset({"q", "k", "v", "o", "gate", "up", "down", "lm_head"})
+
+
+def serving_params(cfg: ModelConfig, params):
+    """The tree the engine holds (`models/__init__.py`): kernels and table in `cfg.dtype`, which
+    is the value every product read of them already, norm scales as given. A train step's or a
+    checkpoint's tree is in `cfg.param_dtype`, float32 as a rule: held so, every program read
+    twice the bytes it multiplied, or converted them once an execution."""
+    return models.cast_leaves(
+        params, cfg.dtype,
+        lambda path: path == ("embedding",) or (path[-1] == "kernel" and path[-2] in _CAST_ON_READ))
 
 
 def kv_slab_shape(cfg: ModelConfig, slots: int, max_seq: int) -> tuple:

@@ -39,6 +39,9 @@ class ModelConfig:
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    # What `init_params`, a checkpoint and the train step hold the weights in (float32: the
+    # optimizer's master copy). The serve engine holds in `dtype` what its programs multiply
+    # in `dtype` (`models/__init__.py`: `serving_params`), cast once when a replica starts.
     param_dtype: Any = jnp.float32
     tie_embeddings: bool = False
     remat: bool = True

@@ -30,6 +30,10 @@ def init_params(cfg, key):
             "head": jax.random.normal(k_h, (cfg.hidden, cfg.vocab_size), jnp.float32)}
 
 
+def serving_params(cfg, params):
+    return params  # drawn in the type its two products read
+
+
 def init_caches(cfg, slots, max_seq):
     return [(jnp.zeros((slots, cfg.hidden), jnp.float32),)]
 
@@ -158,6 +162,24 @@ def test_one_function_refuses_what_a_block_does_not_list(onerow, block, feature)
     else:
         with pytest.raises(NotImplementedError, match=re.escape(models.FEATURES[feature]) + rf".* block '{block}'"):
             models.require(cfg, feature)
+
+
+@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "pangu_moe", "onerow"])
+def test_every_block_offers_what_the_seam_names(onerow, block):
+    """Each function the seam's docstring lists for every block is in the block's module, the
+    engine's `serving_params` among them; and a tree that is already in the served type comes
+    back from it as the tree it was, leaf for leaf (no copy, no second tree)."""
+    cfg = get_config("test-tiny") if block == "llama" else _cfg(block)
+    module = models.block_module(cfg)
+    for name in ("init_params", "serving_params", "init_caches", "prefill", "decode", "init_stats", "report"):
+        assert re.search(rf"^    {name}\(", models.__doc__, re.M), name
+        assert callable(getattr(module, name)), name
+    assert isinstance(module.SUPPORTS, frozenset)
+    leaves = {"kernel": jnp.ones((4, 4), cfg.dtype), "scale": jnp.ones((4,), jnp.float32)}
+    tree = {"embedding": jnp.ones((8, 4), cfg.dtype), "layer_0": {"mlp": {"up": dict(leaves)}}}
+    served = module.serving_params(cfg, tree)
+    assert served is tree
+    assert served["embedding"] is tree["embedding"] and served["layer_0"]["mlp"]["up"]["kernel"] is leaves["kernel"]
 
 
 def test_an_unknown_block_is_named_with_those_known():

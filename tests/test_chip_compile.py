@@ -211,51 +211,114 @@ def test_a_decode_step_updates_the_recurrent_state_in_place_for_v5e(one_chip, st
     assert state in text and not re.search(re.escape(state) + r"\S* copy\(", text)
 
 
+def _dense_params(cfg, sharding, served=True):
+    """The dense block's tree at `cfg`'s widths as operands on that device: as the engine holds it
+    (`llama.serving_params` over the float32 tree `init_params` gives), or that float32 tree."""
+    import functools
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.mesh import unbox
+
+    tree = unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0)))
+    assert {a.dtype for a in jax.tree_util.tree_leaves(tree)} == {jnp.dtype(jnp.float32)}
+    if served:
+        tree = jax.eval_shape(functools.partial(llama.serving_params, cfg), tree)
+    return _shaped(tree, sharding)
+
+
+def _weight_converts(text, params):
+    """The compiled text's lines that convert an array of a kernel's or the table's size to bf16:
+    what a program over float32 weights does to each before (or fused into) its product."""
+    shapes = set()
+    for a in jax.tree_util.tree_leaves(params):
+        if len(a.shape) >= 2:  # as the tree holds it, and as a product reads it: [in, heads x dim], [heads x dim, out]
+            shapes |= {tuple(a.shape), (a.shape[0], math.prod(a.shape[1:])), (math.prod(a.shape[:-1]), a.shape[-1])}
+    return [line.strip()[:140] for line in text.splitlines()
+            if (m := re.search(r"= bf16\[([\d,]+)\]\S* convert\(", line)) and tuple(int(n) for n in m.group(1).split(",")) in shapes]
+
+
 def _sampler_operands(slots, sharding):
     """What `rt_decode` takes after the gate: the slots' temperatures and the sampler's key."""
     return (_operand((slots,), sharding, jnp.float32), _operand((2,), sharding, jnp.uint32))
 
 
-@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b128"])
-def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program):
-    """The dense serve cells' three programs (`DecodeEngine`'s own bodies over `models/llama.py`)
-    at InternLM2-1.8B's widths, 12 slots of 2048 rows and float32 weights, cut to two layers, with
-    the caches donated as the engine donates them: every slab is aliased to its output, and the
-    compiled text holds no copy of one into its own layout. Undonated, each program first copied
-    all 48 slabs of the whole depth (`copy(%caches_...)`, four here), 6.9 ms of a 25.5 ms decode
-    step and 6.7 of a 17.3 ms chunk (PERF.md §6, PR 33). The decode programs no longer hold a slab
-    in any second layout (`test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e`);
-    the chunk's two products read theirs as fused operands."""
+def _dense_program(program, sharding, served=True):
+    """One of the dense serve cells' three programs (`DecodeEngine`'s own bodies over
+    `models/llama.py`) at InternLM2-1.8B's widths and 12 slots of 2048 rows, cut to two layers,
+    compiled over `_dense_params` with the caches donated as the engine donates them.
+    -> (compiled, the tree's operands, the caches', cfg, slots)."""
     import dataclasses
     import functools
     import types
 
     from ray_tpu.llm._engine import DecodeEngine
     from ray_tpu.models import llama
-    from ray_tpu.parallel.mesh import unbox
 
     cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
     slots, T = 12, cfg.max_seq
     engine = types.SimpleNamespace(cfg=cfg, _block=llama, _mesh=None)  # all that the three bodies read of an engine
     engine._decode_step = functools.partial(DecodeEngine._decode_step, engine)
 
-    params = _shaped(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
-    caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), one_chip)
-    vec, i32 = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
-    step = (params, None, vec, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
+    params = _dense_params(cfg, sharding, served)
+    caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), sharding)
+    vec, i32 = _operand((slots,), sharding, jnp.int32), _operand((), sharding, jnp.int32)
+    step = (params, None, vec, vec, caches, vec, _operand((slots,), sharding, jnp.bool_))
     body, donated, args = {
-        "rt_decode": (functools.partial(DecodeEngine._decode_sample, engine), 4, step + _sampler_operands(slots, one_chip)),
+        "rt_decode": (functools.partial(DecodeEngine._decode_sample, engine), 4, step + _sampler_operands(slots, sharding)),
         "rt_decode_multi_n8": (functools.partial(DecodeEngine._decode_multi, engine, n=8), 4, step),
         "rt_prefill_b128": (functools.partial(DecodeEngine._prefill_at, engine), 3,
-                            (params, None, _operand((1, 128), one_chip, jnp.int32), caches, i32, i32, i32, i32)),
+                            (params, None, _operand((1, 128), sharding, jnp.int32), caches, i32, i32, i32, i32)),
     }[program]
-    compiled = jax.jit(body, donate_argnums=(donated,)).lower(*args).compile()
+    return jax.jit(body, donate_argnums=(donated,)).lower(*args).compile(), params, caches, cfg, slots
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b128"])
+def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program):
+    """The dense serve cells' three programs over the tree as the engine holds it
+    (`llama.serving_params` of a float32 tree: kernels and table in bf16), the caches donated:
+    every slab is aliased to its output, and the compiled text holds no copy of one into its own
+    layout. Undonated, each program first copied all 48 slabs of the whole depth
+    (`copy(%caches_...)`, four here), 6.9 ms of a 25.5 ms decode step and 6.7 of a 17.3 ms chunk
+    (PERF.md §6, PR 33). The decode programs no longer hold a slab in any second layout
+    (`test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e`); the chunk's two
+    products read theirs as fused operands. And the weights are read as they are multiplied: no
+    `convert` of a kernel's or the table's size is left in any of the three (over the float32
+    tree a single step read 7.56 GB for 3.78 GB of products and the 8-step program kept a
+    converted copy of every kernel, 3.6 GiB of temporaries at the whole depth: PERF.md §6, PR
+    40), and the plan's arguments beside the caches are two bytes a parameter."""
+    compiled, params, caches, cfg, slots = _dense_program(program, one_chip)
     held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
-    assert compiled.memory_analysis().alias_size_in_bytes == held
-    slab = f"bf16[{slots},{T},{cfg.n_kv_heads},{cfg.head_dim}]"
+    plan = compiled.memory_analysis()
+    assert plan.alias_size_in_bytes == held
+    slab = f"bf16[{slots},{cfg.max_seq},{cfg.n_kv_heads},{cfg.head_dim}]"
     text = compiled.as_text()
     assert slab + "{3,2,1,0" in text  # the slab as the engine holds it: row-major
     assert not re.search(re.escape(slab) + r"\{3,2,1,0[^}]*\} copy\(", text)
+    assert not _weight_converts(text, params)
+    leaves = jax.tree_util.tree_leaves(params)
+    assert {a.dtype for a in leaves if len(a.shape) >= 2} == {jnp.dtype(jnp.bfloat16)}
+    assert {a.dtype for a in leaves if len(a.shape) == 1} == {jnp.dtype(jnp.float32)}  # the norms' scales
+    weights = plan.argument_size_in_bytes - held
+    count = sum(math.prod(a.shape) for a in leaves)
+    assert 2 * count <= weights < 2.002 * count, (weights, count)  # the scales' float32 and the step's vectors over it
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b128"])
+def test_the_dense_programs_over_a_float32_tree_still_convert_the_kernels_for_v5e(one_chip, program):
+    """The control of the case above: the same three programs over the float32 tree a train step
+    or a checkpoint holds convert the kernels (`_dense` casts inside the program; the 8-step
+    program hoists the casts out of its scan and keeps the copies), and their arguments are four
+    bytes a parameter. So the reading above is the tree's doing, and a `serving_params` that stops
+    casting would show."""
+    compiled, params, caches, cfg, _ = _dense_program(program, one_chip, served=False)
+    converts = _weight_converts(compiled.as_text(), params)
+    # the MLP's three a layer and the head at the least (13 to 15 of the 15 kernels here, by the program:
+    # the compiler folds some of the attention's into other fusions; the 8-step program converts the table too)
+    assert len(converts) >= 3 * cfg.n_layers + 1, converts
+    assert any(f"bf16[{cfg.hidden},{cfg.vocab_size}]" in line for line in converts)
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    count = sum(math.prod(a.shape) for a in jax.tree_util.tree_leaves(params))
+    assert compiled.memory_analysis().argument_size_in_bytes - held >= 4 * count
 
 
 @pytest.mark.parametrize("steps", [1, 8], ids=["rt_decode", "rt_decode_multi_n8"])
@@ -277,7 +340,8 @@ def test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e(one_chip,
     cfg, slots = {"llama": (dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False), 12),
                   "granite_hybrid": (_granite_cfg(), 48), "lfm2": (_lfm2_cfg(), 64)}[block]
     module, T = models.block_module(cfg), cfg.max_seq
-    params = _shaped(unbox(jax.eval_shape(lambda k: module.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
+    tree = unbox(jax.eval_shape(lambda k: module.init_params(cfg, k), jax.random.PRNGKey(0)))
+    params = _shaped(jax.eval_shape(lambda tree: module.serving_params(cfg, tree), tree), one_chip)  # as the engine holds it
     caches = _shaped(jax.eval_shape(lambda: module.init_caches(cfg, slots, T)), one_chip)
     vec = _operand((slots,), one_chip, jnp.int32)
 
@@ -316,13 +380,12 @@ def test_the_decode_program_ends_in_a_sampler_that_sorts_nothing_for_v5e(one_chi
 
     from ray_tpu.llm._engine import DecodeEngine
     from ray_tpu.models import llama
-    from ray_tpu.parallel.mesh import unbox
 
     cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
     slots, T = 12, cfg.max_seq
     engine = types.SimpleNamespace(cfg=cfg, _block=llama, _mesh=None)
     engine._decode_step = functools.partial(DecodeEngine._decode_step, engine)
-    params = _shaped(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))), one_chip)
+    params = _dense_params(cfg, one_chip)
     caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), one_chip)
     vec = _operand((slots,), one_chip, jnp.int32)
     args = (params, None, vec, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_)) + _sampler_operands(slots, one_chip)
@@ -369,7 +432,6 @@ def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
     from ray_tpu.llm._engine import DecodeEngine
     from ray_tpu.llm.scheduler.spec import ModelDraft
     from ray_tpu.models import llama
-    from ray_tpu.parallel.mesh import unbox
 
     cfg = dataclasses.replace(_SERVE_CFG, n_layers=2, scan_layers=False, remat=False)
     slots, T = 12, cfg.max_seq
@@ -383,7 +445,7 @@ def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
             return {k: on_mesh(v, path + (k,)) for k, v in tree.items()}
         return _operand(tree.shape, NamedSharding(mesh, tp_plan.param_spec(path, tuple(tree.shape), mesh)), tree.dtype)
 
-    params = on_mesh(unbox(jax.eval_shape(lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))))
+    params = on_mesh(_dense_params(cfg, whole))  # the tree as the engine holds it, then `shard_decode_params`' layout
     caches = _shaped(jax.eval_shape(lambda: llama.init_caches(cfg, slots, T)), tp_plan.kv_cache_sharding(mesh, cfg.n_kv_heads))
     vec, i32 = _operand((slots,), whole, jnp.int32), _operand((), whole, jnp.int32)
     gate = _operand((slots,), whole, jnp.bool_)

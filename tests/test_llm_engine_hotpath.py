@@ -959,3 +959,315 @@ def test_a_program_that_raises_after_consuming_the_caches_ends_the_engine(monkey
         assert engine._memory_owner_report()["components"]["kv_slots"] > 0
     finally:
         engine.shutdown()
+
+
+# -- the engine holds the dense block's weights in the type its programs multiply in -----
+
+
+def _wide_tree(cfg_name="test-tiny", **cfg_kw):
+    """(cfg, tree): a tiny dense model that computes in bfloat16 over float32 weights, as a
+    train step or a checkpoint leaves them (`param_dtype`), flax's boxes stripped."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    from ray_tpu.models.transformer import get_config
+    from ray_tpu.parallel.mesh import unbox
+
+    cfg = get_config(cfg_name, scan_layers=False, remat=False, dtype=jnp.bfloat16, **cfg_kw)
+    assert cfg.param_dtype == jnp.float32
+    return cfg, unbox(llama.init_params(cfg, jax.random.PRNGKey(0)))
+
+
+@contextlib.contextmanager
+def _serving_the_tree_as_given():
+    """Engines built inside keep the dense block's tree in the types it came in: what every
+    engine did before the block said which leaves it reads through a cast."""
+    from ray_tpu.models import llama
+
+    was = llama.serving_params
+    llama.serving_params = lambda cfg, params: params
+    try:
+        yield
+    finally:
+        llama.serving_params = was
+
+
+def test_an_engine_built_from_a_float32_tree_holds_what_its_programs_multiply_in():
+    """Kernels and table in `cfg.dtype`, cast once at construction; norm scales as given;
+    the tree the caller passed untouched; `scheduler_stats()["model"]` counts the tree's
+    bytes and those that were cast."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import DecodeEngine
+
+    cfg, tree = _wide_tree()
+    engine = DecodeEngine(cfg, tree, num_slots=2, max_seq=64, decode_loop=False, prefix_cache=False)
+    try:
+        flat = {jax.tree_util.keystr(path): leaf for path, leaf in jax.tree_util.tree_flatten_with_path(engine.params)[0]}
+        kernels = {k: v for k, v in flat.items() if k.endswith("['kernel']") or k == "['embedding']"}
+        scales = {k: v for k, v in flat.items() if k.endswith("['scale']")}
+        assert len(kernels) == 7 * cfg.n_layers + 2 and len(scales) == 2 * cfg.n_layers + 1
+        assert len(kernels) + len(scales) == len(flat)
+        assert {v.dtype for v in kernels.values()} == {jnp.dtype(jnp.bfloat16)}
+        assert {v.dtype for v in scales.values()} == {jnp.dtype(jnp.float32)}
+        assert all(leaf.dtype == jnp.float32 for leaf in jax.tree_util.tree_leaves(tree))  # the caller's own
+        given = jax.tree_util.tree_leaves(tree)
+        for (path, leaf), was in zip(jax.tree_util.tree_flatten_with_path(engine.params)[0], given):
+            assert np.array_equal(np.asarray(leaf), np.asarray(was.astype(leaf.dtype))), path
+        model = engine.scheduler_stats()["model"]
+        assert model["weight_bytes_cast"] == sum(2 * v.size for v in kernels.values())
+        assert model["weight_bytes"] == model["weight_bytes_cast"] + sum(4 * v.size for v in scales.values())
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("program", ["rt_prefill_b16", "rt_decode", "rt_decode_multi_n4"])
+def test_the_served_trees_programs_give_the_float32_trees_logits_bit_for_bit(program):
+    """The engine's own bodies, jitted as the engine jits them, over the tree it holds and over
+    the float32 tree it was given: the same tokens, logits and cache rows to the last bit (every
+    product read `bf16(w)` before and reads it now; the cast moved, the value did not)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import DecodeEngine
+
+    cfg, tree = _wide_tree()
+    engine = DecodeEngine(cfg, tree, num_slots=2, max_seq=64, decode_loop=False, prefix_cache=False)
+    try:
+        assert engine.params["lm_head"]["kernel"].dtype == jnp.bfloat16 and tree["lm_head"]["kernel"].dtype == jnp.float32
+        prompt = np.zeros((1, 16), np.int32)
+        prompt[0, :11] = np.arange(3, 14)
+        prefill = jax.jit(engine._prefill_at)
+        ids = jnp.zeros((2,), jnp.int32)
+
+        def run(params):
+            caches = engine._block.init_caches(cfg, 2, 64)
+            last, caches = prefill(params, None, jnp.asarray(prompt), caches, jnp.int32(1), jnp.int32(0),
+                                   jnp.int32(11), jnp.int32(0))
+            if program == "rt_prefill_b16":
+                return last, caches
+            step = (params, None, ids, jnp.asarray([0, 7], jnp.int32), caches, jnp.asarray([0, 11], jnp.int32),
+                    jnp.asarray([False, True]))
+            if program == "rt_decode":
+                return jax.jit(engine._decode_sample)(*step, jnp.zeros((2,), jnp.float32), jax.random.PRNGKey(0))
+            return jax.jit(functools.partial(engine._decode_multi, n=4))(*step)
+
+        served, wide = run(engine.params), run(tree)
+        for got, want in zip(jax.tree_util.tree_leaves(served), jax.tree_util.tree_leaves(wide), strict=True):
+            assert got.dtype == want.dtype and np.array_equal(np.asarray(got), np.asarray(want))
+        logits = jax.tree_util.tree_leaves(served)[0 if program == "rt_prefill_b16" else 1]
+        if program != "rt_decode_multi_n4":
+            assert logits.dtype == jnp.float32 and logits.shape[-1] == cfg.vocab_size and float(jnp.std(logits)) > 0
+    finally:
+        engine.shutdown()
+
+
+def _served_greedy_ids(path):
+    """Greedy ids through one path that reads `engine.params` somewhere else than the plain
+    decode round, from an engine built from the float32 tree."""
+    from ray_tpu.llm import DecodeEngine, SamplingParams
+    from ray_tpu.llm.kvcache import PrefixCacheManager
+
+    cfg, tree = _wide_tree()
+    prompt = list(range(1, 14))
+    build = lambda **kw: DecodeEngine(cfg, tree, max_seq=64, **{"num_slots": 2, "prefix_cache": False, **kw})  # noqa: E731
+    if path == "pd":
+        prefiller, decoder = build(num_slots=1, decode_loop=False), build()
+        try:
+            first_logits, kv, plen = prefiller.prefill_detached(prompt)
+            return _collect(decoder, lambda cb: decoder.submit_prefilled(
+                kv, plen, first_logits, SamplingParams(max_tokens=8), cb))
+        finally:
+            prefiller.shutdown()
+            decoder.shutdown()
+    engine = build(**{
+        "lora": dict(lora_config={"max_loras": 2, "rank": 2}),
+        "tp2": dict(tp=2),
+        "speculation": dict(spec_config={"num_spec_tokens": 3}),
+        "early_exit_draft": dict(spec_config={"num_spec_tokens": 3, "draft_layers": 1}),
+        "prefix_attach": dict(multi_step=1, prefix_cache=PrefixCacheManager(4, 1 << 20, name="served")),
+    }[path])
+    try:
+        if path == "lora":
+            rng = np.random.default_rng(0)
+            engine.add_lora("a", {0: {"q_A": rng.normal(size=(cfg.hidden, 2)).astype(np.float32),
+                                      "q_B": rng.normal(size=(2, cfg.hidden)).astype(np.float32)}}, alpha=4.0)
+            return _generate(engine, prompt, max_tokens=8) + _generate(engine, prompt, max_tokens=8, lora="a")
+        out = _generate(engine, prompt, max_tokens=12)
+        if path == "prefix_attach":
+            out += _generate(engine, prompt, max_tokens=12)
+            assert engine.last_attach["cached_tokens"] == 12
+        if path in ("speculation", "early_exit_draft"):
+            assert engine.scheduler_stats()["spec"]["rounds"] > 0
+        return out
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("path", ["lora", "tp2", "speculation", "early_exit_draft", "prefix_attach", "pd"])
+def test_the_served_tree_gives_the_float32_trees_greedy_ids(monkeypatch, path):
+    """Everything that takes `engine.params` beside the decode round (LoRA's base kernels, the
+    TP engine's shards, a speculative round's verify and its drafts, a prefix-cache attach's
+    re-prefill, PD's detached prefill and `submit_prefilled`) emits, from the tree the engine
+    holds, the ids of an engine that keeps the float32 tree it was given."""
+    import jax
+
+    from ray_tpu._private.config import CONFIG
+
+    if path == "tp2" and len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices")
+    monkeypatch.setitem(CONFIG._cache, "llm_prefill_bucket_min", 4)
+    with _serving_the_tree_as_given():
+        want = _served_greedy_ids(path)
+    got = _served_greedy_ids(path)
+    assert got == want and len(got) >= 8
+
+
+def test_a_tree_in_the_served_type_is_held_as_the_very_arrays():
+    """Nothing to cast: every leaf the engine holds is the array it was given (no copy), and
+    `weight_bytes_cast` says 0. So for a tree that arrives partly cast."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import DecodeEngine
+    from ray_tpu.models import llama
+
+    cfg, wide = _wide_tree()
+    served = llama.serving_params(cfg, jax.tree_util.tree_map(lambda x: x, wide))
+    engine = DecodeEngine(cfg, served, num_slots=2, max_seq=64, decode_loop=False, prefix_cache=False)
+    try:
+        for got, given in zip(jax.tree_util.tree_leaves(engine.params), jax.tree_util.tree_leaves(served), strict=True):
+            assert got is given
+        model = engine.scheduler_stats()["model"]
+        assert model["weight_bytes_cast"] == 0
+        assert model["weight_bytes"] == sum(x.nbytes for x in jax.tree_util.tree_leaves(served))
+    finally:
+        engine.shutdown()
+    mixed = jax.tree_util.tree_map(lambda x: x, served)
+    mixed["lm_head"]["kernel"] = wide["lm_head"]["kernel"]
+    engine = DecodeEngine(cfg, mixed, num_slots=2, max_seq=64, decode_loop=False, prefix_cache=False)
+    try:
+        assert engine.params["embedding"] is served["embedding"]
+        assert engine.params["lm_head"]["kernel"].dtype == jnp.bfloat16
+        assert engine.scheduler_stats()["model"]["weight_bytes_cast"] == 2 * wide["lm_head"]["kernel"].size
+    finally:
+        engine.shutdown()
+
+
+def test_a_tree_handed_over_as_a_function_goes_leaf_by_leaf_as_it_is_cast(monkeypatch):
+    """A replica hands the engine `load_model` as a function and keeps no tree: while the engine
+    casts, every float32 leaf already cast is gone (nothing holds it), so start-up never has
+    both trees whole; a tree passed as a tree stays its caller's."""
+    import gc
+    import weakref
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu import models
+    from ray_tpu.llm import DecodeEngine
+
+    cfg = _wide_tree()[0]
+    wide, alive_at_cast = [], []
+
+    def load():
+        _, tree = _wide_tree()
+        wide.extend(weakref.ref(leaf) for leaf in jax.tree_util.tree_leaves(tree))
+        return tree
+
+    wait = jax.block_until_ready
+
+    def counting(x):
+        gc.collect()
+        alive_at_cast.append(sum(ref() is not None for ref in wide))
+        return wait(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    engine = DecodeEngine(cfg, load, num_slots=2, max_seq=64, decode_loop=False, prefix_cache=False)
+    monkeypatch.undo()
+    try:
+        casts = 7 * cfg.n_layers + 2
+        scales = 2 * cfg.n_layers + 1
+        assert len(wide) == casts + scales and len(alive_at_cast) == casts
+        # at the n-th cast's wait the n - 1 leaves cast before it are free (the n-th is the loop's own)
+        assert all(alive <= len(wide) - n for n, alive in enumerate(alive_at_cast)), alive_at_cast
+        gc.collect()
+        assert sum(ref() is not None for ref in wide) == scales  # held on, as given
+        assert all(leaf.dtype == (jnp.float32 if leaf.ndim == 1 else jnp.bfloat16)
+                   for leaf in jax.tree_util.tree_leaves(engine.params))
+    finally:
+        engine.shutdown()
+    assert models.cast_leaves({}, jnp.bfloat16, lambda path: True) == {}
+
+
+def test_a_replica_keeps_no_tree_beside_its_engines_and_weights_returns_that(monkeypatch):
+    """`LLMServer` (and the PD servers and the batch stage, which build their engine the same
+    way) hands `load_model` over as a function: the one tree of the process is the engine's,
+    and `weights()` returns it, in the served types."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.data.llm import EngineProcessorConfig, EngineStage
+    from ray_tpu.llm import LLMConfig, LLMServer
+    from ray_tpu.llm.pd_disagg import DecodeServer, PrefillServer
+
+    cfg = _wide_tree(vocab_size=272, mlp_dim=144)[0]  # widths no other test's engine has
+    config = LLMConfig(model_id="tiny-wide", model_config=cfg, num_slots=2, max_seq=64)
+    server = LLMServer(config)
+    try:
+        got_cfg, params = server.weights()
+        assert got_cfg == cfg and params is server._engine.params
+        assert params["embedding"].dtype == jnp.bfloat16 and params["final_norm"]["scale"].dtype == jnp.float32
+        assert server._engine.scheduler_stats()["model"]["weight_bytes_cast"] > 0
+    finally:
+        server._engine.shutdown()
+    stage = EngineStage(EngineProcessorConfig(model_id="tiny-wide", model_config=cfg,
+                                              engine_kwargs={"num_slots": 2, "max_seq": 64}))
+    builders = {"LLMServer": lambda: LLMServer(config), "PrefillServer": lambda: PrefillServer(config),
+                "DecodeServer": lambda: DecodeServer(config), "EngineStage": lambda: stage}
+    for name, build in builders.items():
+        owner = build()
+        try:
+            gc.collect()
+            wide = [x.shape for x in gc.get_objects() if isinstance(x, jax.Array) and x.dtype == jnp.float32
+                    and x.ndim == 2 and {cfg.vocab_size, cfg.mlp_dim} & set(x.shape)]  # the table, the head, the MLPs
+            assert not wide, (name, wide)
+            held = [x.shape for x in jax.tree_util.tree_leaves(owner._engine.params) if x.dtype == jnp.bfloat16]
+            assert len(held) == 7 * cfg.n_layers + 2
+        finally:
+            owner._engine.shutdown()
+
+
+def test_granite_hybrids_recurrence_parameters_stay_float32_in_the_engine():
+    """A block whose `init_params` draws in the served type hands the engine's tree back as it
+    came: `A_log` and `dt_bias` float32 beside bfloat16 matrices, the very arrays."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import DecodeEngine
+    from ray_tpu.models import granite_hybrid
+    from ray_tpu.models.transformer import ModelConfig
+
+    cfg = ModelConfig(block="granite_hybrid", vocab_size=64, hidden=32, n_layers=2, n_heads=4, n_kv_heads=2,
+                      mlp_dim=64, max_seq=64, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, scan_layers=False,
+                      remat=False, tie_embeddings=True, layer_types=("mamba", "attention"), mamba_n_heads=4,
+                      mamba_d_head=16, mamba_d_state=16, mamba_chunk_size=8, attention_multiplier=0.25,
+                      position_embedding_type="nope")
+    tree = granite_hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    engine = DecodeEngine(cfg, tree, num_slots=2, max_seq=64, decode_loop=False)
+    try:
+        flat = {jax.tree_util.keystr(path): leaf for path, leaf in jax.tree_util.tree_flatten_with_path(engine.params)[0]}
+        wide = {k for k, v in flat.items() if v.dtype == jnp.float32}
+        assert wide and all(k.endswith(("['A_log']", "['dt_bias']")) for k in wide), wide
+        assert any(v.dtype == jnp.bfloat16 for v in flat.values())
+        for got, given in zip(jax.tree_util.tree_leaves(engine.params), jax.tree_util.tree_leaves(tree), strict=True):
+            assert got is given
+        assert engine.scheduler_stats()["model"]["weight_bytes_cast"] == 0
+    finally:
+        engine.shutdown()
