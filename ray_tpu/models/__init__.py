@@ -42,6 +42,7 @@ BLOCKS = {
     "granite_hybrid": "ray_tpu.models.granite_hybrid",
     "lfm2": "ray_tpu.models.lfm2",
     "pangu_moe": "ray_tpu.models.pangu_moe",
+    "xing4": "ray_tpu.models.xing4",
 }
 
 # What a caller may ask of a block, and how the refusal names the caller.

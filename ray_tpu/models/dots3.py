@@ -40,7 +40,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope
+from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope, yarn_inv_freq, yarn_mscale
 from ray_tpu.ops.moe import grouped_experts, sigmoid_routing
 
 _NEG = -1e30
@@ -62,9 +62,17 @@ def _is_full(cfg: ModelConfig, i: int) -> bool:
 def attn_dims(cfg: ModelConfig, full: bool) -> dict:
     """Heads, latent ranks, head sizes and rope base of one kind of layer."""
     if full:
-        return dict(heads=cfg.n_heads, q_rank=cfg.q_lora_rank, kv_rank=cfg.kv_lora_rank,
-                    nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim, v=cfg.v_head_dim,
-                    theta=cfg.rope_theta)
+        d = dict(heads=cfg.n_heads, q_rank=cfg.q_lora_rank, kv_rank=cfg.kv_lora_rank,
+                 nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim, v=cfg.v_head_dim,
+                 theta=cfg.rope_theta)
+        if cfg.rope_scaling:  # a scaled rotary: its table of frequencies and what it multiplies the scores by
+            scaling = dict(cfg.rope_scaling)
+            if scaling.get("type") != "yarn" or yarn_mscale(scaling, "mscale") != yarn_mscale(scaling, "mscale_all_dim"):
+                raise ValueError(f"rope_scaling {scaling}: only yarn with mscale equal to mscale_all_dim "
+                                 "(cos and sin unscaled) is written")
+            d.update(inv_freq=yarn_inv_freq(d["rope"], d["theta"], scaling),
+                     score_scale=yarn_mscale(scaling, "mscale_all_dim") ** 2)
+        return d
     return dict(heads=cfg.swa_n_heads, q_rank=cfg.swa_q_lora_rank, kv_rank=cfg.swa_kv_lora_rank,
                 nope=cfg.swa_qk_nope_head_dim, rope=cfg.swa_qk_rope_head_dim, v=cfg.swa_v_head_dim,
                 theta=cfg.swa_rope_theta)
@@ -238,9 +246,9 @@ def report(cfg: ModelConfig, total: tuple, window: tuple) -> dict:
 # -- projections both paths share ----------------------------------------------------
 
 
-def _rope_rows(x, positions, theta):
+def _rope_rows(x, positions, theta, inv_freq=None):
     """Rotary on rows that have no head axis. x: [..., S, R]; positions: [..., S]."""
-    return _rope(x[..., None, :], positions, theta)[..., 0, :]
+    return _rope(x[..., None, :], positions, theta, inv_freq)[..., 0, :]
 
 
 def _latents(p, x, positions, cfg: ModelConfig, d: dict):
@@ -250,11 +258,11 @@ def _latents(p, x, positions, cfg: ModelConfig, d: dict):
     c_q = c_q * jnp.asarray(_rescale(cfg, d["q_rank"]), c_q.dtype)
     q = _dense(c_q, p["q_b"]["kernel"].reshape(d["q_rank"], -1))
     q = q.reshape(x.shape[:2] + (d["heads"], d["nope"] + d["rope"]))
-    q_nope, q_rope = q[..., :d["nope"]], _rope(q[..., d["nope"]:], positions, d["theta"])
+    q_nope, q_rope = q[..., :d["nope"]], _rope(q[..., d["nope"]:], positions, d["theta"], d.get("inv_freq"))
     kv = _dense(x, p["kv_a"]["kernel"])
     c_kv = _rmsnorm(kv[..., :d["kv_rank"]], p["kv_norm"]["scale"], cfg.norm_eps)
     c_kv = c_kv * jnp.asarray(_rescale(cfg, d["kv_rank"]), c_kv.dtype)
-    k_r = _rope_rows(kv[..., d["kv_rank"]:], positions, d["theta"])
+    k_r = _rope_rows(kv[..., d["kv_rank"]:], positions, d["theta"], d.get("inv_freq"))
     return c_q, q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
 
 

@@ -209,7 +209,7 @@ def _attn_prefill(p, x, cache, offset, cfg: ModelConfig):
     q_pos = positions[0][:, None]
 
     with jax.named_scope("latent"):
-        H, scale = d["heads"], 1.0 / math.sqrt(d["nope"] + d["rope"])
+        H, scale = d["heads"], d.get("score_scale", 1.0) / math.sqrt(d["nope"] + d["rope"])
         kv_b = p["kv_b"]["kernel"].astype(x.dtype)
         q_full = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
 
@@ -248,7 +248,7 @@ def _attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
     with jax.named_scope("latent"):
         # W_kvb folded into the query, the products over the slab as it lies, W_kvb's other half over their output
         dt, kv_b = x.dtype, p["kv_b"]["kernel"].astype(x.dtype)
-        scale = 1.0 / math.sqrt(d["nope"] + d["rope"])
+        scale = d.get("score_scale", 1.0) / math.sqrt(d["nope"] + d["rope"])
         q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], kv_b[..., :d["nope"]], preferred_element_type=jnp.float32)
         q = _pad_row(jnp.concatenate([q_abs.astype(dt), q_rope[:, 0]], axis=-1), W)
         if attention._use_pallas():
@@ -266,11 +266,13 @@ def _attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
 
 def _expert_layer(p, x, valid, cfg: ModelConfig):
     """x: [B, S, D]; valid: [B, S]. The held experts' part of the routed sum plus the shared
-    expert; counts [E] of valid pairs a held expert took. (`dots3._expert_layer` with no
-    selection bias and the published epsilon under the chosen scores' sum.)"""
+    expert; counts [E] of valid pairs a held expert took. (`dots3._expert_layer` with the
+    published epsilon under the chosen scores' sum and, in this block's own tree, no selection
+    bias; a block whose router has one, `xing4`, keeps it at `router/bias`.)"""
     flat = x.reshape(-1, x.shape[-1])
     with jax.named_scope("router"):
-        ids, weights = sigmoid_routing(flat, p["router"]["kernel"], jnp.zeros((cfg.n_routed_experts_total,), jnp.float32),
+        bias = p["router"]["bias"] if "bias" in p["router"] else jnp.zeros((cfg.n_routed_experts_total,), jnp.float32)
+        ids, weights = sigmoid_routing(flat, p["router"]["kernel"], bias,
                                        cfg.experts_per_token, cfg.routed_scaling_factor, eps=ROUTING_EPS)
     with jax.named_scope("experts"):
         y, counts = grouped_experts(flat, ids, weights, valid.reshape(-1), p["experts"]["gate"],

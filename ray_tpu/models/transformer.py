@@ -81,6 +81,9 @@ class ModelConfig:
     # "pangu_moe": dense latent attention over the whole cache (dots3's full-layer latent fields, `mla_rescale`
     # off), a norm after every sub-layer as well as before it, dots3's expert fields (`models/pangu_moe.py`,
     # served only); every layer is of one kind, so it takes no `layer_types`.
+    # "xing4": pangu_moe's latent attention and expert layer (with a selection bias) round a residual of
+    # `hc_mult` streams mixed by manifold-constrained hyper-connections (`ops/hyper_connection.py`), rotary
+    # frequencies scaled as `rope_scaling` says (`models/xing4.py`, served only); the `hc_*` fields are its own.
     block: str = "llama"
     layer_types: tuple = ()            # per layer; dots3: "full_attention" | "sliding_attention";
                                        # granite_hybrid: "mamba" | "attention"; lfm2: "conv" | "full_attention"
@@ -120,9 +123,20 @@ class ModelConfig:
     logits_scaling: float = 1.0        # logits are divided by it
     position_embedding_type: str = "rope"  # "nope": the attention layers rotate nothing
     conv_L_cache: int = 3              # lfm2: taps of a conv layer's causal depthwise convolution
+    hc_mult: int = 0                   # xing4: streams of the residual path
+    hc_sinkhorn_iters: int = 0         # column-then-row normalisations that make the streams' mixing matrix doubly stochastic
+    hc_eps: float = 1e-6               # under the streams' RMS and under every Sinkhorn divisor
+    hc_res_clamp_min: float = -30.0    # the mixing matrix's logits are held to [min, max] before the exponential
+    hc_res_clamp_max: float = 30.0
+    # The published `rope_scaling` group ({"type": "yarn", "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "mscale", "mscale_all_dim"}), kept as sorted pairs so that the config stays hashable; None: plain rotary.
+    # Read by the latent-attention blocks through `dots3.attn_dims` (`yarn_inv_freq`, `yarn_mscale` below).
+    rope_scaling: Any = None
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))  # a JSON list, hashable
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
         if (self.block != "llama" and len(self.layer_types) != self.n_layers
                 and models.names_its_layers(self)):
             raise ValueError(f"block {self.block!r} needs one of layer_types per layer: "
@@ -170,15 +184,44 @@ CONFIGS: dict[str, ModelConfig] = {
 }
 
 
-def _rope_angles(positions: jax.Array, head_dim: int, theta: float):
-    """cos/sin tables for rotary embedding: [B,S,half] f32 each.
+def yarn_inv_freq(head_dim: int, theta: float, scaling: dict):
+    """YaRN's frequency table, float32 [head_dim / 2]: pair i turns at theta^(-2i/d) where it completes more than
+    `beta_fast` turns over the original window, at that over `factor` where it completes fewer than `beta_slow`,
+    and at a linear blend of the two between (the published "yarn" `rope_scaling`; at factor 1 the plain table)."""
+    import numpy as np
+
+    half, factor, window = head_dim // 2, float(scaling["factor"]), scaling["original_max_position_embeddings"]
+    plain = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def pair_of(turns):  # the pair that completes `turns` turns over the original window
+        return head_dim * math.log(window / (2 * math.pi * turns)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(pair_of(scaling.get("beta_slow", 1))), head_dim - 1)
+    blend = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (plain * (1.0 - blend) + plain / factor * blend).astype(np.float32)
+
+
+def yarn_mscale(scaling: dict, key: str) -> float:
+    """YaRN's magnitude m(s) = 0.1 s ln(factor) + 1 for s = scaling[key] (1 where the factor is 1 or the key absent or 0):
+    cos and sin carry m(mscale) / m(mscale_all_dim), the scores m(mscale_all_dim)^2."""
+    s, factor = scaling.get(key) or 0.0, float(scaling["factor"])
+    return 0.1 * s * math.log(factor) + 1.0 if factor > 1.0 and s else 1.0
+
+
+def _rope_angles(positions: jax.Array, head_dim: int, theta: float, inv_freq=None):
+    """cos/sin tables for rotary embedding: [B,S,half] f32 each. `inv_freq`, where given, is the
+    table of frequencies [half] in place of theta's (a scaled rotary: `yarn_inv_freq`).
 
     Computed ONCE per forward (Transformer.__call__) and broadcast through the
     layer scan — inside the scan the transcendentals re-ran every layer (XLA
     does not hoist loop-invariant code out of scans; ~4 ms/step measured at
     the bench shape)."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     if positions.ndim == 1:
         positions = positions[None, :]
     # Angle computation stays f32 (position * freq overflows bf16 precision
@@ -204,9 +247,9 @@ def _rope_apply_bhsd(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def _rope(x: jax.Array, positions: jax.Array, theta: float, inv_freq=None) -> jax.Array:
     """Rotary position embedding. x: [B, S, H, D]; positions: [B, S] or [S]."""
-    cos, sin = _rope_angles(positions, x.shape[-1], theta)
+    cos, sin = _rope_angles(positions, x.shape[-1], theta, inv_freq)
     return _rope_apply(x, cos, sin)
 
 

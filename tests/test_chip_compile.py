@@ -552,14 +552,19 @@ def test_the_lfm2_cells_programs_fit_the_chip_and_read_each_expert_in_place_for_
         assert not re.search(re.escape(matrix) + r"\S* copy\(", text), matrix
 
 
-def _pangu_cfg():
+def _cell_cfg(name: str):
+    """The `model` of `benchmark/configs/<name>.json` as the program's `ModelConfig`."""
     import json
     import os
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "openpangu-ultra-moe-718b.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
         model = json.load(f)["model"]
     return ModelConfig(**{k: getattr(jnp, v) if k in ("dtype", "param_dtype") else v for k, v in model.items()})
+
+
+def _pangu_cfg():
+    return _cell_cfg("openpangu-ultra-moe-718b")
 
 
 @pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b1024"])
@@ -613,3 +618,60 @@ def test_the_pangu_moe_cells_programs_fit_the_chip_and_copy_no_latent_slab_for_v
         assert re.search(r"%latent_attn(\.\d+)? = ", text) and re.search(r'latent/[^"]*latent_attn/pallas_call"', text)
         shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
         assert not {s for s in shapes if T in s and math.prod(s) >= slots * cfg.n_heads * T}, "scores over every row"
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b1024"])
+def test_the_xing4_cells_programs_fit_the_chip_keep_the_streams_whole_and_copy_no_latent_slab_for_v5e(one_chip, program):
+    """`xing4.0-29b-a4b.serve-sessions-mhc48`'s programs at the published widths, all 6 layers of the cut
+    and 48 slots of 8192 rows, the caches donated as the engine donates them: the plan stays under the
+    chip's 15.75 GiB with 9.59 GB of weights and 3.02 GB of cache held; every slab is `bf16[48,8192,640]`
+    row-major, aliased to its output and copied by no operation; a token's four streams are one row of
+    14336 lanes (`[tokens, 14336]`: no array has an axis of 4 beside the lanes, which the chip would pad
+    to a tile of 8 or 16) and Phi lies `bf16[24,14336]`; the coefficients are computed with the tokens on
+    the lane axis by one call of the kernel `hc_map` a sub-layer; a decode step reads the slab through
+    the kernel `latent_attn`."""
+    from ray_tpu.models import xing4
+
+    cfg, slots = _cell_cfg("xing4.0-29b-a4b"), 48
+    T, tokens = cfg.max_seq, 1024 if program == "rt_prefill_b1024" else 48
+    params = _shaped(jax.eval_shape(lambda k: xing4.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: xing4.init_caches(cfg, slots, T)), one_chip)
+    vec, scalar = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
+
+    def steps(n):
+        def run(params, last, caches, lens, gate):
+            def step(carry, _):
+                last, caches, lens = carry
+                logits, caches, stats = xing4.decode(params, cfg, last, caches, lens, gate)
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32), caches, lens + 1), stats
+
+            return jax.lax.scan(step, (last, caches, lens), None, length=n)
+        return jax.jit(run, donate_argnums=(2,)).lower(params, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
+
+    if program == "rt_prefill_b1024":
+        lowered = jax.jit(lambda p, t, c, s, o, n: xing4.prefill(p, cfg, t, c, s, o, n), donate_argnums=(2,)).lower(
+            params, _operand((1, 1024), one_chip, jnp.int32), caches, scalar, scalar, scalar)
+    else:
+        lowered = steps(8 if program.endswith("n8") else 1)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    plan = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert m.alias_size_in_bytes == held == 6 * 48 * 8192 * 640 * 2
+    assert 2 * xing4.num_params(cfg) == 9_585_339_656 and 2 * xing4.num_params(cfg) + held < plan < 15.75 * 2**30, plan / 2**30
+    text = compiled.as_text()
+    layout = next(line for line in text.splitlines() if "entry_computation_layout" in line)
+    assert "bf16[48,8192,640]{2,1,0:T(8,128)(2,1)}" in layout and "bf16[24,14336]{1,0:T(8,128)(2,1)}" in layout
+    copied = [tuple(int(n) for n in mm.group(1).split(",")) for line in text.splitlines()
+              if (mm := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))]
+    assert not [s for s in copied if T in s or math.prod(s) >= slots * T * 640], copied
+    # the streams: rows of 14336 lanes and nothing with the four streams as an axis of their own
+    assert re.search(rf"bf16\[{tokens},14336\]\{{1,0:T\(8,128\)\(2,1\)", text)
+    under_hc = [line for line in text.splitlines() if re.search(r'op_name="[^"]*/hc/', line)]
+    assert under_hc and not [line for line in under_hc if re.search(r"\[(\d+,)*4,3584\]|\[4,\d+,3584\]", line)]
+    # the coefficients: tokens on the lane axis into and out of one kernel call a sub-layer
+    calls = re.findall(r"= f32\[24,(\d+)\]\S* custom-call\([^\n]*hc_map", text)
+    assert len(calls) == 2 * cfg.n_layers and set(calls) == {str(tokens)}, calls
+    assert re.search(r'hc/map/[^"]*hc_map/pallas_call"', text)
+    if program != "rt_prefill_b1024":
+        assert re.search(r"%latent_attn(\.\d+)? = ", text) and re.search(r'latent/[^"]*latent_attn/pallas_call"', text)
