@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope, yarn_inv_freq, yarn_mscale
+from ray_tpu.ops import latent_attention as la
 from ray_tpu.ops.moe import grouped_experts, sigmoid_routing
 
 _NEG = -1e30
@@ -372,33 +373,9 @@ def _full_attn_prefill(p, x, cache, offset, cfg: ModelConfig):
         chosen = _top_k_mask(scores, cfg.index_topk) & (jnp.arange(T)[None, :] <= q_pos)
 
     with jax.named_scope("latent"):
-        H, scale = d["heads"], 1.0 / math.sqrt(d["nope"] + d["rope"])
-        kv_b = p["kv_b"]["kernel"].astype(x.dtype)
         q_full = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
-
-        def attend_block(j, carry):
-            m, l, acc = carry
-            rows = jax.lax.dynamic_slice(lat[0], (j * kb, 0), (kb, lat.shape[-1])).astype(x.dtype)
-            kv = jnp.einsum("kc,chd->khd", rows[:, :d["kv_rank"]], kv_b,
-                            preferred_element_type=jnp.float32).astype(x.dtype)
-            # one product over [nope | rope]: a second one and their sum would each be a
-            # pass over the block's float32 scores, which are what this loop is bound by
-            keys = jnp.concatenate([kv[..., :d["nope"]], jnp.broadcast_to(
-                rows[:, None, d["kv_rank"]:], (kb, H, d["rope"]))], axis=-1)
-            s = jnp.einsum("shd,khd->hsk", q_full, keys, preferred_element_type=jnp.float32)
-            mask = jax.lax.dynamic_slice(chosen, (0, j * kb), (S, kb))[None]
-            s = jnp.where(mask, s * scale, _NEG)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            pr = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-            fade = jnp.exp(m - m_new)
-            acc = acc * fade[..., None] + jnp.einsum(
-                "hsk,khd->hsd", pr.astype(x.dtype), kv[..., d["nope"]:], preferred_element_type=jnp.float32)
-            return m_new, l * fade + jnp.sum(pr, axis=-1), acc
-
-        init = (jnp.full((H, S), _NEG, jnp.float32), jnp.zeros((H, S), jnp.float32),
-                jnp.zeros((H, S, d["v"]), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, n_blocks, attend_block, init)
-        o = (acc / l[..., None]).astype(x.dtype).transpose(1, 0, 2)[None]
+        o = la.latent_chunk_attention(q_full, lat[0], p["kv_b"]["kernel"].astype(x.dtype), offset, kb, d,
+                                      1.0 / math.sqrt(d["nope"] + d["rope"]), mask=chosen)[None]
     return _gated_out(p, x, o, d), (lat, kidx)
 
 

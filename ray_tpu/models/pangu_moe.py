@@ -45,7 +45,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.dots3 import (_NEG, _key_block, _latents, _put_row, _swiglu, attn_dims, num_expert_layers,
+from ray_tpu.models.dots3 import (_key_block, _latents, _put_row, _swiglu, attn_dims, num_expert_layers,
                                   tree_from_shapes)
 from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope
 from ray_tpu.ops import attention, latent_attention as la
@@ -198,42 +198,17 @@ def _out(p, o):
 def _attn_prefill(p, x, cache, offset, cfg: ModelConfig):
     """x: [1, S, D] at positions offset + [0, S); cache: (lat [1, T, W],). Writes the chunk's
     rows, then attends over rows [0, offset + S) in blocks of keys, every one under the
-    causal mask alone (`dots3._full_attn_prefill`'s loop with no selection)."""
+    causal mask alone (`ops/latent_attention.py:latent_chunk_attention` with no selection)."""
     d = dims(cfg)
     S, (lat,) = x.shape[1], cache
     kb = _key_block(lat.shape[1], S)
     positions = offset + jnp.arange(S)[None, :]
     _, q_nope, q_rope, row = _latents(p, x, positions, cfg, d)
     lat = jax.lax.dynamic_update_slice(lat, _pad_row(row, lat.shape[-1]).astype(lat.dtype), (0, offset, 0))
-    n_blocks = (offset + S + kb - 1) // kb
-    q_pos = positions[0][:, None]
-
     with jax.named_scope("latent"):
-        H, scale = d["heads"], d.get("score_scale", 1.0) / math.sqrt(d["nope"] + d["rope"])
-        kv_b = p["kv_b"]["kernel"].astype(x.dtype)
+        scale = d.get("score_scale", 1.0) / math.sqrt(d["nope"] + d["rope"])
         q_full = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
-
-        def attend_block(j, carry):
-            m, l, acc = carry
-            rows = jax.lax.dynamic_slice(lat[0], (j * kb, 0), (kb, lat.shape[-1])).astype(x.dtype)
-            kv = jnp.einsum("kc,chd->khd", rows[:, :d["kv_rank"]], kv_b,
-                            preferred_element_type=jnp.float32).astype(x.dtype)
-            keys = jnp.concatenate([kv[..., :d["nope"]], jnp.broadcast_to(
-                rows[:, None, d["kv_rank"]:d["kv_rank"] + d["rope"]], (kb, H, d["rope"]))], axis=-1)
-            s = jnp.einsum("shd,khd->hsk", q_full, keys, preferred_element_type=jnp.float32)
-            mask = (j * kb + jnp.arange(kb)[None, :] <= q_pos)[None]
-            s = jnp.where(mask, s * scale, _NEG)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            pr = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-            fade = jnp.exp(m - m_new)
-            acc = acc * fade[..., None] + jnp.einsum(
-                "hsk,khd->hsd", pr.astype(x.dtype), kv[..., d["nope"]:], preferred_element_type=jnp.float32)
-            return m_new, l * fade + jnp.sum(pr, axis=-1), acc
-
-        init = (jnp.full((H, S), _NEG, jnp.float32), jnp.zeros((H, S), jnp.float32),
-                jnp.zeros((H, S, d["v"]), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, n_blocks, attend_block, init)
-        o = (acc / l[..., None]).astype(x.dtype).transpose(1, 0, 2)[None]
+        o = la.latent_chunk_attention(q_full, lat[0], p["kv_b"]["kernel"].astype(x.dtype), offset, kb, d, scale)[None]
     return _out(p, o), (lat,)
 
 

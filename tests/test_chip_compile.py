@@ -563,6 +563,22 @@ def _cell_cfg(name: str):
     return ModelConfig(**{k: getattr(jnp, v) if k in ("dtype", "param_dtype") else v for k, v in model.items()})
 
 
+def _chunk_kernel_holds_the_scores(text: str, layers: int, heads: int, queries: int = 1024, kb: int = 1024):
+    """A prefill program's latent attention (`ops/latent_attention.py:latent_chunk_attention`): one call of
+    the kernel `latent_chunk` a layer, inside that layer's loop over key blocks and under the scope
+    `latent`, the carry's three arrays written where they were read; and no float32 array of a block's
+    scores, `[heads, queries, kb]` (537 MB at 128 heads), which XLA's three fusions wrote and read."""
+    calls = [line for line in text.splitlines() if re.search(r"%latent_chunk(\.\d+)? = ", line)]
+    assert len(calls) == layers, len(calls)
+    for line in calls:
+        assert re.search(r'layer_\d+/attn/latent/while/body/[^"]*latent_chunk/pallas_call"', line), line[:300]
+        assert "output_to_operand_aliasing={{0}: (5, {}), {1}: (6, {}), {2}: (7, {})}" in line or \
+            "output_to_operand_aliasing={{0}: (6, {}), {1}: (7, {}), {2}: (8, {})}" in line, line[:300]
+        assert f"f32[{heads},128,{queries}]" in line  # the accumulator, queries on the lanes
+    shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
+    assert not {sh for sh in shapes if math.prod(sh) >= heads * queries * kb and kb in sh and heads in sh}, "a block's scores in memory"
+
+
 def _pangu_cfg():
     return _cell_cfg("openpangu-ultra-moe-718b")
 
@@ -577,7 +593,9 @@ def test_the_pangu_moe_cells_programs_fit_the_chip_and_copy_no_latent_slab_for_v
     under the chip's 15.75 GiB with 8.07 GB of weights and 4.03 GB of cache held, a decode step reads
     the slab through the kernel `latent_attn` and holds no array of scores over every row of every
     slot, and W_qb is multiplied where it lies (kept `[1536, 128, 192]` it was copied into the
-    product's shape every step: a last axis of 192 is a row and a half of lanes)."""
+    product's shape every step: a last axis of 192 is a row and a half of lanes). A chunk's attention
+    over a block of keys is one call of the kernel `latent_chunk` a layer and no array of a block's scores
+    (`_chunk_kernel_holds_the_scores`)."""
     from ray_tpu.models import pangu_moe
 
     cfg, slots = _pangu_cfg(), 16
@@ -618,6 +636,10 @@ def test_the_pangu_moe_cells_programs_fit_the_chip_and_copy_no_latent_slab_for_v
         assert re.search(r"%latent_attn(\.\d+)? = ", text) and re.search(r'latent/[^"]*latent_attn/pallas_call"', text)
         shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
         assert not {s for s in shapes if T in s and math.prod(s) >= slots * cfg.n_heads * T}, "scores over every row"
+        assert "latent_chunk" not in text
+    else:
+        _chunk_kernel_holds_the_scores(text, cfg.n_layers, cfg.n_heads)
+        assert not re.search(r"%latent_attn(\.\d+)? = ", text)
 
 
 @pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b1024"])
@@ -629,7 +651,8 @@ def test_the_xing4_cells_programs_fit_the_chip_keep_the_streams_whole_and_copy_n
     14336 lanes (`[tokens, 14336]`: no array has an axis of 4 beside the lanes, which the chip would pad
     to a tile of 8 or 16) and Phi lies `bf16[24,14336]`; the coefficients are computed with the tokens on
     the lane axis by one call of the kernel `hc_map` a sub-layer; a decode step reads the slab through
-    the kernel `latent_attn`."""
+    the kernel `latent_attn`, and a chunk's attention over a block of keys is one call of the kernel
+    `latent_chunk` a layer with no array of a block's scores (32 heads: 134 MB)."""
     from ray_tpu.models import xing4
 
     cfg, slots = _cell_cfg("xing4.0-29b-a4b"), 48
@@ -675,3 +698,44 @@ def test_the_xing4_cells_programs_fit_the_chip_keep_the_streams_whole_and_copy_n
     assert re.search(r'hc/map/[^"]*hc_map/pallas_call"', text)
     if program != "rt_prefill_b1024":
         assert re.search(r"%latent_attn(\.\d+)? = ", text) and re.search(r'latent/[^"]*latent_attn/pallas_call"', text)
+        assert "latent_chunk" not in text
+    else:
+        _chunk_kernel_holds_the_scores(text, cfg.n_layers, cfg.n_heads)
+
+
+
+def test_the_dots3_cells_chunk_keeps_its_scores_in_the_kernel_and_fits_the_chip_for_v5e(one_chip):
+    """`dots3-note-prev.serve-longctx`'s `rt_prefill_b1024` at the published widths, all 5 layers of the
+    cut and 16 slots of 32768 rows, the caches donated: the two full layers' attention over a block of
+    keys is one call of the kernel `latent_chunk` a layer, the selection's block going in as an int8
+    operand `[keys, queries]`, with no float32 array of a block's scores; the kernel takes the block's
+    expanded keys and values and never the slab, so the 576-wide latent slab (`bf16[16,32768,576]`, not
+    whole rows of lanes: PERF.md section 7) is aliased to its output and copied by no operation; the
+    three sliding layers run no kernel; the plan stays under the chip's 15.75 GiB."""
+    from ray_tpu.models import dots3
+
+    cfg, slots = _cell_cfg("dots3-note-prev"), 16
+    T = cfg.max_seq
+    params = _shaped(jax.eval_shape(lambda k: dots3.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: dots3.init_caches(cfg, slots, T)), one_chip)
+    scalar = _operand((), one_chip, jnp.int32)
+    compiled = jax.jit(lambda p, t, c, s, o, n: dots3.prefill(p, cfg, t, c, s, o, n), donate_argnums=(2,)).lower(
+        params, _operand((1, 1024), one_chip, jnp.int32), caches, scalar, scalar, scalar).compile()
+    m = compiled.memory_analysis()
+    plan = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    # every cache aliased (the rings' 513 rows are padded to whole tiles in the chip's layout: 0.2% more than their shapes)
+    assert held <= m.alias_size_in_bytes < 1.01 * held and 2 * dots3.num_params(cfg) + held < plan < 15.75 * 2**30, plan / 2**30
+    text = compiled.as_text()
+    full = sum(t == "full_attention" for t in cfg.layer_types)
+    assert full == 2 and "bf16[16,32768,576]" in text
+    _chunk_kernel_holds_the_scores(text, full, cfg.n_heads)
+    calls = [line for line in text.splitlines() if re.search(r"%latent_chunk(\.\d+)? = ", line)]
+    assert all("s8[1024,1024]" in line and "32768" not in line.split("custom_call_target")[0] for line in calls)
+    copied = [tuple(int(n) for n in mm.group(1).split(",")) for line in text.splitlines()
+              if (mm := re.search(r"= bf16\[([\d,]+)\]\S* copy\(", line))]
+    # no copy of the slab; of one slot's view of it the one a full layer that the parent had too (38 MB where the
+    # chunk's rows are written into a slab that is not whole rows of lanes: S10), none for the kernel's sake
+    assert not [sh for sh in copied if math.prod(sh) >= slots * T * 576], copied
+    assert len([sh for sh in copied if sh == (1, T, 576)]) <= full, copied
+

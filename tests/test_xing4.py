@@ -136,6 +136,41 @@ def test_chunked_prefill_then_decode_through_the_cache_matches_the_benchmarks_re
     assert 0.5 < want.std() < 2.0 and np.mean(np.argmax(want, axis=-1) == toks) < 0.2  # logits, and not the input's
 
 
+LONG_CHUNKS = {
+    "three-chunks-of-128": ((128, 128), (128, 128), (44, 128)),   # the last one padded; keys in blocks of 128
+    "a-chunk-of-256-and-a-tail": ((256, 256), (44, 64)),          # the tail's bucket is too small for a tile
+}
+
+
+@pytest.mark.parametrize("chunks", sorted(LONG_CHUNKS))
+def test_chunked_prefill_through_the_interpreted_chunk_kernel_matches_the_benchmarks_reference(model, monkeypatch, chunks):
+    """On the TPU a chunk's attention over each block of keys is the kernel `latent_chunk`; here the
+    same trace with the kernel interpreted, over a cache of 512 rows (blocks of 128 keys) into a slot
+    left dirty, with `score_scale` (YaRN's) in the kernel's scale: the prompt's last logits, and decode
+    steps over the rows the chunks wrote."""
+    cfg, params = model
+    toks, kernel, calls = _tokens(304, seed=6), la.latent_chunk_attention, []
+
+    def interpreted(q_full, lat_rows, kv_b, offset, kb, *a, **kw):
+        calls.append((q_full.shape[0], kb, la.chunk_tiles(q_full.shape[0], kb)))
+        return kernel(q_full, lat_rows, kv_b, offset, kb, *a, interpret=True, **kw)
+
+    monkeypatch.setattr(la, "latent_chunk_attention", interpreted)
+    prefill = jax.jit(xing4.prefill, static_argnums=1)  # traced under the patch
+    caches, off = _dirty(xing4.init_caches(cfg, 3, 512)), 0
+    for n, bucket in LONG_CHUNKS[chunks]:
+        pad = np.full((1, bucket), 7, np.int32)
+        pad[0, :n] = toks[off:off + n]
+        last, caches, _ = prefill(params, cfg, jnp.asarray(pad), caches, jnp.int32(1), jnp.int32(off), jnp.int32(300))
+        off += n
+    assert off == 300 and {c[2] for c in calls} == {(b, 128) if b >= 128 else None for _, b in LONG_CHUNKS[chunks]}
+    want = _reference(params, cfg, toks)
+    np.testing.assert_allclose(np.asarray(last), want[299], atol=ATOL)
+    for at in range(300, 304):
+        logits, caches = _decode(cfg, params, toks[at], caches, 1, at)
+        np.testing.assert_allclose(logits, want[at], atol=ATOL)
+
+
 @pytest.mark.parametrize("P", [7, 20, 33])
 def test_the_decode_path_gives_the_prefill_paths_logits(model, P):
     """Position P reached by a decode step after a prefill of P tokens, and as the last of a prefill of
