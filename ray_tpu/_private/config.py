@@ -82,7 +82,7 @@ _DEFS: dict[str, tuple[type, Any, str]] = {
     "serve_handle_max_retries": (int, 3, "deployment-handle resubmissions after replica death before the call fails"),
     "serve_control_loop_interval_s": (float, 0.25, "serve controller reconcile interval"),
     "serve_router_cache_ttl_s": (float, 2.0, "deployment-handle routing-table refresh TTL (scale-ups become visible to existing handles within this window)"),
-    "llm_multi_step": (int, 8, "decode tokens per engine dispatch when every active slot is greedy (on-device argmax chunks; 1 disables)"),
+    "llm_multi_step": (int, 8, "decode tokens per engine dispatch (on-device chunks of steps, each drawing its own token; 1 disables)"),
     "llm_prefill_bucket_min": (int, 16, "smallest prompt padding bucket for compiled prefill programs"),
     "llm_kv_block_size": (int, 16, "token rows per paged KV prefix-cache block; prefixes are reused at whole-block granularity (docs/kvcache.md)"),
     "llm_prefix_cache_bytes": (int, 32 << 20, "host bytes for the per-engine paged KV prefix cache; repeated prompt prefixes attach cached KV and prefill suffix-only (0 disables)"),

@@ -47,6 +47,7 @@ from ray_tpu.llm.scheduler.scheduler import (
     Request,
     ScheduledChunk,
     Scheduler,
+    _host_drawn,
 )
 from ray_tpu.llm.tp import (
     ShardedKVPool,
@@ -112,17 +113,13 @@ def _sample_host(logits_row: np.ndarray, sampling: SamplingParams,
     return int(rng.choice(len(probs), p=probs))
 
 
-def _host_drawn(sampling: SamplingParams, constraint) -> bool:
-    """Whether a slot's decode rounds draw its row on the host, from what the slot
-    carries: a guided slot's mask is a host automaton's `[V]` row a step, and a
-    top-k filter at a temperature is a sort of the row, which the decode program's
-    sampler does not hold (at temperature 0 `_sample_host` ignores `top_k`, and so
-    does the device)."""
-    return constraint is not None or (sampling.temperature > 0 and sampling.top_k > 0)
+def _any_hot(temps, gate):
+    """Whether a round draws: one of its stepping rows has a temperature."""
+    return jnp.any((temps > 0) & gate)
 
 
 def _sample_device(logits, temps, gate, key):
-    """One token a slot from `[B, V]` float32 logits, in the decode program: the
+    """One token a slot from `[B, V]` float32 logits, in the single-step program: the
     row's first maximum at temperature 0 (what `np.argmax` of the pulled row
     gives), and at T > 0 a draw from `softmax(logits / T)` by Gumbel-max, one
     pass over the row and no sort. Returns (tokens [B] int32, the key to carry).
@@ -148,8 +145,26 @@ def _sample_device(logits, temps, gate, key):
         return tokens, key
 
     tokens, key = jax.lax.cond(
-        jnp.any(hot & gate), draw, lambda key: (jnp.argmax(logits, axis=-1), key), key)
+        _any_hot(temps, gate), draw, lambda key: (jnp.argmax(logits, axis=-1), key), key)
     return tokens.astype(jnp.int32), key
+
+
+def _sample_device_flat(logits, temps, gate, key):
+    """`_sample_device`'s tokens and key, token for token, with no control flow: the
+    form the multi-step programs' loop body takes. The same noise (one key a row,
+    from one split of the key) is generated for every row of every step and used on
+    the rows at a temperature; the key moves only in a round that draws. Inside the
+    loop's body a `cond`, or a loop over the rows, cost the dense block's step 0.55
+    to 0.95 ms on the chip in either branch (the TPU's compiler prefetches fewer of
+    a layer's kernels past nested control flow); this form costs a greedy step the
+    noise it throws away (PERF.md §6, PR 44)."""
+    hot = temps > 0
+    new, sub = jax.random.split(key)
+    noise = jax.vmap(lambda k: jax.random.gumbel(k, logits.shape[1:], logits.dtype))(
+        jax.random.split(sub, logits.shape[0]))
+    scaled = logits / jnp.where(hot, temps, 1.0)[:, None] + noise
+    tokens = jnp.argmax(jnp.where(hot[:, None], scaled, logits), axis=-1)
+    return tokens.astype(jnp.int32), jnp.where(_any_hot(temps, gate), new, key)
 
 
 def _traced_on(mesh):
@@ -212,8 +227,9 @@ class DecodeEngine:
         self.B = num_slots
         self.T = max_seq or cfg.max_seq
         # Two generators from the one seed: this one draws a request's first token
-        # and the rows `_host_drawn` names; `_sample_key` is the decode program's
-        # sampler's, carried on the device from round to round (`_decode_sample`).
+        # and the rows `_host_drawn` names; `_sample_key` is the decode programs'
+        # sampler's, carried on the device from round to round (`_decode_sample`,
+        # `_decode_multi`).
         self._np_rng = np.random.default_rng(seed)
         # Tensor parallelism (docs/serving_tp.md): tp > 1 (or a mesh-axes
         # dict) shards the WHOLE decode plane — params, per-slot KV pool,
@@ -283,7 +299,7 @@ class DecodeEngine:
         self._temps = np.zeros((self.B,), np.float32)
         self._temps_dev = self._resident(self._temps)
         self._sample_key = self._resident(jax.random.PRNGKey(seed))
-        # rows the single-step rounds drew, by where (scheduler_stats())
+        # rows the decode rounds drew, by where (scheduler_stats())
         self._rows_sampled = {"device": 0, "host": 0}
         self._stop = False
         # Cross-thread cancel plane (docs/generation.md): cancel() resolves
@@ -637,24 +653,28 @@ class DecodeEngine:
         return (tokens, logits, new_caches, lens, key, *stats)
 
     def _decode_multi(self, params, lora, adapter_ids, last_token, caches, lens,
-                      gate, *, n):
-        """n greedy tokens for every slot in ONE program: lax.scan over decode
-        steps with on-device argmax. Returns ([n, B] tokens, final caches/lens,
-        the steps' stats summed)."""
+                      gate, temps, key, *, n):
+        """n tokens for every slot in ONE program (`rt_decode_multi_n<n>`): a
+        `lax.scan` over decode steps, each drawing its token as the single-step
+        program does (the argmax at temperature 0, a draw otherwise; under the
+        scope `sample`, in the form a loop's body wants: `_sample_device_flat`)
+        and feeding it to the next. The key rides the scan's carry, so n steps
+        here consume it as n single-step rounds do. Returns ([n, B] tokens, final
+        caches/lens, the sampler's next key, the steps' stats summed)."""
 
         def step(carry, _):
-            last, c, l = carry
+            last, c, l, k = carry
             logits, c, l, *stats = self._decode_step(
                 params, lora, adapter_ids, last, c, l, gate
             )
             with jax.named_scope("sample"):
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (nxt, c, l), (nxt, *stats)
+                nxt, k = _sample_device_flat(logits, temps, gate, k)
+            return (nxt, c, l, k), (nxt, *stats)
 
-        (last, caches, lens), (toks, *stats) = jax.lax.scan(
-            step, (last_token, caches, lens), None, length=n
+        (last, caches, lens, key), (toks, *stats) = jax.lax.scan(
+            step, (last_token, caches, lens, key), None, length=n
         )
-        return (toks, caches, lens, *(jnp.sum(s, axis=0) for s in stats))
+        return (toks, caches, lens, key, *(jnp.sum(s, axis=0) for s in stats))
 
     def _spec_verify_batched(self, params, lora, adapter_ids, tokens, caches,
                              lens, gate, constraint_mask):
@@ -965,7 +985,7 @@ class DecodeEngine:
                     self._spec_metrics["accept_rate"].set(spec["accept_rate"])
                 except Exception:
                     pass  # metrics must never break the serving path
-        # Rows the single-step decode rounds drew, by where: in the program, or
+        # Rows the decode rounds drew, by where: in a program (single-step or multi-step), or
         # on the host from pulled logits (guided slots; top-k at a temperature).
         out["rows_sampled_device"] = self._rows_sampled["device"]
         out["rows_sampled_host"] = self._rows_sampled["host"]
@@ -2108,6 +2128,11 @@ class DecodeEngine:
                 jnp.asarray(self._last_token), jnp.asarray(self._lens),
                 jnp.asarray(gate))
 
+    def _hot(self, decode_slots: List[int]) -> int:
+        """How many of a round's slots its program draws at a temperature
+        (`rt.engine.dispatch`'s `hot`): 0 says the round's program takes the argmax."""
+        return int(np.count_nonzero(self._temps[decode_slots]))
+
     def _readback(self, x) -> np.ndarray:
         """A program's result on the host: the dispatch's one device->host pull,
         in the two parts a trace has to tell apart. `.wait` ends when the host
@@ -2140,7 +2165,8 @@ class DecodeEngine:
         host_rows = [i for i in decode_slots
                      if slots[i].active and _host_drawn(slots[i].params, slots[i].constraint)]
         with xprof.span("rt.engine.dispatch", steps=1, slots=len(decode_slots),
-                        rows=int(self._lens[decode_slots].sum())):
+                        rows=int(self._lens[decode_slots].sum()),
+                        hot=self._hot(decode_slots)):
             with xprof.span("rt.engine.dispatch.args"):
                 lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
             with xprof.span("rt.engine.dispatch.call"):
@@ -2195,10 +2221,14 @@ class DecodeEngine:
 
     def _multi_round(self, decode_slots: List[int], n: int):
         """One multi-token dispatch + host-side emission with rollback for
-        slots that stop early (stop_token): their device lens/last_token are
-        corrected back to what was actually consumed."""
+        slots that stop early (stop_token, drawn or greedy): their device
+        lens/last_token are corrected back to what was actually consumed. The
+        program draws every step's token itself (`_decode_multi`), at the slots'
+        temperatures; the sampler's key has then moved on by n steps whatever
+        was consumed."""
         with xprof.span("rt.engine.dispatch", steps=n, slots=len(decode_slots),
-                        rows=int(self._lens[decode_slots].sum())):
+                        rows=int(self._lens[decode_slots].sum()),
+                        hot=self._hot(decode_slots)):
             with xprof.span("rt.engine.dispatch.args"):
                 lora, adapter_ids, last_token, lens, gate = self._step_args(decode_slots)
             with xprof.span("rt.engine.dispatch.call"):
@@ -2207,16 +2237,18 @@ class DecodeEngine:
                     lambda: jax.jit(named(f"rt_decode_multi_n{n}", self._decode_multi, n=n),
                                     donate_argnums=(4,)),
                 )
-                toks_dev, self._caches, _, *stats = decode_multi(
-                    self.params, lora, adapter_ids, last_token, self._caches, lens, gate)
+                toks_dev, self._caches, _, self._sample_key, *stats = decode_multi(
+                    self.params, lora, adapter_ids, last_token, self._caches, lens, gate,
+                    self._temps_dev, self._sample_key)
                 self._note_stats(stats)
         # The chunk's ONE device->host pull: n tokens x B slots per readback
         # (the whole point of multi-step decode).
         toks = self._readback(toks_dev)
-        # Nothing is drawn on the host here (the program took the argmax): the
+        # Nothing is drawn on the host here (the program drew every step): the
         # round's `rt.engine.sample` is emission alone, and says so.
         with xprof.span("rt.engine.sample", slots=len(decode_slots)), \
                 xprof.span("rt.engine.sample.emit"):
+            emitted = 0
             for i in decode_slots:
                 s = self._sched.slots[i]
                 self._lens[i] += n  # device wrote n kv rows for this slot
@@ -2232,8 +2264,10 @@ class DecodeEngine:
                     s.history.append(token)
                     self._last_token[i] = token
                     self._emit(i, token)
+                emitted += consumed
                 if consumed < n:
                     # Early stop: rows past the last consumed token are invisible
                     # once lens rolls back (kv_mask <= lens) and get overwritten
                     # by the slot's next occupant.
                     self._lens[i] = s.host_len
+            self._rows_sampled["device"] += emitted

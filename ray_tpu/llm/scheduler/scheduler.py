@@ -79,6 +79,17 @@ class Slot:
         self.history: List[int] = []
 
 
+def _host_drawn(sampling, constraint) -> bool:
+    """Whether a slot's decode rounds draw its row on the host, from what the slot
+    carries: a guided slot's mask is a host automaton's `[V]` row a step, and a
+    top-k filter at a temperature is a sort of the row, which the decode programs'
+    sampler does not hold (at temperature 0 the host's draw ignores `top_k`, and so
+    does the device). The engine's rounds and the plan's steps both ask here: such a
+    slot's token cannot feed the next step inside a program, so it holds a plan to
+    one step (`Scheduler._choose_multi_step`)."""
+    return constraint is not None or (sampling.temperature > 0 and sampling.top_k > 0)
+
+
 class Request:
     """One admitted unit of work, from submit() to slot activation.
 
@@ -172,8 +183,9 @@ class Plan:
 # `Scheduler._decide_steps` tests them (the first that holds is the plan's
 # `limit`; docs/scheduler.md): multi-step switched off; no slot decoding; a
 # prefill chunk (or attach) in this plan; a speculative phase; a request
-# admitted whose chunks are not in this plan; a non-empty queue; a slot that
-# samples or is guided; a slot with fewer than `steps_max` tokens left; none.
+# admitted whose chunks are not in this plan; a non-empty queue; a slot whose
+# row the host draws (`_host_drawn`: guided, or a top-k filter at a temperature);
+# a slot with fewer than `steps_max` tokens left; none.
 LIMITS = ("off", "no_decode", "chunk", "spec", "prefilling", "queue",
           "sampling", "tail", "none")
 
@@ -691,15 +703,15 @@ class Scheduler:
 
     def _choose_multi_step(self, decode_slots: List[int]):
         """Tokens per decode dispatch, and why not `multi_step` of them: >1
-        only when every active slot is greedy (on-device argmax is exact
-        then), capped at the smallest remaining budget and power-of-two
-        bucketed to bound the jit cache."""
-        if any(self.slots[i].params.temperature > 0
-               or self.slots[i].constraint is not None
+        only when the program draws every slot's token itself (the argmax at
+        temperature 0, the device's sampler at a plain temperature), capped
+        at the smallest remaining budget and power-of-two bucketed to bound
+        the jit cache."""
+        if any(_host_drawn(self.slots[i].params, self.slots[i].constraint)
                for i in decode_slots):
-            # Sampling slots need host-side sampling; GUIDED slots need the
-            # host-side constraint mask before each argmax — the on-device
-            # multi-token argmax chain can honor neither.
+            # A row the host draws (a guided slot's mask, a top-k filter at a
+            # temperature) has to come back before the slot's next step: the
+            # on-device chain of steps can feed itself neither.
             return 1, "sampling"
         remaining = min(
             self.slots[i].params.max_tokens - self.slots[i].generated

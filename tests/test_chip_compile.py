@@ -238,7 +238,7 @@ def _weight_converts(text, params):
 
 
 def _sampler_operands(slots, sharding):
-    """What `rt_decode` takes after the gate: the slots' temperatures and the sampler's key."""
+    """What `rt_decode` and `rt_decode_multi_n<n>` take after the gate: the slots' temperatures and the sampler's key."""
     return (_operand((slots,), sharding, jnp.float32), _operand((2,), sharding, jnp.uint32))
 
 
@@ -265,7 +265,8 @@ def _dense_program(program, sharding, served=True):
     step = (params, None, vec, vec, caches, vec, _operand((slots,), sharding, jnp.bool_))
     body, donated, args = {
         "rt_decode": (functools.partial(DecodeEngine._decode_sample, engine), 4, step + _sampler_operands(slots, sharding)),
-        "rt_decode_multi_n8": (functools.partial(DecodeEngine._decode_multi, engine, n=8), 4, step),
+        "rt_decode_multi_n8": (functools.partial(DecodeEngine._decode_multi, engine, n=8), 4,
+                               step + _sampler_operands(slots, sharding)),
         "rt_prefill_b128": (functools.partial(DecodeEngine._prefill_at, engine), 3,
                             (params, None, _operand((1, 128), sharding, jnp.int32), caches, i32, i32, i32, i32)),
     }[program]
@@ -412,14 +413,39 @@ def test_the_decode_program_ends_in_a_sampler_that_sorts_nothing_for_v5e(one_chi
     assert (out[0].shape, out[0].dtype, out[1].shape, out[1].dtype) == ((slots,), jnp.int32, (slots, cfg.vocab_size), jnp.float32)
 
 
-@pytest.mark.parametrize("program", ["rt_decode", "rt_spec_verify_k4", "rt_prefill_b16", "rt_prefill_b128",
-                                     "prefill_detached_b16", "draft_propose"])
+def test_the_eight_step_program_draws_every_steps_token_whatever_the_temperatures_for_v5e(one_chip):
+    """`rt_decode_multi_n8` at the chat cell's widths (12 slots, 92544 tokens of vocabulary, two
+    layers), the one program a plan of eight steps runs whether its slots are greedy or at a
+    temperature: the temperatures and the sampler's key are operands (`f32[12]`, `u32[2]`), so
+    nothing about them is in the lowering; the scan's body holds the sampler's noise under
+    `sample` and no control flow of the sampler's: no `cond` a step (a conditional, or a loop
+    over the rows, in the scan's body cost the step 0.55 to 0.95 ms on the chip in either
+    branch: PERF.md §6, PR 44) and no `sort`; and what comes back is `[8, 12]` int32 ids, the
+    caches, the lengths and the next key: no `[B, V]` float32 leaves the program."""
+    compiled, _, caches, cfg, slots = _dense_program("rt_decode_multi_n8", one_chip)
+    text = compiled.as_text()
+    sampled = [line for line in text.splitlines() if re.search(r'op_name="jit\([^"]*/while/body/(?:closed_call/)?sample/', line)]
+    assert any("random_bits" in line or "threefry" in line for line in sampled)
+    assert "conditional(" not in text and not re.search(r"\bsort\(", text)
+    entry = next(line for line in text.splitlines() if line.startswith("ENTRY "))
+    assert re.search(r"temps[\w.]*: f32\[%d\]" % slots, entry) and re.search(r"key[\w.]*: u32\[2\]", entry), entry[-400:]
+    toks, held, lens, key = compiled.out_info
+    assert (toks.shape, toks.dtype, lens.shape, key.shape, key.dtype) == ((8, slots), jnp.int32, (slots,), (2,), jnp.uint32)
+    results = [(tuple(a.shape), a.dtype) for a in jax.tree_util.tree_leaves(compiled.out_info)]
+    assert len(results) == 3 + len(jax.tree_util.tree_leaves(caches))
+    assert not [r for r in results if r[0][-1:] == (cfg.vocab_size,)], results
+    root = text[text.index("ENTRY "):]
+    assert f"f32[{slots},{cfg.vocab_size}]" not in next(line for line in root.splitlines() if " ROOT " in line)
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_spec_verify_k4", "rt_prefill_b16",
+                                     "rt_prefill_b128", "prefill_detached_b16", "draft_propose"])
 def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
     """The TP=4 engine's programs over the dense block at InternLM2-1.8B's widths (cut to two
     layers), params and slabs split over `tp` as `llm/tp.py` splits them, each traced as the
-    engine traces it: decode and verify under the mesh (`_engine.py:_traced_on`), where the
-    kernel runs inside a `shard_map` over the KV heads, two calls and the row-parallel
-    all-reduces; a prefill chunk (the smallest bucket: 16 tokens x 16 heads), a detached prefill
+    engine traces it: decode, the eight-step scan with the sampler in its body and verify under
+    the mesh (`_engine.py:_traced_on`), where the kernel runs inside a `shard_map` over the KV
+    heads, two calls and the row-parallel all-reduces; a prefill chunk (the smallest bucket: 16 tokens x 16 heads), a detached prefill
     and the draft's own steps outside any mesh, where a `pallas_call` cannot be lowered
     (`Mosaic kernels cannot be automatically partitioned`): those hold no kernel and compile."""
     import dataclasses
@@ -453,6 +479,8 @@ def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
     body, args = {
         "rt_decode": (functools.partial(DecodeEngine._decode_sample, engine),
                       (params, None, vec, vec, caches, vec, gate) + _sampler_operands(slots, whole)),
+        "rt_decode_multi_n8": (functools.partial(DecodeEngine._decode_multi, engine, n=8),
+                               (params, None, vec, vec, caches, vec, gate) + _sampler_operands(slots, whole)),
         "rt_spec_verify_k4": (functools.partial(DecodeEngine._spec_verify_batched, engine),
                               (params, None, vec, _operand((slots, 5), whole, jnp.int32), caches, vec, gate,
                                _operand((slots, 5, cfg.vocab_size), whole, jnp.float32))),
@@ -467,7 +495,7 @@ def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
     }[program]
     text = jax.jit(body).lower(*args).compile().as_text()
     kernels = len(re.findall(r"%cached_attn(\.\d+)? = ", text))
-    if program in ("rt_decode", "rt_spec_verify_k4"):
+    if program in ("rt_decode", "rt_decode_multi_n8", "rt_spec_verify_k4"):
         assert kernels == cfg.n_layers and " all-reduce" in text
         assert f"bf16[{slots},{T},{cfg.n_kv_heads // 4},{cfg.head_dim}]" in text  # a device's heads of the slab
     else:

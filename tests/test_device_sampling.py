@@ -1,8 +1,9 @@
-"""The single decode step samples on the device (PERF.md §6, PR 37): `rt_decode` ends in a
+"""The decode programs sample on the device (PERF.md §6, PR 37 and PR 44): `rt_decode` ends in a
 sampler (`_engine.py:_sample_device`), a round pulls `[B]` token ids, and the logits leave the
 device only for the rows the host has to draw (`_host_drawn`: a guided slot, a top-k filter at
-a temperature). Engines at test sizes on the CPU; what the sampler compiles to for the chip is
-`tests/test_chip_compile.py`'s."""
+a temperature). The multi-step programs draw each step's token as it does (`_sample_device_flat`), so
+a plan runs its eight steps at a plain temperature too and pulls `[n, B]` ids. Engines at test
+sizes on the CPU; what the sampler compiles to for the chip is `tests/test_chip_compile.py`'s."""
 
 import re
 import threading
@@ -209,3 +210,105 @@ def test_a_greedy_row_is_the_argmax_whatever_its_neighbours_temperatures(dense):
             engine.shutdown()
 
     assert greedy_beside() == greedy_beside(temperature=1.1)
+
+
+def _hot_run(dense, spans, requests, *, multi_step, num_slots=2, seed=5):
+    """The requests' ids through a fresh seeded engine, with its stats, its rounds' `rt.engine.dispatch`
+    attributes and its pulls' bytes."""
+    cfg, params = dense
+    engine = DecodeEngine(cfg, params, num_slots=num_slots, max_seq=64, multi_step=multi_step, prefix_cache=False, seed=seed)
+    del spans[:]
+    try:
+        out, stats = _run(engine, requests), engine.scheduler_stats()
+        rounds = [a for n, a in spans if n == "rt.engine.dispatch"]
+        return out, stats, rounds, [a["bytes"] for n, a in spans if n == "rt.engine.readback"]
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("neighbour", [False, True], ids=["alone", "beside a greedy slot"])
+def test_eight_steps_a_round_at_a_temperature_emit_the_single_step_paths_ids(dense, spans, neighbour):
+    """One seed, one request at T = 0.7 with no stop token (alone, then with a greedy request on
+    the slot beside it): with `multi_step=8` its plans run eight steps in one program, which draws
+    each step's token itself and consumes the key as eight single-step rounds do, so every id is
+    the `multi_step=1` engine's. The rounds say `hot=1`, pull `[n, B]` int32 and never the logits,
+    and count their rows as the device's."""
+    cfg, _ = dense
+    requests = [([5, 9, 17, 3], SamplingParams(max_tokens=30, temperature=0.7), None)]
+    if neighbour:
+        requests.append(([8, 2, 44, 7, 19], SamplingParams(max_tokens=21), None))
+    single, _, one_step, _ = _hot_run(dense, spans, requests, multi_step=1)
+    multi, stats, rounds, pulls = _hot_run(dense, spans, requests, multi_step=8)
+    assert multi == single and [len(multi[i]) for i in multi] == [30, 21][:len(requests)]
+    assert {r["steps"] for r in one_step} == {1} and {r["hot"] for r in one_step} <= {0, 1}
+    steps = [r["steps"] for r in rounds]
+    assert steps.count(8) >= 2 and len(rounds) < len(one_step) / 2, steps
+    assert all(r["hot"] == 1 for r in rounds if r["steps"] == 8 and r["slots"] == len(requests)), rounds
+    # (d) a round of n steps pulls 4 x n x B bytes; a first token its one row; nothing pulls `[B, V]`
+    row, B = 4 * cfg.vocab_size, 2
+    assert sorted(set(pulls) - {row}) == sorted({4 * n * B for n in steps}) and 4 * B * cfg.vocab_size not in pulls
+    assert pulls.count(row) == len(requests)
+    drawn = sum(len(multi[i]) - 1 for i in multi)
+    assert (stats["rows_sampled_device"], stats["rows_sampled_host"]) == (drawn, 0)
+    assert stats["plans"]["by_limit"]["sampling"]["iterations"] == 0 < stats["plans"]["by_limit"]["none"]["iterations"]
+
+
+def test_a_multi_step_programs_results_are_ids_and_the_next_key_and_no_logits(dense):
+    """`rt_decode_multi_n8` as the engine builds it, one program whatever the temperatures it is
+    given (they are an argument, as the key is): `[8, B]` int32 ids, the caches, the lengths, the
+    sampler's next key, and nothing of `[B, V]`. Greedy rows leave the key where it was; a round
+    with a row at a temperature moves it, and that row alone may part from the argmax."""
+    cfg, params = dense
+    engine = DecodeEngine(cfg, params, num_slots=2, max_seq=64, multi_step=8, prefix_cache=False, decode_loop=False)
+    try:
+        multi = jax.jit(lambda *a: engine._decode_multi(*a, n=8))
+        vec, key = jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(7)
+
+        def call(temps):
+            caches = engine._block.init_caches(cfg, 2, 64)
+            return multi(engine.params, None, vec, jnp.asarray([3, 4], jnp.int32), caches, jnp.asarray([1, 2], jnp.int32),
+                         jnp.ones((2,), bool), jnp.asarray(temps, jnp.float32), key)
+
+        greedy, hot = call([0.0, 0.0]), call([0.0, 5.0])
+        assert multi._cache_size() == 1  # the temperatures are data: no second program
+        # one scan over the step, and in its body no control flow: no `cond` a step, no loop over the rows
+        caches = engine._block.init_caches(cfg, 2, 64)
+        (scan,) = [e for e in jax.make_jaxpr(lambda *a: engine._decode_multi(*a, n=8))(
+            engine.params, None, vec, vec, caches, vec, jnp.ones((2,), bool), jnp.zeros((2,), jnp.float32), key).eqns
+            if e.primitive.name in ("scan", "while", "cond")]
+        body = str(scan.params["jaxpr"])
+        assert scan.primitive.name == "scan" and "random_bits" in body
+        assert not re.search(r"\b(cond|while|scan)\[", body)
+        for toks, _, lens, carried, *_ in (greedy, hot):
+            assert (toks.shape, toks.dtype, lens.tolist()) == ((8, 2), jnp.int32, [9, 10])
+            assert (carried.shape, carried.dtype) == (key.shape, key.dtype)
+        assert not any(getattr(x, "shape", ())[-1:] == (cfg.vocab_size,) for x in jax.tree_util.tree_leaves(hot))
+        assert np.array_equal(np.asarray(greedy[3]), np.asarray(key)) and not np.array_equal(np.asarray(hot[3]), np.asarray(key))
+        assert np.array_equal(np.asarray(greedy[0])[:, 0], np.asarray(hot[0])[:, 0])  # the greedy row beside it: its argmax
+        assert not np.array_equal(np.asarray(greedy[0])[:, 1], np.asarray(hot[0])[:, 1])
+    finally:
+        engine.shutdown()
+
+
+def test_a_drawn_stop_token_inside_an_eight_step_round_ends_the_slot_there(dense, spans):
+    """The id a seeded request at T = 0.7 draws at the fourth step of its first eight-step round,
+    given to the same request on the same seed as its stop token: the slot emits up to it and
+    ends, the rows the program wrote past it are rolled back (`lens` is what the slot consumed,
+    not eight more), and the slot's next occupant emits what it emits on a cold engine."""
+    cfg, params = dense
+    prompt, later = [5, 9, 17, 3], ([8, 2, 44, 7, 19], SamplingParams(max_tokens=12), None)
+    free, _, _, _ = _hot_run(dense, spans, [(prompt, SamplingParams(max_tokens=30, temperature=0.7), None)],
+                             multi_step=8, num_slots=1)
+    at = next(p for p in range(2, 8) if free[0][p] not in free[0][:p])  # inside the first round (ids 1 to 8), not its last
+    cold, _, _, _ = _hot_run(dense, spans, [later], multi_step=8, num_slots=1)
+    engine = DecodeEngine(cfg, params, num_slots=1, max_seq=64, multi_step=8, prefix_cache=False, seed=5)
+    del spans[:]
+    try:
+        stopped = _run(engine, [(prompt, SamplingParams(max_tokens=30, temperature=0.7, stop_token_id=free[0][at]), None)])
+        rounds = [a for n, a in spans if n == "rt.engine.dispatch"]
+        assert stopped[0] == free[0][:at + 1] and [(r["steps"], r["hot"]) for r in rounds] == [(8, 1)]
+        assert not engine._sched.slots[0].active and engine._lens[0] == len(prompt) + at  # not + 8
+        assert engine.scheduler_stats()["rows_sampled_device"] == at  # what was emitted, not the eight steps
+        assert _run(engine, [later]) == cold
+    finally:
+        engine.shutdown()

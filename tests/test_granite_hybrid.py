@@ -170,7 +170,8 @@ def test_a_gated_off_slot_keeps_its_state_and_rows_bit_for_bit(model, engine, pr
         assert counts.tolist() == [0, 0, 0, 2]
     else:
         multi = jax.jit(lambda *a: engine._decode_multi(*a, n=4))
-        _, after, _, counts = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate)
+        _, after, _, _, counts = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate,
+                                          jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(0))
         assert counts.tolist() == [0, 0, 0, 8]
     for (b, a) in zip(before, after):
         for x, y in zip(b, a):

@@ -165,7 +165,8 @@ def test_a_gated_off_slot_keeps_its_state_and_rows_bit_for_bit(model, engine, pr
         steps = 1
     else:
         multi = jax.jit(lambda *a: engine._decode_multi(*a, n=4))
-        _, after, _, experts, state = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate)
+        _, after, _, _, experts, state = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate,
+                                                  jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(0))
         steps = 4
     assert state.tolist() == [0, 0, 0, 2 * steps]
     # two slots routed to 2 experts in each of 5 expert layers a step; the gated-off slot is routed nowhere

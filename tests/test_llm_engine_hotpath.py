@@ -625,6 +625,8 @@ def test_mixed_run_yields_every_span_with_iter_covering_its_children(tmp_path, m
     # the single-step rounds say how many rows: the greedy tail's on the device, the top-k request's two on the host
     assert sorted(int(s["host_rows"]) for _, _, s in events["rt.engine.sample"] if "host_rows" in s) == [0, 1, 1]
     assert len(parts["rt.engine.sample.draw"]) == 2
+    # and no round's program drew at a temperature: the top-k request's rows are the host's (`hot` counts the program's)
+    assert [int(s["hot"]) for _, _, s in events["rt.engine.dispatch"]] == [0] * rounds
     # a request's three instants: its record's id, and its record's own times
     for name in _REQUEST_EVENTS:
         assert {str(s["rid"]) for _, _, s in instants[name]} == set(records) and len(records) == 2, name
@@ -768,7 +770,7 @@ def consumed():
             _generate(engine, prompt, max_tokens=6)
             _generate(engine, prompt, max_tokens=6)
             assert engine.last_attach["cached_tokens"] == 12
-            _generate(engine, prompt[:5], max_tokens=3, temperature=0.7)  # sampled: single steps
+            _generate(engine, prompt[:5], max_tokens=3, temperature=0.7)  # sampled: a round of two steps, drawn in the program
         finally:
             engine.shutdown()
         # the verify round and the draft model's two programs over its own caches
@@ -1052,8 +1054,9 @@ def test_the_served_trees_programs_give_the_float32_trees_logits_bit_for_bit(pro
                 return last, caches
             step = (params, None, ids, jnp.asarray([0, 7], jnp.int32), caches, jnp.asarray([0, 11], jnp.int32),
                     jnp.asarray([False, True]))
+            step += (jnp.zeros((2,), jnp.float32), jax.random.PRNGKey(0))
             if program == "rt_decode":
-                return jax.jit(engine._decode_sample)(*step, jnp.zeros((2,), jnp.float32), jax.random.PRNGKey(0))
+                return jax.jit(engine._decode_sample)(*step)
             return jax.jit(functools.partial(engine._decode_multi, n=4))(*step)
 
         served, wide = run(engine.params), run(tree)

@@ -189,7 +189,7 @@ def _limit_case(limit):
     if limit in ("none", "tail", "spec", "queue"):
         _fake_running(sched, 1, max_tokens=7 if limit == "tail" else 1000)  # 6 left: under 8, and 4 a bucket
     if limit == "sampling":
-        sched.slots[0].params = SamplingParams(max_tokens=1000, temperature=0.7)
+        sched.slots[0].params = SamplingParams(max_tokens=1000, temperature=0.7, top_k=40)  # a row the host draws
     if limit in ("no_decode", "chunk", "queue"):
         sched.submit(prompt_request())  # admitted to a free slot, or left queued where both decode
     if limit == "prefilling":
@@ -245,6 +245,42 @@ def test_a_plan_says_what_held_it_under_multi_step(limit):
     assert (stats["queue_depth"], stats["prefilling"]) == (int(limit == "queue"), len(chunks) + int(limit == "prefilling"))
     # the configuration a replica runs under, echoed for its operator (docs/scheduler.md)
     assert (stats["token_budget"], stats["wfq"], stats["tenant_quota"]) == (64, True, sched._tenant_quota)
+
+
+@pytest.mark.parametrize("case, steps, limit", [
+    ("plain temperature", 8, "none"),
+    ("tail", 4, "tail"),
+    ("guided", 1, "sampling"),
+    ("top-k at a temperature", 1, "sampling"),
+    ("top-k at temperature 0", 8, "none"),
+])
+def test_only_a_row_the_host_draws_holds_a_plan_to_one_step(case, steps, limit):
+    """A greedy slot beside one slot of each kind, nothing else to run: a plain temperature is
+    the program's to draw and plans every step `multi_step` allows (or what the slot's last
+    tokens leave: 6 left is a bucket of 4); a guided slot and a top-k filter at a temperature
+    are the host's (`_host_drawn`) and hold the plan to one step under `sampling`; at
+    temperature 0 a top-k filter filters nothing. The engine's rounds ask the same predicate."""
+    from ray_tpu.llm import SamplingParams, _engine
+    from ray_tpu.llm.scheduler import scheduler
+
+    assert _engine._host_drawn is scheduler._host_drawn
+    sched = _unit_sched(multi_step=8)
+    _fake_running(sched, 0)
+    _fake_running(sched, 1)
+    slot = sched.slots[1]
+    slot.params = {
+        "plain temperature": SamplingParams(max_tokens=1000, temperature=0.7),
+        "tail": SamplingParams(max_tokens=7, temperature=0.7),
+        "guided": SamplingParams(max_tokens=1000),
+        "top-k at a temperature": SamplingParams(max_tokens=1000, temperature=0.7, top_k=40),
+        "top-k at temperature 0": SamplingParams(max_tokens=1000, top_k=40),
+    }[case]
+    if case == "guided":
+        slot.constraint = object()  # the rule asks only whether the slot carries one
+    assert scheduler._host_drawn(slot.params, slot.constraint) == (limit == "sampling")
+    assert sched._choose_multi_step([0, 1]) == (steps, limit)
+    plan = sched.next_plan()
+    assert (plan.multi_step, plan.limit, plan.decode_slots, plan.decode_tokens) == (steps, limit, [0, 1], 2 * steps)
 
 
 # -- token-identity across scheduling shapes -------------------------------

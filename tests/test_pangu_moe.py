@@ -291,7 +291,8 @@ def test_a_gated_off_slot_keeps_its_slab_bit_for_bit(model, engine, program):
         steps = 1
     else:
         multi = jax.jit(lambda *a: engine._decode_multi(*a, n=4))
-        _, after, _, experts, latent = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate)
+        _, after, _, _, experts, latent = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate,
+                                                   jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(0))
         steps = 4
     # two slots routed to 8 experts in each of 2 expert layers a step; the gated-off slot is routed nowhere
     assert experts[0] == experts[1] == 2 * 8 * 2 * steps == int(experts[2:].sum())

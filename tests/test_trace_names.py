@@ -60,7 +60,7 @@ def engines():
     try:
         prompt = list(range(1, 14))
         generate(plain, prompt, max_tokens=6)                           # prefill b8, multi-step
-        generate(plain, prompt + [40, 41], max_tokens=3, temperature=0.8)  # attach, single steps
+        generate(plain, prompt + [40, 41], max_tokens=3, temperature=0.8)  # attach, two steps drawn in one program
         plain.prefill_detached(list(range(20, 27)))                     # detached
         plain.prefill_detached(prompt + [50, 51, 52])                   # detached suffix
         generate(spec, prompt, max_tokens=8)                            # draft prefill, propose, verify
@@ -73,7 +73,7 @@ def engines():
 
 
 def _sampler_args(engine):
-    """What `rt_decode` takes after the gate: the slots' temperatures and the sampler's key."""
+    """What `rt_decode` and `rt_decode_multi_n<n>` take after the gate: the slots' temperatures and the sampler's key."""
     return (np.zeros((engine.B,), np.float32), np.zeros((2,), np.uint32))
 
 
@@ -84,7 +84,7 @@ def _engine_programs(plain, spec):
     i32, vec = np.int32(0), np.zeros((B,), np.int32)
     step = (plain.params, None, vec, vec, plain._caches, vec, np.ones((B,), bool))
     out = [(plain._jit_decode, step + _sampler_args(plain))]
-    out += [(prog, step) for prog in plain._jit_decode_multi.values()]
+    out += [(prog, step + _sampler_args(plain)) for prog in plain._jit_decode_multi.values()]
     for key, prog in plain._jit_prefill.items():
         if isinstance(key, int):
             out.append((prog, (plain.params, None, np.zeros((1, key), np.int32), plain._caches, i32, i32, i32, i32)))
@@ -213,7 +213,7 @@ def test_the_dots3_blocks_programs_keep_the_names_and_name_their_mechanisms(dots
     engine = dots3_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step + _sampler_args(engine)) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)
@@ -273,7 +273,7 @@ def test_the_granite_hybrid_blocks_programs_keep_the_names_and_name_a_layers_par
     engine = granite_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step + _sampler_args(engine)) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)
@@ -373,7 +373,7 @@ def test_the_lfm2_blocks_programs_keep_the_names_and_name_a_layers_parts(lfm2_en
     engine = lfm2_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step + _sampler_args(engine)) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)
@@ -543,7 +543,7 @@ def test_the_pangu_moe_blocks_programs_keep_the_names_and_name_their_mechanisms(
     engine = pangu_engine
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step + _sampler_args(engine)) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     names = dict(_lowered(prog, *args) for prog, args in programs)

@@ -292,7 +292,8 @@ def test_a_gated_off_slot_keeps_its_slab_bit_for_bit_and_is_not_counted(model, e
         steps = 1
     else:
         multi = jax.jit(lambda *a: engine._decode_multi(*a, n=4))
-        _, after, _, experts, latent, mixed = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate)
+        _, after, _, _, experts, latent, mixed = multi(params, None, jnp.zeros((3,), jnp.int32), last, caches, lens, gate,
+                                                          jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(0))
         steps = 4
     # two slots routed to 4 experts in each of 2 expert layers a step, through 6 sub-layers; the gated-off slot nowhere
     assert experts[0] == experts[1] == 2 * 4 * 2 * steps == int(experts[4:].sum())
@@ -422,7 +423,7 @@ def test_the_programs_name_the_hyper_connection_beside_the_sub_layers_never_insi
 
     B, i32, vec = engine.B, np.int32(0), np.zeros((engine.B,), np.int32)
     step = (engine.params, None, vec, vec, engine._caches, vec, np.ones((B,), bool))
-    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step) for p in engine._jit_decode_multi.values()]
+    programs = [(engine._jit_decode, step + _sampler_args(engine))] + [(p, step + _sampler_args(engine)) for p in engine._jit_decode_multi.values()]
     programs += [(p, (engine.params, None, np.zeros((1, k), np.int32), engine._caches, i32, i32, i32, i32))
                  for k, p in engine._jit_prefill.items()]
     assert len(programs) >= 4
