@@ -344,13 +344,13 @@ class DecodeEngine:
             self._xprof_owner, ("decode",),
             jax.jit(named("rt_decode", self._decode_sample), donate_argnums=(4,)),
         )
-        # Multi-step decode: N greedy tokens per dispatch (argmax on device,
-        # lax.scan over decode steps) — one host round trip per CHUNK instead
-        # of per token. The win is dispatch-latency-bound regimes (small
-        # models where the step is microseconds); the role of
-        # vLLM's multi-step scheduling (num_scheduler_steps). Engaged only
-        # when every active slot samples greedily; host-side stop/max_tokens
-        # handling rolls per-slot state back after the readback.
+        # Multi-step decode: N tokens per dispatch (a lax.scan over decode steps
+        # with the device sampler inside: argmax for a greedy slot, a draw at its
+        # temperature for the others) — one host round trip per CHUNK instead of
+        # per token; the role of vLLM's multi-step scheduling (num_scheduler_steps).
+        # A plan takes single steps only while some slot's token is the host's to
+        # draw (a top-k filter at a temperature, a constraint: `scheduler.py:_host_drawn`);
+        # host-side stop/max_tokens handling rolls per-slot state back after the readback.
         if multi_step is None:
             multi_step = CONFIG.llm_multi_step
         self._multi_step = max(1, int(multi_step))

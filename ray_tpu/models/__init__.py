@@ -28,6 +28,17 @@ and, with the feature that needs them: `verify` ("speculation"), `gather_rows` a
 `attach_rows` ("prefix_cache", "pd"), `prefill_detached` and `prefill_detached_suffix`
 ("pd"); `models/llama.py` has them all. `lora` and the adapter ids are None and zeros
 for a block that does not list "lora".
+
+A new block writes its own layers, its cache and its counts, and takes the rest by public name
+(a block reads no underscore name of another: `tests/test_scaffold.py`):
+
+    models/scaffold.py   tree_from_shapes(param_shapes(cfg), key, dtype[, draw]) for `init_params`
+                         (the block says how one leaf is drawn), num_params, as_drawn (`serving_params`);
+                         slot_view, write_back, last_row for `prefill`; counts for its stats; head
+    models/latent.py     the latent sub-layer's row c_kv | k_r: attn_dims, latents, put_row, key_block
+    ops/moe.py           swiglu, and routed_experts: router, grouped experts, shared expert
+    ops/                 what reads a cache on the chip: attention.py (KV slabs, through
+                         `llama._attn_cached`), latent_attention.py, ssd.py, hyper_connection.py
 """
 
 from __future__ import annotations

@@ -40,9 +40,11 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope, yarn_inv_freq, yarn_mscale
+from ray_tpu.models import scaffold
+from ray_tpu.models.latent import attn_dims, key_block, latents, put_row, rescale, rope_rows
+from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope
 from ray_tpu.ops import latent_attention as la
-from ray_tpu.ops.moe import grouped_experts, sigmoid_routing
+from ray_tpu.ops.moe import routed_experts, sigmoid_routing, swiglu
 
 _NEG = -1e30
 
@@ -58,34 +60,6 @@ SUPPORTS = frozenset()
 
 def _is_full(cfg: ModelConfig, i: int) -> bool:
     return cfg.layer_types[i] == "full_attention"
-
-
-def attn_dims(cfg: ModelConfig, full: bool) -> dict:
-    """Heads, latent ranks, head sizes and rope base of one kind of layer."""
-    if full:
-        d = dict(heads=cfg.n_heads, q_rank=cfg.q_lora_rank, kv_rank=cfg.kv_lora_rank,
-                 nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim, v=cfg.v_head_dim,
-                 theta=cfg.rope_theta)
-        if cfg.rope_scaling:  # a scaled rotary: its table of frequencies and what it multiplies the scores by
-            scaling = dict(cfg.rope_scaling)
-            if scaling.get("type") != "yarn" or yarn_mscale(scaling, "mscale") != yarn_mscale(scaling, "mscale_all_dim"):
-                raise ValueError(f"rope_scaling {scaling}: only yarn with mscale equal to mscale_all_dim "
-                                 "(cos and sin unscaled) is written")
-            d.update(inv_freq=yarn_inv_freq(d["rope"], d["theta"], scaling),
-                     score_scale=yarn_mscale(scaling, "mscale_all_dim") ** 2)
-        return d
-    return dict(heads=cfg.swa_n_heads, q_rank=cfg.swa_q_lora_rank, kv_rank=cfg.swa_kv_lora_rank,
-                nope=cfg.swa_qk_nope_head_dim, rope=cfg.swa_qk_rope_head_dim, v=cfg.swa_v_head_dim,
-                theta=cfg.swa_rope_theta)
-
-
-def _rescale(cfg: ModelConfig, rank: int) -> float:
-    """`apply_mla_qkv_lora_rescale`: a latent is scaled by sqrt(hidden / rank) after its norm."""
-    return math.sqrt(cfg.hidden / rank) if cfg.mla_rescale else 1.0
-
-
-def num_expert_layers(cfg: ModelConfig) -> int:
-    return cfg.n_layers - cfg.first_k_dense
 
 
 # -- the tree ------------------------------------------------------------------------
@@ -111,8 +85,8 @@ def param_shapes(cfg: ModelConfig) -> dict:
         a = (L, "attn")
         out[a + ("q_a", "kernel")] = ((D, d["q_rank"]), D)
         out[a + ("q_norm", "scale")] = ((d["q_rank"],), 0)
-        fan_q = d["q_rank"] * _rescale(cfg, d["q_rank"]) ** 2
-        fan_kv = d["kv_rank"] * _rescale(cfg, d["kv_rank"]) ** 2
+        fan_q = d["q_rank"] * rescale(cfg, d["q_rank"]) ** 2
+        fan_kv = d["kv_rank"] * rescale(cfg, d["kv_rank"]) ** 2
         out[a + ("q_b", "kernel")] = ((d["q_rank"], H, d["nope"] + d["rope"]), fan_q)
         out[a + ("kv_a", "kernel")] = ((D, d["kv_rank"] + d["rope"]), D)
         out[a + ("kv_norm", "scale")] = ((d["kv_rank"],), 0)
@@ -149,57 +123,17 @@ def param_shapes(cfg: ModelConfig) -> dict:
 
 
 def num_params(cfg: ModelConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in param_shapes(cfg).values())
+    return scaffold.num_params(param_shapes(cfg))
 
 
-def serving_params(cfg: ModelConfig, params):
-    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
-    with `param_dtype` the served type."""
-    return params
+serving_params = scaffold.as_drawn
 
 
 def init_params(cfg: ModelConfig, key):
-    """The tree at seeded random weights in `cfg.param_dtype` (`tree_from_shapes`). The
+    """The tree at seeded random weights in `cfg.param_dtype` (`scaffold.tree_from_shapes`). The
     router's correction bias is drawn at a scale (0.1) that changes some of the choices
     the scores alone would make."""
-    return tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
-
-
-def tree_from_shapes(shapes: dict, key, dtype):
-    """A tree of seeded random leaves from {path tuple: (shape, fan_in)} (`param_shapes`),
-    made on the device one top-level group (a layer, the embedding, the head) a program,
-    so that layers of one kind share theirs."""
-    groups: dict = {}
-    for path, spec in shapes.items():
-        groups.setdefault(path[0], {})[path[1:]] = spec
-    tree = {}
-    for n, (name, leaves) in enumerate(groups.items()):
-        made = _init_group(jax.random.fold_in(key, n), tuple(leaves.items()), dtype)
-        for path, leaf in zip(leaves, made):
-            node = tree
-            for part in (name,) + path[:-1]:
-                node = node.setdefault(part, {})
-            if path:
-                node[path[-1]] = leaf
-            else:
-                tree[name] = leaf
-    return tree
-
-
-def _init_leaves(key, leaves: tuple, dtype):
-    out = []
-    for n, (_, (shape, fan_in)) in enumerate(leaves):
-        if fan_in == 0:
-            out.append(jnp.ones(shape, dtype))
-        else:
-            std = 0.1 if fan_in < 0 else 1.0 / math.sqrt(fan_in)
-            # large leaves are drawn in their own type: a float32 draw of an expert stack is 1 GB
-            draw = dtype if math.prod(shape) >= (1 << 24) else jnp.float32
-            out.append((jax.random.normal(jax.random.fold_in(key, n), shape, draw) * std).astype(dtype))
-    return out
-
-
-_init_group = jax.jit(_init_leaves, static_argnums=(1, 2))
+    return scaffold.tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
 
 
 # -- the cache -----------------------------------------------------------------------
@@ -247,26 +181,6 @@ def report(cfg: ModelConfig, total: tuple, window: tuple) -> dict:
 # -- projections both paths share ----------------------------------------------------
 
 
-def _rope_rows(x, positions, theta, inv_freq=None):
-    """Rotary on rows that have no head axis. x: [..., S, R]; positions: [..., S]."""
-    return _rope(x[..., None, :], positions, theta, inv_freq)[..., 0, :]
-
-
-def _latents(p, x, positions, cfg: ModelConfig, d: dict):
-    """x: [B, S, D] -> c_q [B, S, q_rank], q_nope [B, S, H, nope], q_rope (rotated)
-    [B, S, H, rope], and the row the cache keeps, c_kv | k_r (rotated) [B, S, kv_rank + rope]."""
-    c_q = _rmsnorm(_dense(x, p["q_a"]["kernel"]), p["q_norm"]["scale"], cfg.norm_eps)
-    c_q = c_q * jnp.asarray(_rescale(cfg, d["q_rank"]), c_q.dtype)
-    q = _dense(c_q, p["q_b"]["kernel"].reshape(d["q_rank"], -1))
-    q = q.reshape(x.shape[:2] + (d["heads"], d["nope"] + d["rope"]))
-    q_nope, q_rope = q[..., :d["nope"]], _rope(q[..., d["nope"]:], positions, d["theta"], d.get("inv_freq"))
-    kv = _dense(x, p["kv_a"]["kernel"])
-    c_kv = _rmsnorm(kv[..., :d["kv_rank"]], p["kv_norm"]["scale"], cfg.norm_eps)
-    c_kv = c_kv * jnp.asarray(_rescale(cfg, d["kv_rank"]), c_kv.dtype)
-    k_r = _rope_rows(kv[..., d["kv_rank"]:], positions, d["theta"], d.get("inv_freq"))
-    return c_q, q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
-
-
 def _index_terms(p, x, c_q, positions, cfg: ModelConfig, theta):
     """The indexer's queries [B, S, Hi, Di], key [B, S, Di] and head weights [B, S, Hi]."""
     Hi, Di, R = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
@@ -276,7 +190,7 @@ def _index_terms(p, x, c_q, positions, cfg: ModelConfig, theta):
     mean = jnp.mean(k, axis=-1, keepdims=True)
     k = (k - mean) * jax.lax.rsqrt(jnp.mean((k - mean) ** 2, axis=-1, keepdims=True) + cfg.norm_eps)
     k = (k * p["k_norm"]["scale"].astype(jnp.float32) + p["k_norm"]["bias"].astype(jnp.float32)).astype(x.dtype)
-    k = jnp.concatenate([_rope_rows(k[..., :R], positions, theta), k[..., R:]], axis=-1)
+    k = jnp.concatenate([rope_rows(k[..., :R], positions, theta), k[..., R:]], axis=-1)
     w = _dense(x, p["w"]["kernel"]).astype(jnp.float32) * (Hi ** -0.5 * Di ** -0.5)
     return q, k, w
 
@@ -332,27 +246,14 @@ def _top_k_mask(scores, k: int):
 # -- a prefill chunk of one slot -----------------------------------------------------
 
 
-def _key_block(rows: int, queries: int) -> int:
-    """Keys a chunk of `queries` attends at a time: a power of two from 1024 down that
-    divides the cache's rows at least four times (so that small caches, as the tests' are,
-    still take several blocks), halved while a block of scores (heads x queries x keys,
-    float32) would pass a million a head."""
-    kb = 1024
-    while kb > 16 and (rows % kb or 4 * kb > rows):
-        kb //= 2
-    while kb > 128 and queries * kb > (1 << 20):
-        kb //= 2
-    return math.gcd(rows, kb)
-
-
 def _full_attn_prefill(p, x, cache, offset, cfg: ModelConfig):
     """x: [1, S, D] at positions offset + [0, S); cache: (lat [1, T, W], kidx [1, T, Di]).
     Writes the chunk's rows, then attends over rows [0, offset + S) in blocks."""
     d = attn_dims(cfg, True)
     S, (lat, kidx) = x.shape[1], cache
-    T, kb = lat.shape[1], _key_block(lat.shape[1], S)
+    T, kb = lat.shape[1], key_block(lat.shape[1], S)
     positions = offset + jnp.arange(S)[None, :]
-    c_q, q_nope, q_rope, row = _latents(p, x, positions, cfg, d)
+    c_q, q_nope, q_rope, row = latents(p, x, positions, cfg, d)
     with jax.named_scope("indexer"):
         q_i, k_i, w_i = _index_terms(p["indexer"], x, c_q, positions, cfg, d["theta"])
     lat = jax.lax.dynamic_update_slice(lat, row.astype(lat.dtype), (0, offset, 0))
@@ -386,7 +287,7 @@ def _window_attn_prefill(p, x, cache, offset, n_valid, cfg: ModelConfig):
     d = attn_dims(cfg, False)
     S, (ring,), W = x.shape[1], cache, cfg.sliding_window
     positions = offset + jnp.arange(S)[None, :]
-    _, q_nope, q_rope, row = _latents(p, x, positions, cfg, d)
+    _, q_nope, q_rope, row = latents(p, x, positions, cfg, d)
     row = row.astype(ring.dtype)
     with jax.named_scope("window"):
         before = offset - (W - 1) + jnp.arange(W - 1)
@@ -412,16 +313,6 @@ def _window_attn_prefill(p, x, cache, offset, n_valid, cfg: ModelConfig):
 # -- a decode step of every slot -----------------------------------------------------
 
 
-def _put_row(cache, row, at, gate):
-    """cache: [B, rows, W]; row: [B, 1, W]; slot b's row lands at `at[b]` where `gate[b]`."""
-
-    def put(slot_cache, slot_row, a, g):
-        cur = jax.lax.dynamic_slice(slot_cache, (a, 0), slot_row.shape)
-        return jax.lax.dynamic_update_slice(slot_cache, jnp.where(g, slot_row, cur), (a, 0))
-
-    return jax.vmap(put)(cache, row.astype(cache.dtype), at, gate)
-
-
 def _absorbed(p, q_nope, q_rope, rows, valid, d: dict):
     """Attention over latent rows with W_kvb folded into the query and the output.
     q_*: [B, H, .]; rows: [B, K, kv_rank + rope]; valid: [B, K] -> [B, 1, H, v]."""
@@ -445,10 +336,10 @@ def _full_attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
     lat, kidx = cache
     T = lat.shape[1]
     positions = lens[:, None]
-    c_q, q_nope, q_rope, row = _latents(p, x, positions, cfg, d)
+    c_q, q_nope, q_rope, row = latents(p, x, positions, cfg, d)
     with jax.named_scope("indexer"):
         q_i, k_i, w_i = _index_terms(p["indexer"], x, c_q, positions, cfg, d["theta"])
-    lat, kidx = _put_row(lat, row, lens, gate), _put_row(kidx, k_i, lens, gate)
+    lat, kidx = put_row(lat, row, lens, gate), put_row(kidx, k_i, lens, gate)
     with jax.named_scope("indexer"):
         s = jnp.einsum("bhd,btd->bht", q_i[:, 0], kidx.astype(q_i.dtype), preferred_element_type=jnp.float32)
         scores = jnp.einsum("bht,bh->bt", jax.nn.relu(s), w_i[:, 0])
@@ -465,34 +356,15 @@ def _window_attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
     d = attn_dims(cfg, False)
     (ring,), W = cache, cfg.sliding_window
     positions = lens[:, None]
-    _, q_nope, q_rope, row = _latents(p, x, positions, cfg, d)
+    _, q_nope, q_rope, row = latents(p, x, positions, cfg, d)
     with jax.named_scope("window"):
-        ring = _put_row(ring, row, lens % W, gate)
+        ring = put_row(ring, row, lens % W, gate)
         held = positions - (positions - jnp.arange(W)[None, :]) % W  # the position in each ring row
         o = _absorbed(p, q_nope[:, 0], q_rope[:, 0], ring, held >= 0, d)
     return _gated_out(p, x, o, d), (ring,)
 
 
-# -- the feed-forward sub-layer ------------------------------------------------------
-
-
-def _swiglu(p, x):
-    return _dense(jax.nn.silu(_dense(x, p["gate"]["kernel"])) * _dense(x, p["up"]["kernel"]), p["down"]["kernel"])
-
-
-def _expert_layer(p, x, valid, cfg: ModelConfig):
-    """x: [B, S, D]; valid: [B, S]. The held experts' part of the routed sum plus the
-    shared expert; counts [E] of valid pairs a held expert took."""
-    flat = x.reshape(-1, x.shape[-1])
-    with jax.named_scope("router"):
-        ids, weights = sigmoid_routing(flat, p["router"]["kernel"], p["router"]["bias"],
-                                       cfg.experts_per_token, cfg.routed_scaling_factor)
-    with jax.named_scope("experts"):
-        y, counts = grouped_experts(flat, ids, weights, valid.reshape(-1), p["experts"]["gate"],
-                                    p["experts"]["up"], p["experts"]["down"], first=cfg.first_expert)
-    with jax.named_scope("shared_expert"):
-        y = y + _swiglu(p["shared"], flat)
-    return y.reshape(x.shape), counts
+# -- the layers round the attention ----------------------------------------------------
 
 
 def _forward(params, cfg: ModelConfig, tokens, valid, attend):
@@ -515,19 +387,15 @@ def _forward(params, cfg: ModelConfig, tokens, valid, attend):
                 normed = _rmsnorm(x, layer["mlp_norm"]["scale"], cfg.norm_eps)
             with jax.named_scope("mlp"):
                 if i < cfg.first_k_dense:
-                    x = x + _swiglu(layer["mlp"], normed)
+                    x = x + swiglu(layer["mlp"], normed)
                 else:
-                    y, c = _expert_layer(layer["mlp"], normed, valid, cfg)
+                    y, c = routed_experts(layer["mlp"], normed, valid, cfg.experts_per_token,
+                                          cfg.routed_scaling_factor, first=cfg.first_expert)
                     x, counts = x + y, counts + c
     with jax.named_scope("final_norm"):
         x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    routed = jnp.sum(valid, dtype=jnp.int32) * (cfg.experts_per_token * num_expert_layers(cfg))
+    routed = jnp.sum(valid, dtype=jnp.int32) * (cfg.experts_per_token * scaffold.num_expert_layers(cfg))
     return x, caches, jnp.concatenate([routed[None], jnp.sum(counts)[None], counts])
-
-
-def _head(params, x):
-    with jax.named_scope("lm_head"):
-        return _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
 
 
 def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, lora=None, adapter_id=None):
@@ -536,7 +404,7 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     `slot`. Returns (logits of the prompt's last token if it is in this chunk, caches, stats)."""
     S = tokens.shape[1]
     n_valid = jnp.minimum(S, total_len - offset)
-    view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
+    view = scaffold.slot_view(caches, slot)
 
     def attend(i, p, normed):
         if _is_full(cfg, i):
@@ -544,10 +412,8 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
         return _window_attn_prefill(p, normed, view[i], offset, n_valid, cfg)
 
     x, new, stats = _forward(params, cfg, tokens, jnp.arange(S)[None, :] < n_valid, attend)
-    caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
-                    for a, b in zip(c, n)) for c, n in zip(caches, new)]
-    last = jax.lax.dynamic_slice_in_dim(x[0], jnp.clip(total_len - 1 - offset, 0, S - 1), 1, axis=0)
-    return _head(params, last)[0], caches, (stats,)
+    caches = scaffold.write_back(caches, new, slot)
+    return scaffold.head(params, scaffold.last_row(x, offset, total_len))[0], caches, (stats,)
 
 
 def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, adapter_ids=None):
@@ -560,7 +426,7 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
         return _window_attn_decode(p, normed, caches[i], lens, gate, cfg)
 
     x, new, stats = _forward(params, cfg, last_token[:, None], gate[:, None], attend)
-    return _head(params, x[:, 0]), new, (stats,)
+    return scaffold.head(params, x[:, 0]), new, (stats,)
 
 
 # -- the plain reference -------------------------------------------------------------
@@ -592,11 +458,11 @@ def forward_plain(params, cfg: ModelConfig, tokens, experts=None):
             layer, full = params[f"layer_{i}"], _is_full(cfg, i)
             p, d = layer["attn"], attn_dims(cfg, full)
             h = norm(x, layer["attn_norm"]["scale"])
-            c_q = norm(h @ f32(p["q_a"]["kernel"]), p["q_norm"]["scale"]) * _rescale(cfg, d["q_rank"])
+            c_q = norm(h @ f32(p["q_a"]["kernel"]), p["q_norm"]["scale"]) * rescale(cfg, d["q_rank"])
             q = jnp.einsum("sr,rhd->shd", c_q, f32(p["q_b"]["kernel"]))
             q = jnp.concatenate([q[..., :d["nope"]], rope(q[..., d["nope"]:], d["theta"])], axis=-1)
             kv = h @ f32(p["kv_a"]["kernel"])
-            c_kv = norm(kv[:, :d["kv_rank"]], p["kv_norm"]["scale"]) * _rescale(cfg, d["kv_rank"])
+            c_kv = norm(kv[:, :d["kv_rank"]], p["kv_norm"]["scale"]) * rescale(cfg, d["kv_rank"])
             k_r = rope(kv[:, None, d["kv_rank"]:], d["theta"])
             kvx = jnp.einsum("sc,chd->shd", c_kv, f32(p["kv_b"]["kernel"]))
             k = jnp.concatenate([kvx[..., :d["nope"]], jnp.broadcast_to(k_r, (S, d["heads"], d["rope"]))], axis=-1)

@@ -40,7 +40,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, scaffold
 from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm
 from ray_tpu.ops.ssd import ssd_chunked, ssd_step
 
@@ -111,7 +111,7 @@ def param_shapes(cfg: ModelConfig) -> dict:
 
 
 def num_params(cfg: ModelConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in param_shapes(cfg).values())
+    return scaffold.num_params(param_shapes(cfg))
 
 
 # The embedding's standard deviation. The config gives none. The head is the embedding again,
@@ -135,45 +135,16 @@ def _draw(key, shape, how, dtype):
     if how == "dt_bias":
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
         return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
-    std = EMBEDDING_STD if how == "embedding" else 1.0 / math.sqrt(how)
-    # large leaves are drawn in their own type: a float32 draw of the embedding is 0.8 GB
-    draw = dtype if math.prod(shape) >= (1 << 24) else jnp.float32
-    return (jax.random.normal(key, shape, draw) * std).astype(dtype)
+    return scaffold.normal(key, shape, EMBEDDING_STD if how == "embedding" else 1.0 / math.sqrt(how), dtype)
 
 
-def _init_leaves(key, leaves: tuple, dtype):
-    return [_draw(jax.random.fold_in(key, n), shape, how, dtype) for n, (_, (shape, how)) in enumerate(leaves)]
-
-
-_init_group = jax.jit(_init_leaves, static_argnums=(1, 2))
-
-
-def serving_params(cfg: ModelConfig, params):
-    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
-    with `param_dtype` the served type, and `A_log` and `dt_bias` stay float32, as the recurrence
-    reads them."""
-    return params
+serving_params = scaffold.as_drawn  # `A_log` and `dt_bias` stay float32, as the recurrence reads them
 
 
 def init_params(cfg: ModelConfig, key):
-    """The tree at seeded random weights in `cfg.param_dtype` (A_log and dt_bias in float32),
-    made on the device one top-level group (a layer, the embedding) a program, so that
-    layers of one kind share theirs."""
-    groups: dict = {}
-    for path, spec in param_shapes(cfg).items():
-        groups.setdefault(path[0], {})[path[1:]] = spec
-    tree = {}
-    for n, (name, leaves) in enumerate(groups.items()):
-        made = _init_group(jax.random.fold_in(key, n), tuple(leaves.items()), cfg.param_dtype)
-        for path, leaf in zip(leaves, made):
-            node = tree
-            for part in (name,) + path[:-1]:
-                node = node.setdefault(part, {})
-            if path:
-                node[path[-1]] = leaf
-            else:
-                tree[name] = leaf
-    return tree
+    """The tree at seeded random weights in `cfg.param_dtype` (A_log and dt_bias in float32):
+    `scaffold.tree_from_shapes`, each leaf by `_draw`."""
+    return scaffold.tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype, _draw)
 
 
 # -- the cache and the counts --------------------------------------------------------
@@ -323,10 +294,6 @@ def _head(params, cfg: ModelConfig, x):
         return logits / cfg.logits_scaling
 
 
-def _counts(**named):
-    return (jnp.stack([jnp.asarray(named.get(name, 0), jnp.int32) for name in COUNTS]),)
-
-
 # -- what the engine's programs call ---------------------------------------------------
 
 
@@ -336,7 +303,7 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     (logits of the prompt's last token if it is in this chunk, caches, stats)."""
     S = tokens.shape[1]
     n_valid = jnp.minimum(S, total_len - offset)
-    view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
+    view = scaffold.slot_view(caches, slot)
     positions = offset + jnp.arange(S)[None, :]
     # a query sees the rows up to its own position, the earlier chunks' and this chunk's:
     # `_attn_cached` reads that from the slot's length, `offset`
@@ -347,11 +314,10 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
         return _attention(p, normed, positions, view[i], offset[None], None, cfg)
 
     x, new = _forward(params, cfg, tokens, mix)
-    caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
-                    for a, b in zip(c, n)) for c, n in zip(caches, new)]
-    last = jax.lax.dynamic_slice_in_dim(x[0], jnp.clip(total_len - 1 - offset, 0, S - 1), 1, axis=0)
-    stats = _counts(prefill_positions=S, prefill_padding=S - n_valid, states_reset=offset == 0)
-    return _head(params, cfg, last)[0], caches, stats
+    caches = scaffold.write_back(caches, new, slot)
+    last = scaffold.last_row(x, offset, total_len)
+    stats = scaffold.counts(COUNTS, prefill_positions=S, prefill_padding=S - n_valid, states_reset=offset == 0)
+    return _head(params, cfg, last)[0], caches, (stats,)
 
 
 def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, adapter_ids=None):
@@ -365,7 +331,7 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
         return _attention(p, normed, positions, caches[i], lens, gate, cfg)
 
     x, new = _forward(params, cfg, last_token[:, None], mix)
-    return _head(params, cfg, x[:, 0]), new, _counts(decode_slot_steps=jnp.sum(gate))
+    return _head(params, cfg, x[:, 0]), new, (scaffold.counts(COUNTS, decode_slot_steps=jnp.sum(gate)),)
 
 
 # -- the plain reference -------------------------------------------------------------

@@ -42,9 +42,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, scaffold
 from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm
-from ray_tpu.ops.moe import expert_tile_rows, grouped_experts, sigmoid_routing
+from ray_tpu.ops.moe import expert_tile_rows, routed_experts, sigmoid_routing
 
 # Served by LLMServer / DecodeEngine on one device, and nothing else yet (PERF.md §7): a prefix hit
 # needs a snapshot of the convolution's inputs at a block boundary, the train step an expert layer
@@ -64,10 +64,6 @@ STATE_COUNTS = ("prefill_positions", "prefill_padding", "states_reset", "decode_
 
 def _is_conv(cfg: ModelConfig, i: int) -> bool:
     return cfg.layer_types[i] == "conv"
-
-
-def num_expert_layers(cfg: ModelConfig) -> int:
-    return cfg.n_layers - cfg.first_k_dense
 
 
 def state_bytes(cfg: ModelConfig) -> int:
@@ -121,7 +117,7 @@ def param_shapes(cfg: ModelConfig) -> dict:
 
 
 def num_params(cfg: ModelConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in param_shapes(cfg).values())
+    return scaffold.num_params(param_shapes(cfg))
 
 
 # The embedding's standard deviation: the family's initializer_range. The head is the embedding
@@ -137,44 +133,16 @@ def _draw(key, shape, how, dtype):
         return jnp.ones(shape, dtype)
     if how == "zeros":
         return jnp.zeros(shape, dtype)
-    std = EMBEDDING_STD if how == "embedding" else 1.0 / math.sqrt(how)
-    # large leaves are drawn in their own type: a float32 draw of an expert stack is 0.8 GB
-    draw = dtype if math.prod(shape) >= (1 << 24) else jnp.float32
-    return (jax.random.normal(key, shape, draw) * std).astype(dtype)
+    return scaffold.normal(key, shape, EMBEDDING_STD if how == "embedding" else 1.0 / math.sqrt(how), dtype)
 
 
-def _init_leaves(key, leaves: tuple, dtype):
-    return [_draw(jax.random.fold_in(key, n), shape, how, dtype) for n, (_, (shape, how)) in enumerate(leaves)]
-
-
-_init_group = jax.jit(_init_leaves, static_argnums=(1, 2))
-
-
-def serving_params(cfg: ModelConfig, params):
-    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
-    with `param_dtype` the served type."""
-    return params
+serving_params = scaffold.as_drawn
 
 
 def init_params(cfg: ModelConfig, key):
-    """The tree at seeded random weights in `cfg.param_dtype`, made on the device one top-level
-    group (a layer, the embedding) a program, so that layers of one kind share theirs and no
-    second copy of a layer's experts is ever alive."""
-    groups: dict = {}
-    for path, spec in param_shapes(cfg).items():
-        groups.setdefault(path[0], {})[path[1:]] = spec
-    tree = {}
-    for n, (name, leaves) in enumerate(groups.items()):
-        made = _init_group(jax.random.fold_in(key, n), tuple(leaves.items()), cfg.param_dtype)
-        for path, leaf in zip(leaves, made):
-            node = tree
-            for part in (name,) + path[:-1]:
-                node = node.setdefault(part, {})
-            if path:
-                node[path[-1]] = leaf
-            else:
-                tree[name] = leaf
-    return tree
+    """The tree at seeded random weights in `cfg.param_dtype`: `scaffold.tree_from_shapes`, each
+    leaf by `_draw`."""
+    return scaffold.tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype, _draw)
 
 
 # -- the cache and the counts --------------------------------------------------------
@@ -275,20 +243,6 @@ def _attention(p, normed, positions, cache, write_at, gate, cfg: ModelConfig):
     return out, (k, v)
 
 
-def _expert_layer(p, x, valid, cfg: ModelConfig):
-    """x: [B, S, D]; valid: [B, S]. The routed sum over all the layer's experts; counts [E] of
-    valid pairs an expert took, and the tiles the loop ran for them."""
-    flat = x.reshape(-1, x.shape[-1])
-    with jax.named_scope("router"):
-        ids, weights = sigmoid_routing(flat, p["router"]["kernel"], p["router"]["bias"], cfg.experts_per_token,
-                                       cfg.routed_scaling_factor, eps=ROUTING_EPS)
-    with jax.named_scope("experts"):
-        y, counts = grouped_experts(flat, ids, weights, valid.reshape(-1), p["experts"]["gate"],
-                                    p["experts"]["up"], p["experts"]["down"])
-    tile = expert_tile_rows(flat.shape[0] * cfg.experts_per_token, cfg.n_routed_experts)
-    return y.reshape(x.shape), counts, jnp.sum(-(-counts // tile))
-
-
 def _forward(params, cfg: ModelConfig, tokens, valid, mix, decoding: bool):
     """The layers round `mix(i, layer_params, normed) -> (out, cache_i)`: hidden states after the
     final norm, the caches, and the expert layers' counts (`EXPERT_COUNTS`, then pairs by expert)."""
@@ -311,17 +265,20 @@ def _forward(params, cfg: ModelConfig, tokens, valid, mix, decoding: bool):
                 if i < cfg.first_k_dense:
                     x = x + llama._mlp(layer["mlp"], normed)
                 else:
-                    y, c, t = _expert_layer(layer["mlp"], normed, valid, cfg)
+                    y, c = routed_experts(layer["mlp"], normed, valid, cfg.experts_per_token, cfg.routed_scaling_factor,
+                                          eps=ROUTING_EPS)
+                    # the tiles `grouped_experts`' loop ran for them
+                    t = jnp.sum(-(-c // expert_tile_rows(valid.size * cfg.experts_per_token, cfg.n_routed_experts)))
                     x, counts = x + y, counts + c
                     hit, tiles = hit + jnp.sum(c > 0, dtype=jnp.int32), tiles + t.astype(jnp.int32)
     with jax.named_scope("final_norm"):
         x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    n_moe = num_expert_layers(cfg)
+    n_moe = scaffold.num_expert_layers(cfg)
     pairs = jnp.sum(valid, dtype=jnp.int32) * (cfg.experts_per_token * n_moe)
     named = dict(pairs_routed=pairs, pairs_held=jnp.sum(counts), experts_hit=hit, tiles_run=tiles, layer_steps=n_moe)
     if decoding:
         named.update(decode_experts_hit=hit, decode_layer_steps=n_moe)
-    return x, caches, jnp.concatenate([_counts(EXPERT_COUNTS, **named), counts])
+    return x, caches, jnp.concatenate([scaffold.counts(EXPERT_COUNTS, **named), counts])
 
 
 def _head(params, cfg: ModelConfig, x):
@@ -329,11 +286,6 @@ def _head(params, cfg: ModelConfig, x):
     with jax.named_scope("lm_head"):
         return jax.lax.dot_general(x, params["embedding"].astype(x.dtype), (((x.ndim - 1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
-
-
-def _counts(names: tuple, **named):
-    """One int32 array in the order of `names`, 0 where a program counts nothing under a name."""
-    return jnp.stack([jnp.asarray(named.get(name, 0), jnp.int32) for name in names])
 
 
 # -- what the engine's programs call ---------------------------------------------------
@@ -345,7 +297,7 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     (logits of the prompt's last token if it is in this chunk, caches, stats)."""
     S = tokens.shape[1]
     n_valid = jnp.minimum(S, total_len - offset)
-    view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
+    view = scaffold.slot_view(caches, slot)
     positions = offset + jnp.arange(S)[None, :]
     # a query sees the rows up to its own position, the earlier chunks' and this chunk's:
     # `_attn_cached` reads that from the slot's length, `offset`
@@ -356,10 +308,9 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
         return _attention(p, normed, positions, view[i], offset[None], None, cfg)
 
     x, new, experts = _forward(params, cfg, tokens, jnp.arange(S)[None, :] < n_valid, mix, decoding=False)
-    caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
-                    for a, b in zip(c, n)) for c, n in zip(caches, new)]
-    last = jax.lax.dynamic_slice_in_dim(x[0], jnp.clip(total_len - 1 - offset, 0, S - 1), 1, axis=0)
-    state = _counts(STATE_COUNTS, prefill_positions=S, prefill_padding=S - n_valid, states_reset=offset == 0)
+    caches = scaffold.write_back(caches, new, slot)
+    last = scaffold.last_row(x, offset, total_len)
+    state = scaffold.counts(STATE_COUNTS, prefill_positions=S, prefill_padding=S - n_valid, states_reset=offset == 0)
     return _head(params, cfg, last)[0], caches, (experts, state)
 
 
@@ -374,7 +325,7 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
         return _attention(p, normed, positions, caches[i], lens, gate, cfg)
 
     x, new, experts = _forward(params, cfg, last_token[:, None], gate[:, None], mix, decoding=True)
-    return _head(params, cfg, x[:, 0]), new, (experts, _counts(STATE_COUNTS, decode_slot_steps=jnp.sum(gate)))
+    return _head(params, cfg, x[:, 0]), new, (experts, scaffold.counts(STATE_COUNTS, decode_slot_steps=jnp.sum(gate)))
 
 
 # -- the plain reference -------------------------------------------------------------

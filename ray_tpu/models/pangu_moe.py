@@ -25,8 +25,8 @@ Per layer, every norm an RMSNorm with its own gain:
 
 The two post-norms are the published `sandwich_norm`: this block is the one that has them.
 Every layer is of one kind, so the block takes no `layer_types` (`LAYER_TYPES`). The latent
-projections, the chunk loop's block size and the gated row write are `dots3`'s functions (the
-mathematics is the same, with no rescale of the latents: `mla_rescale` must be off).
+projections, the chunk loop's block size and the gated row write are `models/latent.py`'s (the
+row is `dots3`'s, with no rescale of the latents: `mla_rescale` must be off).
 
 The cache, one array a layer: `[slots, max_seq, 640]` in `cfg.dtype`, a token's row
 c_kv (after its norm) | k_r (after rotary) | zeros: 576 values kept in five whole rows of
@@ -45,11 +45,11 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.dots3 import (_key_block, _latents, _put_row, _swiglu, attn_dims, num_expert_layers,
-                                  tree_from_shapes)
+from ray_tpu.models import scaffold
+from ray_tpu.models.latent import attn_dims, key_block, latents, put_row
 from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm, _rope
 from ray_tpu.ops import attention, latent_attention as la
-from ray_tpu.ops.moe import grouped_experts, sigmoid_routing
+from ray_tpu.ops.moe import routed_experts, sigmoid_routing, swiglu
 
 # Served by LLMServer / DecodeEngine on one device, and nothing else yet (PERF.md §7): a
 # prefix hit would attach latent rows, a draft needs the multi-token-prediction module and a
@@ -73,10 +73,10 @@ _SPLIT = 1024
 
 
 def dims(cfg: ModelConfig) -> dict:
-    """Heads, latent ranks, head sizes and rope base (`dots3.attn_dims` of a full layer)."""
+    """Heads, latent ranks, head sizes and rope base (`latent.attn_dims`)."""
     if cfg.mla_rescale:
         raise ValueError("block 'pangu_moe' does not rescale its latents: set mla_rescale=False")
-    return attn_dims(cfg, True)
+    return attn_dims(cfg)
 
 
 def row_width(cfg: ModelConfig) -> int:
@@ -130,18 +130,15 @@ def param_shapes(cfg: ModelConfig) -> dict:
 
 
 def num_params(cfg: ModelConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in param_shapes(cfg).values())
+    return scaffold.num_params(param_shapes(cfg))
 
 
-def serving_params(cfg: ModelConfig, params):
-    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
-    with `param_dtype` the served type."""
-    return params
+serving_params = scaffold.as_drawn
 
 
 def init_params(cfg: ModelConfig, key):
-    """The tree at seeded random weights in `cfg.param_dtype` (`dots3.tree_from_shapes`)."""
-    return tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
+    """The tree at seeded random weights in `cfg.param_dtype` (`scaffold.tree_from_shapes`)."""
+    return scaffold.tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
 
 
 # -- the cache and the counts --------------------------------------------------------
@@ -159,12 +156,13 @@ def init_stats(cfg: ModelConfig) -> tuple:
     return (jnp.zeros((2 + cfg.n_routed_experts,), jnp.int32), jnp.zeros((2 * len(LATENT_COUNTS),), jnp.int32))
 
 
-def _split(rows):
+def split(rows):
+    """A count of rows as (thousand-and-twenty-fours, remainder): `LATENT_COUNTS`."""
     return jnp.stack([rows // _SPLIT, rows % _SPLIT]).astype(jnp.int32)
 
 
 def _joined(counts) -> dict:
-    """{name: rows} of an array of `LATENT_COUNTS` as `_split` pairs."""
+    """{name: rows} of an array of `LATENT_COUNTS` as `split` pairs."""
     return {name: int(counts[2 * j]) * _SPLIT + int(counts[2 * j + 1]) for j, name in enumerate(LATENT_COUNTS)}
 
 
@@ -195,15 +193,15 @@ def _out(p, o):
     return _dense(o.reshape(o.shape[:2] + (-1,)), p["o"]["kernel"].reshape(-1, p["o"]["kernel"].shape[-1]))
 
 
-def _attn_prefill(p, x, cache, offset, cfg: ModelConfig):
+def attn_prefill(p, x, cache, offset, cfg: ModelConfig):
     """x: [1, S, D] at positions offset + [0, S); cache: (lat [1, T, W],). Writes the chunk's
     rows, then attends over rows [0, offset + S) in blocks of keys, every one under the
     causal mask alone (`ops/latent_attention.py:latent_chunk_attention` with no selection)."""
     d = dims(cfg)
     S, (lat,) = x.shape[1], cache
-    kb = _key_block(lat.shape[1], S)
+    kb = key_block(lat.shape[1], S)
     positions = offset + jnp.arange(S)[None, :]
-    _, q_nope, q_rope, row = _latents(p, x, positions, cfg, d)
+    _, q_nope, q_rope, row = latents(p, x, positions, cfg, d)
     lat = jax.lax.dynamic_update_slice(lat, _pad_row(row, lat.shape[-1]).astype(lat.dtype), (0, offset, 0))
     with jax.named_scope("latent"):
         scale = d.get("score_scale", 1.0) / math.sqrt(d["nope"] + d["rope"])
@@ -212,14 +210,14 @@ def _attn_prefill(p, x, cache, offset, cfg: ModelConfig):
     return _out(p, o), (lat,)
 
 
-def _attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
+def attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
     """x: [B, 1, D], slot b at position lens[b]. Returns (out, cache, rows of this layer's
     slab the products ran over)."""
     d = dims(cfg)
     (lat,) = cache
     B, T, W = lat.shape
-    _, q_nope, q_rope, row = _latents(p, x, lens[:, None], cfg, d)
-    lat = _put_row(lat, _pad_row(row, W), lens, gate)
+    _, q_nope, q_rope, row = latents(p, x, lens[:, None], cfg, d)
+    lat = put_row(lat, _pad_row(row, W), lens, gate)
     with jax.named_scope("latent"):
         # W_kvb folded into the query, the products over the slab as it lies, W_kvb's other half over their output
         dt, kv_b = x.dtype, p["kv_b"]["kernel"].astype(x.dtype)
@@ -236,31 +234,13 @@ def _attn_decode(p, x, cache, lens, gate, cfg: ModelConfig):
     return _out(p, o), (lat,), read
 
 
-# -- the feed-forward sub-layer ------------------------------------------------------
-
-
-def _expert_layer(p, x, valid, cfg: ModelConfig):
-    """x: [B, S, D]; valid: [B, S]. The held experts' part of the routed sum plus the shared
-    expert; counts [E] of valid pairs a held expert took. (`dots3._expert_layer` with the
-    published epsilon under the chosen scores' sum and, in this block's own tree, no selection
-    bias; a block whose router has one, `xing4`, keeps it at `router/bias`.)"""
-    flat = x.reshape(-1, x.shape[-1])
-    with jax.named_scope("router"):
-        bias = p["router"]["bias"] if "bias" in p["router"] else jnp.zeros((cfg.n_routed_experts_total,), jnp.float32)
-        ids, weights = sigmoid_routing(flat, p["router"]["kernel"], bias,
-                                       cfg.experts_per_token, cfg.routed_scaling_factor, eps=ROUTING_EPS)
-    with jax.named_scope("experts"):
-        y, counts = grouped_experts(flat, ids, weights, valid.reshape(-1), p["experts"]["gate"],
-                                    p["experts"]["up"], p["experts"]["down"], first=cfg.first_expert)
-    with jax.named_scope("shared_expert"):
-        y = y + _swiglu(p["shared"], flat)
-    return y.reshape(x.shape), counts
+# -- the layers round the attention ----------------------------------------------------
 
 
 def _forward(params, cfg: ModelConfig, tokens, valid, attend):
     """The layers round `attend(i, layer_params, normed) -> (out, cache_i)`, each sub-layer's
     output normed before it joins the residual. Returns (hidden after the final norm, caches,
-    expert stats [2 + E] as `dots3._forward` counts them)."""
+    expert stats [2 + E]: valid pairs routed, pairs held here, pairs by held expert)."""
     with jax.named_scope("embedding"):
         x = params["embedding"][tokens].astype(cfg.dtype)
     caches, counts = [], jnp.zeros((cfg.n_routed_experts,), jnp.int32)
@@ -281,20 +261,16 @@ def _forward(params, cfg: ModelConfig, tokens, valid, attend):
             normed = norm(layer, "mlp_norm", x)
             with jax.named_scope("mlp"):
                 if i < cfg.first_k_dense:
-                    y = _swiglu(layer["mlp"], normed)
+                    y = swiglu(layer["mlp"], normed)
                 else:
-                    y, c = _expert_layer(layer["mlp"], normed, valid, cfg)
+                    y, c = routed_experts(layer["mlp"], normed, valid, cfg.experts_per_token, cfg.routed_scaling_factor,
+                                          eps=ROUTING_EPS, first=cfg.first_expert)
                     counts = counts + c
                 x = x + norm(layer, "mlp_post_norm", y)
     with jax.named_scope("final_norm"):
         x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    routed = jnp.sum(valid, dtype=jnp.int32) * (cfg.experts_per_token * num_expert_layers(cfg))
+    routed = jnp.sum(valid, dtype=jnp.int32) * (cfg.experts_per_token * scaffold.num_expert_layers(cfg))
     return x, caches, jnp.concatenate([routed[None], jnp.sum(counts)[None], counts])
-
-
-def _head(params, x):
-    with jax.named_scope("lm_head"):
-        return _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
 
 
 def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, lora=None, adapter_id=None):
@@ -304,13 +280,12 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     query sees them. Returns (logits of the prompt's last token if it is in this chunk, caches, stats)."""
     S = tokens.shape[1]
     n_valid = jnp.minimum(S, total_len - offset)
-    view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
+    view = scaffold.slot_view(caches, slot)
     x, new, stats = _forward(params, cfg, tokens, jnp.arange(S)[None, :] < n_valid,
-                             lambda i, p, normed: _attn_prefill(p, normed, view[i], offset, cfg))
-    caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
-                    for a, b in zip(c, n)) for c, n in zip(caches, new)]
-    last = jax.lax.dynamic_slice_in_dim(x[0], jnp.clip(total_len - 1 - offset, 0, S - 1), 1, axis=0)
-    return _head(params, last)[0], caches, (stats, jnp.zeros((2 * len(LATENT_COUNTS),), jnp.int32))
+                             lambda i, p, normed: attn_prefill(p, normed, view[i], offset, cfg))
+    caches = scaffold.write_back(caches, new, slot)
+    logits = scaffold.head(params, scaffold.last_row(x, offset, total_len))[0]
+    return logits, caches, (stats, jnp.zeros((2 * len(LATENT_COUNTS),), jnp.int32))
 
 
 def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, adapter_ids=None):
@@ -319,13 +294,13 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
     read = []
 
     def attend(i, p, normed):
-        out, cache, rows = _attn_decode(p, normed, caches[i], lens, gate, cfg)
+        out, cache, rows = attn_decode(p, normed, caches[i], lens, gate, cfg)
         read.append(rows)
         return out, cache
 
     x, new, stats = _forward(params, cfg, last_token[:, None], gate[:, None], attend)
     visible = jnp.sum(jnp.where(gate, lens + 1, 0))
-    return _head(params, x[:, 0]), new, (stats, jnp.concatenate([_split(visible), _split(read[0])]))
+    return scaffold.head(params, x[:, 0]), new, (stats, jnp.concatenate([split(visible), split(read[0])]))
 
 
 # -- the plain reference -------------------------------------------------------------

@@ -130,7 +130,7 @@ class ModelConfig:
     hc_res_clamp_max: float = 30.0
     # The published `rope_scaling` group ({"type": "yarn", "factor", "original_max_position_embeddings", "beta_fast",
     # "beta_slow", "mscale", "mscale_all_dim"}), kept as sorted pairs so that the config stays hashable; None: plain rotary.
-    # Read by the latent-attention blocks through `dots3.attn_dims` (`yarn_inv_freq`, `yarn_mscale` below).
+    # Read by the latent-attention blocks through `models/latent.py:attn_dims` (`yarn_inv_freq`, `yarn_mscale` below).
     rope_scaling: Any = None
 
     def __post_init__(self):

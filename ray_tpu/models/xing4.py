@@ -15,29 +15,28 @@ functions and says why the layout):
       X    = mix_out(X, F(h), coef)                 H_res X + H_post^T F(h)
     logits = norm_final(sum of the streams) W_head
 
-Attention is `pangu_moe`'s, function for function (the latents, the slab `[slots, max_seq,
-640]`, the chunk loop, the decode step over the slab through the kernel `latent_attn`), with the
-rotary's frequencies and the scores' scale read from `rope_scaling` (`dots3.attn_dims`). A cached
-row is a function of the sub-layer's input, the mixture, so the cache knows nothing of the
-streams. The expert layer is `pangu_moe`'s with a selection bias in the tree (`router/bias`:
-`noaux_tc`, the bias chooses and does not weigh) and every expert of a layer held. Every layer
+Attention is `pangu_moe`'s, function for function (`attn_prefill`, `attn_decode`: the latents, the
+slab `[slots, max_seq, 640]`, the chunk loop, the decode step over the slab through the kernel
+`latent_attn`), with the rotary's frequencies and the scores' scale read from `rope_scaling`
+(`models/latent.py:attn_dims`). A cached row is a function of the sub-layer's input, the mixture,
+so the cache knows nothing of the streams. The expert layer is `pangu_moe`'s call of
+`ops/moe.py:routed_experts` with a selection bias in the tree (`router/bias`: `noaux_tc`, the bias
+chooses and does not weigh) and every expert of a layer held. Every layer
 is of one kind, so the block takes no `layer_types` (`LAYER_TYPES`). The multi-token-prediction
 module is not loaded.
 """
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import pangu_moe
-from ray_tpu.models.dots3 import _swiglu, num_expert_layers, tree_from_shapes
-from ray_tpu.models.pangu_moe import LATENT_COUNTS, _split
-from ray_tpu.models.transformer import ModelConfig, _dense, _rmsnorm
+from ray_tpu.models import pangu_moe, scaffold
+from ray_tpu.models.pangu_moe import LATENT_COUNTS, split
+from ray_tpu.models.transformer import ModelConfig, _rmsnorm
 from ray_tpu.ops import hyper_connection as hc
+from ray_tpu.ops.moe import routed_experts, swiglu
 
 # Served by LLMServer / DecodeEngine on one device, and nothing else yet (PERF.md §7): a prefix
 # hit would attach latent rows, a draft needs the multi-token-prediction module and a program
@@ -80,18 +79,15 @@ def param_shapes(cfg: ModelConfig) -> dict:
 
 
 def num_params(cfg: ModelConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in param_shapes(cfg).values())
+    return scaffold.num_params(param_shapes(cfg))
 
 
-def serving_params(cfg: ModelConfig, params):
-    """The tree the engine holds (`models/__init__.py`), held as drawn: the block is configured
-    with `param_dtype` the served type."""
-    return params
+serving_params = scaffold.as_drawn
 
 
 def init_params(cfg: ModelConfig, key):
-    """The tree at seeded random weights in `cfg.param_dtype` (`dots3.tree_from_shapes`)."""
-    return tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
+    """The tree at seeded random weights in `cfg.param_dtype` (`scaffold.tree_from_shapes`)."""
+    return scaffold.tree_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
 
 
 # -- the cache and the counts --------------------------------------------------------
@@ -158,24 +154,20 @@ def _forward(params, cfg: ModelConfig, tokens, valid, attend, decoding: bool):
             x, cache = _hyper(layer, "attn", x, cfg, lambda h, i=i, layer=layer: attend(i, layer["attn"], h))
             caches.append(cache)
             if i < cfg.first_k_dense:
-                x, _ = _hyper(layer, "mlp", x, cfg, lambda h, layer=layer: (_swiglu(layer["mlp"], h), None))
+                x, _ = _hyper(layer, "mlp", x, cfg, lambda h, layer=layer: (swiglu(layer["mlp"], h), None))
             else:
-                x, c = _hyper(layer, "mlp", x, cfg,
-                              lambda h, layer=layer: pangu_moe._expert_layer(layer["mlp"], h, valid, cfg))
+                x, c = _hyper(layer, "mlp", x, cfg, lambda h, layer=layer: routed_experts(
+                    layer["mlp"], h, valid, cfg.experts_per_token, cfg.routed_scaling_factor,
+                    eps=pangu_moe.ROUTING_EPS, first=cfg.first_expert))
                 counts, hit = counts + c, hit + jnp.sum(c > 0, dtype=jnp.int32)
     with jax.named_scope("final_norm"):
         D = cfg.hidden
         x = sum(x[..., i * D:(i + 1) * D].astype(jnp.float32) for i in range(n)).astype(cfg.dtype)
         x = _rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    n_moe, n_valid = num_expert_layers(cfg), jnp.sum(valid, dtype=jnp.int32)
+    n_moe, n_valid = scaffold.num_expert_layers(cfg), jnp.sum(valid, dtype=jnp.int32)
     named = [n_valid * (cfg.experts_per_token * n_moe), jnp.sum(counts), hit * decoding, jnp.int32(n_moe * decoding)]
     mixed = n_valid * (len(SUBLAYERS) * cfg.n_layers)
     return x, caches, jnp.concatenate([jnp.stack(named).astype(jnp.int32), counts]), mixed[None]
-
-
-def _head(params, x):
-    with jax.named_scope("lm_head"):
-        return _dense(x, params["lm_head"]["kernel"]).astype(jnp.float32)
 
 
 def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, lora=None, adapter_id=None):
@@ -185,14 +177,13 @@ def prefill(params, cfg: ModelConfig, tokens, caches, slot, offset, total_len, l
     caches, stats)."""
     S = tokens.shape[1]
     n_valid = jnp.minimum(S, total_len - offset)
-    view = [tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0) for a in c) for c in caches]
+    view = scaffold.slot_view(caches, slot)
     x, new, experts, mixed = _forward(params, cfg, tokens, jnp.arange(S)[None, :] < n_valid,
-                                      lambda i, p, normed: pangu_moe._attn_prefill(p, normed, view[i], offset, cfg),
+                                      lambda i, p, normed: pangu_moe.attn_prefill(p, normed, view[i], offset, cfg),
                                       decoding=False)
-    caches = [tuple(jax.lax.dynamic_update_slice_in_dim(a, b.astype(a.dtype), slot, axis=0)
-                    for a, b in zip(c, n)) for c, n in zip(caches, new)]
-    last = jax.lax.dynamic_slice_in_dim(x[0], jnp.clip(total_len - 1 - offset, 0, S - 1), 1, axis=0)
-    return _head(params, last)[0], caches, (experts, jnp.zeros((2 * len(LATENT_COUNTS),), jnp.int32), mixed)
+    caches = scaffold.write_back(caches, new, slot)
+    logits = scaffold.head(params, scaffold.last_row(x, offset, total_len))[0]
+    return logits, caches, (experts, jnp.zeros((2 * len(LATENT_COUNTS),), jnp.int32), mixed)
 
 
 def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, adapter_ids=None):
@@ -201,10 +192,10 @@ def decode(params, cfg: ModelConfig, last_token, caches, lens, gate, lora=None, 
     read = []
 
     def attend(i, p, normed):
-        out, cache, rows = pangu_moe._attn_decode(p, normed, caches[i], lens, gate, cfg)
+        out, cache, rows = pangu_moe.attn_decode(p, normed, caches[i], lens, gate, cfg)
         read.append(rows)
         return out, cache
 
     x, new, experts, mixed = _forward(params, cfg, last_token[:, None], gate[:, None], attend, decoding=True)
     visible = jnp.sum(jnp.where(gate, lens + 1, 0))
-    return _head(params, x[:, 0]), new, (experts, jnp.concatenate([_split(visible), _split(read[0])]), mixed)
+    return scaffold.head(params, x[:, 0]), new, (experts, jnp.concatenate([split(visible), split(read[0])]), mixed)

@@ -1,6 +1,6 @@
 """Dense latent attention against the latent slab as it lies: a decode step's, and a prefill chunk's.
 
-With W_kvb folded into the query and the output (`models/dots3.py:_absorbed`), a head's
+With W_kvb folded into the query and the output (`models/pangu_moe.py:attn_decode`), a head's
 key and its value at a cached position are the same latent row: the score is q . row and
 the output the scores' softmax over the rows themselves. So one slab is read once, 128
 heads at a time: per cached row 1152 bytes and 128 x (576 + 512) x 2 FLOP, which on a v5e
@@ -9,10 +9,10 @@ kernel that reads of each slot only the row blocks up to its length; `latent_att
 is the same function as two products over every row of the slab and a mask, which every
 other backend runs (the CPU tests hold the kernel, interpreted, to it).
 
-The slab is `[slots, rows, width]` with `width` a multiple of 128 lanes (`slab_width`:
-c_kv | k_r | zeros, 576 kept as 640): an array whose last axis is not whole rows of 128
-lanes is padded to them by the TPU anyway, and addressed a row at a time only after a copy
-of all of it into another layout (PERF.md §6, PR 35, and §7 on `dots3`'s 576-wide slab).
+The slab is `[slots, rows, width]` with `width` a multiple of 128 lanes (`slab_width`: the row
+`models/latent.py` computes, c_kv | k_r, then zeros: 576 kept as 640): an array whose last axis is
+not whole rows of 128 lanes is padded to them by the TPU anyway, and addressed a row at a time only
+after a copy of all of it into another layout (PERF.md §6, PR 35, and §7 on `dots3`'s 576-wide slab).
 
 A prefill chunk expands keys and values from the latent rows instead, a block of keys at a time
 (`latent_chunk_attention`): per block a layer 86 GFLOP of products over [heads, queries, keys]

@@ -12,9 +12,10 @@ all-to-alls over ICI — no hand-written collectives needed.
 Shapes (E experts, C capacity per expert, k top-k):
     tokens  [B, S, M]  →  dispatch [B, S, E, C]  →  expert in [E, B*C', M] ...
 
-Serve (`sigmoid_routing`, `grouped_experts`; called by `models/dots3.py` and
-`models/lfm2.py`): sigmoid scores with a selection bias and renormalised top-k,
-then a dropless product over the held experts' sorted, tiled pairs (below).
+Serve (`routed_experts`: `sigmoid_routing`, `grouped_experts`, `swiglu`; every served block
+with an expert layer calls it): sigmoid scores with a selection bias and renormalised top-k,
+then a dropless product over the held experts' sorted, tiled pairs, then the shared expert
+where the tree has one (below).
 """
 
 from __future__ import annotations
@@ -112,7 +113,7 @@ class MoEMLP(nn.Module):
 
 # -- dropless, sorted, grouped experts over the experts this chip holds -----------
 #
-# The serving path's expert product (`models/dots3.py`). The router chooses among
+# The serving path's expert product. The router chooses among
 # ALL of a layer's experts; this chip holds `E` of them, ids [first, first + E).
 # Token-expert pairs whose expert lives here are sorted by expert and laid out in
 # tiles of `tile` rows, every expert's group starting on a tile boundary, and one
@@ -120,6 +121,18 @@ class MoEMLP(nn.Module):
 # capacity, no dropped token, and an expert nobody chose costs nothing. Pairs of
 # absent experts add nothing here (their chips add them in a deployment). The
 # train step's `MoEMLP` above is the older capacity-dropping one-hot dispatch.
+
+
+def _matmul(a, b):
+    """a [..., M] @ b [M, N] in a's type, accumulated in float32."""
+    return jax.lax.dot_general(a, b.astype(a.dtype), (((a.ndim - 1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32).astype(a.dtype)
+
+
+def swiglu(p, x):
+    """The gated expert E(h) = (silu(h Wg) * (h Wu)) Wd over a tree {gate, up, down: {kernel}}: a
+    dense layer's feed-forward, and an expert layer's shared expert."""
+    return _matmul(jax.nn.silu(_matmul(x, p["gate"]["kernel"])) * _matmul(x, p["up"]["kernel"]), p["down"]["kernel"])
 
 
 def sigmoid_routing(h, router_kernel, router_bias, k: int, scaling: float = 1.0, eps: float = 0.0):
@@ -185,17 +198,34 @@ def grouped_experts(x, ids, weights, valid, w_gate, w_up, w_down, first: int = 0
     tile_expert = jnp.searchsorted(jnp.cumsum(group_tiles), jnp.arange(rows // tile), side="right")
     tile_expert = jnp.minimum(tile_expert, E - 1).astype(jnp.int32)
 
-    def matmul(a, b):
-        return jax.lax.dot_general(a, b.astype(a.dtype), (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32).astype(a.dtype)
-
     def one_tile(t, ys):
         e = tile_expert[t]
         xt = jax.lax.dynamic_slice(xs, (t * tile, 0), (tile, D))
-        h = jax.nn.silu(matmul(xt, w_gate[e])) * matmul(xt, w_up[e])
-        return jax.lax.dynamic_update_slice(ys, matmul(h, w_down[e]), (t * tile, 0))
+        h = jax.nn.silu(_matmul(xt, w_gate[e])) * _matmul(xt, w_up[e])
+        return jax.lax.dynamic_update_slice(ys, _matmul(h, w_down[e]), (t * tile, 0))
 
     ys = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((rows, D), x.dtype))
     out = ys.at[dest].get(mode="fill", fill_value=0).reshape(N, K, D)
     y = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), weights * held.reshape(N, K))
     return y.astype(x.dtype), counts
+
+
+def routed_experts(p, x, valid, k: int, scaling: float = 1.0, eps: float = 0.0, first: int = 0):
+    """An expert layer over the tree p = {router: {kernel[, bias]}, experts: {gate, up, down}[,
+    shared: {gate, up, down: {kernel}}]}, under the scopes `router`, `experts`, `shared_expert`:
+    the held experts' part of the routed sum (ids [first, first + E)) plus the shared expert where
+    the tree has one. A router without a `bias` chooses by its scores alone. x: [..., D]; valid:
+    x's leading shape, rows that are padding or gated off route nowhere. Returns (y as x, counts
+    [E] int32 of valid pairs a held expert took)."""
+    flat = x.reshape(-1, x.shape[-1])
+    router = p["router"]
+    with jax.named_scope("router"):
+        bias = router["bias"] if "bias" in router else jnp.zeros((router["kernel"].shape[-1],), jnp.float32)
+        ids, weights = sigmoid_routing(flat, router["kernel"], bias, k, scaling, eps=eps)
+    with jax.named_scope("experts"):
+        y, counts = grouped_experts(flat, ids, weights, valid.reshape(-1), p["experts"]["gate"],
+                                    p["experts"]["up"], p["experts"]["down"], first=first)
+    if "shared" in p:
+        with jax.named_scope("shared_expert"):
+            y = y + swiglu(p["shared"], flat)
+    return y.reshape(x.shape), counts

@@ -15,7 +15,7 @@ import pytest
 
 from ray_tpu.models import dots3
 from ray_tpu.models.transformer import ModelConfig, Transformer
-from ray_tpu.ops import latent_attention as la
+from ray_tpu.ops import latent_attention as la, moe
 
 LAYERS = ("full_attention", "full_attention", "sliding_attention", "sliding_attention", "sliding_attention")
 
@@ -43,7 +43,8 @@ def model():
 _PREFILL = jax.jit(dots3.prefill, static_argnums=1)
 _DECODE = jax.jit(dots3.decode, static_argnums=1)
 _PLAIN = jax.jit(dots3.forward_plain, static_argnums=(1, 3))
-_EXPERTS = jax.jit(dots3._expert_layer, static_argnums=3)
+_EXPERTS = jax.jit(lambda p, x, valid, cfg: moe.routed_experts(p, x, valid, cfg.experts_per_token, cfg.routed_scaling_factor,
+                                                              first=cfg.first_expert), static_argnums=3)  # as `dots3._forward` calls it
 
 
 def _plain(params, cfg, toks, experts=None):
@@ -177,7 +178,7 @@ def test_the_shares_of_an_expert_parallel_layer_add_up_to_the_uncut_layer(model)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 9, cfg.hidden))
     valid = jnp.ones((2, 9), bool)
     whole, counts = _EXPERTS(p, x, valid, cfg)
-    shared = dots3._swiglu(p["shared"], x.reshape(-1, cfg.hidden)).reshape(x.shape)
+    shared = moe.swiglu(p["shared"], x.reshape(-1, cfg.hidden)).reshape(x.shape)
     parts, held = 0.0, 0
     for first in range(0, 16, 4):
         share = dataclasses.replace(cfg, n_routed_experts=4, first_expert=first)

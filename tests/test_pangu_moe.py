@@ -19,7 +19,7 @@ import pytest
 from ray_tpu import models
 from ray_tpu.models import pangu_moe as pm
 from ray_tpu.models.transformer import ModelConfig, Transformer
-from ray_tpu.ops import attention, latent_attention as la
+from ray_tpu.ops import attention, latent_attention as la, moe
 
 # float32 paths agree to rounding (1e-5 of logits whose standard deviation is 0.6); the controls
 # move them by thousands of times that, so the limit needs no tuning
@@ -64,7 +64,9 @@ def model():
 _PREFILL = jax.jit(pm.prefill, static_argnums=1)
 _DECODE = jax.jit(pm.decode, static_argnums=1)
 _PLAIN = jax.jit(pm.forward_plain, static_argnums=(1, 3))
-_EXPERTS = jax.jit(pm._expert_layer, static_argnums=3)
+_EXPERTS = jax.jit(lambda p, x, valid, cfg: moe.routed_experts(p, x, valid, cfg.experts_per_token, cfg.routed_scaling_factor,
+                                                              eps=pm.ROUTING_EPS, first=cfg.first_expert),
+                   static_argnums=3)  # as `pangu_moe._forward` calls it
 
 
 def _plain(params, cfg, toks, experts=None):
@@ -315,7 +317,7 @@ def test_the_32_shares_of_an_expert_parallel_layer_add_up_to_the_uncut_layer(mod
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 9, cfg.hidden))
     valid = jnp.ones((2, 9), bool)
     whole, counts = _EXPERTS(p, x, valid, cfg)
-    shared = pm._swiglu(p["shared"], x.reshape(-1, cfg.hidden)).reshape(x.shape)
+    shared = moe.swiglu(p["shared"], x.reshape(-1, cfg.hidden)).reshape(x.shape)
     parts, held = 0.0, 0
     for first in range(0, 64, 2):
         share = dataclasses.replace(cfg, n_routed_experts=2, first_expert=first)
@@ -452,7 +454,7 @@ def test_the_rows_counts_do_not_wrap_where_a_count_of_rows_would(model):
     total = (np.zeros((2 + 64,), np.int64), np.asarray([3 * 2**21, 1000, 5 * 2**21, 7], np.int64))
     out = pm.report(cfg, total, total)["latent"]
     assert out["rows_visible"] == 3 * 2**31 + 1000 and out["rows_read"] == 5 * 2**31 + 7
-    assert pm._split(jnp.int32(16 * 32768)).tolist() == [512, 0]
+    assert pm.split(jnp.int32(16 * 32768)).tolist() == [512, 0]
 
 
 def _refusals():

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from ray_tpu import models
-from ray_tpu.models import dots3, pangu_moe, xing4
+from ray_tpu.models import latent, pangu_moe, xing4
 from ray_tpu.models.transformer import ModelConfig, Transformer, _rope, yarn_inv_freq, yarn_mscale
 from ray_tpu.ops import attention, hyper_connection as hc, latent_attention as la
 
@@ -235,10 +235,10 @@ def test_the_yarn_table_is_the_direct_formula_at_the_published_sizes():
     np.testing.assert_allclose(got[23:], f[23:] / 64, rtol=1e-6)
     np.testing.assert_allclose(got, np.asarray(reference.yarn_inv_freq(64, 1e4, YARN)), rtol=1e-6)
     assert yarn_mscale(YARN, "mscale_all_dim") ** 2 == pytest.approx((0.1 * math.log(64) + 1) ** 2) == pytest.approx(2.005, abs=1e-3)
-    big = dots3.attn_dims(tiny(qk_rope_head_dim=64, rope_scaling=YARN), True)
+    big = latent.attn_dims(tiny(qk_rope_head_dim=64, rope_scaling=YARN), True)
     assert big["score_scale"] == pytest.approx(2.005, abs=1e-3) and big["inv_freq"].shape == (32,)
     with pytest.raises(ValueError, match="only yarn with mscale equal to mscale_all_dim"):
-        dots3.attn_dims(tiny(rope_scaling=dict(YARN, mscale_all_dim=0)), True)
+        latent.attn_dims(tiny(rope_scaling=dict(YARN, mscale_all_dim=0)), True)
 
 
 def test_at_factor_one_the_yarn_table_is_the_plain_rotary_and_without_scaling_nothing_changes():
