@@ -38,7 +38,8 @@ A new block writes its own layers, its cache and its counts, and takes the rest 
     models/latent.py     the latent sub-layer's row c_kv | k_r: attn_dims, latents, put_row, key_block
     ops/moe.py           swiglu, and routed_experts: router, grouped experts, shared expert
     ops/                 what reads a cache on the chip: attention.py (KV slabs, through
-                         `llama._attn_cached`), latent_attention.py, ssd.py, hyper_connection.py
+                         `llama._attn_cached`, and `laguna`'s slabs and rings through `cached_attention`),
+                         latent_attention.py, ssd.py, hyper_connection.py
 """
 
 from __future__ import annotations
@@ -54,6 +55,7 @@ BLOCKS = {
     "lfm2": "ray_tpu.models.lfm2",
     "pangu_moe": "ray_tpu.models.pangu_moe",
     "xing4": "ray_tpu.models.xing4",
+    "laguna": "ray_tpu.models.laguna",
 }
 
 # What a caller may ask of a block, and how the refusal names the caller.

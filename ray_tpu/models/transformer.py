@@ -84,9 +84,16 @@ class ModelConfig:
     # "xing4": pangu_moe's latent attention and expert layer (with a selection bias) round a residual of
     # `hc_mult` streams mixed by manifold-constrained hyper-connections (`ops/hyper_connection.py`), rotary
     # frequencies scaled as `rope_scaling` says (`models/xing4.py`, served only); the `hc_*` fields are its own.
+    # "laguna": grouped-query layers of two kinds in one model, full layers of `n_heads` over `max_seq`-row K/V slabs and
+    # window layers of `swa_n_heads` over a ring of `sliding_window` rows, heads of `head_width`, a sigmoid gate a head, a
+    # rotary table a kind (`rope_theta` under `rope_scaling` over `partial_rotary_factor` of a head; `swa_rope_theta` over
+    # all of it), softmax-routed experts (`router_score`) beside a shared one (`models/laguna.py`, served only).
     block: str = "llama"
-    layer_types: tuple = ()            # per layer; dots3: "full_attention" | "sliding_attention";
+    layer_types: tuple = ()            # per layer; dots3, laguna: "full_attention" | "sliding_attention";
                                        # granite_hybrid: "mamba" | "attention"; lfm2: "conv" | "full_attention"
+    head_width: int = 0                # a head's width where the config states one; 0: hidden // n_heads (`head_dim`)
+    partial_rotary_factor: float = 1.0  # the share of a head, from its start, that a full layer rotates
+    router_score: str = "sigmoid"      # what an expert layer's router makes of its logits: "sigmoid" | "softmax"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -130,7 +137,8 @@ class ModelConfig:
     hc_res_clamp_max: float = 30.0
     # The published `rope_scaling` group ({"type": "yarn", "factor", "original_max_position_embeddings", "beta_fast",
     # "beta_slow", "mscale", "mscale_all_dim"}), kept as sorted pairs so that the config stays hashable; None: plain rotary.
-    # Read by the latent-attention blocks through `models/latent.py:attn_dims` (`yarn_inv_freq`, `yarn_mscale` below).
+    # Read by the latent-attention blocks through `models/latent.py:attn_dims` (`yarn_inv_freq`, `yarn_mscale` below);
+    # laguna's group carries its own `attention_factor`, which its cos and sin are multiplied by.
     rope_scaling: Any = None
 
     def __post_init__(self):
@@ -144,7 +152,7 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.n_heads
+        return self.head_width or self.hidden // self.n_heads
 
     def num_params(self) -> int:
         e = self.vocab_size * self.hidden

@@ -12,9 +12,9 @@ all-to-alls over ICI — no hand-written collectives needed.
 Shapes (E experts, C capacity per expert, k top-k):
     tokens  [B, S, M]  →  dispatch [B, S, E, C]  →  expert in [E, B*C', M] ...
 
-Serve (`routed_experts`: `sigmoid_routing`, `grouped_experts`, `swiglu`; every served block
-with an expert layer calls it): sigmoid scores with a selection bias and renormalised top-k,
-then a dropless product over the held experts' sorted, tiled pairs, then the shared expert
+Serve (`routed_experts`: `sigmoid_routing` or `softmax_routing`, `grouped_experts`, `swiglu`; every
+served block with an expert layer calls it): sigmoid scores with a selection bias, or a softmax's
+probabilities, and renormalised top-k, then a dropless product over the held experts' sorted, tiled pairs, then the shared expert
 where the tree has one (below).
 """
 
@@ -135,20 +135,33 @@ def swiglu(p, x):
     return _matmul(jax.nn.silu(_matmul(x, p["gate"]["kernel"])) * _matmul(x, p["up"]["kernel"]), p["down"]["kernel"])
 
 
+def _router_logits(h, router_kernel):
+    """h [N, D] @ W_r [D, E] in float32 at the highest precision: a near-tied expert is chosen by it."""
+    return jax.lax.dot_general(
+        h.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
+
+
 def sigmoid_routing(h, router_kernel, router_bias, k: int, scaling: float = 1.0, eps: float = 0.0):
     """`noaux_tc` routing: scores `sigmoid(h W_r)` in float32, the `k` experts of
     largest score + bias chosen (the bias chooses and does not weigh), weights the
     chosen scores over their sum (plus `eps`, where a model's code adds one) times
     `scaling`. h: [N, D] -> (ids [N, k] int32, weights [N, k] float32)."""
-    logits = jax.lax.dot_general(
-        h.astype(jnp.float32), router_kernel.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(_router_logits(h, router_kernel))
     _, ids = jax.lax.top_k(scores + router_bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
     total = jnp.sum(chosen, axis=-1, keepdims=True)
     weights = chosen / (total + eps if eps else total) * scaling
     return ids.astype(jnp.int32), weights
+
+
+def softmax_routing(h, router_kernel, k: int, scaling: float = 1.0):
+    """The Qwen-MoE family's routing with `norm_topk_prob`: probabilities `softmax(h W_r)` in float32 over
+    every expert, the `k` largest chosen, weights the chosen probabilities over their sum times `scaling`
+    (the numbers a softmax over the `k` largest logits gives). h: [N, D] -> (ids [N, k] int32, weights
+    [N, k] float32)."""
+    chosen, ids = jax.lax.top_k(jax.nn.softmax(_router_logits(h, router_kernel), axis=-1), k)
+    return ids.astype(jnp.int32), chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
 
 
 def expert_tile_rows(pairs: int, experts: int) -> int:
@@ -210,18 +223,23 @@ def grouped_experts(x, ids, weights, valid, w_gate, w_up, w_down, first: int = 0
     return y.astype(x.dtype), counts
 
 
-def routed_experts(p, x, valid, k: int, scaling: float = 1.0, eps: float = 0.0, first: int = 0):
+def routed_experts(p, x, valid, k: int, scaling: float = 1.0, eps: float = 0.0, first: int = 0,
+                   score: str = "sigmoid"):
     """An expert layer over the tree p = {router: {kernel[, bias]}, experts: {gate, up, down}[,
     shared: {gate, up, down: {kernel}}]}, under the scopes `router`, `experts`, `shared_expert`:
     the held experts' part of the routed sum (ids [first, first + E)) plus the shared expert where
-    the tree has one. A router without a `bias` chooses by its scores alone. x: [..., D]; valid:
-    x's leading shape, rows that are padding or gated off route nowhere. Returns (y as x, counts
-    [E] int32 of valid pairs a held expert took)."""
+    the tree has one. `score` is the router's: "sigmoid" (`sigmoid_routing`; a router without a
+    `bias` chooses by its scores alone) or "softmax" (`softmax_routing`: no bias, no `eps`).
+    x: [..., D]; valid: x's leading shape, rows that are padding or gated off route nowhere.
+    Returns (y as x, counts [E] int32 of valid pairs a held expert took)."""
     flat = x.reshape(-1, x.shape[-1])
     router = p["router"]
     with jax.named_scope("router"):
-        bias = router["bias"] if "bias" in router else jnp.zeros((router["kernel"].shape[-1],), jnp.float32)
-        ids, weights = sigmoid_routing(flat, router["kernel"], bias, k, scaling, eps=eps)
+        if score == "softmax":
+            ids, weights = softmax_routing(flat, router["kernel"], k, scaling)
+        else:
+            bias = router["bias"] if "bias" in router else jnp.zeros((router["kernel"].shape[-1],), jnp.float32)
+            ids, weights = sigmoid_routing(flat, router["kernel"], bias, k, scaling, eps=eps)
     with jax.named_scope("experts"):
         y, counts = grouped_experts(flat, ids, weights, valid.reshape(-1), p["experts"]["gate"],
                                     p["experts"]["up"], p["experts"]["down"], first=first)

@@ -154,7 +154,7 @@ def test_what_the_unseen_block_does_not_list_is_refused_by_its_name(onerow, what
 
 
 @pytest.mark.parametrize("feature", sorted(models.FEATURES))
-@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "pangu_moe", "xing4", "onerow"])
+@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "pangu_moe", "xing4", "laguna", "onerow"])
 def test_one_function_refuses_what_a_block_does_not_list(onerow, block, feature):
     cfg = get_config("test-tiny") if block == "llama" else _cfg(block)
     if feature in models.block_module(cfg).SUPPORTS:
@@ -164,7 +164,7 @@ def test_one_function_refuses_what_a_block_does_not_list(onerow, block, feature)
             models.require(cfg, feature)
 
 
-@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "pangu_moe", "xing4", "onerow"])
+@pytest.mark.parametrize("block", ["llama", "dots3", "granite_hybrid", "lfm2", "pangu_moe", "xing4", "laguna", "onerow"])
 def test_every_block_offers_what_the_seam_names(onerow, block):
     """Each function the seam's docstring lists for every block is in the block's module, the
     engine's `serving_params` among them; and a tree that is already in the served type comes
@@ -183,5 +183,5 @@ def test_every_block_offers_what_the_seam_names(onerow, block):
 
 
 def test_an_unknown_block_is_named_with_those_known():
-    with pytest.raises(ValueError, match=r"unknown block 'nosuch'; known: \['dots3', 'granite_hybrid', 'lfm2', 'llama', 'pangu_moe', 'xing4'\]"):
+    with pytest.raises(ValueError, match=r"unknown block 'nosuch'; known: \['dots3', 'granite_hybrid', 'laguna', 'lfm2', 'llama', 'pangu_moe', 'xing4'\]"):
         models.block_module(_cfg("nosuch"))

@@ -767,3 +767,57 @@ def test_the_dots3_cells_chunk_keeps_its_scores_in_the_kernel_and_fits_the_chip_
     assert not [sh for sh in copied if math.prod(sh) >= slots * T * 576], copied
     assert len([sh for sh in copied if sh == (1, T, 576)]) <= full, copied
 
+
+
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n8", "rt_prefill_b1024"])
+def test_the_laguna_cells_programs_fit_the_chip_and_copy_no_slab_and_score_no_row_past_the_live_ones_for_v5e(one_chip, program):
+    """`laguna-s-2.1.serve-mixedlen24`'s programs at the published widths, all 5 layers of the cut and
+    24 slots of 32768 rows, the caches donated as the engine donates them: a full layer's slabs are
+    `bf16[24,32768,8,128]` and a sliding layer's rings `bf16[24,512,8,128]`, row-major in the program's
+    own layout (a head is a whole row of 128 lanes), every one aliased to its output; no operation
+    copies an array of 32768 rows (a chunk's loop that took its key blocks of `[T, Hkv, D]` had the whole
+    slot's slab laid out head-major before the loop, 67 MB a slab a chunk: PERF.md §6, PR 46); the plan
+    stays under the chip's 15.75 GiB with 6.00 GB of weights and 6.59 GB of cache held; a decode step
+    reads slabs and rings through the kernel `cached_attn`, one call a layer; and a chunk's full layers
+    hold scores over one block of 1024 keys, never over every row of the slab."""
+    from ray_tpu.models import laguna
+
+    cfg, slots = _cell_cfg("laguna-s-2.1"), 24
+    params = _shaped(jax.eval_shape(lambda k: laguna.init_params(cfg, k), jax.random.PRNGKey(0)), one_chip)
+    caches = _shaped(jax.eval_shape(lambda: laguna.init_caches(cfg, slots, cfg.max_seq)), one_chip)
+    vec, scalar = _operand((slots,), one_chip, jnp.int32), _operand((), one_chip, jnp.int32)
+
+    def steps(n):
+        def run(params, last, caches, lens, gate):
+            def step(carry, _):
+                last, caches, lens = carry
+                logits, caches, stats = laguna.decode(params, cfg, last, caches, lens, gate)
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32), caches, lens + 1), stats
+
+            return jax.lax.scan(step, (last, caches, lens), None, length=n)
+        return jax.jit(run, donate_argnums=(2,)).lower(params, vec, caches, vec, _operand((slots,), one_chip, jnp.bool_))
+
+    if program == "rt_prefill_b1024":
+        lowered = jax.jit(lambda p, t, c, s, o, n: laguna.prefill(p, cfg, t, c, s, o, n), donate_argnums=(2,)).lower(
+            params, _operand((1, 1024), one_chip, jnp.int32), caches, scalar, scalar, scalar)
+    else:
+        lowered = steps(8 if program.endswith("n8") else 1)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    plan = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    held = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(caches))
+    assert m.alias_size_in_bytes == held == 24 * (32768 * 8192 + 3 * 512 * 4096)
+    assert 2 * laguna.num_params(cfg) + held < plan < 15.75 * 2**30, plan / 2**30
+    text = compiled.as_text()
+    layout = next(line for line in text.splitlines() if "entry_computation_layout" in line)
+    assert "bf16[24,32768,8,128]{3,2,1,0:T(8,128)(2,1)}" in layout and "bf16[24,512,8,128]{3,2,1,0:T(8,128)(2,1)}" in layout
+    copied = [tuple(int(n) for n in mm.group(1).split(",")) for line in text.splitlines()
+              if (mm := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))]
+    assert not [sh for sh in copied if 32768 in sh or 32768 * 8 in sh], "a slab's rows copied"
+    shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
+    assert not {sh for sh in shapes if 32768 in sh and math.prod(sh) >= 32768 * 1024}, "scores over every row of a slab"
+    kernel_calls = [line for line in text.splitlines() if re.search(r"%cached_attn(\.\d+)? = ", line)]
+    if program == "rt_prefill_b1024":
+        assert not kernel_calls and (8, 6, 1024, 1024) in shapes  # one block of keys' scores, 48 heads: XLA's fusions hold them (PERF.md §7)
+    else:
+        assert len(kernel_calls) == 5 and all("kv_attn" in line for line in kernel_calls)

@@ -14,7 +14,7 @@ import pytest
 from ray_tpu import models
 from ray_tpu.models import scaffold
 
-from tests import test_dots3, test_granite_hybrid, test_lfm2, test_pangu_moe, test_xing4
+from tests import test_dots3, test_granite_hybrid, test_laguna, test_lfm2, test_pangu_moe, test_xing4
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCK_MODULES = {path.rsplit(".", 1)[1] for path in models.BLOCKS.values()}
@@ -91,6 +91,7 @@ SEEDED = {
     "lfm2": (test_lfm2.tiny, "091ce23a1a9ef64c", "f879cf98e2e87100"),
     "pangu_moe": (test_pangu_moe.tiny, "e1c10c060cc7c018", "2f0c1608a565c1ed"),
     "xing4": (test_xing4.tiny, "0cd57067916bcf4e", "f453e21355bf4903"),
+    "laguna": (test_laguna.tiny, "3e6055ec60509bb0", "133baa95e05de8e5"),  # recorded from PR 46's tree, the block's first
 }
 
 
