@@ -38,7 +38,8 @@ A new block writes its own layers, its cache and its counts, and takes the rest 
     models/latent.py     the latent sub-layer's row c_kv | k_r: attn_dims, latents, put_row, key_block
     ops/moe.py           swiglu, and routed_experts: router, grouped experts, shared expert
     ops/                 what reads a cache on the chip: attention.py (KV slabs, through
-                         `llama._attn_cached`, and `laguna`'s slabs and rings through `cached_attention`),
+                         `llama._attn_cached`, and `laguna`'s slabs and rings through `cached_attention`,
+                         which writes a step's rows too; `put_gated`, the write where no kernel runs),
                          latent_attention.py, ssd.py, hyper_connection.py
 """
 

@@ -30,7 +30,8 @@ The cache, one (K, V) pair a layer in `cfg.dtype`, keys kept rotated: a full lay
 `[slots, max_seq, Hkv, head_dim]`, row p the position p; a sliding layer a ring
 `[slots, sliding_window, Hkv, head_dim]`, position p in row p mod window. A softmax does not ask
 for its keys in order, so a decode step's window layer is the full layer's attention
-(`ops/attention.py:cached_attention` on the TPU) over the ring, told a length of at most window - 1.
+(`ops/attention.py:cached_attention` on the TPU, which writes the step's row at p mod window itself)
+over the ring, told a length of at most window - 1.
 A prefill chunk may be longer than the ring: it attends to the ring's rows from before it and to
 its own keys inside the band, a block of queries at a time, then leaves its last rows in the ring;
 its full layers loop over the slab's key blocks up to the chunk's last row and no further.
@@ -309,28 +310,23 @@ def _window_attn_prefill(p, x, cache, offset, n_valid, cfg: ModelConfig):
     return _gated_out(p, x, o), (rk, rv)
 
 
-def _put_gated(cache, row, at, gate):
-    """cache: [B, rows, Hkv, hd]; row: [B, 1, Hkv, hd]; slot b's row lands at `at[b]` where `gate[b]`."""
-
-    def put(slot_cache, slot_row, a, g):
-        cur = jax.lax.dynamic_slice(slot_cache, (a, 0, 0), slot_row.shape)
-        return jax.lax.dynamic_update_slice(slot_cache, jnp.where(g, slot_row, cur), (a, 0, 0))
-
-    return jax.vmap(put)(cache, row.astype(cache.dtype), at, gate)
-
-
 def _attn_decode(p, x, cache, lens, gate, cfg: ModelConfig, full: bool):
     """x: [B, 1, D], slot b at position lens[b]; cache: (K, V) slabs or rings. The new row lands at
     the position (mod the window in a ring), then one query a head over the rows it may see: all
-    up to its own in a slab, and in a ring the whole of it once it has wrapped."""
+    up to its own in a slab, and in a ring the whole of it once it has wrapped. On the TPU the
+    kernel writes the row itself; elsewhere XLA's gated write comes before the products."""
     ck, cv = cache
     q, k, v = _qkv(p, x, lens[:, None], cfg, full)
     with jax.named_scope("kv_attn"):
         at = lens if full else lens % cfg.sliding_window
-        ck, cv = _put_gated(ck, k, at, gate), _put_gated(cv, v, at, gate)
+        k, v = k.astype(ck.dtype), v.astype(cv.dtype)
         seen = jnp.where(gate, lens if full else jnp.minimum(lens, cfg.sliding_window - 1), 0)  # an idle slot: one row
-        attend = attention.cached_attention if attention._use_pallas() else attention.cached_attention_xla
-        o = attend(q, ck, cv, seen, scale=1.0 / math.sqrt(cfg.head_dim))
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        if attention._use_pallas():
+            o, ck, cv = attention.cached_attention(q, ck, cv, seen, scale=scale, new_k=k, new_v=v, write_at=at, gate=gate)
+        else:
+            ck, cv = attention.put_gated(ck, k, at, gate), attention.put_gated(cv, v, at, gate)
+            o = attention.cached_attention_xla(q, ck, cv, seen, scale=scale)
     return _gated_out(p, x, o), (ck, cv)
 
 

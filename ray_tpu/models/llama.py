@@ -159,65 +159,60 @@ def _cached_products(q, k, v, cache_k, cache_v, write_at, cfg, write_gate, scale
     """The new rows into the slab, then attention against it. q: [B, S, H, D];
     k, v: [B, S, Hkv, D] -> (out [B, S, Hkv, G, D], cache_k, cache_v)."""
     B, S = q.shape[:2]
-    origin = (0,) * (cache_k.ndim - 2)
     new_k = k.astype(cache_k.dtype).reshape((B, S) + cache_k.shape[2:])
     new_v = v.astype(cache_v.dtype).reshape((B, S) + cache_v.shape[2:])
+    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor of the split.
+    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
+    # The programs that step every slot of the engine at once (decode, multi-step, verify:
+    # the ones that gate their writes) go, on the TPU, to the kernel that reads of each slot
+    # the row blocks up to its last visible row and nothing past it: the rows no slot holds
+    # were most of a decode step (PERF.md §6, PR 35). It writes the step's rows itself, a copy
+    # a slot behind its own reads: as a gated write of XLA's before it they were a serial loop
+    # of slots x layers x (K, V) row writes, twice the attention they served (PERF.md §6, PR 47).
+    # Their callers trace them under the engine's mesh (`llm/_engine.py:_traced_on`). A one-slot
+    # view has no gate (a prefill chunk of any bucket, a detached prefill, the draft's own
+    # steps): its queries are many or its rows few, and it takes one write of its rows and the
+    # two products over the whole slab, with the mask built from the same lengths; so do, after
+    # XLA's gated write, a slab of heads under 128 wide kept a head a row, which is not
+    # row-major on the TPU, and every other backend, which has no kernel.
+    if write_gate is not None and attention._use_pallas() and attention.cached_attention_takes(cache_k.shape[-1]):
+        return _cached_attention_on_mesh(qg, cache_k, cache_v, write_at, scale, new_k, new_v, write_gate)
     if write_gate is None:
+        origin = (0,) * (cache_k.ndim - 2)
+
         def put(slot_cache, slot_new, at):
             return jax.lax.dynamic_update_slice(slot_cache, slot_new, (at,) + origin)
 
         cache_k = jax.vmap(put)(cache_k, new_k, write_at)
         cache_v = jax.vmap(put)(cache_v, new_v, write_at)
     else:
-        # Gated write: read the current rows and write them back unchanged
-        # when the gate is off. The read and write clamp identically at the
-        # cache end, so an off-gate slot is a no-op even at the boundary.
-        def put_gated(slot_cache, slot_new, at, gate):
-            cur = jax.lax.dynamic_slice(slot_cache, (at,) + origin, slot_new.shape)
-            new = jnp.where(gate, slot_new, cur)
-            return jax.lax.dynamic_update_slice(slot_cache, new, (at,) + origin)
-
-        cache_k = jax.vmap(put_gated)(cache_k, new_k, write_at, write_gate)
-        cache_v = jax.vmap(put_gated)(cache_v, new_v, write_at, write_gate)
-
-    # Grouped queries: head h reads KV head h // G, so Hkv is the major factor of the split.
-    # The programs that step every slot of the engine at once (decode, multi-step, verify:
-    # the ones that gate their writes) go, on the TPU, to the kernel that reads of each slot
-    # the row blocks up to its last visible row and nothing past it: the rows no slot holds
-    # were most of a decode step (PERF.md §6, PR 35). Their callers trace them under the
-    # engine's mesh (`llm/_engine.py:_traced_on`). A one-slot view has no gate (a prefill
-    # chunk of any bucket, a detached prefill, the draft's own steps): its queries are many
-    # or its rows few, and it takes the two products over the whole slab, with the mask
-    # built from the same lengths; so do a slab of heads under 128 wide kept a head a row,
-    # which is not row-major on the TPU, and every other backend, which has no kernel.
-    qg = q.reshape(B, S, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
-    if write_gate is not None and attention._use_pallas() and attention.cached_attention_takes(cache_k.shape[-1]):
-        out = _cached_attention_on_mesh(qg, cache_k, cache_v, write_at, scale)
-    else:
-        out = attention.cached_attention_xla(qg, cache_k, cache_v, write_at, scale=scale)
-    return out, cache_k, cache_v
+        cache_k = attention.put_gated(cache_k, new_k, write_at, write_gate)
+        cache_v = attention.put_gated(cache_v, new_v, write_at, write_gate)
+    return attention.cached_attention_xla(qg, cache_k, cache_v, write_at, scale=scale), cache_k, cache_v
 
 
-def _cached_attention_on_mesh(qg, cache_k, cache_v, lens, scale, interpret: bool = False):
-    """The kernel, on one device or under the mesh of the enclosing `with mesh:` (the TP
-    engine traces its programs inside one). A `pallas_call` has no partitioning rule, and
-    attention is independent per KV head: under a mesh whose `tp` axis splits the slabs'
-    heads the call runs inside a `shard_map` over that axis; where `tp` does not divide
-    them the slabs are whole on every device (`llm/tp.py:kv_cache_sharding`) and so is the call."""
+def _cached_attention_on_mesh(qg, cache_k, cache_v, lens, scale, new_k, new_v, gate, interpret: bool = False):
+    """The kernel, writing the step's rows at `lens` where `gate`, on one device or under the
+    mesh of the enclosing `with mesh:` (the TP engine traces its programs inside one) ->
+    (out, cache_k, cache_v). A `pallas_call` has no partitioning rule, and attention is
+    independent per KV head: under a mesh whose `tp` axis splits the slabs' heads the call runs
+    inside a `shard_map` over that axis, the new rows split as the slabs are; where `tp` does not
+    divide them the slabs are whole on every device (`llm/tp.py:kv_cache_sharding`) and so is the call."""
     mesh = _mesh_to_split_over()
 
-    def run(qg, cache_k, cache_v, lens):
-        return attention.cached_attention(qg, cache_k, cache_v, lens, scale=scale, interpret=interpret)
+    def run(qg, cache_k, cache_v, new_k, new_v, lens, gate):
+        return attention.cached_attention(qg, cache_k, cache_v, lens, scale=scale, new_k=new_k, new_v=new_v,
+                                          write_at=lens, gate=gate, interpret=interpret)
 
     if mesh is None:
-        return run(qg, cache_k, cache_v, lens)
+        return run(qg, cache_k, cache_v, new_k, new_v, lens, gate)
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape.get("tp", 1)
     heads = "tp" if tp > 1 and qg.shape[2] % tp == 0 and cache_k.shape[2] % tp == 0 else None
     slab = P(None, None, heads)
-    return jax.shard_map(run, mesh=mesh, in_specs=(slab, slab, slab, P()), out_specs=slab,
-                         check_vma=False)(qg, cache_k, cache_v, lens)
+    return jax.shard_map(run, mesh=mesh, in_specs=(slab,) * 5 + (P(), P()), out_specs=(slab,) * 3,
+                         check_vma=False)(qg, cache_k, cache_v, new_k, new_v, lens, gate)
 
 
 def _mlp(layer, x):

@@ -574,8 +574,41 @@ def _cached_block_rows(T: int, groups: int) -> int:
     return want if T % want == 0 else T
 
 
-def _cached_attn_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
-                        scale: float, S: int, G: int, groups: int, per_group: int, block: int, T: int):
+def put_gated(cache, new, at, gate):
+    """The plain form of a step's write: cache [B, T, *minor]; new [B, S, *minor] in the cache's
+    type; slot b's S rows land at rows at[b] + [0, S) where gate[b], and nothing moves where not.
+    The current rows are read and written back where the gate is off; the read and the write
+    clamp alike at the cache's end (at T - S), so a gated-off slot is untouched there too."""
+    origin = (0,) * (cache.ndim - 2)
+
+    def put(slot_cache, slot_new, a, g):
+        cur = jax.lax.dynamic_slice(slot_cache, (a,) + origin, slot_new.shape)
+        return jax.lax.dynamic_update_slice(slot_cache, jnp.where(g, slot_new, cur), (a,) + origin)
+
+    return jax.vmap(put)(cache, new, at, gate)
+
+
+_TILE = 16  # flattened slab rows: what a window starts at and spans (a bfloat16 tile in VMEM; a float32 one is 8)
+
+
+def _write_window(at, S: int, groups: int, T: int):
+    """Where slot rows at + [0, S) lie in the flattened slab, for a slab whose cache row is not
+    whole tiles (`groups` no multiple of 8: two or four heads of 64 a row, a TP device's two
+    heads, three or six of any width): the copies of the chip address whole tiles only, so
+    those rows go as a window of `size` flattened rows from `start` (both multiples of `_TILE`,
+    the window inside the slab) that holds them from row `off` on -> (start, off, size). A cache
+    row starts at a multiple of `gcd(groups, _TILE)` inside its tile, so `off` is at most `_TILE`
+    less that. `at` is clamped already; a scalar or one a slot. None where no such window lies
+    inside the slab: its flattened rows are not whole tiles, or fewer than `size`."""
+    size = -(-(_TILE - math.gcd(groups, _TILE) + S * groups) // _TILE) * _TILE
+    if (T * groups) % _TILE or size > T * groups:
+        return None
+    start = jnp.minimum(at * groups // _TILE * _TILE, T * groups - size)
+    return start, at * groups - start, size
+
+
+def _cached_attn_kernel(*refs, scale: float, S: int, G: int, groups: int, per_group: int, block: int, T: int,
+                        writes: bool):
     """Grid (slot,): online softmax over the slot's live row blocks, which the kernel copies in
     itself, two buffers deep, so that a slot costs no step for a block it does not hold.
 
@@ -584,10 +617,27 @@ def _cached_attn_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems
     k_hbm, v_hbm: the whole flattened slabs [B, T * groups, W], left where they are; a block is
     `block` cache rows, each as `groups` rows of W lanes. A score is live where the column's
     lane group is the row's and its cache row is visible; the zeros of q make a group's other
-    heads add nothing."""
+    heads add nothing.
+
+    Where it `writes`, the slabs are the call's outputs too, aliased to its inputs, and the step's
+    new rows (new_k, new_v: the slot's, a block in VMEM) go into them first: where the slot's gate
+    is on, one copy a slab to rows at + [0, S), `at` clamped to T - S as a `dynamic_update_slice`
+    clamps it (a copy out of bounds is not clamped). A cache row of whole tiles (groups a multiple
+    of 8) goes as it is, [B, S * groups, W]. Any other comes placed in its window of whole tiles
+    (`_write_window`: [B, size, W], zeros round it): the window is copied in, takes the new rows,
+    and is copied back. The write is issued at the top of the slot's grid step and waited for
+    before the copy-in of the first block that holds one of its rows is started, so that it runs
+    behind the earlier blocks' copies and products. Every read of a slab goes through the output's
+    reference: on the chip the two are one buffer, interpreted they are two arrays and the rows
+    are in the output's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if writes:
+        (lens_ref, at_ref, gate_ref, q_ref, new_k, new_v, _, _, o_ref, k_hbm, v_hbm,
+         k_buf, v_buf, sems, put_sems, *windows) = refs
+    else:
+        lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
     b = pl.program_id(0)
     lens = lens_ref[b]
     live_blocks = jnp.minimum(lens + S - 1, T - 1) // block + 1
@@ -598,6 +648,45 @@ def _cached_attn_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems
         return (pltpu.make_async_copy(k_hbm.at[b, rows], k_buf.at[buf], sems.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[b, rows], v_buf.at[buf], sems.at[1, buf]))
 
+    if writes:
+        at = jnp.minimum(at_ref[b], T - S)
+        gate = gate_ref[b] != 0
+        if windows:
+            start, off, size = _write_window(at, S, groups, T)
+            rows = pl.ds(pl.multiple_of(start, _TILE), size)
+            sources = windows
+        else:
+            start, rows, sources = at * groups, pl.ds(at * groups, S * groups), (new_k.at[0], new_v.at[0])
+        first_written = start // width
+        puts = (pltpu.make_async_copy(sources[0], k_hbm.at[b, rows], put_sems.at[0]),
+                pltpu.make_async_copy(sources[1], v_hbm.at[b, rows], put_sems.at[1]))
+
+        @pl.when(gate)
+        def _put():
+            if windows:
+                gets = (pltpu.make_async_copy(k_hbm.at[b, rows], windows[0], put_sems.at[0]),
+                        pltpu.make_async_copy(v_hbm.at[b, rows], windows[1], put_sems.at[1]))
+                for get in gets:
+                    get.start()
+                for get in gets:
+                    get.wait()
+                row = jax.lax.broadcasted_iota(jnp.int32, windows[0].shape, 0)
+                new = (row >= off) & (row < off + S * groups)
+                for window, placed in zip(windows, (new_k, new_v)):
+                    window[...] = jnp.where(new, placed[0], window[...])
+            for put in puts:
+                put.start()
+
+        def landed():
+            for put in puts:
+                put.wait()
+
+    def before_copy_in(j):
+        """The new rows have landed before the first block that holds one of them is copied in."""
+        if writes:
+            pl.when(gate & (first_written == j))(landed)
+
+    before_copy_in(0)
     for copy in copies(0, 0):
         copy.start()
     q = q_ref[0]
@@ -609,6 +698,7 @@ def _cached_attn_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems
 
         @pl.when(j + 1 < live_blocks)
         def _next():
+            before_copy_in(j + 1)
             for copy in copies(j + 1, 1 - buf):
                 copy.start()
 
@@ -636,16 +726,30 @@ def _cached_attn_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems
     rows = q.shape[0]
     _, l, acc = jax.lax.fori_loop(0, live_blocks, one_block, (
         jnp.full((rows, 1), _NEG_INF, jnp.float32), jnp.zeros((rows, 1), jnp.float32), jnp.zeros(q.shape, jnp.float32)))
+    if writes:  # rows past the slot's live blocks (a length told under the write row) are read by no block
+        pl.when(gate & (first_written >= live_blocks))(landed)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def cached_attention(q, cache_k, cache_v, lens, *, scale, interpret: bool = False):
+def cached_attention(q, cache_k, cache_v, lens, *, scale, new_k=None, new_v=None, write_at=None, gate=None,
+                     interpret: bool = False):
     """The length-aware kernel: as `cached_attention_xla`, reading of each slot only the row
-    blocks up to its last visible row. The slab goes in as it lies: its rows and the axes
-    between them and the last are flattened, which moves nothing. Jitted, so that a program
-    of 24 layers traces the kernel and lowers it to Mosaic once and calls that 24 times:
-    traced in line, each layer's call costs 0.09 s of every start, warm or cold (PERF.md §6, PR 35)."""
+    blocks up to its last visible row -> (out, cache_k, cache_v). The slab goes in as it lies:
+    its rows and the axes between them and the last are flattened, which moves nothing.
+
+    Handed a step's new rows (new_k, new_v: [B, S, *minor] in the slabs' types), the row each
+    slot writes at (write_at: [B], its own operand: a slab writes at `lens`, a ring at `lens` mod
+    its window and is told a shorter length) and the gate ([B] bool), the kernel writes them
+    into the slabs itself before it reads them, as `put_gated` would have, and the slabs it
+    returns are the ones it was given, in place (`input_output_aliases`); handed none, it writes
+    nothing and returns the slabs as they came. (A slab whose cache row is no whole tile and that
+    holds no window of whole tiles, `_write_window`, is written by `put_gated` first: an odd
+    number of flattened rows, a ring shorter than a window.)
+
+    Jitted, so that a program of 24 layers traces the kernel and lowers it to Mosaic once and
+    calls that 24 times: traced in line, each layer's call costs 0.09 s of every start, warm or
+    cold (PERF.md §6, PR 35)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -662,30 +766,60 @@ def cached_attention(q, cache_k, cache_v, lens, *, scale, interpret: bool = Fals
     qf = qf.reshape(B, rows, W)
     block = _cached_block_rows(T, groups)
     flat = (B, T * groups, W)
+    writes = new_k is not None
+    if writes and groups % 8 and _write_window(0, S, groups, T) is None:
+        cache_k, cache_v = put_gated(cache_k, new_k, write_at, gate), put_gated(cache_v, new_v, write_at, gate)
+        writes = False
     kernel = functools.partial(_cached_attn_kernel, scale=float(scale), S=S, G=G, groups=groups,
-                               per_group=per_group, block=block, T=T)
-    out = pl.pallas_call(
+                               per_group=per_group, block=block, T=T, writes=writes)
+    scalars = [lens.astype(jnp.int32)]
+    per_slot = pl.BlockSpec((1, rows, W), lambda b, *scalars: (b, 0, 0))
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    operands, in_specs = [qf], [per_slot]
+    out_specs, out_shape = [per_slot], [jax.ShapeDtypeStruct((B, rows, W), q.dtype)]
+    scratch = [pltpu.VMEM((2, block * groups, W), cache_k.dtype), pltpu.VMEM((2, block * groups, W), cache_v.dtype),
+               pltpu.SemaphoreType.DMA((2, 2))]
+    aliases = {}
+    if writes:
+        assert new_k.shape == (B, S) + cache_k.shape[2:] and new_k.dtype == cache_k.dtype, (new_k.shape, new_k.dtype)
+        assert new_v.shape == (B, S) + cache_v.shape[2:] and new_v.dtype == cache_v.dtype, (new_v.shape, new_v.dtype)
+        scalars += [write_at.astype(jnp.int32), gate.astype(jnp.int32)]
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+        new_k, new_v = new_k.reshape(B, S * groups, W), new_v.reshape(B, S * groups, W)
+        if groups % 8:  # a cache row is no whole tile: each slot's rows placed in their window of whole tiles
+            _, off, size = _write_window(jnp.minimum(scalars[1], T - S), S, groups, T)
+            row = jnp.arange(size)[None, :, None] - off[:, None, None]
+
+            def placed(new):  # row off + i of a slot's window is its new row i: selects, which no slot serialises
+                return functools.reduce(lambda out, i: jnp.where(row == i, new[:, i:i + 1], out), range(S * groups),
+                                        jnp.zeros((B, size, W), new.dtype))
+
+            new_k, new_v = placed(new_k), placed(new_v)
+            scratch += [pltpu.VMEM((size, W), cache_k.dtype), pltpu.VMEM((size, W), cache_v.dtype)]
+        new_rows = pl.BlockSpec((1, new_k.shape[1], W), lambda b, *scalars: (b, 0, 0))
+        operands += [new_k, new_v]
+        in_specs += [new_rows, new_rows]
+        out_specs += [whole, whole]
+        out_shape += [jax.ShapeDtypeStruct(flat, cache_k.dtype), jax.ShapeDtypeStruct(flat, cache_v.dtype)]
+        slabs_at = len(scalars) + len(operands)  # the slabs' places among the operands, the scalars counted
+        aliases = {slabs_at: 1, slabs_at + 1: 2}
+    out, *slabs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, rows, W), lambda b, lens: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, rows, W), lambda b, lens: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, block * groups, W), cache_k.dtype),
-                pltpu.VMEM((2, block * groups, W), cache_v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
+            in_specs=in_specs + [whole, whole],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, rows, W), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
         name="cached_attn",
-    )(lens.astype(jnp.int32), qf, cache_k.reshape(flat), cache_v.reshape(flat))
+    )(*scalars, *operands, cache_k.reshape(flat), cache_v.reshape(flat))
+    if writes:
+        cache_k, cache_v = slabs[0].reshape(cache_k.shape), slabs[1].reshape(cache_v.shape)
     out = out.reshape(B, groups, per_group, S * G, per_group, D)
     if per_group > 1:
         out = jnp.stack([out[:, :, u, :, u] for u in range(per_group)], axis=2)
-    return jnp.transpose(out.reshape(B, Hkv, S, G, D), (0, 2, 1, 3, 4))
+    return jnp.transpose(out.reshape(B, Hkv, S, G, D), (0, 2, 1, 3, 4)), cache_k, cache_v

@@ -3,6 +3,8 @@ of `models/llama.py:_cached_products`) in interpret mode against the XLA product
 there, which stay the plain form off the TPU: every visible row attended, none past it read
 into a result, at the slab shapes the engine's blocks keep."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,18 +18,23 @@ T = 1024  # rows a slot: eight blocks of the kernel where a cache row is 8 rows 
 
 
 # (head_dim, G, the slab's axes after the rows): the dense block's [Hkv, 128]; heads of 64 as the
-# hybrid blocks keep them, two to a row of 128 lanes, and as a dense model keeps them
+# hybrid blocks keep them, two to a row of 128 lanes, and as a dense model keeps them; and cache rows
+# that no tile of 16 flattened rows holds a whole number of: six heads of 128, six of 64 two to a row
 SLABS = {
     "d128g2": (128, 2, (8, 128)),
     "d64g4_two_heads_a_row": (64, 4, (4, 128)),
     "d64g4_a_head_a_row": (64, 4, (8, 64)),
     "d128g4": (128, 4, (8, 128)),
+    "d128g2_six_rows": (128, 2, (6, 128)),
+    "d64g4_three_rows": (64, 4, (3, 128)),
 }
+# a cache row of three or six flattened rows divides no block size, so those slabs are one block of T rows
+BLOCKED = sorted(name for name, (_, _, minor) in SLABS.items() if attention._cached_block_rows(T, minor[0]) < T)
 
 
-def _case(slab, S, lens, *, scale=None, slab_dtype=jnp.float32):
+def _case(slab, S, lens, *, scale=None, slab_dtype=jnp.float32, T=T):
     D, G, minor = SLABS[slab]
-    Hkv = 8
+    Hkv = math.prod(minor) // D
     B = len(lens)
     keys = jax.random.split(jax.random.PRNGKey(D + G + S), 3)
     q = jax.random.normal(keys[0], (B, S, Hkv, G, D), jnp.float32)
@@ -38,9 +45,11 @@ def _case(slab, S, lens, *, scale=None, slab_dtype=jnp.float32):
 
 
 def _both(q, cache_k, cache_v, lens, scale):
-    got = attention.cached_attention(q, cache_k, cache_v, lens, scale=scale, interpret=True)
+    got, same_k, same_v = attention.cached_attention(q, cache_k, cache_v, lens, scale=scale, interpret=True)
     want = attention.cached_attention_xla(q, cache_k, cache_v, lens, scale=scale)
     assert got.shape == want.shape == q.shape and got.dtype == q.dtype
+    # handed no new rows it writes none: the slabs as they came
+    assert np.array_equal(same_k, cache_k, equal_nan=True) and np.array_equal(same_v, cache_v, equal_nan=True)
     return np.asarray(got), np.asarray(want)
 
 
@@ -53,7 +62,7 @@ def _edges(slab, S):
 
 
 @pytest.mark.parametrize("S", [1, 4], ids=["decode", "verify_s4"])
-@pytest.mark.parametrize("slab", sorted(SLABS))
+@pytest.mark.parametrize("slab", BLOCKED)
 def test_kernel_equals_the_products_at_every_edge_of_a_block(slab, S):
     """One slot a length: 0 (the new row alone), 1, the last row of a block, the first of the
     next, and `T - S`. The kernel reads no block past a slot's last visible row, so a wrong
@@ -71,8 +80,8 @@ def test_kernel_reads_no_block_past_a_slots_last_visible_row(slab):
     block = attention._cached_block_rows(T, minor[0])
     q, cache_k, cache_v, lens, scale = _case(slab, 1, [0, 5, block - 1, block, 2 * block + 3])
     dead = (jnp.arange(T)[None, :] // block > lens[:, None] // block).reshape((len(lens), T) + (1,) * len(minor))
-    got = attention.cached_attention(q, jnp.where(dead, jnp.nan, cache_k), jnp.where(dead, jnp.nan, cache_v),
-                                     lens, scale=scale, interpret=True)
+    got, _, _ = attention.cached_attention(q, jnp.where(dead, jnp.nan, cache_k), jnp.where(dead, jnp.nan, cache_v),
+                                           lens, scale=scale, interpret=True)
     want = attention.cached_attention_xla(q, jnp.where(dead, 0.0, cache_k), jnp.where(dead, 0.0, cache_v),
                                           lens, scale=scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
@@ -93,6 +102,119 @@ def test_kernel_takes_the_scale_it_is_handed_and_a_bfloat16_slab(slab):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     other, _ = _both(*_case(slab, 1, [7, 333], slab_dtype=jnp.bfloat16))
     assert np.abs(other - got).max() > 1e-3  # the scale reached the scores
+
+
+TAKEN = [name for name in BLOCKED if attention.cached_attention_takes(SLABS[name][2][-1])]
+
+
+def _written(slab, S, lens, gate, *, write_at=None, told=None, slab_dtype=jnp.float32, run=None, T=T):
+    """The kernel handed the step's rows against the parent's path, XLA's gated write and then the
+    kernel over the written slabs: the same `out` and the same slabs to the last bit; and near the
+    products over them. `run`: another way to the kernel taking (q, k, v, new_k, new_v, lens, gate)."""
+    q, cache_k, cache_v, lens, scale = _case(slab, S, lens, slab_dtype=slab_dtype, T=T)
+    keys = jax.random.split(jax.random.PRNGKey(S + len(lens)), 2)
+    new_k, new_v = (jax.random.normal(k, cache_k.shape[:1] + (S,) + cache_k.shape[2:]).astype(slab_dtype) for k in keys)
+    at = lens if write_at is None else jnp.asarray(write_at, jnp.int32)
+    told = lens if told is None else jnp.asarray(told, jnp.int32)
+    gate = jnp.asarray(gate)
+    want_k, want_v = attention.put_gated(cache_k, new_k, at, gate), attention.put_gated(cache_v, new_v, at, gate)
+    want, _, _ = attention.cached_attention(q, want_k, want_v, told, scale=scale, interpret=True)
+    if run is None:
+        got = attention.cached_attention(q, cache_k, cache_v, told, scale=scale, new_k=new_k, new_v=new_v, write_at=at,
+                                         gate=gate, interpret=True)
+    else:
+        got = run(q, cache_k, cache_v, new_k, new_v, told, gate, scale)
+    for g, w, what in zip(got, (want, want_k, want_v), ("out", "cache_k", "cache_v")):
+        assert g.shape == w.shape and g.dtype == w.dtype, what
+        if run is None or what != "out":  # another split of the heads is other products: near, not equal
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=what)
+    products = attention.cached_attention_xla(q, want_k, want_v, told, scale=scale)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(products), rtol=2e-5, atol=2e-5)
+    off = ~np.asarray(gate)
+    np.testing.assert_array_equal(np.asarray(got[1])[off], np.asarray(cache_k)[off])  # a gated-off slot: untouched
+    np.testing.assert_array_equal(np.asarray(got[2])[off], np.asarray(cache_v)[off])
+    return np.asarray(got[1]), np.asarray(cache_k)
+
+
+# what a slot's gate is over the lengths of `_edges` and the last row of a block: on everywhere, off
+# in the slot at a block's edge, off in every slot
+GATES = {"every_slot_writes": lambda n: [True] * n, "gate_off_in_one_slot": lambda n: [i != 3 for i in range(n)],
+         "gate_off_in_every_slot": lambda n: [False] * n}
+
+
+@pytest.mark.parametrize("gates", sorted(GATES))
+@pytest.mark.parametrize("S", [1, 4], ids=["decode", "verify_s4"])
+@pytest.mark.parametrize("slab", TAKEN)
+def test_kernel_writes_the_steps_rows_itself_at_every_edge_of_a_block(slab, S, gates):
+    """A slot a write row: 0, 1, the rows that end a block, straddle its edge and start the next,
+    the last row of a block, and `T - S`; the gate on everywhere, off in one slot and off in all.
+    The slabs come back as XLA's gated write left them and `out` as the kernel's over those, bit
+    for bit: the step's queries see the step's rows, and a gated-off slot's slab is untouched."""
+    block = attention._cached_block_rows(T, SLABS[slab][2][0])
+    lens = _edges(slab, S) + [block - 1]
+    after, before = _written(slab, S, lens, GATES[gates](len(lens)))
+    assert (after != before).any() == (gates != "gate_off_in_every_slot")
+
+
+@pytest.mark.parametrize("S", [1, 4], ids=["decode", "verify_s4"])
+@pytest.mark.parametrize("slab", TAKEN)
+def test_kernel_clamps_a_write_past_the_caches_end_as_the_gated_write_does(slab, S):
+    """Slots at `T - S` (the last write that fits), one row past it, at the last row and past the
+    cache: XLA's `dynamic_update_slice` lands those at `T - S`, and so does the kernel, which
+    clamps the row before its copy (a copy out of bounds is not clamped for it); the last slot's
+    gate is off there, and its last rows are untouched."""
+    _written(slab, S, [T - S, T - S + 1, T - 1, T + 5, T + 5], [True, True, True, True, False])
+
+
+@pytest.mark.parametrize("S", [1, 4], ids=["decode", "verify_s4"])
+@pytest.mark.parametrize("slab", TAKEN)
+def test_kernel_writes_a_bfloat16_slab(slab, S):
+    """The engine's slabs: bfloat16 rows under float32 queries, two rows to a 32-bit word."""
+    block = attention._cached_block_rows(T, SLABS[slab][2][0])
+    _written(slab, S, [0, 7, block - 1, block, 333, T - S, T + 5], [True, True, True, True, False, True, True],
+             slab_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("slab", TAKEN)
+def test_kernel_writes_a_ring_at_its_own_row_under_a_length_told_shorter(slab):
+    """`laguna`'s window layers: a ring of W rows written at `lens % W` and told `min(lens, W - 1)`,
+    so the write row is its own operand: before the wrap the two agree; after it the row lies
+    anywhere in the ring, in the first block, at an edge and in the last, under a length that
+    reads every block. An idle slot is told 0 and writes nothing."""
+    W = T
+    block = attention._cached_block_rows(W, SLABS[slab][2][0])
+    lens = np.asarray([5, W - 1, W, W + 5, 3 * W + block - 1, 2 * W + block, 7 * W - 1, 4 * W + 77])
+    gate = np.asarray([True] * 7 + [False])
+    _written(slab, 1, np.where(gate, np.minimum(lens, W - 1), 0), gate, write_at=lens % W)
+
+
+@pytest.mark.parametrize("slab_dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("S", [1, 4], ids=["decode", "verify_s4"])
+@pytest.mark.parametrize("slab", sorted(set(SLABS) - set(BLOCKED)))
+def test_kernel_writes_a_cache_row_that_starts_anywhere_in_a_tile(slab, S, slab_dtype):
+    """A cache row of six flattened rows starts at any even row of a tile of 16 and one of three at
+    any row, so the window of whole tiles that carries a write has to hold `16 - gcd(groups, 16)`
+    rows before it (a window sized for two or four rows a cache row, which divide 16, dropped the
+    rows past it and nothing said so): every start in a tile, the cache's end and past it."""
+    rows = 128  # one block either way, so a short slab tests the same
+    lens = list(range(17)) + [77, rows - S - 1, rows - S, rows + 5, 40]
+    _written(slab, S, lens, [True] * (len(lens) - 1) + [False], slab_dtype=slab_dtype, T=rows)
+
+
+@pytest.mark.parametrize("S,rows", [(1, 10), (4, 4)], ids=["rows_no_whole_tiles", "a_ring_under_a_window"])
+def test_a_slab_that_holds_no_window_is_written_before_the_kernel(S, rows):
+    """Forty flattened rows are no whole tiles, and sixteen are fewer than the 32 a window for four
+    cache rows of four takes: no window of whole tiles lies in such a slab, so `put_gated` writes it
+    and the kernel reads it, with the same results."""
+    assert attention._write_window(0, S, 4, rows) is None
+    _written("d64g4_two_heads_a_row", S, [0, 3, rows - S, rows + 2, 1], [True, True, True, True, False], T=rows)
+
+
+def test_kernel_lands_rows_written_past_the_blocks_it_reads():
+    """The two numbers a slot are independent: a write row in a block past the last the length
+    makes live is written all the same, before the kernel's end."""
+    after, before = _written("d128g2", 1, [3, 3], [True, True], write_at=[900, 5])
+    assert (after[0, 900] != before[0, 900]).all() and (after[1, 5] != before[1, 5]).all()
 
 
 def _layer(cfg, key):
@@ -173,24 +295,30 @@ def test_the_gated_programs_take_the_kernel_and_a_one_slot_view_never_does(monke
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-5, atol=2e-5)
 
 
-def test_the_kernel_runs_inside_a_shard_map_over_the_meshs_tp_axis():
+@pytest.mark.parametrize("S", [1, 4], ids=["decode", "verify_s4"])
+def test_the_kernel_runs_inside_a_shard_map_over_the_meshs_tp_axis(S):
     """Under the TP engine's mesh (`with mesh:` round the trace) the call is split over the KV
-    heads, each device's heads against its own part of the slabs, and gives the one-device result."""
+    heads, each device's heads and new rows against its own part of the slabs (four heads of
+    128 a device: a cache row of half a tile), and gives the one-device result: both slabs
+    written bit for bit, a gated-off slot's kept, and `out` the products' over them."""
     devices = jax.devices()
     if len(devices) < 2:
         pytest.skip("one device")
     from ray_tpu.llm import tp as tp_plan
 
     mesh = tp_plan.build_tp_mesh(2, devices=devices[:2])
-    q, cache_k, cache_v, lens, scale = _case("d128g2", 1, [3, 100])
-    want = attention.cached_attention_xla(q, cache_k, cache_v, lens, scale=scale)
     slab = tp_plan.kv_cache_sharding(mesh, 8)
-    with mesh:
-        run = jax.jit(lambda q, k, v, n: llama._cached_attention_on_mesh(q, k, v, n, scale, interpret=True))
-        got = run(q, jax.device_put(cache_k, slab), jax.device_put(cache_v, slab), lens)
-        text = run.lower(q, jax.device_put(cache_k, slab), jax.device_put(cache_v, slab), lens).as_text()
-    assert "shard_map" in text or "manual" in text
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    texts = []
+
+    def on_mesh(q, k, v, new_k, new_v, lens, gate, scale):
+        with mesh:
+            run = jax.jit(lambda *a: llama._cached_attention_on_mesh(*a[:3], a[5], scale, a[3], a[4], a[6], interpret=True))
+            args = (q, *(jax.device_put(x, slab) for x in (k, v, new_k, new_v)), lens, gate)
+            texts.append(run.lower(*args).as_text())
+            return run(*args)
+
+    _written("d128g2", S, [3, 100, 127, T - S, 500], [True, True, True, True, False], run=on_mesh)
+    assert "shard_map" in texts[0] or "manual" in texts[0]
 
 
 # -- the benchmark's reader of the mechanism: `kv_attn_roofline.serve` -------------------------

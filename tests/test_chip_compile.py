@@ -237,6 +237,17 @@ def _weight_converts(text, params):
             if (m := re.search(r"= bf16\[([\d,]+)\]\S* convert\(", line)) and tuple(int(n) for n in m.group(1).split(",")) in shapes]
 
 
+def _slab_updates(text, *slabs):
+    """The compiled text's `dynamic-update-slice`s into an array of one of these shapes: XLA's
+    write of rows into a K or V slab. A decode program holds none since the kernel writes a
+    step's rows itself (PERF.md §6, PR 47: the gated write was one a slot a slab a layer, run one
+    after another); a chunk keeps its one a slab."""
+    shapes = {tuple(shape) for shape in slabs}
+    return [line.strip()[:160] for line in text.splitlines()
+            if (m := re.search(r"= \w+\[([\d,]+)\]\S* dynamic-update-slice\(", line))
+            and tuple(int(n) for n in m.group(1).split(",")) in shapes]
+
+
 def _sampler_operands(slots, sharding):
     """What `rt_decode` and `rt_decode_multi_n<n>` take after the gate: the slots' temperatures and the sampler's key."""
     return (_operand((slots,), sharding, jnp.float32), _operand((2,), sharding, jnp.uint32))
@@ -282,7 +293,9 @@ def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program
     (`copy(%caches_...)`, four here), 6.9 ms of a 25.5 ms decode step and 6.7 of a 17.3 ms chunk
     (PERF.md §6, PR 33). The decode programs no longer hold a slab in any second layout
     (`test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e`); the chunk's two
-    products read theirs as fused operands. And the weights are read as they are multiplied: no
+    products read theirs as fused operands, and its rows go into each slab by one
+    `dynamic-update-slice`, where a decode program holds none: the kernel writes a step's rows
+    (PERF.md §6, PR 47). And the weights are read as they are multiplied: no
     `convert` of a kernel's or the table's size is left in any of the three (over the float32
     tree a single step read 7.56 GB for 3.78 GB of products and the 8-step program kept a
     converted copy of every kernel, 3.6 GiB of temporaries at the whole depth: PERF.md §6, PR
@@ -295,6 +308,8 @@ def test_the_dense_programs_write_the_kv_slab_in_place_for_v5e(one_chip, program
     text = compiled.as_text()
     assert slab + "{3,2,1,0" in text  # the slab as the engine holds it: row-major
     assert not re.search(re.escape(slab) + r"\{3,2,1,0[^}]*\} copy\(", text)
+    written = _slab_updates(text, (slots, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim))
+    assert len(written) == (2 * cfg.n_layers if program == "rt_prefill_b128" else 0), written  # a chunk's own write; a step's is the kernel's
     assert not _weight_converts(text, params)
     leaves = jax.tree_util.tree_leaves(params)
     assert {a.dtype for a in leaves if len(a.shape) >= 2} == {jnp.dtype(jnp.bfloat16)}
@@ -331,8 +346,9 @@ def test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e(one_chip,
     donates them: the text holds the `cached_attn` call, every cache array is aliased to its
     output, no operation copies an array of a slab's size (the parent's programs copied every
     head-64 slab into another layout each step, `jit_rt_decode:copy.24` and its like, a quarter of
-    a `granite_hybrid` step: PERF.md §6, PR 35), and no `[B, Hkv, G, S, T]` array of scores over
-    every row of every slot is left."""
+    a `granite_hybrid` step: PERF.md §6, PR 35), no `dynamic-update-slice` writes into a slab
+    (the kernel writes the step's rows: PERF.md §6, PR 47), and no `[B, Hkv, G, S, T]` array of
+    scores over every row of every slot is left."""
     from ray_tpu import models
     from ray_tpu.parallel.mesh import unbox
 
@@ -363,6 +379,8 @@ def test_the_decode_programs_read_the_slabs_through_the_kernel_for_v5e(one_chip,
     sized = [line.strip()[:160] for line in text.splitlines()
              if (m := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)) and math.prod(int(n) for n in m.group(1).split(",")) >= slab]
     assert not sized, sized
+    kv = module.init_caches(cfg, slots, 8)[-1][0].shape  # the last layer is an attention layer in all three
+    assert not _slab_updates(text, (slots, T) + tuple(kv[2:])), "XLA writes a step's rows"
     G = cfg.n_heads // cfg.n_kv_heads
     shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
     assert not {s for s in shapes if T in s and math.prod(s) == slots * cfg.n_kv_heads * G * T}, "scores over every row"
@@ -399,15 +417,24 @@ def test_the_decode_program_ends_in_a_sampler_that_sorts_nothing_for_v5e(one_chi
     # The sampler leaves the block's schedule alone: every layer's norm scales are fetched behind a
     # product, as without it. Drawn as one `[B, V]` fusion the compiler started those fetches after
     # the product before them and each `copy-done` waited: 0.3 ms a step on the chip (PERF.md §6, PR 37).
+    # Since the kernel writes a step's rows (PERF.md §6, PR 47) XLA's gated write is gone from between a
+    # layer's first fetch and its first product, and in a program this shallow (every kernel prefetched at
+    # its start) an `attn_norm` scale's 8 KB come behind the copy that lays that layer's k or v kernel out
+    # for its product, 4 MB, and nothing else; the cell's 24 layers keep 47 of 48 fetches behind a product.
     entry = text[text.index("ENTRY "):].splitlines()
     at = {m.group(1): i for i, line in enumerate(entry) if (m := re.match(r"\s*%([\w.\-]+) = ", line))}
-    fetched = 0
+    kv_kernel = rf"= bf16\[{cfg.n_kv_heads * cfg.head_dim},{cfg.hidden}\]\S* copy\(.*op_name=\"[^\"]*/layer_%s/attn/dot_general\""
+    fetched, behind_a_copy = 0, 0
     for i, line in enumerate(entry):
         done = re.match(r"\s*%copy-done[\w.\-]* = .*copy-done\(%([\w.\-]+)\)", line)
-        if done and re.search(r"copy-start\(%params__layer_\d+____\w+_norm", entry[at[done.group(1)]]):
+        if done and (scale := re.search(r"copy-start\(%params__layer_(\d+)____(\w+_norm)", entry[at[done.group(1)]])):
             fetched += 1
             between = entry[at[done.group(1)]:i]
-            assert any(" fusion(" in op and "dot_general" in op and "kind=kOutput" in op for op in between), line.strip()[:120]
+            if not any(" fusion(" in op and "dot_general" in op and "kind=kOutput" in op for op in between):
+                layer, norm = scale.groups()
+                assert norm == "attn_norm" and any(re.search(kv_kernel % layer, op) for op in between), line.strip()[:120]
+                behind_a_copy += 1
+    assert behind_a_copy <= cfg.n_layers
     assert fetched == 2 * cfg.n_layers
     out = jax.eval_shape(functools.partial(DecodeEngine._decode_sample, engine), *args)
     assert (out[0].shape, out[0].dtype, out[1].shape, out[1].dtype) == ((slots,), jnp.int32, (slots, cfg.vocab_size), jnp.float32)
@@ -498,6 +525,8 @@ def test_the_tp_engines_programs_compile_for_v5e_2x2(v5e_2x2, program):
     if program in ("rt_decode", "rt_decode_multi_n8", "rt_spec_verify_k4"):
         assert kernels == cfg.n_layers and " all-reduce" in text
         assert f"bf16[{slots},{T},{cfg.n_kv_heads // 4},{cfg.head_dim}]" in text  # a device's heads of the slab
+        # two heads a device: a cache row of a quarter of a tile, written through its window of whole tiles
+        assert not _slab_updates(text, (slots, T, cfg.n_kv_heads // 4, cfg.head_dim))
     else:
         assert kernels == 0 and "tpu_custom_call" not in text
 
@@ -817,7 +846,10 @@ def test_the_laguna_cells_programs_fit_the_chip_and_copy_no_slab_and_score_no_ro
     shapes = {tuple(int(n) for n in dims.split(",")) for dims in re.findall(r"\bf32\[([\d,]+)\]", text)}
     assert not {sh for sh in shapes if 32768 in sh and math.prod(sh) >= 32768 * 1024}, "scores over every row of a slab"
     kernel_calls = [line for line in text.splitlines() if re.search(r"%cached_attn(\.\d+)? = ", line)]
+    written = _slab_updates(text, (24, 32768, 8, 128), (24, 512, 8, 128))
     if program == "rt_prefill_b1024":
         assert not kernel_calls and (8, 6, 1024, 1024) in shapes  # one block of keys' scores, 48 heads: XLA's fusions hold them (PERF.md §7)
+        assert written  # the chunk's own rows
     else:
         assert len(kernel_calls) == 5 and all("kv_attn" in line for line in kernel_calls)
+        assert not written, written  # the kernel writes a step's row into slab and ring (PERF.md §6, PR 47)

@@ -241,13 +241,15 @@ def test_the_decode_step_through_the_kernel_is_the_step_through_the_products(mon
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
     kernel, calls = attention.cached_attention, []
 
-    def interpreted(q, ck, cv, seen, scale):
-        calls.append((ck.shape[1], q.shape[3], np.asarray(seen).tolist()))
-        return kernel(q, ck, cv, seen, scale=scale, interpret=True)
+    def interpreted(q, ck, cv, seen, scale, new_k, new_v, write_at, gate):
+        calls.append((ck.shape[1], q.shape[3], np.asarray(seen).tolist(), np.asarray(write_at).tolist()))
+        assert np.asarray(gate).tolist() == [False, True, True] and new_k.shape == (3, 1) + ck.shape[2:]
+        return kernel(q, ck, cv, seen, scale=scale, new_k=new_k, new_v=new_v, write_at=write_at, gate=gate, interpret=True)
 
     monkeypatch.setattr(attention, "cached_attention", interpreted)
     got, got_caches, counted_kernel = lg.decode(params, cfg, last, caches, lens, gate)
-    assert calls == [(32, 3, [0, 27, 5]), (16, 5, [0, 15, 5]), (16, 5, [0, 15, 5])]  # a ring is told at most window - 1
+    # a ring is told at most window - 1 and writes at the position mod the window; the kernel writes the row itself
+    assert calls == [(32, 3, [0, 27, 5], [9, 27, 5]), (16, 5, [0, 15, 5], [9, 11, 5]), (16, 5, [0, 15, 5], [9, 11, 5])]
     np.testing.assert_allclose(np.asarray(got)[1:], np.asarray(want)[1:], atol=ATOL)
     for a, b in zip(got_caches, want_caches):  # a later layer's rows are a function of the layers before it
         for x, y in zip(a, b):

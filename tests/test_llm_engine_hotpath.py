@@ -1069,6 +1069,68 @@ def test_the_served_trees_programs_give_the_float32_trees_logits_bit_for_bit(pro
         engine.shutdown()
 
 
+@pytest.mark.parametrize("program", ["rt_decode", "rt_decode_multi_n4"])
+def test_the_decode_programs_through_the_kernel_that_writes_give_the_xla_paths_step(monkeypatch, program):
+    """The engine's decode bodies as the TPU runs them (the kernel `cached_attn` writes the step's
+    K and V rows itself and attends; here interpreted, heads of 128) against the same bodies on
+    XLA's gated write and the two products: the same tokens, the first layer's slabs to the last
+    bit (its rows are a function of the tokens alone; a gated-off slot's untouched), and the
+    logits and the later layers' rows as near as another order of the softmax's sums leaves them."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import DecodeEngine
+    from ray_tpu.models import llama
+    from ray_tpu.models.transformer import get_config
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import unbox
+
+    cfg = get_config("test-tiny", hidden=256, n_heads=2, n_kv_heads=1, scan_layers=False, remat=False)
+    assert cfg.head_dim == 128 and attention.cached_attention_takes(cfg.head_dim)
+    engine = DecodeEngine(cfg, unbox(llama.init_params(cfg, jax.random.PRNGKey(0))), num_slots=3, max_seq=64,
+                          decode_loop=False, prefix_cache=False)
+    try:
+        prompt = np.zeros((1, 16), np.int32)
+        prompt[0, :11] = np.arange(3, 14)
+        prefill = jax.jit(engine._prefill_at)
+
+        def run():
+            caches = engine._block.init_caches(cfg, 3, 64)
+            for slot in (1, 2):
+                _, caches = prefill(engine.params, None, jnp.asarray(prompt), caches, jnp.int32(slot), jnp.int32(0),
+                                    jnp.int32(11), jnp.int32(0))
+            before = [np.asarray(k) for k, _ in caches]
+            step = (engine.params, None, jnp.zeros((3,), jnp.int32), jnp.asarray([0, 7, 9], jnp.int32), caches,
+                    jnp.asarray([0, 11, 11], jnp.int32), jnp.asarray([False, True, True]),
+                    jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(0))
+            body = engine._decode_sample if program == "rt_decode" else functools.partial(engine._decode_multi, n=4)
+            return before, jax.jit(body)(*step)
+
+        before, want = run()
+        calls = []
+        monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+        monkeypatch.setattr(attention, "cached_attention", lambda *a, kernel=attention.cached_attention, **kw: (
+            calls.append(kw["new_k"].shape), kernel(*a, **{**kw, "interpret": True}))[1])
+        _, got = run()
+        assert calls == [(3, 1, 1, 128)] * cfg.n_layers  # traced once a layer, a scan's body once for its steps
+        steps = 1 if program == "rt_decode" else 4
+        got_caches, want_caches = got[2 if program == "rt_decode" else 1], want[2 if program == "rt_decode" else 1]
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))  # the tokens
+        for layer, ((gk, gv), (wk, wv)) in enumerate(zip(got_caches, want_caches)):
+            for g, w in ((gk, wk), (gv, wv)):
+                np.testing.assert_array_equal(np.asarray(g)[0], np.asarray(w)[0])  # the gated-off slot
+                np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-5, atol=2e-5)
+            np.testing.assert_array_equal(np.asarray(gk)[0], before[layer][0])
+            assert not np.array_equal(np.asarray(gk)[1, 11:11 + steps], before[layer][1, 11:11 + steps])  # the rows landed
+        np.testing.assert_array_equal(np.asarray(got_caches[0][0])[:, :12], np.asarray(want_caches[0][0])[:, :12])
+        if program == "rt_decode":
+            np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=2e-4, atol=2e-4)  # the logits
+    finally:
+        engine.shutdown()
+
+
 def _served_greedy_ids(path):
     """Greedy ids through one path that reads `engine.params` somewhere else than the plain
     decode round, from an engine built from the float32 tree."""
