@@ -2,17 +2,16 @@
 
 This is where the reference framework leans on CUDA (vLLM/torch SDPA under Ray's LLM and
 Train libraries); the TPU rebuild owns the kernel. Forward is an online-softmax flash
-kernel tiled for the MXU (q blocked over the grid, k/v streamed per block); backward is a
-custom VJP that recomputes attention blockwise in plain XLA (a Pallas backward kernel is a
-later optimization). On non-TPU backends the reference JAX implementation runs instead, so
-the same model code tests on the virtual CPU mesh.
+kernel tiled for the MXU (q blocked over the grid, a head's k/v walked in strips up to the
+diagonal); backward is a custom VJP over a one-pass Pallas kernel. On non-TPU backends, and
+for a call under 128 rows on any (`flash_takes`), the reference JAX implementation runs
+instead, so the same model code tests on the virtual CPU mesh.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -56,22 +55,36 @@ def _attention_with_lse(q, k, v, *, causal, scale, positions_q=None, positions_k
 
 # ------------------------------------------------------------------ pallas kernel
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                      scale: float, causal: bool):
-    """Grid (BH, nq, nk), nk innermost+sequential: online softmax state lives in VMEM
-    scratch across k-steps (canonical TPU flash structure — no dynamic lane slicing).
+def _lanes(x, n: int):
+    """A statistic kept the same in every lane, [rows, L], as [rows, n]: whole copies side by
+    side, cut to n."""
+    return jnp.tile(x, (1, -(-n // x.shape[1])))[:, :n]
 
-    Refs are the raw (1, x, y) blocks; values are squeezed after load (ref-level
-    slicing of lane-padded blocks is rejected by Mosaic). Scratch: acc [BQ,D] f32,
-    m/l [BQ,1] f32.
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                      scale: float, causal: bool, T: int, strip: int):
+    """Grid (BH, nq, nk), nk innermost+sequential: online softmax state lives in VMEM
+    scratch across k-steps. A step does work only where the mask leaves any: it goes
+    through its key block in strips of `strip` columns, first those every row of the query
+    block sees (`full` columns), with no iota, compare or select; then under the mask those
+    only some rows see (up to `live`: the strip the diagonal crosses, and the one T ends in
+    where the last key block hangs over it); the rest in no strip at all. A key block
+    wholly above the diagonal has `live` 0 and costs its grid step alone: its index map
+    repeats the last visible block's, so nothing is copied for it (`_flash_forward`).
+
+    Refs are the raw (1, x, y) blocks. Scratch: acc [BQ, D] f32; m and l [BQ, L] f32,
+    L = 128 lanes on the chip: m the same in every lane, so that a strip's scores meet it
+    with no broadcast, l a partial sum a lane (column c's weight in lane c mod L), summed
+    over the lanes once, at the end.
     """
     from jax.experimental import pallas as pl
 
-    block_q = q_ref.shape[1]
+    block_q, D = q_ref.shape[1], q_ref.shape[2]
     block_k = k_ref.shape[1]
-    i_q = pl.program_id(1)
+    L = m_ref.shape[1]
     j = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    q_start = pl.program_id(1) * block_q
+    k_start = j * block_k
 
     @pl.when(j == 0)
     def _init():
@@ -79,48 +92,85 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_start = i_q * block_q
-    k_start = j * block_k
-    # Causal: skip blocks entirely above the diagonal (traced predicate).
-    visible = (k_start <= q_start + block_q - 1) if causal else (j >= 0)
+    # the last column every row of the query block sees, and the last any row sees
+    if causal:
+        sees_all, sees_any = jnp.minimum(q_start, T - 1), jnp.minimum(q_start + block_q - 1, T - 1)
+    else:
+        sees_all = sees_any = T - 1
+    full = jnp.clip(sees_all + 1 - k_start, 0, block_k)
+    live = jnp.clip(sees_any + 1 - k_start, 0, block_k)
 
-    @pl.when(visible)
-    def _compute():
-        # Keep inputs in their native (bf16) dtype: the MXU takes them directly and
-        # accumulates in f32 via preferred_element_type; f32 casts would halve
-        # throughput. Scale is folded into the f32 logits.
-        q = q_ref[:][0]
-        k_blk = k_ref[:][0]
-        v_blk = v_ref[:][0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [BQ, BK] f32
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[:] = m_new
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def one_strip(masked: bool):
+        def body(c, carry):
+            at = pl.multiple_of(c * strip, strip)
+            # Inputs stay in their native (bf16) dtype: the MXU takes them directly and
+            # accumulates in f32; the scale is folded into the f32 scores.
+            k_blk = k_ref[0, pl.ds(at, strip), :]
+            v_blk = v_ref[0, pl.ds(at, strip), :]
+            s = jax.lax.dot_general(
+                q_ref[0], k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [BQ, strip] f32
+            if masked:
+                cols = k_start + at + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                seen = cols < T
+                if causal:
+                    seen &= cols <= q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                s = jnp.where(seen, s, _NEG_INF)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, strip))
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[:] = m_new
+            l_ref[:] = l_ref[:] * alpha + sum(p[:, lane:lane + L] for lane in range(0, strip, L))
+            acc_ref[:] = acc_ref[:] * _lanes(alpha, D) + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return carry
+        return body
 
-    @pl.when(j == num_k - 1)
+    # Two unmasked strips an iteration: the second's scores come off the MXU while the first's
+    # weights are still being computed (5% of the kernel at 512 rows: PERF.md §6, PR 48).
+    unmasked, n_full = one_strip(masked=False), full // strip
+    jax.lax.fori_loop(0, n_full // 2, lambda c, carry: unmasked(2 * c + 1, unmasked(2 * c, carry)), None)
+    pl.when(n_full % 2 == 1)(lambda: unmasked(n_full - 1, None))
+    jax.lax.fori_loop(n_full, (live + strip - 1) // strip, one_strip(masked=True), None)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:], 1e-30)
+        l = jnp.maximum(jnp.sum(l_ref[:], axis=-1, keepdims=True), 1e-30)
         o_ref[:] = (acc_ref[:] / l)[None].astype(o_ref.dtype)
-        lse_ref[:] = (m_ref[:] + jnp.log(l))[None]
+        lse_ref[:] = (m_ref[:, :1] + jnp.log(l))[None]
+
+
+def _flash_blocks(S: int, T: int, D: int, dtype) -> tuple[int, int]:
+    """(block_q, block_k) of the forward kernel for q [.., S, D] against k, v [.., T, D]: 512
+    query rows a grid step where they divide S (256 rows run a quarter slower, 1024 leave
+    more of the diagonal's strip masked: the chip's sweep at S = T = 1024 to 8192, D = 64
+    and 128, PERF.md §6, PR 48), and a head's whole K and V as one block, copied once a head
+    and walked in strips inside the step, while both, two buffers each, fit 8 MB of VMEM
+    (8192 rows of bfloat16 at D <= 128); past that the largest power of two that does and
+    divides T. A size no such block divides gets one block that covers it."""
+    block_q = next((b for b in (512, 256, 128) if S % b == 0), S)
+    fits = (8 << 20) // (4 * max(D, 128) * jnp.dtype(dtype).itemsize)
+    if T <= fits:
+        return block_q, T
+    return block_q, next((b for b in (8192, 4096, 2048, 1024, 512, 256, 128) if b <= fits and T % b == 0), T)
+
+
+def flash_takes(S: int, T: int) -> bool:
+    """Whether a call of S query rows against T keys is the kernels', forward and backward: 128
+    rows of each and more. Under that a head's one block of scores is less work than a kernel's
+    start, and the call is a flax `init` at a few tokens or a short ring shard, dispatched eagerly,
+    where every dispatch traces and lowers the kernel's body afresh (48 a dense serve set-up:
+    PERF.md §6, PR 49): it runs `_attention_with_lse`, the body every other backend runs."""
+    return min(S, T) >= 128
 
 
 def _flash_forward(q, k, v, *, causal: bool, scale: float, block_q: int, block_k: int,
                    interpret: bool, layout: str = "bshd"):
     """q:[B,S,H,D] k/v:[B,T,H,D] (kv heads already expanded) -> (out, lse [B,H,S]).
+    Causal, query i sees keys j <= i, whatever S and T.
 
     layout="bhsd": operands arrive [B,H,S,D] (the kernel's native layout) and
     the output returns [B,H,S,D] — no transposes touch HBM. The model's train
@@ -145,20 +195,32 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float, block_q: int, block_k
     block_q = min(block_q, S)
     block_k = min(block_k, T)
     grid = (B * H, pl.cdiv(S, block_q), pl.cdiv(T, block_k))  # nk innermost
+    # a strip is as wide as the diagonal's step through a key block, so that one strip a
+    # query block crosses it
+    strip = math.gcd(block_q, block_k)
+    lanes = math.gcd(strip, 128)
 
-    kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal)
+    def kv_block(bh, i, j):
+        if causal:  # a block the diagonal hides repeats the last visible one: no copy
+            j = jnp.minimum(j, jnp.minimum(i * block_q + block_q - 1, T - 1) // block_k)
+        return bh, j, 0
+
+    kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal, T=T, strip=strip)
 
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((1, block_k, D), kv_block),
+            pl.BlockSpec((1, block_k, D), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            # lse as [BH, S, 1]: trailing dims (block_q, 1) satisfy TPU tile rules.
+            # lse as [BH, S, 1], a column the chip keeps in tiles of 128 lanes (134 MB at
+            # [64, 4096]): the form the backward kernel reads it in, so that in a train step
+            # it goes from kernel to kernel as it lies; as a row of lanes, [BH, 1, S], the
+            # step pays two relayouts a layer more (PERF.md §6, PR 48)
             pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
@@ -167,8 +229,8 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float, block_q: int, block_k
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -348,16 +410,11 @@ def _flash_attention_fwd_impl(q, k, v, causal, scale):
         rep = H // Hkv
         k_full = jnp.repeat(k, rep, axis=2)
         v_full = jnp.repeat(v, rep, axis=2)
-    if _use_pallas():
-        # Defaults retuned round 5 (v5e, S=1024/D=64; docs/perf.md):
-        # BQ 256 + full-row BK measured 42.8 TFLOPS vs 27.3 at the old 512 —
-        # the kernel is VPU-elementwise-bound, and smaller q blocks pipeline
-        # the softmax work against the MXU better.
+    if _use_pallas() and flash_takes(q.shape[1], k.shape[1]):
+        block_q, block_k = _flash_blocks(q.shape[1], k.shape[1], D, q.dtype)
         out, lse = _flash_forward(
             q, k_full, v_full, causal=causal, scale=eff_scale,
-            block_q=int(os.environ.get("RAY_TPU_FLASH_BQ", "256")),
-            block_k=int(os.environ.get("RAY_TPU_FLASH_BK", "1024")),
-            interpret=False,
+            block_q=block_q, block_k=block_k, interpret=False,
         )
     else:
         out, lse = _attention_with_lse(q, k_full, v_full, causal=causal, scale=eff_scale)
@@ -376,9 +433,9 @@ def _flash_fwd_rule(q, k, v, causal, scale):
 
 
 def _flash_bwd_rule(causal, scale, residuals, g):
-    """Flash backward: Pallas two-pass kernels on TPU (dk/dv then dq, p
-    recomputed blockwise — no [S,T] tensor reaches HBM); recompute-based XLA
-    einsums elsewhere."""
+    """Flash backward: the one-pass Pallas kernel on TPU for the calls the kernels take
+    (`flash_takes`; p recomputed blockwise, no [S,T] tensor reaches HBM); recompute-based
+    XLA einsums elsewhere."""
     q, k, v, out, lse = residuals
     B, S, H, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
@@ -387,7 +444,7 @@ def _flash_bwd_rule(causal, scale, residuals, g):
     k_full = jnp.repeat(k, rep, axis=2) if rep > 1 else k
     v_full = jnp.repeat(v, rep, axis=2) if rep > 1 else v
 
-    if _use_pallas():
+    if _use_pallas() and flash_takes(S, T):
         dq, dk, dv = _flash_backward(
             q, k_full, v_full, out, lse, g, causal=causal, scale=eff_scale,
             block_q=512, block_k=1024,
@@ -474,12 +531,11 @@ def _flash_bhsd_fwd_impl(q, k, v, causal, scale):
     D = q.shape[-1]
     eff_scale = scale if scale is not None else 1.0 / math.sqrt(D)
     k_full, v_full = _expand_kv_bhsd(k, v, q.shape[1])
-    if _use_pallas():
+    if _use_pallas() and flash_takes(q.shape[2], k.shape[2]):
+        block_q, block_k = _flash_blocks(q.shape[2], k.shape[2], D, q.dtype)
         return _flash_forward(
             q, k_full, v_full, causal=causal, scale=eff_scale,
-            block_q=int(os.environ.get("RAY_TPU_FLASH_BQ", "256")),
-            block_k=int(os.environ.get("RAY_TPU_FLASH_BK", "1024")),
-            interpret=False, layout="bhsd",
+            block_q=block_q, block_k=block_k, interpret=False, layout="bhsd",
         )
     out, lse = _attention_with_lse(
         jnp.transpose(q, (0, 2, 1, 3)), jnp.transpose(k_full, (0, 2, 1, 3)),
@@ -504,7 +560,7 @@ def _flash_bhsd_bwd_rule(causal, scale, residuals, g):
     rep = H // Hkv
     k_full, v_full = _expand_kv_bhsd(k, v, H)
 
-    if _use_pallas():
+    if _use_pallas() and flash_takes(S, T):
         dq, dk, dv = _flash_backward(
             q, k_full, v_full, out, lse, g, causal=causal, scale=eff_scale,
             block_q=512, block_k=1024,
@@ -515,7 +571,7 @@ def _flash_bhsd_bwd_rule(causal, scale, residuals, g):
             dv = dv.reshape(B, Hkv, rep, T, D).sum(axis=2).astype(v.dtype)
         return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
-    # XLA fallback (CPU tests / f32): normalize into the shared bshd backward
+    # XLA fallback (CPU tests, a call under 128 rows): normalize into the shared bshd backward
     # — the extra transposes only exist on backends where they cost nothing,
     # and the numerically sensitive math stays in ONE place.
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731

@@ -23,13 +23,15 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models.llama import _attn_cached
 from ray_tpu.models.transformer import ModelConfig, fused_cross_entropy_loss
-from ray_tpu.ops.attention import _flash_backward, _flash_forward
+from ray_tpu.ops.attention import _flash_backward, _flash_blocks, _flash_forward
 
 # (batch, heads, seq, head_dim) as the repo's configurations run the kernel, bf16.
 SHAPES = {
     "gpt2-125m": (8, 12, 1024, 64),
     "llama3-1b": (1, 32, 8192, 64),
     "llama3-8b": (2, 32, 2048, 128),
+    "mistral-7b-train-seq4k": (2, 32, 4096, 128),  # the two train cells' calls (PERF.md §4)
+    "internlm2-1.8b-train-fsdp4": (2, 16, 4096, 128),
 }
 
 
@@ -86,18 +88,39 @@ def _laid_out(model: str, layout: str):
 @pytest.mark.parametrize("layout", ["bshd", "bhsd"])
 @pytest.mark.parametrize("model", sorted(SHAPES))
 def test_flash_forward_compiles_for_v5e(one_chip, model, layout):
-    shape, _, d = _laid_out(model, layout)
+    shape, (_, _, s), d = _laid_out(model, layout)
     x = _operand(shape, one_chip)
+    block_q, block_k = _flash_blocks(s, s, d, x.dtype)  # as the two call sites choose them
 
     def fwd(q, k, v):
         return _flash_forward(q, k, v, causal=True, scale=1.0 / math.sqrt(d),
-                              block_q=256, block_k=1024, interpret=False, layout=layout)
+                              block_q=block_q, block_k=block_k, interpret=False, layout=layout)
 
     text = jax.jit(fwd).lower(x, x, x).compile().as_text()
     assert "tpu_custom_call" in text
     # the kernel's own name, in both layouts: a device trace shows the instruction's
     # name, and the reduction finds the kernel by it (PERF.md §3)
     assert re.search(r"%flash_fwd(\.\d+)? = ", text) and 'flash_fwd/pallas_call"' in text
+
+
+@pytest.mark.parametrize("shape", [*sorted(SHAPES), (1024, 4096, 128), (4096, 32768, 128), (16384, 16384, 64), (200, 200, 64)])
+def test_the_forward_blocks_come_from_the_calls_shapes_and_not_from_the_environment(shape, monkeypatch):
+    """`_flash_blocks` gives blocks that divide S and T or cover them and a key block that fits the
+    kernel's fast memory two buffers deep; and nothing of `ops/attention.py` reads the variables
+    the blocks once came from."""
+    import inspect
+
+    from ray_tpu.ops import attention
+
+    s, t, d = (SHAPES[shape][2], SHAPES[shape][2], SHAPES[shape][3]) if shape in SHAPES else shape
+    monkeypatch.setenv("RAY_TPU_FLASH_BQ", "128")
+    monkeypatch.setenv("RAY_TPU_FLASH_BK", "128")
+    block_q, block_k = _flash_blocks(s, t, d, jnp.bfloat16)
+    assert s % block_q == 0 and t % block_k == 0, (block_q, block_k)  # a block that covers is the size itself
+    assert block_q in (s, 512, 256, 128)
+    assert block_k == t or 4 * block_k * max(d, 128) * 2 <= 8 << 20
+    source = inspect.getsource(attention)
+    assert "RAY_TPU_FLASH" not in source and "environ" not in source
 
 
 @pytest.mark.parametrize("layout", ["bshd", "bhsd"])

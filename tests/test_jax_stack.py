@@ -26,20 +26,35 @@ def test_logical_to_spec():
     assert spec[2] is None or spec[2] not in ("dp",)
 
 
-def test_flash_attention_matches_reference_interpret():
-    from ray_tpu.ops.attention import _flash_forward, reference_attention
+@pytest.mark.parametrize("S, T, D, block_q, block_k, causal", [
+    (256, 256, 64, 128, 128, True),  # equal blocks: a crossing strip a step and nothing else to skip
+    # a key block wider than the query block: in one head steps the diagonal hides (their
+    # index map repeats a block), unmasked strips in pairs and alone, and a crossing block
+    # whose last strips are dead
+    (2048, 2048, 64, 256, 1024, True),
+    (2048, 2048, 128, 512, 2048, True),  # a head's whole K and V as one block, as the chip runs it
+    (512, 512, 32, 256, 128, True),  # block_k < block_q: two crossing blocks a query block
+    (384, 384, 64, 128, 256, True),  # S no multiple of the key block: the last one hangs over T
+    (256, 512, 64, 128, 256, True),  # T != S (a ring shard's): keys past the last query are never read
+    (512, 256, 64, 128, 256, True),  # and queries past the last key see all of T
+    (512, 512, 64, 128, 256, False),  # no mask: the unmasked body everywhere
+    (256, 384, 64, 128, 256, False),  # but for the strip T ends in
+])
+def test_flash_attention_matches_reference_interpret(S, T, D, block_q, block_k, causal):
+    from ray_tpu.ops.attention import _attention_with_lse, _flash_forward
 
-    B, S, H, D = 2, 256, 4, 64
+    B, H = (2, 4) if S * T <= 256 * 256 else (1, 2)
     key = jax.random.PRNGKey(0)
     q, k, v = (
-        jax.random.normal(jax.random.fold_in(key, i), (B, S, H, D), jnp.float32)
+        jax.random.normal(jax.random.fold_in(key, i), (B, T if i else S, H, D), jnp.float32)
         for i in range(3)
     )
     out, lse = _flash_forward(
-        q, k, v, causal=True, scale=D**-0.5, block_q=128, block_k=128, interpret=True
+        q, k, v, causal=causal, scale=D**-0.5, block_q=block_q, block_k=block_k, interpret=True
     )
-    ref = reference_attention(q, k, v, causal=True)
+    ref, ref_lse = _attention_with_lse(q, k, v, causal=causal, scale=D**-0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5, rtol=2e-5)
 
 
 def test_flash_backward_matches_reference_interpret():
@@ -148,6 +163,74 @@ def test_gqa_flash_matches_reference():
         np.asarray(reference_attention(q, k, v)),
         atol=2e-5, rtol=2e-5,
     )
+
+
+def _kernels_in(program, *args) -> set:
+    """The Pallas kernels a program would dispatch on a TPU, by name, read off its jaxpr: traced
+    here with `_use_pallas` true, nothing lowered."""
+    import re
+
+    text = str(jax.make_jaxpr(program)(*args))
+    kernels = set(re.findall(r"name=(flash_fwd|flash_bwd)\b", text))
+    assert ("pallas_call" in text) == bool(kernels)
+    return kernels
+
+
+def _qkv(layout: str, S: int, T: int, H=4, Hkv=2, D=64):
+    """bfloat16 q of S rows and H heads, k and v of T rows and Hkv heads, in `layout`."""
+    def one(i, rows, heads):
+        shape = (1, heads, rows, D) if layout == "bhsd" else (1, rows, heads, D)
+        return jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(3), i), shape, jnp.bfloat16)
+
+    return one(0, S, H), one(1, T, Hkv), one(2, T, Hkv)
+
+
+@pytest.mark.parametrize("case, layout, S, T, kernels", [
+    ("init", "bhsd", 8, 8, set()),  # the dense block's tree: the flax forward at 8 tokens, layer by layer
+    ("jaxpr", "bhsd", 8, 8, set()),
+    ("jaxpr", "bhsd", 64, 64, set()),
+    ("jaxpr", "bshd", 64, 64, set()),
+    ("jaxpr", "bhsd", 256, 64, set()),  # a shard's keys under 128 rows
+    ("jaxpr", "bhsd", 127, 127, set()),
+    ("jaxpr", "bhsd", 128, 128, {"flash_fwd", "flash_bwd"}),
+    ("jaxpr", "bshd", 128, 256, {"flash_fwd", "flash_bwd"}),
+    ("jaxpr", "bhsd", 4096, 4096, {"flash_fwd", "flash_bwd"}),  # the train cells' call
+    ("agrees", "bhsd", 8, 8, set()),
+    ("agrees", "bshd", 8, 8, set()),
+])
+def test_a_call_under_128_rows_is_not_the_flash_kernels(case, layout, S, T, kernels, monkeypatch):
+    """On a TPU (`_use_pallas` true) `flash_takes` hands a call whose S or T is under 128 rows to
+    `_attention_with_lse`, forward and backward, by the shapes alone: `llama.init_params` then
+    dispatches no Mosaic kernel (PERF.md §6, PR 49), and both layouts give what the plain body gives."""
+    import dataclasses
+
+    from ray_tpu.llm import LLMConfig, engine_config
+    from ray_tpu.models.transformer import Transformer, get_config
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    assert attention.flash_takes(S, T) == bool(kernels)
+    if case == "init":
+        cfg = engine_config(LLMConfig(model_id="tiny", model_config=dataclasses.replace(get_config("test-tiny"), attention="flash")))
+        assert cfg.attention == "flash" and not cfg.scan_layers and not cfg.remat
+        assert _kernels_in(lambda key: Transformer(cfg).init(key, jnp.zeros((1, S), jnp.int32)), jax.random.PRNGKey(0)) == kernels
+        return
+    entry = attention.flash_attention_bhsd if layout == "bhsd" else attention.flash_attention
+    loss = lambda q, k, v: jnp.sum(entry(q, k, v).astype(jnp.float32) ** 2)  # noqa: E731
+    q, k, v = _qkv(layout, S, T)
+    if case == "agrees":
+        # evaluated on the CPU with the switch on: a `pallas_call` would not even lower here
+        to_bshd = (lambda x: jnp.transpose(x, (0, 2, 1, 3))) if layout == "bhsd" else (lambda x: x)
+        ref = lambda q, k, v: attention._attention_with_lse(  # noqa: E731
+            to_bshd(q), *(jnp.repeat(to_bshd(x), 2, axis=2) for x in (k, v)), causal=True, scale=None)[0]
+        np.testing.assert_array_equal(np.asarray(to_bshd(entry(q, k, v)), np.float32), np.asarray(ref(q, k, v), np.float32))
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda q, k, v: jnp.sum(ref(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), atol=0.1, rtol=0.05)
+        return
+    assert _kernels_in(entry, q, k, v) == kernels - {"flash_bwd"}
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), q, k, v) == kernels
 
 
 def test_ring_attention_matches_full():
